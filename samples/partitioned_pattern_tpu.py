@@ -34,5 +34,10 @@ for i in range(5000):
             float(np.round(rng.uniform(50, 400) * 4) / 4)),
            timestamp=1_000 + i * 10)
 rt.flush()
-print(f"alerts: {n[0]} (all 128 card partitions matched on one device kernel)")
+import jax
+dev = jax.devices()[0]
+placed = rt.explain()["queries"]["fraud"]
+print(f"alerts: {n[0]} (128 card partitions; query placed on "
+      f"{placed['path']}/{placed.get('family')}, backend {dev.platform} "
+      f"{dev.device_kind!r})")
 mgr.shutdown()

@@ -2,10 +2,21 @@
 # CI smoke: the tier-1 suite plus a ~5-second end-to-end service check
 # (deploy an app over REST, push events, assert /metrics exposes
 # nonzero counters).  Exits nonzero on any failure.
+#
+# This is the pinned-CPU lane: it asserts behaviour and claims no device
+# rate.  Several steps below keep two runtimes in two processes alive at
+# once (the kill -9 recovery smoke's parent builds a reference runtime and
+# then starts service children) — fine on the CPU, impossible on one chip,
+# where a chip belongs to one process at a time.  On the chip the program
+# runs through `python chip_smoke.py` and `bench.py --chaos`, whose parent
+# stays JAX-free (README "Testing").
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
-export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
+export JAX_PLATFORMS=cpu
+# CPU executables must not land in the in-checkout compile cache the chip
+# machine would be offered (siddhi_tpu/__init__.py)
+export JAX_ENABLE_COMPILATION_CACHE=false
 
 echo "== compileall =="
 # every module must at least parse/compile — a syntax error in a rarely

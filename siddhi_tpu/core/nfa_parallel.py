@@ -2,8 +2,7 @@
 
 The sequential device kernel (nfa_device.NFAKernel) walks one event per
 `lax.scan` step per lane: throughput is bounded by the T-long dependency
-chain, not by math (BENCH_r05: ~0.01-0.02x the single-thread C++ roofline
-on the P=1 pattern configs).  *Simultaneous Finite Automata* (arXiv
+chain, not by math.  *Simultaneous Finite Automata* (arXiv
 1405.0562) breaks that chain: simulate the automaton from EVERY state,
 compose per-event transition functions associatively, and the whole
 block collapses to log-depth scans.  First-match semantics make the
@@ -700,7 +699,10 @@ class ParallelChainKernel:
         read.  idx_of maps refpart -> index array (per-head or
         per-match, caller's choice)."""
         env = self._param_env(ev)
-        for k in keys:
+        # sorted: `keys` is a set of strings, whose iteration order moves
+        # with the process's hash seed — and with it the traced op order,
+        # the HLO text and the persistent compile cache's key
+        for k in sorted(keys):
             if k == "__timestamp__":
                 if comp_j is not None:
                     env[k] = base_ts + ev["__flat.__ts__"][comp_j] \
@@ -1114,7 +1116,7 @@ class ParallelChainKernel:
             def sel_q(r):
                 return jnp.clip(_first_hit(rank_heaps[pi], L, s_m,
                                            ra_m + r, "ge"), 0, F - 1)
-            for rp in rps:
+            for rp in sorted(rps):        # set of str: see _gather_env
                 _b, cidx = _base_ref(rp)
                 if cidx is None or cidx == "last":
                     if pi == S - 1:

@@ -2393,7 +2393,7 @@ class SiddhiManager:
     def __init__(self, isolated_broker: bool = False,
                  allow_scripts: bool = True):
         self.allow_scripts = allow_scripts
-        # persistent XLA kernel cache (backend-keyed dir; best-effort)
+        # persistent XLA compile cache (siddhi_tpu/__init__.py)
         from .. import _enable_kernel_cache
         _enable_kernel_cache()
         # entry-point extension discovery (once per process; reference:

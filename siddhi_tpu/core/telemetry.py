@@ -44,6 +44,8 @@ import time
 from collections import defaultdict, deque
 from typing import Callable, Optional
 
+import jax.monitoring
+
 STAGES = ("parse", "plan", "compile", "host_build", "ingest", "kernel",
           "transfer", "scatter")
 
@@ -390,30 +392,23 @@ class _StreamTimer:
 
 
 # ---------------------------------------------------------------------------
-# XLA persistent-cache observation (process-global, best-effort)
+# XLA persistent-cache observation (process-global)
 # ---------------------------------------------------------------------------
 
 XLA_CACHE = {"hits": 0, "misses": 0}
 
 
-def _watch_xla_cache() -> None:
+def _on_jax_event(event: str, **_kw) -> None:
     """Count the persistent compilation cache's hit/miss events (the
-    disk cache enabled by `_enable_kernel_cache`).  Event names are jax
-    internals — match loosely and tolerate absence."""
-    try:
-        from jax._src import monitoring as _mon
-
-        def _listener(event, *a, **k):
-            if "cache_hit" in event:
-                XLA_CACHE["hits"] += 1
-            elif "cache_miss" in event:
-                XLA_CACHE["misses"] += 1
-        _mon.register_event_listener(_listener)
-    except Exception:      # pragma: no cover - observation is best-effort
-        pass
+    disk cache placed by `siddhi_tpu._enable_kernel_cache`): JAX records
+    `/jax/compilation_cache/cache_hits` and `.../cache_misses`."""
+    if event.endswith("/cache_hits"):
+        XLA_CACHE["hits"] += 1
+    elif event.endswith("/cache_misses"):
+        XLA_CACHE["misses"] += 1
 
 
-_watch_xla_cache()
+jax.monitoring.register_event_listener(_on_jax_event)
 
 
 # ---------------------------------------------------------------------------
