@@ -987,248 +987,10 @@ def slo_demo(target_ms=25, rate=5000, seconds=6.0, keys=8):
             "pass": bool(held and eps >= 0.9 * rate)}
 
 
-def trace_breakdown(app, n_batches=16, batch=2048, keys=8,
-                    trace_out="bench_trace.json"):
-    """Per-stage breakdown of end-to-end detect latency (config 3 shape):
-    run the tape with statistics + the flight recorder on, reset after
-    warm-up (so steady state is measured, not compiles), then read the
-    stage histograms back.  The warm-up pass covers the ENTIRE tape —
-    match-buffer growth (the (T, M) retry shape) only triggers on the
-    batch whose match volume overflows the first-flush guess, so a
-    prefix warm-up would leave a fresh ~1s compile inside the timed
-    region and misattribute the breakdown to it; the timed pass replays
-    the tape shifted forward past the `within` horizon (stale partials
-    expire, time stays monotonic, every kernel shape is already cached).
-    `coverage` is the fraction of the timed wall clock the named stage
-    spans account for — the observability acceptance bar (>= 0.9 means
-    regressions are attributable); the remainder is python dispatch glue
-    between spans.  Valid because the traced app is synchronous (no
-    @app:async): all spans run on the caller thread, so their seconds
-    are disjoint slices of the wall clock — an async app would overlap
-    ingest with dispatch and the sum would overstate.  Also exports the
-    recorder as Chrome trace_event JSON (`trace_out`).
-
-    Since ISSUE 17 the run carries `@app:profile('all')` and the
-    kernel-vs-host split comes from the phase profiler's blocked-kernel
-    attribution (core/profiler.py) instead of the stage-histogram
-    approximation — same keys (`kernel_share`, `host_dispatch_share`),
-    better numerator: the old `kernel` stage span measured dispatch-call
-    wall, which under async dispatch is NOT device execution time.  The
-    full per-phase report lands under `profile`."""
-    from siddhi_tpu import SiddhiManager
-
-    mgr = SiddhiManager()
-    rt = mgr.create_app_runtime("@app:profile('all')\n" + app)
-    rt.enable_stats(True)
-    rt.stats.tracer.enabled = True
-    delivered = [0]
-    rt.add_batch_callback(
-        "Out", lambda b: delivered.__setitem__(0, delivered[0] + b.n))
-    rt.start()
-    h = rt.input_handler(STREAM)
-    tape = make_tape(n_batches * batch, batch, keys=keys)
-    batches = _columnar(rt, STREAM, tape, keys)
-    for cols, ts in batches:
-        h.send_batch(cols, ts)
-    rt.flush()
-    rt.stats.reset()                 # steady state only: compiles are done
-    if rt.profiler is not None:
-        rt.profiler.reset()
-    delivered[0] = 0
-    # replay shifted well past the within-window so the warm pass's
-    # partials expire instead of matching across the seam
-    shift = np.int64(int(batches[-1][1][-1]) - int(batches[0][1][0])
-                     + 60_000)
-    n_timed = sum(int(t[1].shape[0]) for t in batches)
-    t0 = time.perf_counter()
-    for cols, ts in batches:
-        h.send_batch(cols, ts + shift)
-    rt.flush()
-    wall = time.perf_counter() - t0
-    rep = rt.statistics()
-    expl = rt.explain()
-    prof_rep = rt.profile()
-    n_trace = rt.stats.export_chrome_trace(trace_out)
-    mgr.shutdown()
-
-    stages = {st: td for st, td in rep["stages"].items()
-              if td.get("seconds") and st not in ("parse", "plan")}
-    covered = sum(td["seconds"] for td in stages.values())
-    # kernel-vs-host-dispatch split (ROADMAP item 2 "push the
-    # host-dispatch share down"): the phase profiler's blocked-kernel
-    # attribution — device = h2d + kernel + d2h shares of the batch
-    # wall; everything else (pack/unpack, python dispatch, sink) is
-    # host.  The old stage approximation (`kernel` + `transfer` span
-    # seconds) stays as the fallback for a profiler-less runtime.
-    agg = prof_rep.get("aggregate") or {}
-    if agg.get("shares"):
-        kernel_share = agg["device_share"]
-        host_share = agg["host_dispatch_share"]
-    else:
-        dev_s = sum(stages.get(st, {}).get("seconds", 0.0)
-                    for st in ("kernel", "transfer"))
-        kernel_share = round(dev_s / wall, 3)
-        host_share = round((wall - dev_s) / wall, 3)
-    # the chosen pattern plan family per query (the PR-6/13 families):
-    # a trace that can't name the family can't attribute a regression
-    families = {q: ent["family"] for q, ent in
-                expl.get("queries", {}).items() if ent.get("family")}
-    out = {
-        "events": n_timed, "batch": batch, "matches": delivered[0],
-        "end_to_end_s": round(wall, 4),
-        "eps": round(n_timed / wall),
-        "coverage": round(covered / wall, 3),
-        "plan_family": (next(iter(families.values()))
-                        if len(families) == 1 else families) or None,
-        "kernel_share": kernel_share,
-        "host_dispatch_share": host_share,
-        # the phase profiler's own report: per-phase seconds/shares,
-        # coverage of the dispatch wall, per-plan roofline fold — the
-        # continuous surface bench numbers are now derived from
-        "profile": {
-            "coverage": agg.get("coverage"),
-            "shares": agg.get("shares"),
-            "phases_s": agg.get("phases_s"),
-            "host_dispatch_share": agg.get("host_dispatch_share"),
-            "plans": {name: {k: pv.get(k) for k in
-                             ("host_dispatch_share", "kernel_eps",
-                              "end_to_end_eps", "roofline")}
-                      for name, pv in
-                      (prof_rep.get("plans") or {}).items()},
-        },
-        "stages": {st: {
-            "seconds": round(td["seconds"], 4),
-            "share": round(td["seconds"] / wall, 3),
-            **{k: td[k] for k in ("p50_ms", "p95_ms", "p99_ms") if k in td},
-        } for st, td in sorted(stages.items(),
-                               key=lambda kv: -kv[1]["seconds"])},
-        "chrome_trace": {"path": trace_out, "events": n_trace},
-    }
-    if "device" in rep:
-        out["device"] = rep["device"]
-    return out
-
-
-def tracing_overhead(smoke=True, reps=None) -> dict:
-    """The tracing plane's overhead contract (docs/OBSERVABILITY.md):
-    config-3 TCP-frame ingest eps with tracing OFF (`@app:trace('off')`
-    — `rt.tracing is None`, the pre-tracing hot path), ON-BUT-UNSAMPLED
-    (tracer live, the sampling modulo never fires — the always-on-ring
-    steady state), and the default 1-in-16 sampling.  Off and unsampled
-    must both cost <= 5% vs each other's envelope; variants run
-    interleaved round-robin and score best-of so thermal/GC drift
-    lands on every variant equally."""
-    from siddhi_tpu import SiddhiManager
-    from siddhi_tpu.net import TcpFrameClient
-
-    n = 1 << 14 if smoke else 1 << 16
-    batch = 1024 if smoke else 4096
-    warm = 2
-    tape = make_tape(n + warm * batch, batch)
-    batches = _tape_str_batches(tape)
-    n_timed = sum(t["n"] for t in tape[warm:])
-    reps = reps if reps is not None else (2 if smoke else 3)
-
-    def run(head):
-        mgr = SiddhiManager()
-        rt = mgr.create_app_runtime(
-            head + "@source(type='tcp', port='0')\n" + DEV["patterns"] + C3)
-        rt.start()
-        cli = TcpFrameClient("127.0.0.1", rt.sources[0].port, STREAM,
-                             TcpFrameClient.cols_of_schema(
-                                 rt.schemas[STREAM]))
-        for cols, ts in batches[:warm]:
-            cli.send_batch(cols, ts)
-        cli.barrier(timeout=120)
-        t0 = time.perf_counter()
-        for cols, ts in batches[warm:]:
-            cli.send_batch(cols, ts)
-        cli.barrier(timeout=120)
-        dt = time.perf_counter() - t0
-        cli.close()
-        mgr.shutdown()
-        return n_timed / dt
-
-    variants = {"off": "@app:trace('off')\n",
-                "unsampled": "@app:trace(sample='1000000000')\n",
-                "sampled_16": ""}           # the default
-    runs: dict = {k: [] for k in variants}
-    for _ in range(reps):
-        for name, head in variants.items():
-            runs[name].append(run(head))
-    eps = {k: max(v) for k, v in runs.items()}
-    out = {"events": n_timed, "batch": batch,
-           "eps": {k: round(v) for k, v in eps.items()}}
-    for k in ("unsampled", "sampled_16"):
-        out[f"{k}_overhead_pct"] = round(
-            100.0 * (1.0 - eps[k] / eps["off"]), 2)
-    # the acceptance bar: off and on-but-unsampled within 5%
-    out["pass"] = out["unsampled_overhead_pct"] <= 5.0
-    return out
-
-
-def profile_overhead(smoke=True, reps=None) -> dict:
-    """The phase profiler's overhead contract (docs/OBSERVABILITY.md):
-    config-3 TCP-frame ingest eps with the profiler OFF
-    (`@app:profile('off')` — `rt.profiler is None`, zero hooks) vs the
-    DEFAULT 1-in-32 duty cycle.  Default sampling must cost <= 3% —
-    the always-on bar; same interleaved best-of discipline as
-    tracing_overhead so thermal/GC drift lands on both variants.  The
-    smoke tape is 4x tracing_overhead's: a 3% band needs a timed
-    region long enough that scheduler jitter sits well under it."""
-    from siddhi_tpu import SiddhiManager
-    from siddhi_tpu.net import TcpFrameClient
-
-    n = 1 << 16
-    batch = 2048 if smoke else 4096
-    warm = 2
-    tape = make_tape(n + warm * batch, batch)
-    batches = _tape_str_batches(tape)
-    n_timed = sum(t["n"] for t in tape[warm:])
-    # a 3% band needs more best-of depth than tracing's 5%: at 2-3 reps
-    # one slow 'off' outlier reads as a double-digit phantom overhead
-    reps = reps if reps is not None else 4
-
-    def run(head):
-        mgr = SiddhiManager()
-        rt = mgr.create_app_runtime(
-            head + "@source(type='tcp', port='0')\n" + DEV["patterns"] + C3)
-        rt.start()
-        cli = TcpFrameClient("127.0.0.1", rt.sources[0].port, STREAM,
-                             TcpFrameClient.cols_of_schema(
-                                 rt.schemas[STREAM]))
-        for cols, ts in batches[:warm]:
-            cli.send_batch(cols, ts)
-        cli.barrier(timeout=120)
-        t0 = time.perf_counter()
-        for cols, ts in batches[warm:]:
-            cli.send_batch(cols, ts)
-        cli.barrier(timeout=120)
-        dt = time.perf_counter() - t0
-        cli.close()
-        mgr.shutdown()
-        return n_timed / dt
-
-    variants = {"off": "@app:profile('off')\n",
-                "sampled_32": ""}           # the default duty cycle
-    runs: dict = {k: [] for k in variants}
-    for _ in range(reps):
-        for name, head in variants.items():
-            runs[name].append(run(head))
-    eps = {k: max(v) for k, v in runs.items()}
-    out = {"events": n_timed, "batch": batch,
-           "eps": {k: round(v) for k, v in eps.items()},
-           "sampled_32_overhead_pct": round(
-               100.0 * (1.0 - eps["sampled_32"] / eps["off"]), 2)}
-    out["pass"] = out["sampled_32_overhead_pct"] <= 3.0
-    return out
-
-
 def harness_info() -> dict:
     """Provenance block recorded with every bench result (BENCH_DETAIL
     + summary): two runs whose harness blocks differ are not comparable
-    and scripts/perfcheck.py refuses tight-band comparisons across a
-    config-hash change."""
+    and a comparison across a config-hash change is not tight."""
     import hashlib
     import os
     import subprocess
@@ -2962,9 +2724,8 @@ def _print_summary(summary: dict, cap: int = 2048) -> None:
     value fails to serialize), a minimal headline line prints instead,
     so the last stdout line ALWAYS round-trips through json.loads
     (pinned by scripts/smoke.sh and tests/test_bench_summary.py)."""
-    drop_order = ("stage_shares_config3", "configs", "roofline",
-                  "transport", "trace_coverage_config3", "tracing",
-                  "profile", "harness", "durability", "placement")
+    drop_order = ("configs", "roofline", "transport", "harness",
+                  "durability", "placement")
     try:
         line = json.dumps(summary)
         for key in drop_order:
@@ -3324,42 +3085,6 @@ def main(argv=None):
         if not res["pass"]:
             sys.exit(1)
         return
-    if "--trace" in argv:
-        # fast mode: per-stage breakdown (the diagnosability check —
-        # where does a detect-latency millisecond go?) of config 3 AND
-        # the partitioned config 4, each naming its chosen plan family
-        # and the profiler-attributed kernel-vs-host-dispatch split
-        # (ROADMAP item 2's measurement), plus the frame-tracing and
-        # phase-profiler overhead contracts.  --trace MUST be checked
-        # before --smoke: `--trace --smoke` is the perfcheck sentinel's
-        # input (scripts/perfcheck.py) and used to silently run the
-        # bench_overlap smoke instead.  --smoke shrinks the tapes.
-        smoke = "--smoke" in argv
-        tr = trace_breakdown(DEV["patterns"] + C3,
-                             n_batches=8 if smoke else 16,
-                             batch=1024 if smoke else 2048)
-        head4 = "@app:partitionCapacity(1000)\n@app:deviceSlots(32)\n"
-        tr4 = _safe("trace config4", lambda: trace_breakdown(
-            head4 + C4, n_batches=4 if smoke else 8,
-            batch=1024 if smoke else 2048, keys=1000,
-            trace_out="bench_trace_c4.json"), {})
-        ov = _safe("tracing overhead",
-                   lambda: tracing_overhead(smoke=True), {})
-        pov = _safe("profile overhead",
-                    lambda: profile_overhead(smoke=True), {})
-        print(json.dumps({"metric": "stage_breakdown_config3",
-                          "value": tr["coverage"],
-                          "unit": "fraction_of_e2e_latency_attributed",
-                          **tr,
-                          "config4": {k: tr4.get(k) for k in
-                                      ("eps", "coverage", "plan_family",
-                                       "kernel_share",
-                                       "host_dispatch_share",
-                                       "profile")},
-                          "tracing_overhead": ov,
-                          "profile_overhead": pov,
-                          "harness": _safe("harness", harness_info, {})}))
-        return
     if "--smoke" in argv:
         # CI sanity (scripts/smoke.sh): a short pipelined-vs-unpipelined
         # run over the multi-plan config — asserts identical match
@@ -3449,9 +3174,7 @@ def main(argv=None):
              make_tape(big * 8, big), 8, warm=4))}]
     c3["latency_demo"] = _safe("latency_demo", lambda: latency_demo(
         DEV["patterns"] + C3, HOST["patterns"] + C3))
-    c3["trace"] = _safe("trace", lambda: trace_breakdown(
-        DEV["patterns"] + C3), {})
-    _mark("frontier + latency demo + trace done", t0)
+    _mark("frontier + latency demo done", t0)
 
     head = ("@app:partitionCapacity(1000)\n@app:deviceSlots(32)\n")
     configs["4_partitioned_1k"] = bench_config(
@@ -3461,12 +3184,6 @@ def main(argv=None):
     configs["4_partitioned_1k"]["kernel_eps"] = kernel_eps(
         head + C4, "pattern", batch=1 << 18, keys=1000, info=info4)
     configs["4_partitioned_1k"]["plan_family"] = info4.get("plan_family")
-    # per-config stage breakdown (BENCH_DETAIL.json): the partitioned
-    # config's plan family + kernel-vs-host-dispatch split, small scale
-    configs["4_partitioned_1k"]["trace"] = _safe(
-        "trace config4", lambda: trace_breakdown(
-            head + C4, n_batches=8, batch=2048, keys=1000,
-            trace_out="bench_trace_c4.json"), {})
 
     c5 = c5_app(1000)
     c5_outs = tuple(f"Out{i}" for i in range(16))
@@ -3582,19 +3299,6 @@ def main(argv=None):
                     lambda: durability_bench(smoke=True), {})
     _mark("durability overhead done", t0)
 
-    # tracing-overhead column (ISSUE 15): the frame-tracing plane must
-    # cost <= 5% of config-3 TCP-ingest eps when off or on-but-unsampled
-    trace_ov = _safe("tracing overhead",
-                     lambda: tracing_overhead(smoke=True), {})
-    _mark("tracing overhead done", t0)
-
-    # profiler-overhead column (ISSUE 17): the phase profiler at the
-    # default 1-in-32 duty cycle must cost <= 3% of config-3 TCP-ingest
-    # eps vs @app:profile('off') — the always-on acceptance bar
-    prof_ov = _safe("profile overhead",
-                    lambda: profile_overhead(smoke=True), {})
-    _mark("profile overhead done", t0)
-
     # transport-vs-host-vs-kernel breakdown per config: the
     # "transport-bound" calibration note as a MEASURED column.  For each
     # config: the kernel-only ceiling, the end-to-end in-process engine
@@ -3654,8 +3358,6 @@ def main(argv=None):
         "roofline": roofline,
         "transport": net_res,
         "durability": dur_res,
-        "tracing": trace_ov,
-        "profile": prof_ov,
         "transport_breakdown": breakdown,
         "configs": configs,
     }
@@ -3668,30 +3370,11 @@ def main(argv=None):
     # BENCH "parsed": null) goes to BENCH_DETAIL.json and the parseable
     # summary stays bounded; _print_summary degrades the payload rather
     # than ever emitting an oversized/unparseable final line
-    tr = c3.get("trace") or {}
     summary = {
         "metric": detail["metric"], "value": detail["value"],
         "unit": detail["unit"], "vs_baseline": detail["vs_baseline"],
         "vs_production_claim": detail["vs_production_claim"],
         "p99_detect_ms": detail["p99_detect_ms"],
-        "trace_coverage_config3": tr.get("coverage"),
-        "stage_shares_config3": {st: d.get("share") for st, d in
-                                 tr.get("stages", {}).items()},
-        # the tracing plane's overhead contract: off vs on-but-unsampled
-        # TCP-ingest eps (<= 5% — docs/OBSERVABILITY.md overhead table)
-        "tracing": ({"eps": trace_ov.get("eps"),
-                     "unsampled_overhead_pct":
-                         trace_ov.get("unsampled_overhead_pct"),
-                     "sampled_16_overhead_pct":
-                         trace_ov.get("sampled_16_overhead_pct"),
-                     "pass": trace_ov.get("pass")}
-                    if trace_ov else None),
-        # the phase profiler's overhead contract: default 1-in-32 duty
-        # cycle vs @app:profile('off') TCP-ingest eps (<= 3% — ISSUE 17)
-        "profile": ({"sampled_32_overhead_pct":
-                         prof_ov.get("sampled_32_overhead_pct"),
-                     "pass": prof_ov.get("pass")}
-                    if prof_ov else None),
         "harness": detail["harness"] or None,
         "roofline": {k: {kk: v.get(kk) for kk in
                          ("plan_family", "kernel_eps", "vs_native_cpp")}

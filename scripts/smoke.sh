@@ -715,16 +715,4 @@ assert isinstance(parsed, dict) and "metric" in parsed, parsed
 print("OK: bench --smoke last line parses:", parsed["metric"])
 EOF
 
-echo "== perf-regression sentinel =="
-# scripts/perfcheck.py: fresh bench.py --trace --smoke vs the checked-in
-# scripts/perf_baseline.json.  Exits 1 when the host-dispatch-share odds
-# move past the band (the "someone made dispatch 2x more host-bound"
-# regression), when any phase share drifts beyond its absolute band, or
-# when attribution coverage drops below 0.9.  Raw eps is warn-only (CI
-# machines jitter); a baseline written on a different workload config
-# (config_hash mismatch) downgrades to a stale-baseline note so config
-# refactors don't hard-fail until the baseline is regenerated
-# (perfcheck.py --write-baseline, committed alongside)
-python scripts/perfcheck.py
-
 echo "smoke: PASS"

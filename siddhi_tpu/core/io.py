@@ -565,28 +565,22 @@ class Sink:
     def publish_attempt(self, payload) -> None:
         """One raw publish attempt through the fault-injection point
         (also the replay entry used by ErrorStore.replay).  Records a
-        `sink.publish` span on the originating frame's trace: the live
-        thread-local scope (set by runtime._flush_sink_outbox) for
-        in-line publishes, or the resumable ctx a stored payload
-        carries — so an ErrorStore replay after a breaker shed still
-        lands on the SAME tree, not an orphan."""
-        self.rt.inject("sink.publish", self.stream_id)
-        h = getattr(getattr(self.rt, "_trace_tls", None), "handle", None)
+        `sink.send` span on the originating frame's trace: the live
+        thread-local scope (set by runtime._flush_sink_outbox, inside
+        its `sink.publish`) for in-line publishes, or the resumable ctx
+        a stored payload carries — so an ErrorStore replay after a
+        breaker shed still lands on the SAME tree, not an orphan."""
+        rt = self.rt
+        rt.inject("sink.publish", self.stream_id)
+        h = rt.current_trace()
         if h is None:
             tc = getattr(payload, "trace_ctx", None)
-            tr = getattr(self.rt, "tracing", None)
-            if tc is not None and tr is not None:
-                h = tr.resume(*tc)
-        if h is None:
+            if tc is not None and rt.tracing is not None:
+                h = rt.tracing.resume(*tc)
+        with rt.span("sink.send", handle=h, sink=self.stream_id,
+                     transport=getattr(self, "transport",
+                                       type(self).__name__)):
             self.publish(payload)
-            return
-        t0 = time.perf_counter()
-        try:
-            self.publish(payload)
-        finally:
-            h.mark("sink.publish", t0, time.perf_counter() - t0,
-                  sink=self.stream_id,
-                  transport=getattr(self, "transport", type(self).__name__))
 
     def _publish_guarded(self, payload) -> None:
         if not self.breaker.allow():

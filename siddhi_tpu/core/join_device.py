@@ -463,7 +463,7 @@ class DeviceJoinPlan(QueryPlan):
 
     def _finalize_impl(self) -> list:
         bufs, self._buffered = self._buffered, []
-        with self.rt.stats.stage("host_build", plan=self.name):
+        with self.rt.span("host_build", plan=self.name):
             lc, lts, lseq, ln = self._side_arrays(self.left, bufs)
             rc, rts, rseq, rn = self._side_arrays(self.right, bufs)
         if ln == 0 and rn == 0:
@@ -541,7 +541,7 @@ class DeviceJoinPlan(QueryPlan):
 
     def _materialize(self, entry: dict, update_mirrors: bool = False) -> list:
         while True:
-            with self.rt.stats.stage("transfer", plan=self.name):
+            with self.rt.span("transfer", plan=self.name):
                 ipack = np.asarray(entry["res"]["i"])      # ONE pull
             nL, nR = int(ipack[0]), int(ipack[1])
             M = entry["M"]

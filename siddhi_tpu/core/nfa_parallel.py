@@ -796,6 +796,10 @@ class ParallelChainKernel:
     # -- the block --------------------------------------------------------
 
     def _block_impl(self, ev, M: int):
+        # every phase runs under a jax.named_scope, so each device
+        # operation's `op_name` says which phase it belongs to and the
+        # names survive a recompile (XLA's own `while.73` do not)
+        scope = jax.named_scope
         prog, nfak = self.prog, self.nfak
         S = prog.S
         F = ev["__flat.__ts__"].shape[0]
@@ -809,29 +813,32 @@ class ParallelChainKernel:
         # force a second structural compile at flush 2)
         seq = ev["__flat.__seq__"]
         valid = jnp.arange(F, dtype=_I32) < nev
-        nmask = {(pi, ni): self._node_mask(ev, n, ts, valid, base_ts)
-                 for pi, pos in enumerate(prog.positions)
-                 for ni, n in enumerate(pos.nodes)}
+        with scope("node_masks"):
+            nmask = {(pi, ni): self._node_mask(ev, n, ts, valid, base_ts)
+                     for pi, pos in enumerate(prog.positions)
+                     for ni, n in enumerate(pos.nodes)}
 
         chase = _chase_lanes(prog) if self.family == "dfa" else []
         if chase:
             lane_of = {pn: k for k, pn in enumerate(chase)}
-            suffix, packed, nblk, NB = self._dfa_tables(
-                [nmask[pn] for pn in chase], F, L)
+            with scope("dfa_tables"):
+                suffix, packed, nblk, NB = self._dfa_tables(
+                    [nmask[pn] for pn in chase], F, L)
 
         scan_next: dict = {}
 
         def nxt(pi, ni, s):
             """First index >= s matching chase node (pi, ni); L if none."""
-            if chase and (pi, ni) in lane_of:
-                return self._dfa_next(lane_of[(pi, ni)], s, suffix,
-                                      packed, nblk, NB, L)
-            key = (pi, ni)
-            if key not in scan_next:
-                scan_next[key] = _next_static_scan(nmask[key], L)
-            nx = scan_next[key]
-            return jnp.where(s < F, nx[jnp.clip(s, 0, F - 1)],
-                             jnp.int32(L))
+            with scope("next_hit"):
+                if chase and (pi, ni) in lane_of:
+                    return self._dfa_next(lane_of[(pi, ni)], s, suffix,
+                                          packed, nblk, NB, L)
+                key = (pi, ni)
+                if key not in scan_next:
+                    scan_next[key] = _next_static_scan(nmask[key], L)
+                nx = scan_next[key]
+                return jnp.where(s < F, nx[jnp.clip(s, 0, F - 1)],
+                                 jnp.int32(L))
 
         # occurrence ranks per count position: inclusive cumulative match
         # count + a segment tree over it — "the r-th occurrence after
@@ -842,14 +849,16 @@ class ParallelChainKernel:
         for pi, pos in enumerate(prog.positions):
             if pos.kind != "count":
                 continue
-            r = jnp.cumsum(nmask[(pi, 0)].astype(_I32), dtype=_I32)
-            ranks[pi] = r
-            rank_heaps[pi] = _build_heap(r, valid, L, "max",
-                                         jnp.dtype(jnp.int64))
+            with scope("rank_heaps"):
+                r = jnp.cumsum(nmask[(pi, 0)].astype(_I32), dtype=_I32)
+                ranks[pi] = r
+                rank_heaps[pi] = _build_heap(r, valid, L, "max",
+                                             jnp.dtype(jnp.int64))
 
         def select(pi, s, r):
             """First index >= s whose inclusive occurrence rank >= r."""
-            return _first_hit(rank_heaps[pi], L, s, r, "ge")
+            with scope("rank_select"):
+                return _first_hit(rank_heaps[pi], L, s, r, "ge")
 
         # expiry heap: the sequential kernel expires a waiting instance
         # on the FIRST arriving event whose age exceeds the position's
@@ -859,25 +868,32 @@ class ParallelChainKernel:
         # ts, so checking the matched event alone would resurrect
         # instances the sequential kernel killed.  i64 aggregation:
         # ts offsets reach ±2^30 and ts+W must not wrap i32.
-        ts_heap = _build_heap(ts, valid, L, "max", jnp.dtype(jnp.int64))
-        ts64 = ts.astype(jnp.int64)
+        with scope("expiry_heap"):
+            ts_heap = _build_heap(ts, valid, L, "max", jnp.dtype(jnp.int64))
+            ts64 = ts.astype(jnp.int64)
 
         def killer(s, within_ms):
             """First event at or after s past the head's `within` horizon
             (per-head v = head ts + W; queries indexed by head)."""
-            return _first_hit(ts_heap, L, s, ts64 + jnp.int64(within_ms),
-                              "gt")
+            with scope("within_kill"):
+                return _first_hit(ts_heap, L, s,
+                                  ts64 + jnp.int64(within_ms), "gt")
 
         def threshold_next(hop: HopNode, s, idx_of):
-            th = hop.threshold
-            agg = "max" if th.op in ("gt", "ge") else "min"
-            own = ev[f"__flat.{hop.scode}.{th.own_key.split('.', 1)[1]}"]
-            env = self._gather_env(ev, idx_of, th.rhs.reads, F, base_ts)
-            v = jnp.broadcast_to(th.rhs.fn(env), (F,))
-            dt = _tree_dtype(own.dtype, v.dtype)
-            heap = _build_heap(own, nmask[self.prog.ref_of[hop.ref]], L,
-                               agg, dt)
-            return _first_hit(heap, L, s, v, th.op)
+            with scope("threshold_next"):
+                th = hop.threshold
+                agg = "max" if th.op in ("gt", "ge") else "min"
+                own = ev[f"__flat.{hop.scode}."
+                         f"{th.own_key.split('.', 1)[1]}"]
+                env = self._gather_env(ev, idx_of, th.rhs.reads, F,
+                                       base_ts)
+                v = jnp.broadcast_to(th.rhs.fn(env), (F,))
+                dt = _tree_dtype(own.dtype, v.dtype)
+                with scope("heap"):
+                    heap = _build_heap(own,
+                                       nmask[self.prog.ref_of[hop.ref]],
+                                       L, agg, dt)
+                return _first_hit(heap, L, s, v, th.op)
 
         # ---- the state chase: every event index is a candidate head ----
         j0 = jnp.arange(F, dtype=_I32)
@@ -900,280 +916,287 @@ class ParallelChainKernel:
         if head.kind == "count":
             # the arming event IS occurrence 1 (host _alloc_head): the
             # rank base excludes it, the select starts AT the head
-            ra = ranks[0][j0] - 1
-            count_ctx[0] = (j0, ra)
-            jmin = select(0, j0, ra + jnp.int32(head.min_count))
-            kl = killer(j0 + 1, head.within_ms)
-            if S > 1:
-                ok, d = step_fail(ok, kl, jmin)
-                dead = dead | d
-                pend_count = (0, head)
-                j = jnp.clip(jmin, 0, F - 1)
+            with scope("hop0"):
+                ra = ranks[0][j0] - 1
+                count_ctx[0] = (j0, ra)
+                jmin = select(0, j0, ra + jnp.int32(head.min_count))
+                kl = killer(j0 + 1, head.within_ms)
+                if S > 1:
+                    ok, d = step_fail(ok, kl, jmin)
+                    dead = dead | d
+                    pend_count = (0, head)
+                    j = jnp.clip(jmin, 0, F - 1)
         else:
             idx_of[head.nodes[0].ref] = j0
 
         final_count = prog.positions[S - 1].kind == "count"
 
         for pi in range(1, S):
-            pos = prog.positions[pi]
-            if pos.kind == "single":
-                hop = pos.nodes[0]
-                s = j + 1
-                if not prog.sequence:
-                    if pend_count is not None:
-                        # the successor consumes the armed count: the
-                        # station never waits AT this position, so the
-                        # COUNT's within (anchored at the head) bounds
-                        # this advance and the successor's own never
-                        # applies (host parity: at_pos is never true for
-                        # a count's successor)
-                        _cpi, cpos = pend_count
-                        kl = killer(s, cpos.within_ms)
-                        pend_count = None
+            with scope(f"hop{pi}"):
+                pos = prog.positions[pi]
+                if pos.kind == "single":
+                    hop = pos.nodes[0]
+                    s = j + 1
+                    if not prog.sequence:
+                        if pend_count is not None:
+                            # the successor consumes the armed count: the
+                            # station never waits AT this position, so the
+                            # COUNT's within (anchored at the head) bounds
+                            # this advance and the successor's own never
+                            # applies (host parity: at_pos is never true for
+                            # a count's successor)
+                            _cpi, cpos = pend_count
+                            kl = killer(s, cpos.within_ms)
+                            pend_count = None
+                        else:
+                            kl = killer(s, pos.within_ms)
+                    if prog.sequence:
+                        # strict succession: the hop consumes EXACTLY the
+                        # next valid event — mask/filter/expiry all resolve
+                        # by direct gather at s
+                        sc = jnp.clip(s, 0, F - 1)
+                        m = nmask[(pi, 0)][sc]
+                        if hop.step_conjs:
+                            senv = self._gather_env(ev, idx_of, set().union(
+                                *[ce.reads for ce in hop.step_conjs]), F,
+                                base_ts)
+                            for a in prog.schemas[hop.ref].attributes:
+                                col = ev.get(f"__flat.{hop.scode}.{a.name}")
+                                if col is not None:
+                                    senv[f"{hop.ref}.{a.name}"] = col[sc]
+                            senv["__timestamp__"] = base_ts \
+                                + ts64[sc]
+                            for ce in hop.step_conjs:
+                                m = m & jnp.broadcast_to(ce.fn(senv), m.shape)
+                        expired = ts64[sc] > ts64[j0] \
+                            + jnp.int64(pos.within_ms)
+                        have = s < nev
+                        jn = jnp.where(have & m & ~expired, s, jnp.int32(L))
+                        dead = dead | (ok & have & (expired | ~m))
+                        ok = ok & (jn < F)
+                    elif hop.threshold is not None:
+                        jn = threshold_next(hop, s, idx_of)
+                        ok, d = step_fail(ok, kl, jn)
+                        dead = dead | d
                     else:
-                        kl = killer(s, pos.within_ms)
-                if prog.sequence:
-                    # strict succession: the hop consumes EXACTLY the
-                    # next valid event — mask/filter/expiry all resolve
-                    # by direct gather at s
-                    sc = jnp.clip(s, 0, F - 1)
-                    m = nmask[(pi, 0)][sc]
-                    if hop.step_conjs:
-                        senv = self._gather_env(ev, idx_of, set().union(
-                            *[ce.reads for ce in hop.step_conjs]), F,
-                            base_ts)
-                        for a in prog.schemas[hop.ref].attributes:
-                            col = ev.get(f"__flat.{hop.scode}.{a.name}")
-                            if col is not None:
-                                senv[f"{hop.ref}.{a.name}"] = col[sc]
-                        senv["__timestamp__"] = base_ts \
-                            + ts64[sc]
-                        for ce in hop.step_conjs:
-                            m = m & jnp.broadcast_to(ce.fn(senv), m.shape)
-                    expired = ts64[sc] > ts64[j0] \
-                        + jnp.int64(pos.within_ms)
-                    have = s < nev
-                    jn = jnp.where(have & m & ~expired, s, jnp.int32(L))
-                    dead = dead | (ok & have & (expired | ~m))
-                    ok = ok & (jn < F)
-                elif hop.threshold is not None:
-                    jn = threshold_next(hop, s, idx_of)
-                    ok, d = step_fail(ok, kl, jn)
-                    dead = dead | d
-                else:
-                    jn = nxt(pi, 0, s)
-                    ok, d = step_fail(ok, kl, jn)
-                    dead = dead | d
-                j = jnp.clip(jn, 0, F - 1)
-                idx_of[hop.ref] = j
-            elif pos.kind == "logical":
-                s = j + 1
-                jl = nxt(pi, 0, s)
-                jr = nxt(pi, 1, s)
-                if pos.op == "or":
-                    jd = jnp.minimum(jl, jr)
-                else:
-                    jd = jnp.where((jl < F) & (jr < F),
-                                   jnp.maximum(jl, jr), jnp.int32(L))
-                kl = killer(s, pos.within_ms)
-                ok, d = step_fail(ok, kl, jd)
-                dead = dead | d
-                jdc = jnp.clip(jd, 0, F - 1)
-                for ni, n in enumerate(pos.nodes):
-                    jside = jl if ni == 0 else jr
+                        jn = nxt(pi, 0, s)
+                        ok, d = step_fail(ok, kl, jn)
+                        dead = dead | d
+                    j = jnp.clip(jn, 0, F - 1)
+                    idx_of[hop.ref] = j
+                elif pos.kind == "logical":
+                    s = j + 1
+                    jl = nxt(pi, 0, s)
+                    jr = nxt(pi, 1, s)
                     if pos.op == "or":
-                        # winner captures its own first match; loser is
-                        # absent (presence row nulls it host-side)
-                        idx_of[n.ref] = jnp.clip(jside, 0, F - 1)
-                        pres_of[n.ref] = jside == jd
+                        jd = jnp.minimum(jl, jr)
                     else:
-                        # AND stations re-capture while waiting: the
-                        # emitted value is the LAST side match at or
-                        # before the done event
-                        pv = _prev_static_scan(nmask[(pi, ni)])
-                        idx_of[n.ref] = jnp.clip(pv[jdc], 0, F - 1)
-                        pres_of[n.ref] = jnp.ones((F,), bool)
-                j = jdc
-            else:                       # count (non-head entry)
-                entry = j
-                ra = ranks[pi][entry]   # entry event is NOT an occurrence
-                count_ctx[pi] = (entry + 1, ra)
-                if pi < S - 1:
-                    jmin = select(pi, entry + 1,
-                                  ra + jnp.int32(pos.min_count))
-                    kl = killer(entry + 1, pos.within_ms)
-                    ok, d = step_fail(ok, kl, jmin)
+                        jd = jnp.where((jl < F) & (jr < F),
+                                       jnp.maximum(jl, jr), jnp.int32(L))
+                    kl = killer(s, pos.within_ms)
+                    ok, d = step_fail(ok, kl, jd)
                     dead = dead | d
-                    pend_count = (pi, pos)
-                    j = jnp.clip(jmin, 0, F - 1)
+                    jdc = jnp.clip(jd, 0, F - 1)
+                    for ni, n in enumerate(pos.nodes):
+                        jside = jl if ni == 0 else jr
+                        if pos.op == "or":
+                            # winner captures its own first match; loser is
+                            # absent (presence row nulls it host-side)
+                            idx_of[n.ref] = jnp.clip(jside, 0, F - 1)
+                            pres_of[n.ref] = jside == jd
+                        else:
+                            # AND stations re-capture while waiting: the
+                            # emitted value is the LAST side match at or
+                            # before the done event
+                            pv = _prev_static_scan(nmask[(pi, ni)])
+                            idx_of[n.ref] = jnp.clip(pv[jdc], 0, F - 1)
+                            pres_of[n.ref] = jnp.ones((F,), bool)
+                    j = jdc
+                else:                       # count (non-head entry)
+                    entry = j
+                    ra = ranks[pi][entry]   # entry event is NOT an occurrence
+                    count_ctx[pi] = (entry + 1, ra)
+                    if pi < S - 1:
+                        jmin = select(pi, entry + 1,
+                                      ra + jnp.int32(pos.min_count))
+                        kl = killer(entry + 1, pos.within_ms)
+                        ok, d = step_fail(ok, kl, jmin)
+                        dead = dead | d
+                        pend_count = (pi, pos)
+                        j = jnp.clip(jmin, 0, F - 1)
 
-        # ---- emission candidates --------------------------------------
-        if final_count:
-            fpos = prog.positions[S - 1]
-            s_occ, ra = count_ctx[S - 1]
-            kl = killer(s_occ, fpos.within_ms)
-            C = fpos.max_count - fpos.min_count + 1
-            lvs, comps = [], []
-            for c in range(fpos.min_count, fpos.max_count + 1):
-                jc = select(S - 1, s_occ, ra + jnp.int32(c))
-                lvs.append(ok & (jc < kl))
-                comps.append(jnp.clip(jc, 0, F - 1))
-            lv_all = jnp.stack(lvs)                 # (C, F)
-            comp_all = jnp.stack(comps)
-            # single-arm resolution: parked at max, or dead
-            resolved = dead | lvs[-1]
-        else:
-            C = 1
-            lv_all = ok[None, :]
-            comp_all = j[None, :]
-            resolved = dead | ok
-
-        # dedup: completions at or before the previous flush's last seq
-        # are tail replays — suppressed on device, per lane
-        lv_all = lv_all & (seq[comp_all] > prev_seq.astype(_I32))
-
-        arm_flag = jnp.int32(0)
-        if prog.single_arm:
-            # ONE instance ever: the first head match arms it; everything
-            # else never existed.  The meta flag tells the host whether
-            # the arm is still pending (keep dispatching) or resolved.
-            hm = nmask[(0, 0)]
-            h0 = jnp.min(jnp.where(hm, j0, jnp.int32(F)))
-            lv_all = lv_all & (j0[None, :] == h0)
-            arm_off = ev.get("__arm_done__")
-            if arm_off is not None:
-                lv_all = lv_all & (arm_off.astype(_I32) == 0)
-            has_head = h0 < F
-            r0 = resolved[jnp.clip(h0, 0, F - 1)]
-            arm_flag = jnp.where(
-                has_head,
-                jnp.where(r0, jnp.int32(ARM_RESOLVED),
-                          jnp.int32(ARM_PENDING)),
-                jnp.int32(ARM_NONE))
-            if arm_off is not None:
-                arm_flag = jnp.where(arm_off.astype(_I32) != 0,
-                                     jnp.int32(ARM_RESOLVED), arm_flag)
-
-        # ---- compaction: (slot, head) candidates -> M match rows ------
-        lvf = lv_all.reshape(C * F)
-        pos_ = jnp.cumsum(lvf.astype(_I32), dtype=_I32) - lvf
-        n = pos_[-1] + lvf[-1]
-        wpos = jnp.where(lvf & (pos_ < M), pos_, M)
-
-        def compact(a):
-            return jnp.zeros((M,), a.dtype).at[wpos].set(
-                a.reshape(C * F) if a.ndim == 2 else jnp.tile(a, C),
-                mode="drop")
-
-        hm_ = compact(jnp.broadcast_to(j0[None, :], (C, F)))
-        cm_ = compact(jnp.broadcast_to(
-            jnp.arange(C, dtype=_I32)[:, None], (C, F)))
-        comp_m = compact(comp_all)
-
-        # per-match capture indices: single/logical refs gather their
-        # per-head chase results; count refs rank/select at the match's
-        # completion index (collection is station-independent in the
-        # sequential kernel — occurrences keep absorbing until max or
-        # the park freeze at completion)
-        midx: dict = {}
-        mpres: dict = {}
-        for rp, arr in idx_of.items():
-            midx[rp] = arr[hm_] if arr is not j0 else hm_
-        for rp, arr in pres_of.items():
-            mpres[rp] = arr[hm_]
-
-        need = set()
-        for ce in list(nfak.sel_fns.values()) \
-                + ([nfak.having] if nfak.having else []):
-            need.update(ce.reads)
-        need_bases: dict = {}
-        for k in need:
-            if "." in k and not k.startswith("__"):
-                need_bases.setdefault(_base_ref(k.split(".", 1)[0])[0],
-                                      set()).add(k.split(".", 1)[0])
-        for k in nfak.out_names:
-            if k.startswith("__present__."):
-                rp = k[len("__present__."):]
-                need_bases.setdefault(_base_ref(rp)[0], set()).add(rp)
-
-        for pi, pos in enumerate(prog.positions):
-            if pos.kind != "count":
-                continue
-            ref = pos.nodes[0].ref
-            rps = need_bases.get(ref)
-            if not rps:
-                continue
-            s_occ, ra = count_ctx[pi]
-            s_m = s_occ[hm_] if s_occ.ndim else s_occ
-            ra_m = ra[hm_]
-            if pi == S - 1:
-                q_m = jnp.int32(pos.min_count) + cm_
+        with scope("emit_candidates"):
+            # ---- emission candidates --------------------------------------
+            if final_count:
+                fpos = prog.positions[S - 1]
+                s_occ, ra = count_ctx[S - 1]
+                kl = killer(s_occ, fpos.within_ms)
+                C = fpos.max_count - fpos.min_count + 1
+                lvs, comps = [], []
+                for c in range(fpos.min_count, fpos.max_count + 1):
+                    jc = select(S - 1, s_occ, ra + jnp.int32(c))
+                    lvs.append(ok & (jc < kl))
+                    comps.append(jnp.clip(jc, 0, F - 1))
+                lv_all = jnp.stack(lvs)                 # (C, F)
+                comp_all = jnp.stack(comps)
+                # single-arm resolution: parked at max, or dead
+                resolved = dead | lvs[-1]
             else:
-                avail = ranks[pi][comp_m] - ra_m
-                q_m = jnp.minimum(avail, jnp.int32(pos.max_count)) \
-                    if pos.max_count < UNBOUNDED else avail
+                C = 1
+                lv_all = ok[None, :]
+                comp_all = j[None, :]
+                resolved = dead | ok
 
-            def sel_q(r):
-                return jnp.clip(_first_hit(rank_heaps[pi], L, s_m,
-                                           ra_m + r, "ge"), 0, F - 1)
-            for rp in sorted(rps):        # set of str: see _gather_env
-                _b, cidx = _base_ref(rp)
-                if cidx is None or cidx == "last":
-                    if pi == S - 1:
-                        midx[rp] = comp_m   # the emitting occurrence
-                    else:
-                        midx[rp] = sel_q(q_m)
-                    mpres[rp] = q_m >= 1
-                elif cidx == "last-1":
-                    midx[rp] = sel_q(q_m - 1)
-                    mpres[rp] = q_m >= 2
+            # dedup: completions at or before the previous flush's last seq
+            # are tail replays — suppressed on device, per lane
+            lv_all = lv_all & (seq[comp_all] > prev_seq.astype(_I32))
+
+            arm_flag = jnp.int32(0)
+            if prog.single_arm:
+                # ONE instance ever: the first head match arms it; everything
+                # else never existed.  The meta flag tells the host whether
+                # the arm is still pending (keep dispatching) or resolved.
+                hm = nmask[(0, 0)]
+                h0 = jnp.min(jnp.where(hm, j0, jnp.int32(F)))
+                lv_all = lv_all & (j0[None, :] == h0)
+                arm_off = ev.get("__arm_done__")
+                if arm_off is not None:
+                    lv_all = lv_all & (arm_off.astype(_I32) == 0)
+                has_head = h0 < F
+                r0 = resolved[jnp.clip(h0, 0, F - 1)]
+                arm_flag = jnp.where(
+                    has_head,
+                    jnp.where(r0, jnp.int32(ARM_RESOLVED),
+                              jnp.int32(ARM_PENDING)),
+                    jnp.int32(ARM_NONE))
+                if arm_off is not None:
+                    arm_flag = jnp.where(arm_off.astype(_I32) != 0,
+                                         jnp.int32(ARM_RESOLVED), arm_flag)
+
+        with scope("compact"):
+            # ---- compaction: (slot, head) candidates -> M match rows ------
+            lvf = lv_all.reshape(C * F)
+            pos_ = jnp.cumsum(lvf.astype(_I32), dtype=_I32) - lvf
+            n = pos_[-1] + lvf[-1]
+            wpos = jnp.where(lvf & (pos_ < M), pos_, M)
+
+            def compact(a):
+                return jnp.zeros((M,), a.dtype).at[wpos].set(
+                    a.reshape(C * F) if a.ndim == 2 else jnp.tile(a, C),
+                    mode="drop")
+
+            hm_ = compact(jnp.broadcast_to(j0[None, :], (C, F)))
+            cm_ = compact(jnp.broadcast_to(
+                jnp.arange(C, dtype=_I32)[:, None], (C, F)))
+            comp_m = compact(comp_all)
+
+        with scope("capture"):
+            # per-match capture indices: single/logical refs gather their
+            # per-head chase results; count refs rank/select at the match's
+            # completion index (collection is station-independent in the
+            # sequential kernel — occurrences keep absorbing until max or
+            # the park freeze at completion)
+            midx: dict = {}
+            mpres: dict = {}
+            for rp, arr in idx_of.items():
+                midx[rp] = arr[hm_] if arr is not j0 else hm_
+            for rp, arr in pres_of.items():
+                mpres[rp] = arr[hm_]
+
+            need = set()
+            for ce in list(nfak.sel_fns.values()) \
+                    + ([nfak.having] if nfak.having else []):
+                need.update(ce.reads)
+            need_bases: dict = {}
+            for k in need:
+                if "." in k and not k.startswith("__"):
+                    need_bases.setdefault(_base_ref(k.split(".", 1)[0])[0],
+                                          set()).add(k.split(".", 1)[0])
+            for k in nfak.out_names:
+                if k.startswith("__present__."):
+                    rp = k[len("__present__."):]
+                    need_bases.setdefault(_base_ref(rp)[0], set()).add(rp)
+
+            for pi, pos in enumerate(prog.positions):
+                if pos.kind != "count":
+                    continue
+                ref = pos.nodes[0].ref
+                rps = need_bases.get(ref)
+                if not rps:
+                    continue
+                s_occ, ra = count_ctx[pi]
+                s_m = s_occ[hm_] if s_occ.ndim else s_occ
+                ra_m = ra[hm_]
+                if pi == S - 1:
+                    q_m = jnp.int32(pos.min_count) + cm_
                 else:
-                    want = jnp.int32(int(cidx) + 1)
-                    midx[rp] = sel_q(want)
-                    mpres[rp] = q_m >= want
+                    avail = ranks[pi][comp_m] - ra_m
+                    q_m = jnp.minimum(avail, jnp.int32(pos.max_count)) \
+                        if pos.max_count < UNBOUNDED else avail
 
-        env = self._gather_env(ev, midx, need, F, base_ts, comp_j=comp_m)
-        sel = {name: jnp.broadcast_to(ce.fn(env), (M,))
-               for name, ce in nfak.sel_fns.items()}
-        mvalid = jnp.arange(1, M + 1, dtype=_I32) <= n
-        if nfak.having is not None:
-            henv = dict(env)
-            henv.update(sel)
-            mvalid = mvalid & jnp.broadcast_to(nfak.having.fn(henv), (M,))
-        sel["__timestamp__"] = ts[comp_m]
-        sel["__seq__"] = seq[comp_m]
-        sel["__head_seq__"] = seq[hm_]
-        if nfak.emit_qid:
-            qid = ev.get("__lane_qid__", jnp.int32(0))
-            sel["__qid__"] = jnp.broadcast_to(qid.astype(_I32), (M,))
-        for name in nfak.out_names:
-            if not name.startswith("__present__."):
-                continue
-            rp = name[len("__present__."):]
-            pr = mpres.get(rp)
-            if pr is None:
-                pr = jnp.ones((M,), bool)
-            sel[name] = pr.astype(_I32)
+                def sel_q(r):
+                    return jnp.clip(_first_hit(rank_heaps[pi], L, s_m,
+                                               ra_m + r, "ge"), 0, F - 1)
+                for rp in sorted(rps):        # set of str: see _gather_env
+                    _b, cidx = _base_ref(rp)
+                    if cidx is None or cidx == "last":
+                        if pi == S - 1:
+                            midx[rp] = comp_m   # the emitting occurrence
+                        else:
+                            midx[rp] = sel_q(q_m)
+                        mpres[rp] = q_m >= 1
+                    elif cidx == "last-1":
+                        midx[rp] = sel_q(q_m - 1)
+                        mpres[rp] = q_m >= 2
+                    else:
+                        want = jnp.int32(int(cidx) + 1)
+                        midx[rp] = sel_q(want)
+                        mpres[rp] = q_m >= want
 
-        NO_DL = jnp.int32(2 ** 31 - 1)
-        meta = (jnp.zeros((M,), _I32)
-                .at[0].set(n).at[3].set(NO_DL).at[4].set(arm_flag))
-        irows = [meta]
-        if nfak.having is not None:
-            irows.append(mvalid.astype(_I32))
-        frows = []
-        for name in nfak.out_names:
-            col = sel[name]
-            if col.dtype == jnp.float64:
-                frows.append(col)
-            elif col.dtype == jnp.float32:
-                irows.append(lax.bitcast_convert_type(col, _I32))
-            elif col.dtype == jnp.int64:
-                irows.append(_hi32(col))
-                irows.append(_lo32(col))
-            else:
-                irows.append(col.astype(_I32))
-        out = {"i": jnp.stack(irows, axis=0)}
-        if frows:
-            out["f"] = jnp.stack(frows, axis=0)
+        with scope("select"):
+            env = self._gather_env(ev, midx, need, F, base_ts, comp_j=comp_m)
+            sel = {name: jnp.broadcast_to(ce.fn(env), (M,))
+                   for name, ce in nfak.sel_fns.items()}
+            mvalid = jnp.arange(1, M + 1, dtype=_I32) <= n
+            if nfak.having is not None:
+                henv = dict(env)
+                henv.update(sel)
+                mvalid = mvalid & jnp.broadcast_to(nfak.having.fn(henv), (M,))
+            sel["__timestamp__"] = ts[comp_m]
+            sel["__seq__"] = seq[comp_m]
+            sel["__head_seq__"] = seq[hm_]
+            if nfak.emit_qid:
+                qid = ev.get("__lane_qid__", jnp.int32(0))
+                sel["__qid__"] = jnp.broadcast_to(qid.astype(_I32), (M,))
+            for name in nfak.out_names:
+                if not name.startswith("__present__."):
+                    continue
+                rp = name[len("__present__."):]
+                pr = mpres.get(rp)
+                if pr is None:
+                    pr = jnp.ones((M,), bool)
+                sel[name] = pr.astype(_I32)
+
+        with scope("pack"):
+            NO_DL = jnp.int32(2 ** 31 - 1)
+            meta = (jnp.zeros((M,), _I32)
+                    .at[0].set(n).at[3].set(NO_DL).at[4].set(arm_flag))
+            irows = [meta]
+            if nfak.having is not None:
+                irows.append(mvalid.astype(_I32))
+            frows = []
+            for name in nfak.out_names:
+                col = sel[name]
+                if col.dtype == jnp.float64:
+                    frows.append(col)
+                elif col.dtype == jnp.float32:
+                    irows.append(lax.bitcast_convert_type(col, _I32))
+                elif col.dtype == jnp.int64:
+                    irows.append(_hi32(col))
+                    irows.append(_lo32(col))
+                else:
+                    irows.append(col.astype(_I32))
+            out = {"i": jnp.stack(irows, axis=0)}
+            if frows:
+                out["f"] = jnp.stack(frows, axis=0)
         return out

@@ -639,6 +639,18 @@ class DevicePatternPlan(QueryPlan):
                            cache_hit=hit, nbytes=env_nbytes(ev),
                            prof=prof)
 
+    def _pull(self, out: dict) -> tuple:
+        """The blocking pull of one block's packed outputs: the wait for
+        the device, then ONE D2H transfer per pack; notes the bytes."""
+        with self.rt.span("transfer", plan=self.name):
+            ipack = np.asarray(out["i"])
+            fpack = np.asarray(out["f"]) if "f" in out else None
+        prof = self.rt.profiler
+        if prof is not None:
+            prof.note_bytes(self.name, "d2h", ipack.nbytes
+                            + (0 if fpack is None else fpack.nbytes))
+        return ipack, fpack
+
     def device_metrics(self) -> dict:
         """Sampled device gauges: lane occupancy + state-frontier width
         (one D2H pull of `occ`), partition-key fill, capacity drops."""
@@ -698,7 +710,7 @@ class DevicePatternPlan(QueryPlan):
             self._anchor_ms()
         bufs, self._buffered = self._buffered, []
 
-        with self.rt.stats.stage("host_build", plan=self.name):
+        with self.rt.span("host_build", plan=self.name):
             # 1. union columns over all buffered batches
             N = sum(b.n for _s, b in bufs)
             ts = np.empty(N, dtype=np.int64)
@@ -731,7 +743,7 @@ class DevicePatternPlan(QueryPlan):
                 cols[k] = cols[k][order]
         if self._chunk_cfg is not None:
             return self._run_chunked_flat(ts, seq, scode, cols, part)
-        with self.rt.stats.stage("host_build", plan=self.name):
+        with self.rt.span("host_build", plan=self.name):
             if self.broadcast_events:
                 idx_within = np.arange(N, dtype=np.int64)
                 part = np.zeros(N, dtype=_I32)
@@ -838,18 +850,14 @@ class DevicePatternPlan(QueryPlan):
                 dispatched.append((j, pre, ev, T, M, out))
             restart = None
             for j, pre, ev, T, M, out in dispatched:
-                with self.rt.stats.stage("transfer", plan=self.name):
-                    ipack = np.asarray(out["i"])   # ONE D2H transfer
-                    fpack = np.asarray(out["f"]) if "f" in out else None
+                ipack, fpack = self._pull(out)
                 n, ofs, ofl = (int(ipack[0, 0]), int(ipack[0, 1]),
                                int(ipack[0, 2]))
                 while n > M:                   # exact re-run, bigger buffer
                     M = pow2_at_least(n) if self.broadcast_events \
                         else _m_bucket(n)
                     _st2, out = self._call_block(self.kernel, T, M, pre, ev)
-                    with self.rt.stats.stage("transfer", plan=self.name):
-                        ipack = np.asarray(out["i"])
-                        fpack = np.asarray(out["f"]) if "f" in out else None
+                    ipack, fpack = self._pull(out)
                     n, ofs, ofl = (int(ipack[0, 0]), int(ipack[0, 1]),
                                    int(ipack[0, 2]))
                 self._m_hint = max(self._m_hint, M)
@@ -921,7 +929,7 @@ class DevicePatternPlan(QueryPlan):
 
     def _run_chunked_flat_inner(self, ts, seq, scode, cols) -> list:
         fam = self.family
-        with self.rt.stats.stage("host_build", plan=self.name):
+        with self.rt.span("host_build", plan=self.name):
             cfg = self._chunk_cfg
             W = int(cfg["W"])
             if self._tail is not None:
@@ -1074,7 +1082,7 @@ class DevicePatternPlan(QueryPlan):
             raise
 
     def _run_lanes_flat_inner(self, ts, seq, scode, cols, part) -> list:
-        with self.rt.stats.stage("host_build", plan=self.name):
+        with self.rt.span("host_build", plan=self.name):
             W0 = int(self._chunk_cfg["W"])
             tl = self._lane_tail
             held = None
@@ -1215,7 +1223,7 @@ class DevicePatternPlan(QueryPlan):
         (no chunk-lane geometry — the kernel is log-depth in T).  With
         `lanes`, the SAME block runs once per lane under jax.vmap
         (partitioned (L, F) grids / fused broadcast lanes)."""
-        with self.rt.stats.stage("host_build", plan=self.name):
+        with self.rt.span("host_build", plan=self.name):
             kern = self._parallel_kernel()
             if self.mesh is not None and lanes:
                 # lane axis shards over the mesh; shared scalars and
@@ -1240,10 +1248,7 @@ class DevicePatternPlan(QueryPlan):
     def _materialize_par(self, e: dict):
         lanes = e.get("L")
         while True:
-            with self.rt.stats.stage("transfer", plan=self.name):
-                ipack = np.asarray(e["out"]["i"])
-                fpack = np.asarray(e["out"]["f"]) if "f" in e["out"] \
-                    else None
+            ipack, fpack = self._pull(e["out"])
             n = int(ipack[..., 0, 0].max()) if lanes else int(ipack[0, 0])
             if n > e["M"]:      # final-count emission burst: exact retry
                 e = self._dispatch_par(e["ev"], e["F"], _m_bucket_chunk(n),
@@ -1269,7 +1274,7 @@ class DevicePatternPlan(QueryPlan):
         return self._unpack_block(ipack, fpack, n)
 
     def _dispatch_chunk(self, ev, K, T, M, ts_base, seq_base) -> dict:
-        with self.rt.stats.stage("host_build", plan=self.name):
+        with self.rt.span("host_build", plan=self.name):
             kern = self._chunk_kernel(K)
             st0 = kern.init_state()
             if self.mesh is not None:
@@ -1295,10 +1300,7 @@ class DevicePatternPlan(QueryPlan):
         if "F" in e:                  # scan/dfa-family entry
             return self._materialize_par(e)
         while True:
-            with self.rt.stats.stage("transfer", plan=self.name):
-                ipack = np.asarray(e["out"]["i"])
-                fpack = np.asarray(e["out"]["f"]) if "f" in e["out"] \
-                    else None
+            ipack, fpack = self._pull(e["out"])
             n, ofs, ofl = (int(ipack[0, 0]), int(ipack[0, 1]),
                            int(ipack[0, 2]))
             if n > e["M"]:
@@ -1376,7 +1378,7 @@ class DevicePatternPlan(QueryPlan):
                                  np.arange(ipack.shape[1]) < n)
 
     def _unpack_rows(self, ipack, fpack, base_valid):
-        with self.rt.stats.stage("scatter", plan=self.name):
+        with self.rt.span("scatter", plan=self.name):
             if self.kernel.having is not None:
                 valid = base_valid & (ipack[1] != 0)
                 ii = 2
@@ -1422,7 +1424,7 @@ class DevicePatternPlan(QueryPlan):
 
     def _rows_to_batches(self, chunks: list) -> list:
         """chunks: list of (tss, seqs, hseqs, data) columnar match tables."""
-        with self.rt.stats.stage("scatter", plan=self.name):
+        with self.rt.span("scatter", plan=self.name):
             chunks = [c for c in chunks if c is not None]
             if not chunks or self.events_for == ast.OutputEventsFor.EXPIRED:
                 return []

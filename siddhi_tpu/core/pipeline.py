@@ -86,7 +86,8 @@ class DispatchPipeline:
 
     __slots__ = ("plan", "depth", "entries", "_materialize", "_t_disp",
                  "_held", "dispatches", "max_depth", "overlap_s", "wait_s",
-                 "origin", "_origins", "inject", "_ready", "prof")
+                 "origin", "_origins", "inject", "_ready", "prof",
+                 "set_trace")
 
     def __init__(self, plan_name: str, materialize: Callable,
                  depth: int = 0):
@@ -114,6 +115,9 @@ class DispatchPipeline:
         # d2h_materialize phase (outermost-wins: inner `transfer`
         # stages inside a plan's materialize are suppressed)
         self.prof = None
+        # runtime._set_trace, wired with it: a deferred entry
+        # materializes under the trace of the frame it was dispatched for
+        self.set_trace = None
         # results materialized but not yet handed to the caller: a later
         # entry failing mid-drain must not discard an earlier entry's
         # already-materialized outputs — they survive here and return on
@@ -170,13 +174,15 @@ class DispatchPipeline:
             t0 = time.perf_counter()
             self.overlap_s += t0 - t_disp
             # frame tracing: a deferred entry still knows the batch it
-            # was dispatched for — the materialize span (which may land
-            # D batches later, on the scheduler thread) parents on that
-            # frame's tree, and the materialized outputs inherit the
-            # handle so sink egress stays connected
+            # was dispatched for — the plan's transfer/unpack spans
+            # (which may land D batches later, on the scheduler thread)
+            # record on that frame's tree, and the materialized outputs
+            # inherit the handle so sink egress stays connected
             od = None if origin is None \
                 else getattr(origin[1], "__dict__", None)
-            h = None if od is None else od.get("_trace")
+            h = None if od is None or self.set_trace is None \
+                else od.get("_trace")
+            prev_tr = None if h is None else self.set_trace(h)
             pspan = None
             if self.prof is not None:
                 self.prof.note_bytes(self.plan, "d2h",
@@ -189,8 +195,6 @@ class DispatchPipeline:
                 res = self._materialize(entry)
                 if h is not None:
                     res = list(res)
-                    h.mark("materialize", t0, time.perf_counter() - t0,
-                          plan=self.plan)
                     for r in res:
                         b = getattr(r, "batch", None)
                         if b is not None:
@@ -211,6 +215,8 @@ class DispatchPipeline:
             finally:
                 if pspan is not None:
                     pspan.__exit__(None, None, None)
+                if h is not None:
+                    self.set_trace(prev_tr)
             self.wait_s += time.perf_counter() - t0
         out, self._ready = self._ready, []
         return out

@@ -23,6 +23,7 @@ from .batch import EventBatch
 from .expr import (CompiledExpr, ExprError, MultiStreamContext,
                    SingleStreamContext, compile_expression, jnp_dtype)
 from .schema import TIMESTAMP_DTYPE, StreamSchema, StringTable, dtype_of
+from .telemetry import call_kernel, env_nbytes
 
 # aggregator function names recognized in selectors (reference:
 # core:query/selector/attribute/aggregator/*)
@@ -306,89 +307,104 @@ class FilterProjectPlan(QueryPlan):
     def _make_step(self):
         filt, sel = self._filter, self._sel
 
+        # named scopes: the device operations keep an `op_name` path
+        # (jit(step)/compare, ...) that survives a recompile
         def step(env):
             n = next(iter(env.values())).shape[0]
-            mask = (jnp.broadcast_to(filt.fn(env), (n,))  # 0-d if constant
-                    if filt is not None else jnp.ones(n, dtype=bool))
-            outs = [None if pt is not None else fn(env)
-                    for fn, pt in zip(sel.fns, sel.passthrough)]
+            with jax.named_scope("compare"):
+                mask = (jnp.broadcast_to(filt.fn(env), (n,))  # 0-d if const
+                        if filt is not None else jnp.ones(n, dtype=bool))
+            with jax.named_scope("project"):
+                outs = [None if pt is not None else fn(env)
+                        for fn, pt in zip(sel.fns, sel.passthrough)]
             if sel.having is not None:
-                henv = dict(env)
-                h_reads = set(sel.having.reads)
-                for nm, col, pt in zip(sel.names, outs, sel.passthrough):
-                    if nm not in h_reads:
-                        continue        # env is pruned: only map names read
-                    henv[nm] = env[pt] if pt is not None else col
-                mask = mask & sel.having.fn(henv)
+                with jax.named_scope("having"):
+                    henv = dict(env)
+                    h_reads = set(sel.having.reads)
+                    for nm, col, pt in zip(sel.names, outs,
+                                           sel.passthrough):
+                        if nm not in h_reads:
+                            continue    # env is pruned: only map names read
+                        henv[nm] = env[pt] if pt is not None else col
+                    mask = mask & sel.having.fn(henv)
             # the mask travels bit-packed: the bool row is 8x the packed
             # words on the device->host pull
-            pad = -(-n // 32) * 32
-            if pad != n:
-                mask = jnp.concatenate([mask, jnp.zeros(pad - n, bool)])
-            words = (mask.reshape(-1, 32).astype(jnp.uint32)
-                     << jnp.arange(32, dtype=jnp.uint32)[None, :]) \
-                .sum(axis=1).astype(jnp.uint32)   # sum may promote to u64
-            return jax.lax.bitcast_convert_type(words, jnp.int32), \
-                [o for o in outs if o is not None]
+            with jax.named_scope("mask_pack"):
+                pad = -(-n // 32) * 32
+                if pad != n:
+                    mask = jnp.concatenate([mask, jnp.zeros(pad - n, bool)])
+                words = (mask.reshape(-1, 32).astype(jnp.uint32)
+                         << jnp.arange(32, dtype=jnp.uint32)[None, :]) \
+                    .sum(axis=1).astype(jnp.uint32)  # sum may promote to u64
+                return jax.lax.bitcast_convert_type(words, jnp.int32), \
+                    [o for o in outs if o is not None]
         return step
 
     def process(self, stream_id: str, batch: EventBatch) -> list:
         if batch.n == 0 or self.emits_nothing:
             return []
-        host_env = {a.name: batch.columns[a.name] for a in self.in_schema.attributes}
-        host_env["__timestamp__"] = batch.timestamps
-        if self._filter is None and self._sel.having is None \
-                and all(pt is not None for pt in self._sel.passthrough):
+        rt = self.rt
+        with rt.span("host_build", plan=self.name):
+            host_env = {a.name: batch.columns[a.name]
+                        for a in self.in_schema.attributes}
+            host_env["__timestamp__"] = batch.timestamps
+            passthrough = self._filter is None \
+                and self._sel.having is None \
+                and all(pt is not None for pt in self._sel.passthrough)
+            if not passthrough:
+                env = {k: host_env[k] for k in sorted(self._need)
+                       if k in host_env
+                       and host_env[k].dtype != np.dtype(object)}
+        if passthrough:
             # pure pass-through (no filter/having/computed column): nothing
             # for the device to do — emit the batch directly (NOTE: keyed
             # on plan shape, not on the read-set — constant filters and
             # constant columns have empty reads but still must evaluate)
             mask = np.ones(batch.n, dtype=bool)
             return self._pipe.push((None, [], host_env, batch, mask))
-        env = {k: host_env[k] for k in sorted(self._need)
-               if k in host_env and host_env[k].dtype != np.dtype(object)}
-        if self.rt is not None:
-            self.rt.inject("dispatch", self.name)
-        prof = None if self.rt is None else self.rt.profiler
-        if prof is not None:
-            from .telemetry import env_nbytes
-            prof.note_bytes(self.name, "h2d", env_nbytes(env))
-            mask_w, outs = prof.run_kernel(self._step, (env,),
-                                           cache_hit=self._warm)
-        else:
-            mask_w, outs = self._step(env)
+        rt.inject("dispatch", self.name)
+        mask_w, outs = call_kernel(
+            rt.stats, self.name, self._step, (env,), cache_hit=self._warm,
+            nbytes=env_nbytes(env), prof=rt.profiler)
         self._warm = True
         from .pipeline import start_d2h
         start_d2h([mask_w] + list(outs))    # pulls overlap device compute
         return self._pipe.push((mask_w, outs, host_env, batch, None))
 
     def _materialize(self, mask_w, outs, host_env, batch, mask) -> list:
+        span = self.rt.span
         if mask is None:
-            words = np.asarray(mask_w)
-            mask = ((words.view(np.uint32)[:, None]
-                     >> np.arange(32, dtype=np.uint32)) & 1
-                    ).astype(bool).reshape(-1)[:batch.n]
-        if not mask.any():
-            return []
-        ts = batch.timestamps[mask]
-        cols = {}
-        outs = iter(outs)
-        for nm, t, pt in zip(self._sel.names, self._sel.types, self._sel.passthrough):
-            if pt is not None:
-                cols[nm] = host_env[pt][mask]
-            else:
-                arr = np.asarray(next(outs))
-                if arr.ndim == 0:       # constant column: 0-d on device
-                    arr = np.broadcast_to(arr, (batch.n,))
-                cols[nm] = arr[mask].astype(dtype_of(t))
-        if self.offset:
-            ts = ts[self.offset:]
-            cols = {k: v[self.offset:] for k, v in cols.items()}
-        if self.limit is not None:
-            ts = ts[:self.limit]
-            cols = {k: v[:self.limit] for k, v in cols.items()}
-        out = EventBatch(self.out_schema, ts, cols, len(ts))
-        return [OutputBatch(self.output_target, out)]
+            # the pulls: the wait for the device and the D2H copies
+            with span("transfer", plan=self.name):
+                words = np.asarray(mask_w)
+                outs = [np.asarray(o) for o in outs]
+        with span("unpack", plan=self.name, events=batch.n):
+            if mask is None:
+                mask = ((words.view(np.uint32)[:, None]
+                         >> np.arange(32, dtype=np.uint32)) & 1
+                        ).astype(bool).reshape(-1)[:batch.n]
+            if not mask.any():
+                return []
+            ts = batch.timestamps[mask]
+            cols = {}
+            outs = iter(outs)
+            for nm, t, pt in zip(self._sel.names, self._sel.types,
+                                 self._sel.passthrough):
+                if pt is not None:
+                    cols[nm] = host_env[pt][mask]
+                else:
+                    arr = next(outs)
+                    if arr.ndim == 0:   # constant column: 0-d on device
+                        arr = np.broadcast_to(arr, (batch.n,))
+                    cols[nm] = arr[mask].astype(dtype_of(t))
+            if self.offset:
+                ts = ts[self.offset:]
+                cols = {k: v[self.offset:] for k, v in cols.items()}
+            if self.limit is not None:
+                ts = ts[:self.limit]
+                cols = {k: v[:self.limit] for k, v in cols.items()}
+            out = EventBatch(self.out_schema, ts, cols, len(ts))
+            return [OutputBatch(self.output_target, out)]
 
 
 # ---------------------------------------------------------------------------

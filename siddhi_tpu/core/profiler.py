@@ -1,9 +1,8 @@
 """Continuous device-time attribution: the per-dispatch phase profiler.
 
-PR 15's `bench.py --trace` showed host dispatch/materialization — not the
-kernel — bounds end-to-end eps, but that split existed only as a one-shot
-offline bench line.  This module makes the same attribution continuous
-and per-plan in a *live* engine: every dispatch round (pattern
+Host dispatch/materialization — not the kernel — often bounds end-to-end
+eps.  This module makes that attribution continuous and per-plan in a
+*live* engine: every dispatch round (pattern
 scan/dfa/chunk/seq, window, join, filter, fused multi-query — plus the
 runtime's sink egress) attributes its wall time into six phases:
 
@@ -38,7 +37,7 @@ numpy array yields a device array with the *identical* ShapedArray aval,
 so substituting the uploaded leaves into the jit call triggers no
 recompile and no second upload.
 
-Surfaces: `rt.profile()` (totals + windowed ring + roofline fold),
+Surfaces: `rt.profile()` (totals + windowed ring),
 `GET /siddhi/artifact/profile`, Prometheus
 `siddhi_tpu_phase_seconds_total{plan,phase}` /
 `siddhi_tpu_host_dispatch_share{plan}`, and a host-share breach trigger
@@ -59,7 +58,7 @@ import time
 from typing import Callable, Optional
 
 from ..utils.locks import new_lock
-from .telemetry import Histogram
+from .telemetry import NOOP_SPAN, Histogram, Span
 
 PHASES = ("h2d_upload", "kernel_compute", "d2h_materialize",
           "host_pack_unpack", "python_dispatch", "sink_egress")
@@ -71,6 +70,10 @@ HOST_PHASES = ("host_pack_unpack", "python_dispatch", "sink_egress")
 # device plan ("_runtime" = scatter/emit between rounds, "_sink" = sink
 # outbox egress)
 PSEUDO_PLANS = ("_runtime", "_sink")
+
+
+def _no_span(name: str):
+    return NOOP_SPAN
 
 
 class _Acc:
@@ -141,7 +144,7 @@ class _Round:
         self.attr_total += dt
 
 
-class _RoundCM:
+class _RoundCM(Span):
     __slots__ = ("prof", "plan", "events", "t0", "rd", "nested")
 
     def __init__(self, prof: "PhaseProfiler", plan: str, events: int):
@@ -181,27 +184,31 @@ class _RoundCM:
         return False
 
 
-class _PhaseSpan:
+class _PhaseSpan(Span):
     """Outermost-wins phase span.  Nested spans mapping into an already
     open phase (the `transfer` stage inside the pipeline's materialize
     wrap) are suppressed; explicit attributions made *inside* the span
     (a sampled kernel re-dispatch during an M-overflow replay) are
     subtracted, so one second of wall is never counted twice."""
 
-    __slots__ = ("prof", "name", "t0", "rd", "mark", "direct")
+    __slots__ = ("prof", "name", "plan", "events", "t0", "rd", "mark",
+                 "direct")
 
     _SUPPRESSED = -1.0
 
-    def __init__(self, prof: "PhaseProfiler", name: str):
+    def __init__(self, prof: "PhaseProfiler", name: str, plan: str,
+                 events: int):
         self.prof = prof
         self.name = name
+        self.plan = plan            # takes the span outside any round
+        self.events = events
 
     def __enter__(self):
         rd = getattr(self.prof._tls, "round", None)
         self.rd = rd
         if rd is None:
-            # outside any round (callback scatter between rounds):
-            # attribute directly to the "_runtime" pseudo-plan
+            # outside any round (callback scatter between rounds, sink
+            # egress): attribute directly to the span's pseudo-plan
             self.direct = True
             self.mark = 0.0
         else:
@@ -217,7 +224,7 @@ class _PhaseSpan:
     def __exit__(self, *exc):
         dt = time.perf_counter() - self.t0
         if self.direct:
-            self.prof.note("_runtime", self.name, dt)
+            self.prof.note(self.plan, self.name, dt, self.events)
         elif self.mark != self._SUPPRESSED:
             rd = self.rd
             inner = rd.attr_total - self.mark
@@ -264,43 +271,63 @@ class PhaseProfiler:
         """Wrap one plan dispatch round (process / collect / finalize)."""
         return _RoundCM(self, plan, events)
 
-    def phase(self, name: str) -> _PhaseSpan:
+    def phase(self, name: str, plan: str = "_runtime",
+              events: int = 0) -> _PhaseSpan:
         """Wrap a region whose wall belongs to one phase (outermost
-        wins; see _PhaseSpan)."""
-        return _PhaseSpan(self, name)
+        wins; see _PhaseSpan).  Outside any round it is attributed to
+        the pseudo-plan `plan`."""
+        return _PhaseSpan(self, name, plan, events)
 
-    def run_kernel(self, fn, args: tuple, cache_hit: bool = True):
+    def run_kernel(self, fn, args: tuple, cache_hit: bool = True,
+                   span: Optional[Callable] = None):
         """Invoke a jitted kernel.  On a sampled round: time the numpy
         leaf upload (h2d_upload) and the device execution via
         `block_until_ready` (kernel_compute).  Unsampled rounds and
         compile calls (cache_hit=False — trace+XLA time must not skew
         the kernel estimate) dispatch untouched.
 
+        `span(name)` opens an engine span (telemetry.call_kernel passes
+        the runtime's): the upload and the dispatch are its `kernel`
+        span on every round, and the probe's wait for the device is a
+        `transfer` span of its own, so that the sampled rounds do not
+        read as slow dispatches.
+
         The duty cycle counts KERNEL-carrying rounds, decided here on
         the round's first warm call: collect polls and scheduler pumps
         open rounds with no kernel, and a per-round cycle would burn
         most of its samples on them."""
         rd = getattr(self._tls, "round", None)
-        if rd is None or not cache_hit:
-            return fn(*args)
-        rd.has_kernel = True
-        if rd.sampled is None:
-            se = self.sample_every
-            rd.sampled = se <= 1 or (next(self._rctr) % se == 0)
-        if not rd.sampled:
-            return fn(*args)
+        if rd is not None and cache_hit:
+            rd.has_kernel = True
+            if rd.sampled is None:
+                se = self.sample_every
+                rd.sampled = se <= 1 or (next(self._rctr) % se == 0)
+        if rd is None or not cache_hit or not rd.sampled:
+            if span is None:
+                return fn(*args)
+            with span("kernel"):    # (call_kernel spans a compile itself)
+                return fn(*args)
+        if span is None:
+            span = _no_span
+        # the probe owns the open phase: a phase-mapped span inside it
+        # (`transfer`) must not count the wait a second time
+        owner, rd.cur_phase = rd.cur_phase, "kernel_compute"
         try:
             import jax
-            t0 = time.perf_counter()
-            args = tuple(_device_put_leaves(a) for a in args)
-            t1 = time.perf_counter()
-            out = fn(*args)
-            out = jax.block_until_ready(out)
+            with span("kernel"):
+                t0 = time.perf_counter()
+                args = tuple(_device_put_leaves(a) for a in args)
+                t1 = time.perf_counter()
+                out = fn(*args)
+            with span("transfer"):
+                out = jax.block_until_ready(out)
             t2 = time.perf_counter()
         except Exception:
             with self._lock:
                 self.probe_failures += 1
             return fn(*args)
+        finally:
+            rd.cur_phase = owner
         rd.add("h2d_upload", t1 - t0)
         # t1..t2 = python dispatch + device execution; the dispatch-call
         # overhead is small vs a blocked kernel and is what this phase
@@ -566,71 +593,6 @@ def _device_put_leaves(x):
     if isinstance(x, list):
         return [_device_put_leaves(v) for v in x]
     return x
-
-
-# ---------------------------------------------------------------------------
-# roofline fold
-# ---------------------------------------------------------------------------
-
-# plan family -> native-C++ roofline family (the bench's native_baseline
-# measures the sequence-pattern and partitioned families; window/join/
-# filter have no native column yet)
-_ROOFLINE_FAMILY = {"scan": "sequence", "dfa": "sequence",
-                    "chunk": "sequence", "seq": "sequence",
-                    "partitioned": "partitioned"}
-
-_roofline_cache: dict = {"loaded": False, "eps": {}}
-
-
-def _native_roofline() -> dict:
-    """{family: native_cpp_eps} from scripts/perf_baseline.json (or
-    $SIDDHI_PERF_BASELINE).  Best-effort: a deployed engine without the
-    repo checkout simply reports no roofline columns."""
-    if _roofline_cache["loaded"]:
-        return _roofline_cache["eps"]
-    eps: dict = {}
-    path = os.environ.get("SIDDHI_PERF_BASELINE")
-    if not path:
-        here = os.path.dirname(os.path.abspath(__file__))
-        path = os.path.join(here, "..", "..", "scripts",
-                            "perf_baseline.json")
-    try:
-        import json
-        with open(path) as f:
-            base = json.load(f)
-        for key, v in (base.get("native_cpp_eps") or {}).items():
-            fam = "sequence" if "sequence" in key else (
-                "partitioned" if "partitioned" in key else key)
-            if isinstance(v, (int, float)) and v > 0:
-                eps[fam] = float(v)
-    except Exception:
-        pass
-    _roofline_cache["loaded"] = True
-    _roofline_cache["eps"] = eps
-    return eps
-
-
-def fold_roofline(rep: dict, plans) -> None:
-    """Attach per-plan roofline columns to a profile() report: kernel
-    eps (from the sampled estimate) vs the native-C++ roofline eps vs
-    end-to-end eps — the bench's roofline math, live."""
-    native = _native_roofline()
-    by_name = {getattr(p, "name", None): p for p in plans}
-    for name, pv in (rep.get("plans") or {}).items():
-        plan = by_name.get(name)
-        fam = getattr(plan, "family", None) if plan is not None else None
-        if fam is None and plan is not None:
-            # fused multi-query wrapper: the family lives on the inner plan
-            fam = getattr(getattr(plan, "inner", None), "family", None)
-        roof = {"plan_family": fam,
-                "kernel_eps": pv.get("kernel_eps"),
-                "end_to_end_eps": pv.get("end_to_end_eps")}
-        nat = native.get(_ROOFLINE_FAMILY.get(fam, fam))
-        if nat:
-            roof["native_cpp_eps"] = nat
-            if pv.get("kernel_eps"):
-                roof["vs_native_cpp"] = round(pv["kernel_eps"] / nat, 3)
-        pv["roofline"] = roof
 
 
 # ---------------------------------------------------------------------------

@@ -26,6 +26,7 @@ from ..utils.locks import new_lock, new_rlock
 from .batch import BatchBuilder, EventBatch
 from .planner import OutputBatch, PlanError, QueryPlan
 from .schema import StreamSchema, StringTable
+from .telemetry import StatisticsManager
 
 
 @dataclass
@@ -302,9 +303,9 @@ class SiddhiAppRuntime:
         # dumps.  `@app:trace('off')` -> None (zero hot-path cost); the
         # thread-local scope hands the active frame's handle across the
         # feed -> freeze -> dispatch -> egress call chain.
-        from .tracing import tracer_from_annotations
+        from .tracing import TraceScope, tracer_from_annotations
         self.tracing = tracer_from_annotations(app)
-        self._trace_tls = threading.local()
+        self._trace_tls = TraceScope()
         # continuous device-time attribution (core/profiler.py): every
         # dispatch round splits its wall into the six-phase taxonomy,
         # kernel/h2d via duty-cycle block_until_ready sampling.
@@ -389,11 +390,11 @@ class SiddhiAppRuntime:
         # the teardown sequence must run once, not interleave
         self._shutdown_mutex = new_lock("SiddhiAppRuntime._shutdown_mutex")
 
-        from .telemetry import StatisticsManager
         self.stats = StatisticsManager(self)
+        self.span = self.stats.span     # THE span primitive (telemetry.SPANS)
         sa = qast.find_annotation(app.annotations, "app:statistics")
         if sa is not None and (sa.element() or "true").lower() != "false":
-            self.stats.enabled = True
+            self.stats.enable(True)
             # keyed elements only: the lone-positional fallback would turn
             # @app:statistics('true') into interval='true'
             rep = next((v for k, v in sa.elements if k == "reporter"), None)
@@ -413,7 +414,7 @@ class SiddhiAppRuntime:
             from ..analysis import strict_check
             strict_check(self)
 
-        with self.stats.stage("plan"):
+        with self.span("plan"):
             self._build()
 
     # -- construction --------------------------------------------------------
@@ -435,6 +436,9 @@ class SiddhiAppRuntime:
             pipe.inject = (lambda p=plan: self.inject("d2h", p.name))
             # the pipeline's blocking pull is the d2h_materialize phase
             pipe.prof = self.profiler
+            # ... and runs under the trace of the frame it was
+            # dispatched for, however many batches later
+            pipe.set_trace = self._set_trace
         self._known_query_names.add(getattr(plan, "callback_name", plan.name))
         for sid in plan.input_streams:
             self._subscribers[sid].append(plan)
@@ -531,8 +535,10 @@ class SiddhiAppRuntime:
                     for ob in p.fire_start(now):
                         self._emit(p, ob)
             self._drain()
-        if self.stats.enabled and self.stats.reporter is not None:
-            self.stats.start_reporting()
+        if self.stats.enabled:
+            self.stats.enable(True)     # re-arm after a shutdown()
+            if self.stats.reporter is not None:
+                self.stats.start_reporting()
         if self._async and self._ingest_thread is None:
             self._start_ingest_worker()
         for s in self.sources:
@@ -795,25 +801,23 @@ class SiddhiAppRuntime:
         return [s for s in self.sources if s.stream_id == stream_id]
 
     def enable_stats(self, on: bool = True) -> None:
-        """Runtime statistics toggle (reference: SiddhiAppRuntime.enableStats:763)."""
-        self.stats.enabled = on
+        """Runtime statistics toggle (reference: SiddhiAppRuntime.enableStats:763).
+        On, every engine span (telemetry.SPANS) is timed into
+        `statistics()["stages"]` and written, as `siddhi:<name>`, into
+        whatever `jax.profiler` trace is running."""
+        self.stats.enable(on)
 
     def statistics(self) -> dict:
         return self.stats.report()
 
     def profile(self, window: Optional[int] = None) -> dict:
         """Device-time attribution report (core/profiler.py): per-plan
-        phase seconds/shares, host-dispatch share, the windowed ring
-        (last `window` snapshots; all when None), and the roofline fold
-        — kernel eps (sampled estimate) vs the bench's native-C++
-        roofline eps vs end-to-end eps per plan family.  `{"mode":
-        "off"}` when `@app:profile('off')` disabled the plane."""
+        phase seconds/shares, host-dispatch share and the windowed ring
+        (last `window` snapshots; all when None).  `{"mode": "off"}`
+        when `@app:profile('off')` disabled the plane."""
         if self.profiler is None:
             return {"mode": "off"}
-        from .profiler import fold_roofline
-        rep = self.profiler.profile(window=window)
-        fold_roofline(rep, self._plans)
-        return rep
+        return self.profiler.profile(window=window)
 
     # -- frame tracing (core/tracing.py) -------------------------------------
 
@@ -821,13 +825,13 @@ class SiddhiAppRuntime:
         """The frame TraceHandle active on THIS thread (None when the
         in-flight work is untraced) — set by the net feed path, the
         dispatch loop's scatter block, and the sink outbox flush."""
-        return getattr(self._trace_tls, "handle", None)
+        return self._trace_tls.handle
 
     def _set_trace(self, h):
         """Install `h` as this thread's active trace; returns the
         previous handle for the caller's finally-restore."""
         tls = self._trace_tls
-        prev = getattr(tls, "handle", None)
+        prev = tls.handle
         tls.handle = h
         return prev
 
@@ -988,7 +992,7 @@ class SiddhiAppRuntime:
         if missing:
             raise ValueError(
                 f"stream {stream_id!r}: send_batch missing columns {missing}")
-        with self.stats.stage("ingest") as _sp:
+        with self.span("ingest") as _sp:
             cols: dict = {}
             to_encode: list = []
             n = None
@@ -1016,8 +1020,7 @@ class SiddhiAppRuntime:
                 cols[a.name] = arr
             if not n:
                 return
-            if self.stats.enabled:   # row count known only at span close
-                _sp.events = n       # (guard: _NOOP is a shared singleton)
+            _sp.events = n      # row count known only at span close
             if timestamps is None:
                 ts = None
             else:
@@ -1029,26 +1032,33 @@ class SiddhiAppRuntime:
                         f"stream {stream_id!r}: {ts.shape[0]} timestamps for "
                         f"{n} rows")
         with self._lock:
-            for name in to_encode:      # shared-table writes: locked
-                # vectorized: the dict is consulted once per DISTINCT value
-                cols[name] = self.strings.encode_many(cols[name])
-            if ts is None:
-                ts = np.full(n, self.now_ms(), dtype=np.int64)
             b = self._builders.get(stream_id)
             if b is None:
                 b = self._builders[stream_id] = BatchBuilder(
                     schema, self.strings, self.batch_capacity)
-            seqs = np.arange(self._seq + 1, self._seq + 1 + n,
-                              dtype=np.int64)
-            self._seq += n
-            if self._playback and timestamps is not None:
-                # advance the event-time clock (row-path advance()) by the
-                # batch MAXIMUM: an unsorted timestamp array must not
-                # rewind event time (ts[-1] could).  Wall-stamped batches
-                # must NOT anchor playback time.
-                self._clock_ms = int(ts.max())
-            b.append_columnar(ts, cols, seqs)
-            batch = self._freeze(stream_id, b)
+
+            # string encoding, sequence stamping and the append are part
+            # of this send's `freeze` span
+            h = self._frame_trace(stream_id)
+            with self.span("freeze", events=n, handle=h, stream=stream_id):
+                for name in to_encode:  # shared-table writes: locked
+                    # vectorized: the dict is consulted once per DISTINCT
+                    # value
+                    cols[name] = self.strings.encode_many(cols[name])
+                if ts is None:
+                    ts = np.full(n, self.now_ms(), dtype=np.int64)
+                seqs = np.arange(self._seq + 1, self._seq + 1 + n,
+                                  dtype=np.int64)
+                self._seq += n
+                if self._playback and timestamps is not None:
+                    # advance the event-time clock (row-path advance())
+                    # by the batch MAXIMUM: an unsorted timestamp array
+                    # must not rewind event time (ts[-1] could).
+                    # Wall-stamped batches must NOT anchor playback time.
+                    self._clock_ms = int(ts.max())
+                b.append_columnar(ts, cols, seqs)
+                batch = self._freeze_traced(stream_id, b, h)
+            self._slo_stamp(stream_id, batch)
             if self._async and self._ingest_q is not None:
                 # async mode: older batches may still sit in the ingest
                 # queue — stage through the same outbox so FIFO holds
@@ -1138,28 +1148,44 @@ class SiddhiAppRuntime:
         append propagates: the frame must not be processed with no
         durable record (the net feed path captures it whole into the
         ErrorStore; direct senders see the error)."""
-        # frame tracing: a net-fed frame carries its handle in the
-        # thread-local scope (producer-stamped or admission-sampled);
-        # anything else — direct sends, REST rows — makes its sampling
-        # decision here, where every externally admitted frame is born
-        h = getattr(self._trace_tls, "handle", None)
+        h = self._frame_trace(stream_id)
+        with self.span("freeze", events=len(b), handle=h, stream=stream_id):
+            batch = self._freeze_traced(stream_id, b, h)
+        self._slo_stamp(stream_id, batch)
+        return batch
+
+    def _frame_trace(self, stream_id: str):
+        """The trace handle of the frame being frozen: a net-fed frame
+        carries its handle in the thread-local scope (producer-stamped
+        or admission-sampled); anything else — direct sends, REST rows —
+        makes its sampling decision here, where every externally
+        admitted frame is born."""
+        h = self._trace_tls.handle
         if h is None and self.tracing is not None:
             h = self.tracing.begin_frame(stream_id)
-        t0f = time.perf_counter() if h is not None else 0.0
+        return h
+
+    def _slo_stamp(self, stream_id: str, batch: EventBatch) -> None:
+        if self.slo is not None:
+            t0 = self._builder_t0.pop(stream_id, None)
+            batch.__dict__["_slo_t0"] = \
+                t0 if t0 is not None else time.perf_counter()
+
+    def _freeze_traced(self, stream_id: str, b: BatchBuilder,
+                       h) -> EventBatch:
         batch = b.freeze_and_clear()
         if h is not None:
             batch.__dict__["_trace"] = h
         if self.wal is not None and not self._wal_replaying:
             try:
-                t0w = time.perf_counter() if h is not None else 0.0
-                seq = self.wal.append(stream_id, batch.timestamps,
-                                      batch.columns, self.strings,
-                                      schema=batch.schema)
-                if h is not None:
+                with self.span("wal.append", handle=h,
+                               stream=stream_id) as sp:
+                    seq = self.wal.append(stream_id, batch.timestamps,
+                                          batch.columns, self.strings,
+                                          schema=batch.schema)
                     # the trace rides the WAL plane's frame identity:
                     # the per-stream durable seq names this frame
-                    h.mark("wal.append", t0w, time.perf_counter() - t0w,
-                          stream=stream_id, seq=seq)
+                    sp.note(seq=seq)
             except BaseException as e:
                 # the builder is already cleared: rows buffered by
                 # EARLIER successful sends ride this frozen batch, so a
@@ -1175,13 +1201,6 @@ class SiddhiAppRuntime:
                 self.stats.on_fault(stream_id, "wal.append")
                 e._wal_captured = True
                 raise
-        if h is not None:
-            h.mark("freeze", t0f, time.perf_counter() - t0f,
-                  stream=stream_id, events=batch.n)
-        if self.slo is not None:
-            t0 = self._builder_t0.pop(stream_id, None)
-            batch.__dict__["_slo_t0"] = \
-                t0 if t0 is not None else time.perf_counter()
         return batch
 
     def _apply_batch_target(self, n: int) -> None:
@@ -1299,38 +1318,39 @@ class SiddhiAppRuntime:
         The net feed path DEFERS delivery past its feed-vs-retire gate
         (thread-local `defer_sink`): a sink retry backoff must never
         stall an undeploy waiting on the gate."""
-        if getattr(self._trace_tls, "defer_sink", 0):
+        if self._trace_tls.defer_sink:
             return                      # the gate holder flushes after
-        prof = self.profiler
         while True:
             try:        # pop-then-use: safe vs the scheduler pump thread
                 fn, events, h = self._sink_outbox.pop(0)
             except IndexError:
                 return
-            _st0 = time.perf_counter() if prof is not None else 0.0
             try:
-                if h is None:
+                n = len(events)
+            except TypeError:
+                n = 0
+            # deliver under the originating frame's trace scope so the
+            # sink's spans land on the right tree even when the flush
+            # happens on the scheduler/ingest thread
+            prev = self._set_trace(h)
+            try:
+                with self.span("sink.publish", events=n, handle=h):
                     fn(events)
-                    continue
-                # deliver under the originating frame's trace scope so
-                # the sink records its publish span on the right tree
-                # even when the flush happens on the scheduler/ingest
-                # thread
-                prev = self._set_trace(h)
-                try:
-                    fn(events)
-                finally:
-                    self._trace_tls.handle = prev
             finally:
-                if prof is not None:
-                    try:
-                        n = len(events)
-                    except TypeError:
-                        n = 0
-                    prof.note("_sink", "sink_egress",
-                              time.perf_counter() - _st0, events=n)
+                self._trace_tls.handle = prev
 
     def _drain(self) -> None:
+        # a batch's trace handle is this thread's active trace from the
+        # moment the batch is popped: its scatter, every plan round and
+        # the finalize rounds it causes record their spans on its tree
+        tls = self._trace_tls
+        prev_tr = tls.handle
+        try:
+            self._drain_traced(tls, prev_tr)
+        finally:
+            tls.handle = prev_tr
+
+    def _drain_traced(self, tls, prev_tr) -> None:
         guard = 0
         prof = self.profiler
         while True:
@@ -1354,10 +1374,7 @@ class SiddhiAppRuntime:
                         pipe.origin = None
                 for plan in self._plans:
                     try:
-                        if prof is not None:
-                            with prof.round(plan.name):
-                                obs = plan.finalize()
-                        else:
+                        with self.span("dispatch", plan=plan.name):
                             obs = plan.finalize()
                     except Exception as e:
                         obs = self._recover_finalize(plan, e)
@@ -1390,11 +1407,12 @@ class SiddhiAppRuntime:
                         h.__dict__["_slo_t0"] = t0b
                 self._pending[:0] = [(sid, h) for h in halves]
                 continue
-            # the stream timer opens a batch-trace scope and feeds the
-            # per-stream latency histogram (one clock read per batch);
-            # a traced frame's id rides into the histogram as the
-            # bucket exemplar (`/metrics` OpenMetrics exemplars)
+            # the stream timer feeds the per-stream latency histogram
+            # (one clock read per batch); a traced frame's id rides into
+            # the histogram as the bucket exemplar (`/metrics`
+            # OpenMetrics exemplars)
             h_tr = batch.__dict__.get("_trace")
+            tls.handle = prev_tr if h_tr is None else h_tr
             # batch wall = the profiler's coverage denominator: rounds +
             # scatter must attribute >= ~90% of this (docs/OBSERVABILITY.md)
             _pt0 = time.perf_counter() if prof is not None else 0.0
@@ -1409,17 +1427,11 @@ class SiddhiAppRuntime:
                     # handle into its outbox entry, so egress spans land
                     # on this frame's tree even though publish happens
                     # later, outside the lock, possibly on another thread
-                    prev_tr = self._set_trace(h_tr) \
-                        if h_tr is not None else None
-                    try:
-                        with self.stats.stage("scatter", events=batch.n):
-                            for cb in cbs_b:
-                                cb(batch)
-                            for cb in cbs_s:  # junction callbacks: each
-                                cb(self._decode(batch))  # gets its own list
-                    finally:
-                        if h_tr is not None:
-                            self._trace_tls.handle = prev_tr
+                    with self.span("scatter", events=batch.n):
+                        for cb in cbs_b:
+                            cb(batch)
+                        for cb in cbs_s:      # junction callbacks: each
+                            cb(self._decode(batch))  # gets its own list
                 fault_err = None
                 subs = self._subscribers.get(sid, ())
                 # dispatch round: every subscribed plan dispatches its
@@ -1435,21 +1447,18 @@ class SiddhiAppRuntime:
                 for plan in subs:
                     if self._debugger is not None:
                         self._debugger.check_in(plan, batch)
-                    t0d = time.perf_counter() if h_tr is not None else 0.0
                     try:
-                        if prof is not None:
-                            with prof.round(plan.name, batch.n):
-                                if self.stats.enabled:
-                                    with self.stats.time_plan(plan.name,
-                                                              batch.n):
-                                        obs = plan.process(sid, batch)
-                                else:
-                                    obs = plan.process(sid, batch)
-                        elif self.stats.enabled:
-                            with self.stats.time_plan(plan.name, batch.n):
-                                obs = plan.process(sid, batch)
-                        else:
+                        # one span: the profiler's round, the per-query
+                        # histogram, the profiler-clock annotation and
+                        # the frame's tree
+                        with self.span("dispatch", plan=plan.name,
+                                       events=batch.n) as sp:
                             obs = plan.process(sid, batch)
+                        # (0.0 unless the span was timed: statistics
+                        # may be switched on from another thread)
+                        if sp.seconds and self.stats.enabled:
+                            self.stats.query[plan.name].observe(
+                                sp.seconds, batch.n)
                     except Exception as e:
                         obs = self._recover_process(plan, sid, batch, e)
                         if obs is None:
@@ -1458,8 +1467,6 @@ class SiddhiAppRuntime:
                             fault_err = e    # route once per batch, below
                             continue
                     if h_tr is not None:
-                        h_tr.mark("dispatch", t0d,
-                                 time.perf_counter() - t0d, plan=plan.name)
                         for ob in obs:
                             # derived emissions inherit the frame's trace
                             # so downstream drains + sink egress stay on
@@ -1471,10 +1478,7 @@ class SiddhiAppRuntime:
                         self._emit(plan, ob)
                 for plan in subs:
                     try:
-                        if prof is not None:
-                            with prof.round(plan.name):
-                                obs = plan.collect_ready()
-                        else:
+                        with self.span("dispatch", plan=plan.name):
                             obs = plan.collect_ready()
                     except Exception as e:
                         # pipelined entries carry their origin batch: a
@@ -1558,12 +1562,9 @@ class SiddhiAppRuntime:
         routing: a pipelined entry that fails to materialize routes the
         batch it was dispatched for (per its stream's @OnError action)
         while later entries keep flowing."""
-        prof = self.profiler
         try:
-            if prof is not None:
-                with prof.round(plan.name):
-                    return getattr(plan, fn_name)()
-            return getattr(plan, fn_name)()
+            with self.span("dispatch", plan=plan.name):
+                return getattr(plan, fn_name)()
         except Exception as e:
             origin = getattr(e, "fault_origin", None)
             if origin is None or not self._handle_batch_fault(
@@ -1886,7 +1887,7 @@ class SiddhiAppRuntime:
             or getattr(plan, "callback_name", plan.name)
         cbs = self._query_callbacks.get(cb_name, ())
         if cbs:
-            with self.stats.stage("scatter", events=ob.batch.n):
+            with self.span("scatter", events=ob.batch.n):
                 ts_last = int(ob.batch.timestamps[-1]) if ob.batch.n else 0
                 for cb in cbs:              # fresh Event list per callback:
                     events = self._decode(ob.batch)   # mutation-safe
@@ -1894,6 +1895,12 @@ class SiddhiAppRuntime:
                         cb(ts_last, None, events)
                     else:
                         cb(ts_last, events, None)
+        with self.span("emit", plan=plan.name, events=ob.batch.n):
+            self._route(plan, ob)
+
+    def _route(self, plan: QueryPlan, ob: OutputBatch) -> None:
+        """Hand one output batch on: table writer, named window, or
+        stamped with global seqs and queued for its target stream."""
         # table targets route through the plan's table writer (reference:
         # OutputParser-chosen Insert/Update/Delete/UpdateOrInsert callbacks)
         if plan.table_writer is not None:

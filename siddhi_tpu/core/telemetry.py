@@ -16,38 +16,53 @@ Layout:
   * `Histogram` — HDR-style fixed log-bucket latency histogram (pure
     python, no deps): 16 sub-buckets per octave over 1 µs..~4000 s, so
     p50/p95/p99 carry <= ~4.5 % relative quantile error at O(1)/record.
-  * `Tracker` — per-(stream|query|stage) counter + histogram.
-  * `PipelineTracer` — span-based flight recorder: a bounded ring of the
-    last N batch traces (lex/parse -> plan -> compile -> host-batch-build
-    -> device-dispatch -> block_until_ready -> callback-scatter), with
-    Chrome `trace_event` JSON export.
+  * `Tracker` — per-(stream|query|span) counter + histogram.
+  * `StatisticsManager.span` — THE span primitive: one `perf_counter`
+    pair per span feeds every sink that is on (the `stages` trackers, a
+    `jax.profiler.TraceAnnotation` on the profiler's clock, the frame's
+    causal `TraceHandle` tree, the phase profiler).  `SPANS` is the
+    taxonomy (docs/OBSERVABILITY.md prints it).
   * `StatisticsManager` — hangs off the runtime's batch dispatch loop;
-    enabled statistics cost one clock read per (stream, plan) batch.
+    enabled statistics cost two clock reads, a locked tracker update and
+    a TraceAnnotation per span.
   * reporter SPI (`register_stats_reporter`) with console / log /
     prometheus reporters; `render_prometheus` emits the text exposition
     served by `service.py`'s `GET /metrics`.
   * `SiddhiDebugger` — micro-batch-boundary breakpoints (unchanged).
 
-Pipeline stage names (the leaf spans; `report()["stages"]`):
-  parse, plan, compile, host_build, ingest, kernel, transfer, scatter.
 `kernel` is the jitted dispatch call (async: it returns once the device
 has the work); `transfer` is block_until_ready + the D2H pull, so on the
 async path it includes the device execution wait.
 """
 from __future__ import annotations
 
+import gc
 import json
 import math
 import os
 import threading
 import time
-from collections import defaultdict, deque
+from collections import defaultdict
+from functools import partial
 from typing import Callable, Optional
 
 import jax.monitoring
+import jax.profiler
 
-STAGES = ("parse", "plan", "compile", "host_build", "ingest", "kernel",
-          "transfer", "scatter")
+from ..utils.locks import new_lock
+
+# every span the engine records, by layer (docs/OBSERVABILITY.md has the
+# table: thread, parent, which sinks record it when)
+SPANS = (
+    "parse", "plan", "compile",                         # build
+    "net.wait", "net.decode", "admit", "queue_wait",    # wire
+    "ingest", "frame", "freeze", "wal.append",          # ingest
+    "dispatch", "host_build", "kernel", "transfer",     # dispatch
+    "unpack", "scatter", "emit",
+    "sink.publish", "sink.encode", "sink.send",         # egress
+    "gc",                                               # process
+)
+SPAN_PREFIX = "siddhi:"     # TraceAnnotation names in a jax.profiler trace
 
 
 # ---------------------------------------------------------------------------
@@ -141,9 +156,13 @@ def _le_label(seconds: float) -> str:
 
 
 class Tracker:
-    __slots__ = ("events", "batches", "seconds", "hist", "exemplars")
+    __slots__ = ("events", "batches", "seconds", "hist", "exemplars",
+                 "_lock")
 
     def __init__(self):
+        # spans close on serve threads and the scheduler pump as well
+        # as the dispatch thread: observe() is locked
+        self._lock = new_lock("Tracker._lock")
         self.events = 0
         self.batches = 0
         self.seconds = 0.0
@@ -157,6 +176,12 @@ class Tracker:
                 trace_id: Optional[str] = None) -> None:
         """One timed batch; a traced frame's id becomes the bucket
         exemplar linking the latency histogram back to its span tree."""
+        with self._lock:
+            self._observe_locked(seconds, events, trace_id)
+
+    def _observe_locked(self, seconds: float, events: int, trace_id) -> None:
+        # (the collector hook calls this bare: it is the only writer of
+        # its tracker and may hold no lock, see `_on_gc`)
         self.events += events
         self.batches += 1
         self.seconds += seconds
@@ -193,6 +218,10 @@ class Tracker:
         return out
 
     def as_dict(self, buckets: bool = False) -> dict:
+        with self._lock:    # a scrape races observe() on other threads
+            return self._as_dict_locked(buckets)
+
+    def _as_dict_locked(self, buckets: bool) -> dict:
         d = {"events": self.events, "batches": self.batches}
         if self.seconds:
             d["seconds"] = self.seconds
@@ -209,106 +238,25 @@ class Tracker:
             if buckets:
                 d["buckets"] = self.bucket_counts()
                 if self.exemplars:
-                    # list() snapshot: a scrape races the dispatch
-                    # thread's first insert into a new coarse bucket
-                    d["exemplars"] = {k: list(v) for k, v in
-                                      list(self.exemplars.items())}
+                    d["exemplars"] = {k: list(v)
+                                      for k, v in self.exemplars.items()}
         return d
 
 
 # ---------------------------------------------------------------------------
-# span tracing / flight recorder
+# the span primitive
 # ---------------------------------------------------------------------------
 
-class PipelineTracer:
-    """Bounded in-memory flight recorder of the last N batch traces.
+class Span:
+    """What `StatisticsManager.span` hands back, whichever sinks are on:
+    a context manager with `seconds` and `t_end` (perf_counter at close;
+    0.0 / None unless the span was timed for statistics or a trace), a
+    settable `events` and `note(**args)`.  This base is the no-op."""
 
-    A "batch trace" is the list of stage spans recorded while one
-    micro-batch moved through the dispatch loop; spans recorded outside
-    a batch scope (parse/plan/compile at build time) become standalone
-    one-span traces.  Span nesting is positional — Chrome's trace viewer
-    reconstructs parent/child from (ts, dur) containment per thread, so
-    the recorder stores flat (name, t0, dur, plan) tuples."""
-
-    def __init__(self, capacity: int = 256):
-        self.capacity = capacity
-        self.enabled = False
-        self.traces: deque = deque(maxlen=capacity)
-        self._tls = threading.local()
-        self._t0 = time.perf_counter()
-
-    # -- batch scope -------------------------------------------------------
-
-    def begin_batch(self, label: str) -> None:
-        if not self.enabled:
-            return
-        self._tls.spans = []
-        self._tls.label = label
-        self._tls.bt0 = time.perf_counter()
-
-    def end_batch(self) -> None:
-        if not self.enabled:
-            return
-        spans = getattr(self._tls, "spans", None)
-        if spans is None:
-            return
-        now = time.perf_counter()
-        self.traces.append({
-            "label": self._tls.label,
-            "t0": self._tls.bt0 - self._t0,
-            "dur": now - self._tls.bt0,
-            "tid": threading.get_ident() % 100_000,
-            "spans": spans,
-        })
-        self._tls.spans = None
-
-    def add(self, name: str, t0: float, dur: float,
-            plan: Optional[str] = None) -> None:
-        if not self.enabled:
-            return
-        rec = (name, t0 - self._t0, dur, plan)
-        spans = getattr(self._tls, "spans", None)
-        if spans is None:            # standalone span (build-time etc.)
-            self.traces.append({
-                "label": name, "t0": t0 - self._t0, "dur": dur,
-                "tid": threading.get_ident() % 100_000, "spans": [rec]})
-        else:
-            spans.append(rec)
-
-    # -- export ------------------------------------------------------------
-
-    def chrome_trace(self) -> list:
-        """Chrome `trace_event` JSON (the array form): load via
-        chrome://tracing or https://ui.perfetto.dev."""
-        evs = []
-        for tr in list(self.traces):
-            evs.append({"name": tr["label"], "cat": "batch", "ph": "X",
-                        "ts": round(tr["t0"] * 1e6, 1),
-                        "dur": round(tr["dur"] * 1e6, 1),
-                        "pid": 1, "tid": tr["tid"]})
-            for name, t0, dur, plan in tr["spans"]:
-                ev = {"name": name, "cat": "stage", "ph": "X",
-                      "ts": round(t0 * 1e6, 1), "dur": round(dur * 1e6, 1),
-                      "pid": 1, "tid": tr["tid"]}
-                if plan:
-                    ev["args"] = {"plan": plan}
-                evs.append(ev)
-        return evs
-
-    def export_chrome_trace(self, path: str) -> int:
-        evs = self.chrome_trace()
-        tmp = path + ".tmp"
-        with open(tmp, "w") as f:
-            json.dump(evs, f)
-        os.replace(tmp, path)
-        return len(evs)
-
-    def reset(self) -> None:
-        self.traces.clear()
-
-
-class _Noop:
+    __slots__ = ()
     seconds = 0.0
+    t_end = None
+    events = property(lambda self: 0, lambda self, n: None)
 
     def __enter__(self):
         return self
@@ -316,56 +264,78 @@ class _Noop:
     def __exit__(self, *exc):
         return False
 
+    def note(self, **args) -> None:
+        pass
 
-_NOOP = _Noop()
+
+NOOP_SPAN = Span()      # the shared off path: no clock read, no state
 
 
-class _StageTimer:
-    __slots__ = ("mgr", "name", "events", "plan", "t0", "seconds",
-                 "_pspan")
+class _Span(Span):
+    """One open span: ONE `perf_counter` pair feeds every sink that is
+    on — the `stages` tracker and a `jax.profiler.TraceAnnotation`
+    (statistics enabled), the frame's `TraceHandle` tree (the frame is
+    traced), and the piggy-backed profiler phase/round.  Built by
+    `StatisticsManager.span` only."""
 
-    def __init__(self, mgr, name, events, plan, pspan=None):
+    __slots__ = ("mgr", "name", "plan", "events", "handle", "args", "t0",
+                 "t_end", "seconds", "_pspan", "_ann", "_sid", "_parent")
+
+    def __init__(self, mgr, name, plan, events, handle, pspan, t0, args):
         self.mgr = mgr
         self.name = name
-        self.events = events
         self.plan = plan
+        self.events = events
+        self.handle = handle
+        self.args = args
+        self.t0 = t0            # not None: the interval started earlier
+        self.t_end = None
         self.seconds = 0.0
-        # piggy-backed profiler phase span (core/profiler.py): stages
-        # that map onto a dispatch phase record both from one timer
         self._pspan = pspan
+        self._ann = None
+
+    def note(self, **args) -> None:
+        """Arguments known only inside the span (a WAL seq, an admission
+        verdict): recorded on the frame's tree."""
+        self.args.update(args)
 
     def __enter__(self):
         if self._pspan is not None:
             self._pspan.__enter__()
-        self.t0 = time.perf_counter()
+        h = self.handle
+        if h is not None:
+            self._sid, self._parent = h.open()
+        if self.mgr.enabled:
+            kw = dict(self.args)
+            if self.plan is not None:
+                kw["plan"] = self.plan
+            if h is not None:
+                kw["trace"] = h.trace_id
+            self._ann = jax.profiler.TraceAnnotation(
+                SPAN_PREFIX + self.name, **kw)
+            self._ann.__enter__()
+        now = time.perf_counter()
+        if self.t0 is None:
+            self.t0 = now
         return self
 
     def __exit__(self, *exc):
-        dt = time.perf_counter() - self.t0
-        self.seconds = dt
-        self.mgr.stages[self.name].observe(dt, self.events)
-        self.mgr.tracer.add(self.name, self.t0, dt, plan=self.plan)
+        self.t_end = now = time.perf_counter()
+        self.seconds = dt = now - self.t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self.mgr.stage_tracker(self.name).observe(dt, self.events)
+        h = self.handle
+        if h is not None:
+            args = self.args
+            if self.plan is not None:
+                args["plan"] = self.plan
+            if self.events:
+                args["events"] = self.events
+            h.close(self._sid, self._parent, self.name, self.t0, dt,
+                    args or None)
         if self._pspan is not None:
             self._pspan.__exit__(*exc)
-        return False
-
-
-class _PlanTimer:
-    __slots__ = ("mgr", "name", "n", "start")
-
-    def __init__(self, mgr, name, n):
-        self.mgr = mgr
-        self.name = name
-        self.n = n
-
-    def __enter__(self):
-        self.start = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        dt = time.perf_counter() - self.start
-        self.mgr.query[self.name].observe(dt, self.n)
-        self.mgr.tracer.add(f"query:{self.name}", self.start, dt)
         return False
 
 
@@ -379,7 +349,6 @@ class _StreamTimer:
         self.trace_id = trace_id
 
     def __enter__(self):
-        self.mgr.tracer.begin_batch(f"{self.sid} x{self.n}")
         self.start = time.perf_counter()
         return self
 
@@ -387,8 +356,54 @@ class _StreamTimer:
         dt = time.perf_counter() - self.start
         self.mgr.stream_in[self.sid].observe(dt, self.n,
                                              trace_id=self.trace_id)
-        self.mgr.tracer.end_batch()
         return False
+
+
+# ---------------------------------------------------------------------------
+# the `gc` span: ONE hook per process
+# ---------------------------------------------------------------------------
+
+# A collection starts inside whatever allocation tripped it, under any
+# lock that thread holds (a Tracker's, while `as_dict` builds its dict).
+# So the hook takes no lock: it writes each watching manager's `_gc`
+# tracker through `_observe` (collections are serialised by the
+# interpreter: one writer) and marks the thread's open frame tree, which
+# is a deque append.
+_gc_lock = new_lock("telemetry._gc_lock")   # the watcher list; never the hook
+_gc_watchers: tuple = ()    # managers with statistics on (replaced whole)
+_gc_open = None             # (t0, annotation) of the collection under way
+
+
+def _watch_gc(mgr, on: bool) -> None:
+    global _gc_watchers
+    with _gc_lock:
+        rest = tuple(m for m in _gc_watchers if m is not mgr)
+        _gc_watchers = rest + (mgr,) if on else rest
+        hooked = _on_gc in gc.callbacks
+        if _gc_watchers and not hooked:
+            gc.callbacks.append(_on_gc)
+        elif hooked and not _gc_watchers:
+            gc.callbacks.remove(_on_gc)
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    # start and stop run back to back on the thread that tripped the
+    # collector: one slot is enough
+    global _gc_open
+    if phase == "start":
+        ann = jax.profiler.TraceAnnotation(SPAN_PREFIX + "gc",
+                                           generation=info["generation"])
+        ann.__enter__()
+        _gc_open = (time.perf_counter(), ann)
+    elif _gc_open is not None:
+        now = time.perf_counter()
+        (t0, ann), _gc_open = _gc_open, None
+        ann.__exit__(None, None, None)
+        for mgr in _gc_watchers:    # each app reports the process's pauses
+            mgr._gc._observe_locked(now - t0, 0, None)
+            h = mgr.rt._trace_tls.handle
+            if h is not None:
+                h.mark("gc", t0, now - t0, generation=info["generation"])
 
 
 # ---------------------------------------------------------------------------
@@ -432,29 +447,30 @@ def call_kernel(stats, plan: str, fn, args: tuple, *, cache_hit: bool,
     block compiled while stats were off is never misreported as a
     compile after `enable_stats(True)`.
 
-    `prof` (core/profiler.py PhaseProfiler, or None) routes the call
-    through the sampled h2d/kernel probe and records H2D bytes into the
-    phase plane.  Note: on a *sampled* round the stats `kernel` span
-    includes the probe's block_until_ready (full device wait), where
-    the steady-state span measures only the async dispatch — the
-    profiler's kernel_compute estimate is the authoritative device
-    time; the stage histogram keeps its dispatch-latency meaning for
-    the 31-in-32 unsampled majority."""
+    `prof` (core/profiler.py PhaseProfiler, or None) routes a warm
+    call through the sampled h2d/kernel probe and records H2D bytes into
+    the phase plane.  The `kernel` span is the upload and the async
+    dispatch on every round; on a *sampled* round the probe's
+    block_until_ready (the full device wait) is a `transfer` span of its
+    own."""
     if prof is not None and nbytes:
         prof.note_bytes(plan, "h2d", nbytes)
-    if stats is None or not stats.enabled:
+    if stats is None or not (stats.enabled or stats.traced()):
         if prof is not None:
             return prof.run_kernel(fn, args, cache_hit=cache_hit)
         return fn(*args)
-    stats.on_kernel_cache(plan, cache_hit)
+    stats.on_kernel_cache(plan, cache_hit)      # (counted only when enabled)
     if nbytes:
         stats.add_transfer_bytes(plan, nbytes)
-    with stats.stage("kernel" if cache_hit else "compile", plan=plan) as sp:
-        out = prof.run_kernel(fn, args, cache_hit=cache_hit) \
-            if prof is not None else fn(*args)
-    if not cache_hit:
+    if not cache_hit:                           # a compile is never probed
+        with stats.span("compile", plan=plan) as sp:
+            out = fn(*args)
         stats.on_compile(plan, sp.seconds)
-    return out
+        return out
+    if prof is None:
+        with stats.span("kernel", plan=plan):
+            return fn(*args)
+    return prof.run_kernel(fn, args, span=partial(stats.span, plan=plan))
 
 
 # ---------------------------------------------------------------------------
@@ -1019,7 +1035,7 @@ class StatisticsManager:
         self.enabled = False
         self.stream_in: dict = defaultdict(Tracker)
         self.query: dict = defaultdict(Tracker)
-        self.stages: dict = defaultdict(Tracker)
+        self.stages: dict = {}       # span name -> Tracker (stage_tracker)
         self.device: dict = defaultdict(lambda: defaultdict(float))
         # fault dispositions per stream/scope (ALWAYS counted — faults
         # are rare and must be visible even with statistics off)
@@ -1028,7 +1044,7 @@ class StatisticsManager:
         # on `enabled`): the queryable-state plane is its own surface
         # (REST + wire QUERY frames) and its p99 is an SLO input
         self.store_query = Tracker()
-        self.tracer = PipelineTracer()
+        self._gc = Tracker()         # the `gc` span (written by `_on_gc`)
         self._t0 = time.perf_counter()
         self.reporter = None
         self.interval_s: float = 5.0
@@ -1061,6 +1077,9 @@ class StatisticsManager:
         self._rep_thread.start()
 
     def stop_reporting(self) -> None:
+        """Runtime shutdown: stop the reporter pump and unhook the
+        process-wide gc callback (start() re-arms it via enable())."""
+        _watch_gc(self, False)
         if self._rep_stop is not None:
             self._rep_stop.set()
             self._rep_thread.join(timeout=2)
@@ -1076,43 +1095,76 @@ class StatisticsManager:
 
     # -- recording hooks -----------------------------------------------------
 
+    def enable(self, on: bool = True) -> None:
+        """The statistics toggle.  On also puts this manager among the
+        watchers of the process's one `gc.callbacks` hook (`gc` span: a
+        collector pause is a stall no other span can name); off takes it
+        out, and the last one out removes the hook."""
+        self.enabled = bool(on)
+        _watch_gc(self, self.enabled)
+
     def time_stream(self, sid: str, n: int, trace_id=None):
         """Times one micro-batch's full pass through the dispatch loop
-        (callbacks + every subscribed plan) and opens a batch-trace
-        scope; a traced frame's id rides into the latency histogram as
-        the bucket exemplar."""
+        (callbacks + every subscribed plan); a traced frame's id rides
+        into the latency histogram as the bucket exemplar."""
         if not self.enabled:
-            return _NOOP
+            return NOOP_SPAN
         return _StreamTimer(self, sid, n, trace_id)
 
-    def time_plan(self, name: str, n: int):
-        """Context manager timing one plan.process batch."""
-        return _PlanTimer(self, name, n)
+    # spans that map onto the device-time profiler (core/profiler.py):
+    # one timer records both planes.  (phase, pseudo-plan that takes the
+    # attribution outside any dispatch round)
+    _SPAN_PHASE = {"host_build": ("host_pack_unpack", "_runtime"),
+                   "unpack": ("host_pack_unpack", "_runtime"),
+                   "scatter": ("host_pack_unpack", "_runtime"),
+                   "transfer": ("d2h_materialize", "_runtime"),
+                   "sink.publish": ("sink_egress", "_sink")}
 
-    # pipeline stages that map onto a dispatch phase of the device-time
-    # profiler (core/profiler.py): one timer records both planes
-    _STAGE_PHASE = {"host_build": "host_pack_unpack",
-                    "transfer": "d2h_materialize",
-                    "scatter": "host_pack_unpack"}
+    def span(self, name: str, *, plan: Optional[str] = None, events: int = 0,
+             handle=None, t0: Optional[float] = None, **args):
+        """Context manager round one span of `SPANS` — the ONE way the
+        engine records time.  Sinks, each fed from the same clock pair:
+        statistics enabled -> `report()["stages"][name]` and a
+        `siddhi:<name>` TraceAnnotation in whatever jax.profiler trace
+        is running (this thread, the device's clock); a traced frame
+        (`handle`, else the thread's active trace) -> the frame's causal
+        tree; a profiler phase (`_SPAN_PHASE`; `dispatch` opens the
+        plan's round) whenever the profiler exists.  With none of them
+        on this returns the shared `NOOP_SPAN`: no object, no clock read.
+        `t0` backdates the interval (a wait that began on another
+        thread); `args` annotate the tree's span."""
+        rt = self.rt
+        prof = rt.profiler
+        pspan = None
+        if prof is not None:
+            if name == "dispatch":
+                pspan = prof.round(plan, events)
+            else:
+                ph = self._SPAN_PHASE.get(name)
+                if ph is not None:
+                    pspan = prof.phase(ph[0], ph[1], events)
+        if handle is None:
+            handle = rt._trace_tls.handle   # (a class default when unset)
+        if handle is None and not self.enabled:
+            return NOOP_SPAN if pspan is None else pspan
+        return _Span(self, name, plan, events, handle, pspan, t0, args)
 
-    def stage(self, name: str, events: int = 0, plan: Optional[str] = None):
-        """Context manager timing one pipeline-stage span.  Stages that
-        map onto a profiler phase keep recording into the phase plane
-        even with statistics disabled (the profiler is its own knob)."""
-        prof = getattr(self.rt, "profiler", None)
-        phase = self._STAGE_PHASE.get(name) if prof is not None else None
-        if not self.enabled:
-            return prof.phase(phase) if phase is not None else _NOOP
-        return _StageTimer(self, name, events, plan,
-                           pspan=None if phase is None
-                           else prof.phase(phase))
+    def traced(self) -> bool:
+        """Is a frame's trace active on this thread?"""
+        return self.rt._trace_tls.handle is not None
+
+    def stage_tracker(self, name: str) -> Tracker:
+        # setdefault is atomic: two threads closing the first span of a
+        # name never end up with a tracker each
+        t = self.stages.get(name)
+        return t if t is not None else self.stages.setdefault(name, Tracker())
 
     def note_stage(self, name: str, seconds: float, events: int = 0) -> None:
         """Record an already-measured span (parse time measured before
         the runtime — and its stats manager — existed)."""
         if not self.enabled:
             return
-        self.stages[name].observe(seconds, events)
+        self.stage_tracker(name).observe(seconds, events)
 
     def observe_store_query(self, seconds: float, rows: int,
                             trace=None) -> None:
@@ -1203,6 +1255,8 @@ class StatisticsManager:
             "queries": {k: v.as_dict() for k, v in list(self.query.items())},
             "stages": {k: v.as_dict() for k, v in list(self.stages.items())},
         }
+        if self._gc.batches:
+            rep["stages"]["gc"] = self._gc.as_dict()
         dev = self.device_report()
         if dev:
             rep["device"] = dev
@@ -1330,17 +1384,12 @@ class StatisticsManager:
         return render_prometheus({self.rt.app.name: self.report()},
                                  openmetrics=openmetrics)
 
-    def export_chrome_trace(self, path: str) -> int:
-        """Write the flight recorder as Chrome trace_event JSON; returns
-        the event count."""
-        return self.tracer.export_chrome_trace(path)
-
     def reset(self) -> None:
         self.stream_in.clear()
         self.query.clear()
         self.stages.clear()
+        self._gc = Tracker()
         self.device.clear()
-        self.tracer.reset()
         self._t0 = time.perf_counter()
 
 

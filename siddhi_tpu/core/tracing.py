@@ -1,22 +1,26 @@
 """End-to-end frame tracing — causal cross-thread span trees.
 
-The PR-1 `PipelineTracer` records thread-local per-batch spans, which
-breaks at every thread hand-off of the serving path (net reader ->
-admission park -> WAL append -> dispatch pipeline -> scheduler-pump
-materialization -> sink egress).  This module is the causal plane that
-survives the hops: one ingested frame yields ONE trace — a tree of
-spans linked by explicit (trace_id, span_id, parent_id) edges, no
-matter which `siddhi-*` thread recorded each span.
+A thread's own clock breaks at every thread hand-off of the serving
+path (net reader -> admission park -> WAL append -> dispatch pipeline
+-> scheduler-pump materialization -> sink egress).  This module is the
+causal plane that survives the hops: one ingested frame yields ONE
+trace — a tree of spans linked by explicit (trace_id, span_id,
+parent_id) edges, no matter which `siddhi-*` thread recorded each span.
+It is one SINK of the engine's span primitive
+(`telemetry.StatisticsManager.span`), which records into it whenever
+the frame carries a handle.
 
 Pieces:
 
   * `TraceHandle` — the per-frame carrier.  It rides the `Work` unit
     through admission, the frozen `EventBatch` through dispatch and the
-    `DispatchPipeline`, and the sink outbox to egress.  `mark()` records
-    one span parented on the handle's current head and advances the
-    head, so the recorded spans form a causal chain/tree
-    (admit -> wal.append -> freeze -> dispatch -> materialize ->
-    sink.publish) with no orphans.
+    `DispatchPipeline`, and the sink outbox to egress.  A span parents
+    on the handle's current head — the span it is nested in, else the
+    stage that finished before it — and becomes the head, so the
+    recorded spans form one causal tree (net.decode -> admit ->
+    queue_wait -> freeze[wal.append] -> dispatch[host_build -> kernel
+    -> transfer -> unpack] -> sink.publish[sink.encode -> sink.send])
+    with no orphans.
   * `FrameTracer` — the per-runtime recorder: a bounded always-on ring
     of completed spans (cheap: one deque append per span), sampling
     (`@app:trace(sample='N')` — 1 in N server-assigned frames gets a
@@ -30,11 +34,10 @@ Pieces:
     `trace_event` JSON (with hostname metadata) to the configured dir.
     Per-kind cooldown bounds dump churn.
 
-The overhead contract (docs/OBSERVABILITY.md): tracing off
-(`@app:trace('off')` -> `rt.tracing is None`) or on-but-unsampled
-costs <= 5 % of config-3 TCP-ingest eps — the unsampled hot path is
-one counter increment and a modulo per frozen frame, and every other
-hook is gated on a `None` handle check.
+Cost (docs/OBSERVABILITY.md has the chip reading): tracing off
+(`@app:trace('off')` -> `rt.tracing is None`) or on-but-unsampled, the
+hot path is one counter increment and a modulo per frozen frame, and
+every other hook is gated on a `None` handle check.
 """
 from __future__ import annotations
 
@@ -62,9 +65,14 @@ TRIGGER_KINDS = (
                           # above @app:hostShareAlert — the profile dump
 )
 
-# span names the engine records (docs/OBSERVABILITY.md span taxonomy)
-SPAN_NAMES = ("frame", "admit", "wal.append", "freeze", "dispatch",
-              "materialize", "sink.publish")
+
+class TraceScope(threading.local):
+    """`rt._trace_tls`: the frame trace active on this thread (`handle`)
+    and the net feed path's deferred-sink depth.  Class defaults, so a
+    thread that never set one reads None / 0 at plain attribute cost."""
+
+    handle = None
+    defer_sink = 0
 
 
 class TraceHandle:
@@ -81,12 +89,25 @@ class TraceHandle:
         self.trace_id = trace_id
         self.head = head
 
-    def mark(self, name: str, t0: float, dur: float, **args) -> int:
-        """Record one completed span (t0 = perf_counter at start) as a
-        child of the current head; the new span becomes the head."""
-        sid = self.tracer._record(self.trace_id, self.head, name, t0, dur,
-                                  args or None)
+    def open(self) -> tuple:
+        """Start a span: (its id, its parent's).  It is the head while
+        open, so spans recorded inside it are its children."""
+        sid, parent = next(self.tracer._span_ids), self.head
         self.head = sid
+        return sid, parent
+
+    def close(self, sid: int, parent: int, name: str, t0: float,
+              dur: float, args: Optional[dict]) -> None:
+        """Record the span `open` started (t0 = perf_counter at its
+        start); it stays the head: the next stage parents on it."""
+        self.tracer._record(self.trace_id, sid, parent, name, t0, dur, args)
+        self.head = sid
+
+    def mark(self, name: str, t0: float, dur: float, **args) -> int:
+        """Record one already-timed span as a child of the current head
+        (the zero-length `frame` root; replayed spans)."""
+        sid, parent = self.open()
+        self.close(sid, parent, name, t0, dur, args or None)
         return sid
 
     def ctx(self) -> tuple:
@@ -175,13 +196,11 @@ class FrameTracer:
         payload replay, cross-hop continuations)."""
         return TraceHandle(self, str(trace_id), int(head))
 
-    def _record(self, trace_id: str, parent: int, name: str, t0: float,
-                dur: float, args: Optional[dict]) -> int:
-        sid = next(self._span_ids)
+    def _record(self, trace_id: str, sid: int, parent: int, name: str,
+                t0: float, dur: float, args: Optional[dict]) -> None:
         self._ring.append((trace_id, sid, parent, name,
                            t0 - self._epoch, dur,
                            threading.current_thread().name, args))
-        return sid
 
     # -- read side -----------------------------------------------------------
 

@@ -925,7 +925,7 @@ class DeviceWindowAggPlan(QueryPlan):
     def process(self, stream_id: str, batch: EventBatch) -> list:
         if batch.n == 0:
             return []
-        with self.rt.stats.stage("host_build", plan=self.name):
+        with self.rt.span("host_build", plan=self.name):
             T = pow2_at_least(batch.n)
             if self.mesh is not None:
                 # the sharded 't' axis must divide the device count
@@ -981,7 +981,7 @@ class DeviceWindowAggPlan(QueryPlan):
         bpack = None
         while True:
             res = entry["res"]
-            with self.rt.stats.stage("transfer", plan=self.name):
+            with self.rt.span("transfer", plan=self.name):
                 if slim:
                     bpack = np.asarray(res["b"])
                     overflow = int(bpack[0])
@@ -998,7 +998,7 @@ class DeviceWindowAggPlan(QueryPlan):
                       for e in chain]
             entry = redone[0]
             self._pipe.requeue(redone[1:])
-        with self.rt.stats.stage("transfer", plan=self.name):
+        with self.rt.span("transfer", plan=self.name):
             ipack = np.asarray(res["i"]) if "i" in res else None
             fpack = np.asarray(res["f"]) if "f" in res else None
         batch = entry["batch"]

@@ -65,7 +65,7 @@ def generate_markdown() -> str:
     from .core.expr import SCALAR_FUNCTIONS
     from .core.io import SINK_MAPPERS, SINK_TYPES, SOURCE_MAPPERS, SOURCE_TYPES
     from .core.record_table import STORE_TYPES
-    from .core.stats import REPORTERS
+    from .core.telemetry import REPORTERS
     from .interp.expr import PY_FUNCTIONS
     from .interp.engine import STREAM_FUNCTIONS, WINDOW_TYPES
     from .interp.aggregators import AGGREGATOR_CLASSES

@@ -104,6 +104,9 @@ class Work:
     rows: Callable[[], list]
     stream_id: str = ""
     trace: object = None
+    # perf_counter at the end of its `admit` span, when that was timed:
+    # where its `queue_wait` span (park + runtime gate) starts
+    t_admit: Optional[float] = None
 
 
 @dataclass

@@ -38,6 +38,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
+from ..core.telemetry import NOOP_SPAN
 from ..utils.locks import new_lock, new_rlock
 from . import frame as fp
 from .admission import ADMIT, AdmissionController, Work
@@ -102,15 +103,17 @@ class TcpWire:
     def write(self, data: bytes) -> None:
         self.sock.sendall(data)
 
-    def poll(self) -> list:
+    def poll(self, wait=NOOP_SPAN) -> list:
         """Complete frames available now (possibly []); raises
         EOFError/OSError when the connection dies.  Blocks at most one
-        socket-timeout interval."""
+        socket-timeout interval, inside `wait` (the connection's
+        `net.wait` span: nothing buffered, waiting for the producer)."""
         frames = fp.parse_buffer_inplace(self._buf)
         if frames:
             return frames
         try:
-            b = self.sock.recv(1 << 16)
+            with wait:
+                b = self.sock.recv(1 << 16)
         except socket.timeout:
             return []
         if not b:
@@ -158,13 +161,14 @@ class WsWire:
             elif opcode != 0xA:               # binary/text/continuation
                 self._stream_buf += body
 
-    def poll(self) -> list:
+    def poll(self, wait=NOOP_SPAN) -> list:
         self._unwrap()
         frames = fp.parse_buffer_inplace(self._stream_buf)
         if frames:
             return frames
         try:
-            b = self.sock.recv(1 << 16)
+            with wait:
+                b = self.sock.recv(1 << 16)
         except socket.timeout:
             return []
         if not b:
@@ -237,6 +241,11 @@ class Connection:
         # producer-stamped trace context (TRACE frame) for the NEXT
         # DATA frame on this connection
         self._next_trace = None
+
+    def wait_span(self):
+        """The `net.wait` span of this connection's next blocking read
+        (`NOOP_SPAN` before HELLO binds a runtime)."""
+        return NOOP_SPAN if self.rt is None else self.rt.span("net.wait")
 
     # -- frame dispatch -----------------------------------------------------
 
@@ -484,12 +493,6 @@ class Connection:
             # serve loop accounts a protocol error instead of the
             # RuntimeError escaping and killing the thread unhandled
             raise fp.FrameDesync(f"decode fault: {e}") from e
-        ts, cols = fp.decode_data(payload, self.schema)
-        for name in self._str_cols:     # one gather per string column
-            cols[name] = self.remap.apply(cols[name])
-        n = int(ts.shape[0])
-        self.frames += 1  # lint: unlocked-ok (single serve-thread writer; _wlock only serializes wire writes)
-        self.events += n  # lint: unlocked-ok (single serve-thread writer; _wlock only serializes wire writes)
         # frame tracing: a producer-stamped id (TRACE frame) always
         # traces; otherwise the runtime tracer makes the sampling call.
         # The handle rides the Work so a parked ('oldest') frame fed
@@ -501,16 +504,22 @@ class Connection:
             h = tracer.begin_frame(
                 self.stream_id, trace_id=None if tc is None else tc[0],
                 parent=0 if tc is None else tc[1])
+        with rt.span("net.decode", handle=h, bytes=len(payload)):
+            ts, cols = fp.decode_data(payload, self.schema)
+            for name in self._str_cols:     # one gather per string column
+                cols[name] = self.remap.apply(cols[name])
+        n = int(ts.shape[0])
+        self.frames += 1  # lint: unlocked-ok (single serve-thread writer; _wlock only serializes wire writes)
+        self.events += n  # lint: unlocked-ok (single serve-thread writer; _wlock only serializes wire writes)
         work = self.server.make_work(rt, self.stream_id, self.schema,
                                      ts, cols, len(payload), trace=h)
-        t0a = time.perf_counter() if h is not None else 0.0
-        d = self.ctrl.submit(work, stop=self.server.stopping)
-        if h is not None:
-            # the admit span covers the admission decision including
-            # any block-policy wait; a parked frame's queue time shows
-            # as the gap between admit and its (later) wal.append
-            h.mark("admit", t0a, time.perf_counter() - t0a,
-                  action=d.action, events=n)
+        # the admit span covers the admission decision including any
+        # block-policy wait; the park of a queued frame and the wait for
+        # the runtime gate are its queue_wait span, which starts here
+        with rt.span("admit", handle=h, events=n) as sp:
+            d = self.ctrl.submit(work, stop=self.server.stopping)
+            sp.note(action=d.action)
+        work.t_admit = sp.t_end
         for w in d.ready:
             # guarded: queued work is mixed-provenance (REST batches
             # share the controller and their feeds can raise, e.g. a
@@ -672,9 +681,11 @@ class NetServer:
             # sink retry backoff sleeping under the gate would stall
             # retire()/undeploy for the whole backoff schedule
             tls = rt._trace_tls
-            tls.defer_sink = getattr(tls, "defer_sink", 0) + 1
+            tls.defer_sink += 1
             try:
-                with gate:
+                with rt.span("queue_wait", t0=work.t_admit):
+                    gate.acquire()
+                try:
                     store = getattr(rt, "_net_retired_store", None)
                     if store is not None:
                         store.add(stream_id, "net.undeployed",
@@ -696,6 +707,8 @@ class NetServer:
                                 events=rows_of_columns(schema, ts, cols,
                                                        rt.strings))
                         rt.stats.on_fault(stream_id, "net.feed")
+                finally:
+                    gate.release()
             finally:
                 tls.defer_sink -= 1
             rt._flush_sink_outbox()
@@ -714,10 +727,11 @@ class NetServer:
                 finally:
                     rt._trace_tls.handle = prev
 
-        return Work(n=int(ts.shape[0]), nbytes=nbytes, feed=feed,
+        work = Work(n=int(ts.shape[0]), nbytes=nbytes, feed=feed,
                     rows=lambda: rows_of_columns(schema, ts, cols,
                                                  rt.strings),
                     stream_id=stream_id, trace=trace)
+        return work
 
     # -- lifecycle ----------------------------------------------------------
 
@@ -820,8 +834,8 @@ class NetServer:
             sock.settimeout(0.2)
             conn = Connection(self, label, send=wire.write)
             while not self._stop.is_set():
-                frames = wire.poll()    # buffer-based: a timeout mid-
-                if not frames:          # frame can never desync
+                frames = wire.poll(conn.wait_span())  # buffer-based: a
+                if not frames:      # timeout mid-frame can never desync
                     conn.pump()
                     continue
                 for ftype, payload in frames:
@@ -899,7 +913,8 @@ class NetServer:
 
         def loop():
             while not self._stop.is_set():
-                data = ring.pop(timeout=0.1)
+                with conn.wait_span():
+                    data = ring.pop(timeout=0.1)
                 if data is None:
                     conn.pump()
                     continue

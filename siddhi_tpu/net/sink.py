@@ -193,7 +193,8 @@ class TcpSink(Sink):
     def _encode_events(self, events: list) -> bytes:
         """Events -> one self-contained frame blob (delta + DATA).
         Columnarizes ONCE per batch — no per-event wire work."""
-        with self._io_lock:
+        with self.rt.span("sink.encode", events=len(events)), \
+                self._io_lock:
             return self._encode_events_locked(events)
 
     def _encode_events_locked(self, events: list) -> bytes:

@@ -70,7 +70,7 @@ Endpoints (JSON unless noted):
                                     (docs/OBSERVABILITY.md "Device-time
                                     profiling"): per-plan phase shares,
                                     host-dispatch share, windowed ring
-                                    (last <n> snapshots), roofline fold
+                                    (last <n> snapshots)
   GET  /siddhi/artifact/tuning[?siddhiApp=<name>]
                                     the persisted execution-geometry tuning
                                     cache (docs/AUTOTUNING.md): entries +
@@ -779,8 +779,8 @@ class SiddhiService:
                 window: Optional[int] = None) -> dict:
         """GET /siddhi/artifact/profile: the device-time attribution
         plane (docs/OBSERVABILITY.md "Device-time profiling") — per-plan
-        phase seconds/shares, host-dispatch share, windowed ring, and
-        the roofline fold, for every deployed app (or just `app`).
+        phase seconds/shares, host-dispatch share and windowed ring, for
+        every deployed app (or just `app`).
         `window` limits each app's ring to its last N snapshots."""
         names = [app] if app is not None else sorted(self.runtimes)
         return {"apps": {n: self.runtimes[n].profile(window=window)

@@ -38,9 +38,7 @@ def test_print_summary_oversize_degrades_to_parseable(capsys):
          "roofline": {"a": list(range(200))},
          "transport": {"b": "y" * 500},
          "placement": {"c": "z" * 300},
-         "durability": {"d": "w" * 300},
-         "stage_shares_config3": {"s": 1.0},
-         "trace_coverage_config3": 0.97}
+         "durability": {"d": "w" * 300}}
     bench._print_summary(dict(s), cap=512)
     line = _last_line(capsys)
     assert len(line) <= 512

@@ -152,8 +152,11 @@ def test_periodic_reporting_and_clean_stop(mgr):
     rt.start()
     rt.input_handler("S").send((1,))
     rt.flush()
+    # wait on the report that has seen the event, not on the clock: the
+    # 20 ms pump can fire before the first event is counted
     deadline = _time.time() + 5
-    while not got and _time.time() < deadline:
+    while _time.time() < deadline and not (
+            got and "S" in got[-1]["streams"]):
         _time.sleep(0.01)
     assert got, "periodic reporter never fired"
     assert "S" in got[-1]["streams"]
@@ -190,36 +193,6 @@ def test_prometheus_render(mgr):
             continue
         val = ln.rsplit(" ", 1)[1]
         assert val == "NaN" or float(val) is not None
-
-
-def test_chrome_trace_export(mgr, tmp_path):
-    import json as _json
-    rt = mgr.create_app_runtime("""
-        @app:statistics('true')
-        define stream S (x int);
-        from S[x > 0] select x insert into O;
-    """)
-    rt.stats.tracer.enabled = True
-    collect(rt, "O")
-    rt.input_handler("S").send([(1,), (2,)])
-    rt.flush()
-    path = str(tmp_path / "trace.json")
-    n = rt.stats.export_chrome_trace(path)
-    evs = _json.loads(open(path).read())
-    assert n == len(evs) and n > 0
-    assert all(e["ph"] == "X" and e["dur"] >= 0 for e in evs)
-    batches = [e for e in evs if e["cat"] == "batch"]
-    assert any(e["name"].startswith("S x") for e in batches)
-
-
-def test_flight_recorder_bounded():
-    from siddhi_tpu.core.telemetry import PipelineTracer
-    tr = PipelineTracer(capacity=4)
-    tr.enabled = True
-    for i in range(10):
-        tr.add(f"span{i}", float(i), 0.001)
-    assert len(tr.traces) == 4               # ring: last N only
-    assert tr.traces[0]["label"] == "span6"
 
 
 def test_device_metrics_sampled(mgr):
@@ -319,8 +292,27 @@ def test_custom_stream_function(mgr):
     assert out == [(4,), (4,)]
 
 
-def test_custom_aggregator(mgr):
-    from siddhi_tpu.interp.aggregators import Aggregator, register_aggregator
+@pytest.fixture
+def scratch_aggregator():
+    """Registers an aggregator for one test and takes it out again: the
+    registry is process-wide, and a leftover entry without metadata
+    fails test_extension_meta when both files share an xdist worker."""
+    from siddhi_tpu.core.planner import AGGREGATOR_NAMES
+    from siddhi_tpu.interp.aggregators import (AGGREGATOR_CLASSES,
+                                               register_aggregator)
+    names = []
+
+    def register(name, cls):
+        names.append(name.lower())
+        register_aggregator(name, cls)
+    yield register
+    for name in names:
+        AGGREGATOR_CLASSES.pop(name, None)
+        AGGREGATOR_NAMES.discard(name)
+
+
+def test_custom_aggregator(mgr, scratch_aggregator):
+    from siddhi_tpu.interp.aggregators import Aggregator
     from siddhi_tpu.query.ast import AttrType
 
     class ConcatAgg(Aggregator):
@@ -348,7 +340,7 @@ def test_custom_aggregator(mgr):
         def restore(self, st):
             self.parts = list(st["parts"])
 
-    register_aggregator("strConcat", ConcatAgg)
+    scratch_aggregator("strConcat", ConcatAgg)
     rt = mgr.create_app_runtime("""
         define stream S (s string);
         from S select strConcat(s) as joined insert into O;

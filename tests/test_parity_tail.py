@@ -7,7 +7,7 @@ import pytest
 from siddhi_tpu import SiddhiManager
 from siddhi_tpu.core.config import InMemoryConfigManager
 from siddhi_tpu.core.io import InMemoryBroker
-from siddhi_tpu.core.stats import register_stats_reporter
+from siddhi_tpu.core.telemetry import register_stats_reporter
 
 
 @pytest.fixture

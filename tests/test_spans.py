@@ -1,0 +1,469 @@
+"""The engine's one span source (`telemetry.StatisticsManager.span`, docs/
+OBSERVABILITY.md "Span taxonomy"): one clock pair feeds the stage trackers,
+a `siddhi:<name>` TraceAnnotation on the profiler's clock, the frame's
+causal tree and the phase profiler; off, it is the shared no-op."""
+import glob
+import os
+import re
+import sys
+import threading
+import time
+
+import jax
+import jax.profiler
+import numpy as np
+import pytest
+
+from siddhi_tpu import SiddhiManager
+from siddhi_tpu.core import telemetry
+from siddhi_tpu.core.telemetry import NOOP_SPAN, SPAN_PREFIX, SPANS, Tracker
+from siddhi_tpu.net import TcpFrameClient
+from siddhi_tpu.net.client import FrameReceiver
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+STOCK = "define stream S (sym string, p double, v int);\n"
+FILTER = STOCK + ("@info(name='q') from S[p > 100] select sym, p "
+                  "insert into Out;\n")
+PATTERN = ("@app:partitionCapacity(16)\n" + STOCK +
+           "partition with (sym of S) begin\n"
+           "@info(name='q') from every e1=S[p > 100] -> e2=S[p > e1.p] "
+           "within 1 sec select e1.p as a, e2.p as b insert into Out;\n"
+           "end;\n")
+
+
+def _batch(k, n, keys=8):
+    """Batch k: keys in turn (every key the same count, so a lane grid
+    keeps its shape from batch to batch), seeded prices, 1 ms apart."""
+    r = np.random.default_rng(k)
+    return ({"sym": np.array([f"K{i % keys}" for i in range(n)]),
+             "p": np.round(r.uniform(90, 130, n) * 4) / 4,
+             "v": r.integers(1, 100, n).astype(np.int32)},
+            1_700_000_000_000 + np.arange(k * n, (k + 1) * n,
+                                          dtype=np.int64))
+
+
+def _host_events(trace_dir):
+    """[(name, start_ns, duration_ns, thread line)] of the /host:CPU plane."""
+    path = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                     recursive=True)[0]
+    out = []
+    for pl in jax.profiler.ProfileData.from_file(path).planes:
+        if pl.name != "/host:CPU":
+            continue
+        for ln in pl.lines:
+            out += [(e.name, e.start_ns, e.duration_ns, ln.name)
+                    for e in ln.events]
+    return out
+
+
+# (a) one clock read, two sinks: the profiler's host plane and the trackers
+
+@pytest.mark.parametrize("app,n,want", [
+    (FILTER, 1 << 15,
+     ("freeze", "host_build", "kernel", "transfer", "unpack", "scatter")),
+    (PATTERN, 1 << 11,
+     ("freeze", "host_build", "kernel", "transfer", "scatter")),
+], ids=["filter", "partitioned-pattern"])
+def test_profiler_trace_holds_the_engine_spans(tmp_path, app, n, want):
+    mgr = SiddhiManager()
+    rt = mgr.create_app_runtime(app)
+    rows = [0]
+    rt.add_batch_callback("Out", lambda b: rows.__setitem__(0, rows[0] + b.n))
+    rt.start()
+    h = rt.input_handler("S")
+    try:
+        for k in range(3):              # every shape compiled beforehand
+            h.send_batch(*_batch(k, n))
+            rt.flush()
+        rt.enable_stats()
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            with jax.profiler.TraceAnnotation("test:window"):
+                for k in range(3, 7):
+                    h.send_batch(*_batch(k, n))
+                    rt.flush()
+        finally:
+            jax.profiler.stop_trace()
+        stages = rt.statistics()["stages"]
+    finally:
+        mgr.shutdown()
+    assert rows[0] > 0
+    events = _host_events(str(tmp_path))
+    (w0, wd), = [(s, d) for name, s, d, _ln in events
+                 if name == "test:window"]
+    for name in want:
+        mine = [(s, d) for ev, s, d, _ln in events
+                if ev == SPAN_PREFIX + name]
+        assert mine, (name, sorted({e[0] for e in events}))
+        assert all(w0 <= s and s + d <= w0 + wd for s, d in mine), name
+        assert len(mine) == stages[name]["batches"], name
+        # the annotation is entered just before the first clock read and
+        # left just after the second: a microsecond or two a span, and
+        # once in a while a collector pause or a thread switch between
+        traced = sum(d for _s, d in mine) / 1e9
+        assert traced == pytest.approx(stages[name]["seconds"], rel=0.05,
+                                       abs=5e-6 * len(mine) + 3e-4), name
+    assert not [e for e in events if e[0] == SPAN_PREFIX + "compile"]
+
+
+# (b) one producer-stamped frame over loopback TCP: one tree, two threads
+
+def _tcp_out(app, port):
+    return app.replace(
+        "insert into Out;\n", "insert into Out;\n"
+        f"@sink(type='tcp', host='127.0.0.1', port='{port}')\n"
+        "define stream Out (sym string, p double);\n")
+
+
+def _one_tree(spans):
+    ids = {s["span"] for s in spans}
+    assert [s["name"] for s in spans if s["parent"] == 0] == ["frame"]
+    assert not [s for s in spans if s["parent"] and s["parent"] not in ids]
+    assert all(s["name"] in SPANS for s in spans)
+    return {s["span"]: s for s in spans}
+
+
+def test_wire_frame_is_one_tree_across_threads():
+    """The serve thread decodes, admits and freezes; under @app:async the
+    ingest worker dispatches, pulls and publishes: one tree all the same."""
+    recv = FrameReceiver()
+    mgr = SiddhiManager()
+    rt = mgr.create_app_runtime(
+        "@app:async\n@source(type='tcp', port='0')\n"
+        + _tcp_out(FILTER, recv.port))
+    rt.enable_stats()
+    rt.start()
+    try:
+        cli = TcpFrameClient("127.0.0.1", rt.sources[0].port, "S",
+                             TcpFrameClient.cols_of_schema(rt.schemas["S"]))
+        cols, ts = _batch(0, 64)
+        cli.send_batch(cols, ts)        # compiles the step
+        cli.barrier(timeout=120)
+        cli.send_batch(cols, ts + 64, trace_id="one-tree")
+        cli.barrier(timeout=120)
+        deadline = time.monotonic() + 30
+        while time.monotonic() < deadline and not any(
+                s["name"] == "sink.publish"
+                for s in rt.tracing.traces().get("one-tree", ())):
+            time.sleep(0.01)
+        spans = rt.tracing.traces()["one-tree"]
+        stages = rt.statistics()["stages"]
+        cli.close()
+    finally:
+        rt.shutdown()
+        recv.stop()
+    by_id = _one_tree(spans)
+    order = ["frame", "net.decode", "admit", "queue_wait", "freeze",
+             "dispatch", "transfer", "sink.publish"]
+    # each stage of the chain is there and starts no earlier than the
+    # one before it
+    t0 = [min(s["t0_s"] for s in spans if s["name"] == n) for n in order]
+    assert t0 == sorted(t0), dict(zip(order, t0))
+    # nesting is by parent edge, not by the clock alone
+    for child, parent in (("sink.encode", "sink.publish"),
+                          ("sink.send", "sink.encode"),
+                          ("host_build", "dispatch")):
+        sp = next(s for s in spans if s["name"] == child)
+        assert by_id[sp["parent"]]["name"] == parent, (child, sp)
+    threads = {s["name"]: s["thread"] for s in spans}
+    assert threads["net.decode"] != threads["transfer"], threads
+    # the serve thread's blocking reads were named too
+    assert stages["net.wait"]["batches"] >= 1
+    assert stages["queue_wait"]["batches"] >= 2
+
+
+def test_direct_send_tree_crosses_to_the_ingest_worker():
+    mgr = SiddhiManager()
+    rt = mgr.create_app_runtime("@app:trace('all')\n@app:async\n" + FILTER)
+    rt.add_batch_callback("Out", lambda b: None)
+    rt.start()
+    try:
+        rt.input_handler("S").send_batch(*_batch(0, 64))
+        rt.flush()
+        (spans,) = rt.tracing.traces().values()
+    finally:
+        rt.shutdown()
+    _one_tree(spans)
+    threads = {s["name"]: s["thread"] for s in spans}
+    assert threads["freeze"] != threads["dispatch"], threads
+    for want in ("freeze", "dispatch", "host_build", "transfer", "unpack",
+                 "scatter"):
+        assert want in threads, (want, threads)
+
+
+# (c) the off path
+
+def test_off_path_is_the_noop_singleton(monkeypatch):
+    def boom(*a, **kw):
+        raise AssertionError("TraceAnnotation built with statistics off")
+    monkeypatch.setattr(telemetry.jax.profiler, "TraceAnnotation", boom)
+    mgr = SiddhiManager()
+    rt = mgr.create_app_runtime(
+        "@app:trace('off')\n@app:profile('off')\n" + FILTER)
+    rt.start()
+    try:
+        for name in SPANS:
+            assert rt.span(name, plan="q", events=3) is NOOP_SPAN, name
+        rt.input_handler("S").send_batch(*_batch(0, 256))
+        rt.flush()
+        assert rt.statistics()["stages"] == {}
+    finally:
+        mgr.shutdown()
+
+
+def test_profiler_only_path_builds_no_span_object():
+    """The default runtime (profiler on, statistics off, frame unsampled):
+    a phase-mapped span is the profiler's own phase object, anything else
+    the no-op."""
+    mgr = SiddhiManager()
+    rt = mgr.create_app_runtime("@app:trace('off')\n" + FILTER)
+    try:
+        assert rt.span("freeze") is NOOP_SPAN
+        assert type(rt.span("transfer")).__name__ == "_PhaseSpan"
+        assert type(rt.span("dispatch", plan="q")).__name__ == "_RoundCM"
+    finally:
+        mgr.shutdown()
+
+
+@pytest.mark.parametrize("header,stats", [
+    ("@app:trace('off')\n@app:profile('off')\n", False),   # the no-op
+    ("@app:trace('off')\n", False),         # the profiler's phase / round
+    ("@app:trace('all')\n", True)])         # a timed span
+def test_every_span_has_one_surface(header, stats):
+    """Whatever `span()` hands back, a caller reads `seconds` and
+    `t_end`, sets `events` and calls `note()` without asking what it is."""
+    mgr = SiddhiManager()
+    rt = mgr.create_app_runtime(header + FILTER)
+    rt.enable_stats(stats)
+    try:
+        for name in ("ingest", "transfer", "dispatch"):
+            with rt.span(name, plan="q") as sp:
+                sp.events = 7
+                sp.note(action="x")
+            assert isinstance(sp, telemetry.Span)
+            if stats:
+                assert sp.seconds > 0 and sp.t_end is not None
+                assert sp.events == 7
+            else:
+                assert sp.seconds == 0.0 and sp.t_end is None
+        assert NOOP_SPAN.events == 0
+    finally:
+        mgr.shutdown()
+
+
+# (d) trackers are shared between threads now
+
+def test_tracker_observe_from_four_threads_loses_nothing():
+    tr = Tracker()
+    n = 20_000
+
+    def work():
+        for _ in range(n):
+            tr.observe(1e-6, events=2)
+    threads = [threading.Thread(target=work, name=f"siddhi-test-{i}")
+               for i in range(4)]
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)     # switch threads mid-observe
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+    finally:
+        sys.setswitchinterval(old)
+    assert tr.batches == 4 * n and tr.events == 8 * n
+    assert tr.hist.count == 4 * n
+    assert tr.seconds == pytest.approx(4 * n * 1e-6)
+
+
+def test_first_span_of_a_name_from_many_threads_shares_one_tracker():
+    mgr = SiddhiManager()
+    rt = mgr.create_app_runtime(FILTER)
+    rt.enable_stats()
+    try:
+        go = threading.Event()
+
+        def work():
+            go.wait()
+            for _ in range(200):
+                with rt.span("net.decode"):
+                    pass
+        threads = [threading.Thread(target=work, name=f"siddhi-test-{i}")
+                   for i in range(4)]
+        for t in threads:
+            t.start()
+        go.set()
+        for t in threads:
+            t.join()
+        assert rt.statistics()["stages"]["net.decode"]["batches"] == 800
+    finally:
+        mgr.shutdown()
+
+
+# (e) the pattern plan counts what it pulls
+
+def test_pattern_plan_notes_d2h_bytes():
+    mgr = SiddhiManager()
+    rt = mgr.create_app_runtime(PATTERN)
+    rows = [0]
+    rt.add_batch_callback("Out", lambda b: rows.__setitem__(0, rows[0] + b.n))
+    rt.start()
+    try:
+        rt.input_handler("S").send_batch(*_batch(0, 512))
+        rt.flush()
+        plans = rt.statistics()["profile"]["plans"]
+    finally:
+        mgr.shutdown()
+    assert rows[0] > 0
+    assert plans["q"]["bytes"]["d2h"] > 0 and plans["q"]["bytes"]["h2d"] > 0
+
+
+# (f) the taxonomy is the documentation's
+
+def _doc_table():
+    with open(os.path.join(ROOT, "docs", "OBSERVABILITY.md")) as f:
+        text = f.read()
+    sec = text.split("## Span taxonomy", 1)[1].split("\n## ", 1)[0]
+    return re.findall(r"^\| `([a-z_.]+)` \|", sec, flags=re.M)
+
+
+@pytest.mark.parametrize("name", SPANS)
+def test_span_is_in_the_documented_taxonomy(name):
+    assert name in _doc_table()
+
+
+def test_documented_taxonomy_names_nothing_else():
+    assert sorted(_doc_table()) == sorted(SPANS)
+    assert len(set(SPANS)) == len(SPANS)
+
+
+def test_every_span_the_source_opens_is_in_the_taxonomy():
+    """One tuple: a `span("...")` literal anywhere in the package names a
+    member of SPANS, and nothing records time another way."""
+    pat = re.compile(r"""\bspan\(\s*["']([a-z_.]+)["']""")
+    seen = set()
+    for path in glob.glob(os.path.join(ROOT, "siddhi_tpu", "**", "*.py"),
+                          recursive=True):
+        with open(path) as f:
+            src = f.read()
+        seen.update(pat.findall(src))
+        assert "stats.stage(" not in src and "time_plan" not in src, path
+    assert seen and seen <= set(SPANS), seen - set(SPANS)
+    # `compile`/`kernel` are chosen at run time, `frame` is the root
+    # marker, `gc` is written by the process's lock-free collector hook
+    assert set(SPANS) - seen <= {"compile", "kernel", "frame", "parse",
+                                 "gc"}, set(SPANS) - seen
+
+
+# the process span
+
+def test_gc_span_and_its_hook_follow_enable_stats():
+    import gc
+    hook = telemetry._on_gc
+    mgr = SiddhiManager()
+    rt = mgr.create_app_runtime(FILTER)
+    try:
+        before = gc.callbacks.count(hook)    # (another app may be watching)
+        assert rt.stats not in telemetry._gc_watchers
+        rt.enable_stats(True)
+        rt.enable_stats(True)
+        assert gc.callbacks.count(hook) == 1
+        assert telemetry._gc_watchers.count(rt.stats) == 1
+        gc.collect()
+        st = rt.statistics()["stages"]["gc"]
+        assert st["batches"] >= 1 and st["seconds"] > 0
+        rt.enable_stats(False)
+        assert rt.stats not in telemetry._gc_watchers
+        assert gc.callbacks.count(hook) == before
+        rt.enable_stats(True)
+    finally:
+        mgr.shutdown()
+    assert rt.stats not in telemetry._gc_watchers
+    assert gc.callbacks.count(hook) == before
+
+
+def test_two_apps_share_one_gc_hook_and_count_a_pause_once_each():
+    import gc
+    mgr = SiddhiManager()
+    rts = [mgr.create_app_runtime(f"@app:name('gc{i}')\n" + FILTER)
+           for i in range(2)]
+    try:
+        for rt in rts:
+            rt.enable_stats()
+        assert gc.callbacks.count(telemetry._on_gc) == 1
+        n0 = [rt.stats._gc.batches for rt in rts]
+        was = gc.isenabled()
+        gc.disable()            # only the three collections below
+        try:
+            for _ in range(3):
+                gc.collect()
+            got = [rt.stats._gc.batches - n for rt, n in zip(rts, n0)]
+        finally:
+            if was:
+                gc.enable()
+        assert got == [3, 3]
+    finally:
+        mgr.shutdown()
+
+
+_SCRAPE_UNDER_GC = """
+import faulthandler, gc, sys, threading, time
+faulthandler.dump_traceback_later(40, exit=True)    # a hang prints stacks
+sys.path.insert(0, %r)
+from siddhi_tpu import SiddhiManager
+rt = SiddhiManager().create_app_runtime(%r)
+rt.enable_stats()
+rt.start()
+gc.collect()
+gc.set_threshold(1, 1, 1)   # nearly every container allocation collects:
+stop = threading.Event()    # the scrape's own dicts trip the hook under
+def churn():                # the Tracker locks it holds
+    while not stop.is_set():
+        [[] for _ in range(50)]
+t = threading.Thread(target=churn, daemon=True)
+t.start()
+end = time.monotonic() + 1.5
+n = 0
+while time.monotonic() < end:
+    assert rt.statistics()["stages"]["gc"]["batches"] > 0
+    rt.stats.prometheus()
+    n += 1
+stop.set()
+t.join()
+gc.set_threshold(700, 10, 10)
+rt.shutdown()
+print("scrapes", n)
+"""
+
+
+def test_scrape_while_collections_run_does_not_deadlock():
+    """The gc hook runs inside whatever allocation tripped the collector,
+    `Tracker.as_dict`'s own under the tracker's lock included: it must
+    take no lock (this hung within seconds when `gc` was an ordinary
+    span)."""
+    import subprocess
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_ENABLE_COMPILATION_CACHE="false")
+    r = subprocess.run(
+        [sys.executable, "-c", _SCRAPE_UNDER_GC % (ROOT, FILTER)],
+        env=env, capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr[-4000:]
+    assert "scrapes" in r.stdout
+
+
+def test_backdated_span_counts_the_wait_before_it():
+    mgr = SiddhiManager()
+    rt = mgr.create_app_runtime(FILTER)
+    rt.enable_stats()
+    try:
+        t0 = time.perf_counter()
+        time.sleep(0.02)
+        with rt.span("queue_wait", t0=t0) as sp:
+            pass
+        assert sp.seconds >= 0.02
+        st = rt.statistics()["stages"]["queue_wait"]
+        assert st["seconds"] == pytest.approx(sp.seconds)
+    finally:
+        mgr.shutdown()

@@ -17,7 +17,7 @@ import numpy as np
 import pytest
 
 from siddhi_tpu import SiddhiManager
-from siddhi_tpu.core.telemetry import render_prometheus
+from siddhi_tpu.core.telemetry import SPANS, render_prometheus
 from siddhi_tpu.core.tracing import FrameTracer
 from siddhi_tpu.net import TcpFrameClient
 from siddhi_tpu.net import frame as fp
@@ -72,11 +72,19 @@ def test_e2e_tcp_durable_frame_trace_tree(tmp_path):
         traces = rt.tracing.traces()
         assert "prod-e2e-1" in traces, sorted(traces)
         names = _tree_check(traces["prod-e2e-1"])
-        # the causal chain the issue pins: admission -> wal.append ->
-        # freeze -> device dispatch -> materialize -> sink egress
-        for want in ("frame", "admit", "wal.append", "freeze",
-                     "dispatch", "materialize", "sink.publish"):
+        # the causal chain: wire decode -> admission -> gate ->
+        # freeze (WAL append inside) -> device dispatch -> result pull
+        # -> sink egress
+        for want in ("frame", "net.decode", "admit", "queue_wait",
+                     "wal.append", "freeze", "dispatch", "transfer",
+                     "sink.publish", "sink.encode", "sink.send"):
             assert want in names, (want, names)
+        by_id = {s["span"]: s for s in traces["prod-e2e-1"]}
+        for child, parent in (("wal.append", "freeze"),
+                              ("sink.encode", "sink.publish")):
+            sp = next(s for s in traces["prod-e2e-1"]
+                      if s["name"] == child)
+            assert by_id[sp["parent"]]["name"] == parent, sp
         # the wal.append span names the durable frame seq (trace rides
         # the WAL plane's per-stream frame identity)
         wal_span = next(s for s in traces["prod-e2e-1"]
@@ -122,9 +130,9 @@ def test_traced_vs_untraced_outputs_byte_identical():
 # ---------------------------------------------------------------------------
 
 def test_depth4_pipelined_window_single_tree():
-    """Depth-4 deferred materialization: the materialize span lands up
+    """Depth-4 deferred materialization: the transfer span lands up
     to 4 batches later (and on flush) — every frame's tree must still
-    be connected, with the materialize parented into ITS frame."""
+    be connected, with the transfer parented into ITS frame."""
     mgr = SiddhiManager()
     rt = mgr.create_app_runtime(
         "@app:trace('all')\n@app:deviceWindows('always')\n"
@@ -143,9 +151,9 @@ def test_depth4_pipelined_window_single_tree():
     for tid, spans in traces.items():
         names = _tree_check(spans)
         assert "freeze" in names and "dispatch" in names
-        assert "materialize" in names, (tid, names)
+        assert "transfer" in names, (tid, names)
         mat_threads.update(s["thread"] for s in spans
-                           if s["name"] == "materialize")
+                           if s["name"] == "transfer")
     assert mat_threads            # recorded, wherever they ran
 
 
@@ -221,9 +229,9 @@ def test_sink_retry_after_breaker_stays_one_tree():
         assert len(traces) == 1
         spans = next(iter(traces.values()))
         names = _tree_check(spans)
-        pubs = [s for s in spans if s["name"] == "sink.publish"]
+        sends = [s for s in spans if s["name"] == "sink.send"]
         # the failed attempt AND the successful replay, same trace
-        assert len(pubs) >= 2, names
+        assert len(sends) >= 2, names
         deadline = time.monotonic() + 10
         while time.monotonic() < deadline and not recv.rows():
             time.sleep(0.02)
@@ -268,9 +276,7 @@ def test_slo_breach_trigger_exports_dump(tmp_path):
         assert md["hostname"]                     # federation merge key
         assert md["app"] == rt.app.name
         # the dump's slowest span names the breaching stage
-        assert md["slowest"]["name"] in (
-            "admit", "wal.append", "freeze", "dispatch", "materialize",
-            "sink.publish")
+        assert md["slowest"]["name"] in SPANS
         assert rt.tracing.metrics()["triggers"].get("slo_breach")
         assert rt.tracing.dump_summaries()
     finally:
