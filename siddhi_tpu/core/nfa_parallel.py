@@ -397,6 +397,63 @@ def _chase_lanes(prog: ParallelProgram) -> list:
     return lanes
 
 
+@dataclass(frozen=True)
+class ExpiryAsk:
+    """One place the lane block needs a head's expiry index: "the first
+    event at or after s past the horizon `head ts + within_ms`"."""
+    within_ms: int
+    fresh: bool               # build the query; False = reuse the held index
+
+
+# hop kind -> does the hop apply the expiry index it asks for to every
+# surviving instance (step_fail / the final count's `ok & (jc < kl)`), so
+# that alive implies j < kl afterwards?  Sharing leans on exactly this.
+_ENFORCES_EXPIRY = {"count": True, "single": True, "logical": True,
+                    "sequence": False}   # strict succession tests ts[j+1]
+
+
+def _expiry_plan(prog: ParallelProgram) -> dict:
+    """{position index: ExpiryAsk} for every position at which the lane
+    block needs a head's expiry index (a count head at 0, every
+    non-sequence hop, a final count at S-1 — asked in emit_candidates).
+
+    kl(s) = min{i >= s : ts[i] > horizon}, L when none.  A hop that asked
+    from s and enforced its answer leaves every surviving instance at an
+    index j with s <= j < kl(s); the next hop asks from j + 1, which lies
+    in (s, kl(s)], so under the SAME horizon kl(j + 1) == kl(s): the
+    smaller range cannot hold an earlier hit, and still holds kl(s).
+    Nothing there orders ts, so regressed timestamps share alike; dead
+    instances never observe kl.  A query is therefore fresh exactly when
+    no index is held, the horizon (anchor, within_ms) differs from the
+    held one's, or a hop since did not enforce it."""
+    asks: dict = {}
+    held = None                  # horizon every surviving instance is inside
+    pend = None                  # armed count position awaiting its advance
+    S = prog.S
+    for pi, pos in enumerate(prog.positions):
+        kind = "sequence" if prog.sequence and pos.kind == "single" \
+            else pos.kind
+        if pi == 0 and kind != "count":
+            continue             # a (1,1) head waits for nothing
+        if kind == "sequence":
+            held = None
+            continue
+        # a count's successor consumes the armed count: the station never
+        # waits AT the successor, so the COUNT's within bounds that advance
+        # and the successor's own never applies (host parity: at_pos is
+        # never true for a count's successor)
+        src = pend if kind == "single" and pend is not None else pos
+        # (anchor, span): every horizon counts from the head's ts today
+        horizon = ("head", src.within_ms)
+        asks[pi] = ExpiryAsk(src.within_ms, fresh=horizon != held)
+        held = horizon if _ENFORCES_EXPIRY[kind] else None
+        if kind == "single":
+            pend = None
+        elif kind == "count" and pi < S - 1:
+            pend = pos
+    return asks
+
+
 def _classify_prog(prog: ParallelProgram) -> dict:
     """Family verdicts for a successfully-lowered chase program (shared
     between the built-kernel classifier above and the analysis-time
@@ -623,6 +680,12 @@ class ParallelChainKernel:
         self.f64 = nfak.f64
         self._mode = nfak._mode
         self._block_cache: dict = {}
+        self.expiry_plan = _expiry_plan(prog)
+        fresh = sum(a.fresh for a in self.expiry_plan.values())
+        # how often the one-query-per-horizon sharing engages: static per
+        # compiled plan, shown by rt.explain()
+        self.expiry_queries = {"built": fresh,
+                               "shared": len(self.expiry_plan) - fresh}
 
     # NFAKernel-compatible surface consumed by _call_block / bench
     def block_fn(self, T, M: int):
@@ -872,12 +935,22 @@ class ParallelChainKernel:
             ts_heap = _build_heap(ts, valid, L, "max", jnp.dtype(jnp.int64))
             ts64 = ts.astype(jnp.int64)
 
-        def killer(s, within_ms):
+        asks = self.expiry_plan
+        held_kl = None
+
+        def killer(pi, s):
             """First event at or after s past the head's `within` horizon
-            (per-head v = head ts + W; queries indexed by head)."""
-            with scope("within_kill"):
-                return _first_hit(ts_heap, L, s,
-                                  ts64 + jnp.int64(within_ms), "gt")
+            (per-head v = head ts + W; queries indexed by head).  One
+            descent per distinct horizon: a position whose ask is not
+            fresh reads the index the chain already holds
+            (_expiry_plan)."""
+            nonlocal held_kl
+            if asks[pi].fresh:
+                with scope("within_kill"):
+                    held_kl = _first_hit(
+                        ts_heap, L, s,
+                        ts64 + jnp.int64(asks[pi].within_ms), "gt")
+            return held_kl
 
         def threshold_next(hop: HopNode, s, idx_of):
             with scope("threshold_next"):
@@ -903,7 +976,6 @@ class ParallelChainKernel:
         idx_of = {}                     # refpart -> per-head value index
         pres_of = {}                    # refpart -> per-head presence bool
         count_ctx = {}                  # pi -> (s_occ, ra) occurrence base
-        pend_count = None               # (pi, entry) awaiting its advance
         j = j0
 
         def step_fail(alive, kl, jn):
@@ -920,11 +992,10 @@ class ParallelChainKernel:
                 ra = ranks[0][j0] - 1
                 count_ctx[0] = (j0, ra)
                 jmin = select(0, j0, ra + jnp.int32(head.min_count))
-                kl = killer(j0 + 1, head.within_ms)
+                kl = killer(0, j0 + 1)
                 if S > 1:
                     ok, d = step_fail(ok, kl, jmin)
                     dead = dead | d
-                    pend_count = (0, head)
                     j = jnp.clip(jmin, 0, F - 1)
         else:
             idx_of[head.nodes[0].ref] = j0
@@ -938,18 +1009,7 @@ class ParallelChainKernel:
                     hop = pos.nodes[0]
                     s = j + 1
                     if not prog.sequence:
-                        if pend_count is not None:
-                            # the successor consumes the armed count: the
-                            # station never waits AT this position, so the
-                            # COUNT's within (anchored at the head) bounds
-                            # this advance and the successor's own never
-                            # applies (host parity: at_pos is never true for
-                            # a count's successor)
-                            _cpi, cpos = pend_count
-                            kl = killer(s, cpos.within_ms)
-                            pend_count = None
-                        else:
-                            kl = killer(s, pos.within_ms)
+                        kl = killer(pi, s)
                     if prog.sequence:
                         # strict succession: the hop consumes EXACTLY the
                         # next valid event — mask/filter/expiry all resolve
@@ -993,7 +1053,7 @@ class ParallelChainKernel:
                     else:
                         jd = jnp.where((jl < F) & (jr < F),
                                        jnp.maximum(jl, jr), jnp.int32(L))
-                    kl = killer(s, pos.within_ms)
+                    kl = killer(pi, s)
                     ok, d = step_fail(ok, kl, jd)
                     dead = dead | d
                     jdc = jnp.clip(jd, 0, F - 1)
@@ -1019,10 +1079,9 @@ class ParallelChainKernel:
                     if pi < S - 1:
                         jmin = select(pi, entry + 1,
                                       ra + jnp.int32(pos.min_count))
-                        kl = killer(entry + 1, pos.within_ms)
+                        kl = killer(pi, entry + 1)
                         ok, d = step_fail(ok, kl, jmin)
                         dead = dead | d
-                        pend_count = (pi, pos)
                         j = jnp.clip(jmin, 0, F - 1)
 
         with scope("emit_candidates"):
@@ -1030,7 +1089,7 @@ class ParallelChainKernel:
             if final_count:
                 fpos = prog.positions[S - 1]
                 s_occ, ra = count_ctx[S - 1]
-                kl = killer(s_occ, fpos.within_ms)
+                kl = killer(S - 1, s_occ)
                 C = fpos.max_count - fpos.min_count + 1
                 lvs, comps = [], []
                 for c in range(fpos.min_count, fpos.max_count + 1):

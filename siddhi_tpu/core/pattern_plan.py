@@ -598,6 +598,14 @@ class DevicePatternPlan(QueryPlan):
             self._par_kerns[self.family] = kern
         return kern
 
+    @property
+    def expiry_queries(self) -> Optional[dict]:
+        """{'built': b, 'shared': s}: `within` expiry descents the parallel
+        block makes per head, and positions that reuse one (EXPLAIN)."""
+        if self.family not in ("scan", "dfa"):
+            return None
+        return dict(self._parallel_kernel().expiry_queries)
+
     def _rebase(self, min_ts: int, min_seq: int) -> None:
         """Shift the plan's ts/seq bases forward and adjust persistent slot
         offsets so i32 locals never overflow.  Ancient slots clamp to

@@ -232,6 +232,9 @@ def _query_entry(rt, plan) -> Optional[dict]:
     fam = getattr(plan, "family", None)
     if kind == "pattern" and fam is not None:
         ent["family"] = fam
+        expiry = getattr(plan, "expiry_queries", None)
+        if expiry:
+            ent["expiry_queries"] = expiry
         families = getattr(plan, "families", None)
         if families:
             rejected = {f: r for f, r in sorted(families.items())

@@ -365,6 +365,32 @@ def test_ineligible_family_reasons_reach_explain():
         mgr.shutdown()
 
 
+@pytest.mark.parametrize("force,want", [
+    ("", {"built": 1, "shared": 1}),   # the default family here is scan
+    ("@app:patternFamily('seq')\n", None),            # no expiry heap there
+])
+def test_explain_shows_expiry_queries_of_parallel_families(force, want):
+    """`expiry_queries` sits beside `family` for a device pattern query of
+    a parallel family: how many `within` descents the block makes per head
+    (built) and how many positions read one already made (shared).  The
+    sequential kernel has no such query and no such key."""
+    mgr, rt = _build(force + "@app:devicePatterns('always')\n" + """
+        define stream S (sym string, price double);
+        @info(name='q')
+        from every e1=S[price > 100] -> e2=S[price > e1.price]
+            -> e3=S[price > e2.price] within 10 sec
+        select e1.price as p1, e2.price as p2, e3.price as p3
+        insert into Out;
+    """)
+    ent = rt.explain()["queries"]["q"]
+    assert ent["path"] == "device" and ent["kind"] == "pattern"
+    assert ent["family"] == ("scan" if want else "seq")
+    assert ent.get("expiry_queries") == want, ent
+    assert list(ent)[:5] == ["path", "plan", "kind", "family"] \
+        + (["expiry_queries"] if want else ["rejected"]), list(ent)
+    mgr.shutdown()
+
+
 def test_placement_statistics_and_prometheus():
     from siddhi_tpu.core.telemetry import render_prometheus
     mgr, rt = _build("""

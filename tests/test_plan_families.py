@@ -169,7 +169,7 @@ INELIGIBLE = {
 }
 
 
-def _run(head, q, n=900, batches=3, seed=11, dt=7, keys=4):
+def _run(head, q, n=900, batches=3, seed=11, dt=7, keys=4, plan_out=None):
     mgr = SiddhiManager()
     with warnings.catch_warnings():
         warnings.simplefilter("ignore", RuntimeWarning)
@@ -196,20 +196,32 @@ def _run(head, q, n=900, batches=3, seed=11, dt=7, keys=4):
                      int(rng.integers(1, 1000))),
                     timestamp=ts0 + i * dt)
         rt.flush()
+    if plan_out is not None:
+        plan_out["explain"] = rt.explain()
     mgr.shutdown()
     return fam, families, rows
 
 
-@pytest.fixture(scope="module")
-def host_rows():
+def _cached_rows(head):
     cache = {}
 
     def get(q):
         if q not in cache:
-            _f, _e, rows = _run("@app:devicePatterns('never')\n", q)
+            _f, _e, rows = _run(head, q)
             cache[q] = rows
         return cache[q]
     return get
+
+
+@pytest.fixture(scope="module")
+def host_rows():
+    return _cached_rows("@app:devicePatterns('never')\n")
+
+
+@pytest.fixture(scope="module")
+def seq_rows():
+    return _cached_rows("@app:patternFamily('seq')\n"
+                        "@app:devicePatterns('always')\n")
 
 
 # dfa provably rejects these (sequence/nonevery/final-count shapes) and
@@ -487,11 +499,11 @@ end;
 
 
 def _run_part(head, n=1200, batches=4, seed=3, dt=7, keys=37,
-              plan_out=None):
+              plan_out=None, q=PART_Q):
     mgr = SiddhiManager()
     with warnings.catch_warnings():
         warnings.simplefilter("ignore", RuntimeWarning)
-        rt = mgr.create_app_runtime(head + PART_HEAD + PART_Q)
+        rt = mgr.create_app_runtime(head + PART_HEAD + q)
     rows = []
     rt.add_callback("Out", lambda evs: rows.extend(
         (e.timestamp, tuple(round(float(x), 3) for x in e.data))
@@ -767,3 +779,352 @@ def test_tuning_cache_plan_family_round_trip(tmp_path):
     key = next(iter(data2["entries"]))
     data2["entries"][key]["geometry"]["plan_family"] = "bogus"
     assert validate_cache_data(data2)
+
+
+# ---------------------------------------------------------------------------
+# one `within` query per horizon, shared down the chain (ISSUE 27): the
+# lane block asks "when does this instance expire?" once per distinct
+# horizon; every shape below must stay byte-identical to the interpreter
+# whether its hops share the head's query or must make their own
+# ---------------------------------------------------------------------------
+
+# name -> (query, expiry_queries the plan must report, dfa eligible)
+EXPIRY = {
+    "hops3_one_within": (
+        "from every e1=S[price > 118] -> e2=S[price < 100] -> "
+        "e3=S[price > e1.price] -> e4=S[price < e2.price] within 2 sec "
+        "select e1.price as a, e2.price as b, e3.price as c, e4.price as d "
+        "insert into Out;",
+        {"built": 1, "shared": 2}, True),
+    "hops4_one_within": (
+        "from every e1=S[price > 120] -> e2=S[price < 100] -> "
+        "e3=S[price > 118] -> e4=S[price < 98] -> e5=S[price > e1.price] "
+        "within 3 sec select e1.price as a, e2.price as b, e3.price as c, "
+        "e4.price as d, e5.price as e insert into Out;",
+        {"built": 1, "shared": 3}, True),
+    # per-element horizons that differ from their predecessor's: every hop
+    # must make its own query
+    "within_short_then_long": (
+        "from every e1=S[price > 110] -> (e2=S[price < 100]) "
+        "within 300 milliseconds -> e3=S[price > e1.price] within 2 sec "
+        "select e1.price as a, e2.price as b, e3.price as c "
+        "insert into Out;",
+        {"built": 2, "shared": 0}, True),
+    "within_long_then_short": (
+        "from every e1=S[price > 110] -> e2=S[price < 100] -> "
+        "(e3=S[price > e1.price]) within 300 milliseconds within 2 sec "
+        "select e1.price as a, e2.price as b, e3.price as c "
+        "insert into Out;",
+        {"built": 2, "shared": 0}, True),
+    "within_long_short_long": (
+        "from every e1=S[price > 112] -> e2=S[price < 100] -> "
+        "(e3=S[price > e1.price]) within 400 milliseconds -> "
+        "e4=S[price < 102] within 2 sec select e1.price as a, "
+        "e2.price as b, e3.price as c, e4.price as d insert into Out;",
+        {"built": 3, "shared": 0}, True),
+    "count_head_successor": (
+        "from every e1=S[price > 112]<1:3> -> e2=S[price < 96] -> "
+        "e3=S[price > 120] within 1 sec select e1[0].price as a, "
+        "e1[last].price as b, e2.price as c, e3.price as d "
+        "insert into Out;",
+        {"built": 1, "shared": 2}, True),
+    "logical_pair_chain": (
+        "from every e1=S[price > 120] -> e2=S[price < 100] and "
+        "e3=S[price > 125] -> e4=S[price < 97] within 2 sec "
+        "select e1.price as a, e2.price as b, e3.price as c, "
+        "e4.price as d insert into Out;",
+        {"built": 1, "shared": 1}, True),
+    "final_count_chain": (
+        "from every e1=S[price > 118] -> e2=S[price > 122] -> "
+        "e3=S[price < 97]<2:3> within 1 sec select e1.price as a, "
+        "e2.price as b, e3[last].price as c insert into Out;",
+        {"built": 1, "shared": 1}, False),
+}
+
+
+def _lane_app(q):
+    return ("partition with (sym of S)\nbegin\n  @info(name='q') "
+            + q + "\nend;\n")
+
+
+@pytest.mark.parametrize("name,fam,layout", [
+    (n, f, lay) for n, (_q, _e, dfa_ok) in EXPIRY.items()
+    for f in FAMILIES if f == "scan" or dfa_ok
+    for lay in ("flat", "lanes")])
+def test_shared_expiry_differential(name, fam, layout, host_rows, seq_rows):
+    q, expiry, _dfa_ok = EXPIRY[name]
+    info: dict = {}
+    if layout == "flat":
+        used, _families, dev = _run(
+            f"@app:patternFamily('{fam}')\n@app:devicePatterns('always')\n",
+            q, plan_out=info)
+        # in emission order against the sequential device kernel; the
+        # interpreter orders completions of ONE event differently on
+        # chains of four positions and more (every device family agrees),
+        # so it is held as a multiset
+        assert dev == seq_rows(q), (name, fam, len(dev))
+        dev, host = sorted(dev), sorted(host_rows(q))
+    else:
+        # 5 keys: a key sees an event every 35 ms, so chains of four and
+        # five positions still complete inside their horizons
+        used, dev = _run_part(
+            f"@app:patternFamily('{fam}')\n@app:partitionCapacity(8)\n",
+            keys=5, plan_out=info, q=_lane_app(q))
+        _f, host = _run_part("@app:devicePatterns('never')\n", keys=5,
+                             q=_lane_app(q))
+        assert info["metrics"].get("dispatches_lane_vmapped", 0) >= 1
+    assert used == fam, (name, fam, used)
+    assert info["explain"]["queries"]["q"]["expiry_queries"] == expiry
+    assert len(dev) > 0, f"{name}: no matches — tape too easy?"
+    assert dev == host, (name, fam, layout, len(dev), len(host),
+                         dev[:3], host[:3])
+
+
+C4_Q = ("from every e1=S[price > 100] -> e2=S[price > e1.price] -> "
+        "e3=S[price > e2.price] within 1 sec "
+        "select e1.price as p1, e2.price as p2, e3.price as p3 "
+        "insert into Out;")
+# (ms offset, price): timestamps regress after each killer event, so some
+# heads are expired while waiting at hop 1 and some at hop 2, by an event
+# that sits BEFORE the one that would have completed them
+OOO_SENDS = [
+    (0, 101.0),      # head A
+    (100, 102.0),    # A's e2; head B
+    (2000, 50.0),    # past both horizons: A dies at hop 2, B at hop 1
+    (300, 150.0),    # regressed: would be A's e3 and B's e2; head D
+    (400, 151.0),    # D's e2; head E
+    (500, 152.0),    # D's e3 -> (150, 151, 152); E's e2; head F
+    (2500, 60.0),    # E dies at hop 2, F at hop 1
+    (600, 160.0),    # regressed: would be E's e3 and F's e2; head G
+    (700, 170.0),    # G's e2
+    (800, 180.0),    # G's e3 -> (160, 170, 180)
+]
+OOO_WANT = [(150.0, 151.0, 152.0), (160.0, 170.0, 180.0)]
+
+
+def _run_sends(head, app, sends, plan_out=None):
+    """Feed (sym, ms offset, price) rows in ONE flush; rows as sorted
+    (timestamp, data) pairs."""
+    mgr = SiddhiManager()
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", RuntimeWarning)
+        rt = mgr.create_app_runtime(head + app)
+    rows = []
+    rt.add_callback("Out", lambda evs: rows.extend(
+        (e.timestamp, tuple(e.data)) for e in evs))
+    rt.start()
+    plan = next((p for p in rt._plans
+                 if isinstance(p, DevicePatternPlan)), None)
+    if plan_out is not None:
+        plan_out["plan"] = plan
+    ih = rt.input_handler("S")
+    for sym, dt, p in sends:
+        ih.send((sym, p, 1), timestamp=1_700_000_000_000 + dt)
+    rt.flush()
+    fam = plan.family if plan is not None else None
+    mgr.shutdown()
+    return fam, sorted(rows)
+
+
+@pytest.mark.parametrize("fam,layout", [
+    (f, lay) for f in ("seq",) + FAMILIES for lay in ("flat", "lanes")])
+def test_regressed_timestamps_die_at_hop1_and_hop2(fam, layout):
+    # dfa rejects C4 (no static hop to bit-pack): it takes the chain with
+    # a static last hop, which this tape completes with the same rows
+    q = C4_Q.replace("e3=S[price > e2.price]", "e3=S[price > 151.5]") \
+        if fam == "dfa" else C4_Q
+    if layout == "flat":
+        app = HEAD + q
+        sends = [("K", dt, p) for dt, p in OOO_SENDS]
+        force = (f"@app:patternFamily('{fam}')\n"
+                 "@app:devicePatterns('always')\n")
+        want = OOO_WANT
+    else:
+        # the same tape on two keys, interleaved, beside an in-order key
+        app = PART_HEAD + _lane_app(q)
+        sends = [(k, dt, p) for dt, p in OOO_SENDS for k in ("A", "B")] \
+            + [("C", 3000 + 10 * i, 150.0 + i) for i in range(3)]
+        force = f"@app:patternFamily('{fam}')\n@app:partitionCapacity(8)\n"
+        want = OOO_WANT * 2 + [(150.0, 151.0, 152.0)]
+    _f, host = _run_sends("@app:devicePatterns('never')\n", app, sends)
+    assert sorted(d for _t, d in host) == sorted(want), host
+    used, dev = _run_sends(force, app, sends)
+    assert used == fam
+    assert dev == host, (fam, layout, dev, host)
+
+
+def _record_lane_blocks(kern, out):
+    """Wrap a kernel's block_fn so every dispatch's (T, M, ev) lands in
+    `out` as numpy."""
+    import jax
+    orig = kern.block_fn
+
+    def block_fn(T, M):
+        fn = orig(T, M)
+
+        def call(state, ev):
+            out.append((T, M, jax.tree_util.tree_map(np.asarray, ev)))
+            return fn(state, ev)
+        return call
+    kern.block_fn = block_fn
+
+
+@pytest.mark.parametrize("name", ["c4", "count_head_successor",
+                                  "logical_pair_chain",
+                                  "final_count_chain"])
+def test_lane_block_bytes_equal_unshared_queries(name):
+    """The packed output of the lane block is byte-identical to the block
+    that makes EVERY expiry query afresh (the block as it was before the
+    sharing) on the (lanes, F) grids of a seeded partitioned run whose
+    timestamps regress on about a third of the events."""
+    import dataclasses
+    from siddhi_tpu.core.nfa_parallel import ParallelChainKernel
+    q = C4_Q.replace("1 sec", "400 milliseconds") if name == "c4" \
+        else EXPIRY[name][0]
+    mgr = SiddhiManager()
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", RuntimeWarning)
+        rt = mgr.create_app_runtime(
+            "@app:partitionCapacity(16)\n" + PART_HEAD + _lane_app(q))
+    rt.start()
+    plan = next(p for p in rt._plans if isinstance(p, DevicePatternPlan))
+    kern = plan._parallel_kernel()
+    assert kern.expiry_queries["shared"] >= 1
+    calls: list = []
+    _record_lane_blocks(kern, calls)
+    rng = np.random.default_rng(3)
+    ih = rt.input_handler("S")
+    for b in range(3):
+        for j in range(400):
+            i = b * 400 + j
+            late = int(rng.integers(-300, 300)) if rng.random() < 0.3 else 0
+            ih.send((f"K{rng.integers(0, 9)}",
+                     float(np.round(rng.uniform(90, 130) * 4) / 4), 1),
+                    timestamp=1_700_000_000_000 + i * 7 + late)
+        rt.flush()
+    mgr.shutdown()
+    assert calls and all(isinstance(T, tuple) for T, _M, _ev in calls)
+    shared = ParallelChainKernel(kern.prog, kern.nfak, kern.family)
+    fresh = ParallelChainKernel(kern.prog, kern.nfak, kern.family)
+    fresh.expiry_plan = {pi: dataclasses.replace(a, fresh=True)
+                         for pi, a in fresh.expiry_plan.items()}
+    matches = 0
+    for T, M, ev in calls:
+        got = shared.block_fn(T, M)({}, ev)[1]
+        want = fresh.block_fn(T, M)({}, ev)[1]
+        assert sorted(got) == sorted(want)
+        for k in want:
+            assert np.asarray(got[k]).tobytes() \
+                == np.asarray(want[k]).tobytes(), (name, T, M, k)
+        matches += int(np.asarray(want["i"])[:, 0, 0].sum())
+    assert matches > 0, f"{name}: no match in any lane"
+
+
+def _c4_kernel():
+    from siddhi_tpu.core.nfa_parallel import ParallelChainKernel
+    mgr = SiddhiManager()
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", RuntimeWarning)
+        rt = mgr.create_app_runtime(
+            "@app:partitionCapacity(16)\n" + PART_HEAD + PART_Q)
+    plan = next(p for p in rt._plans if isinstance(p, DevicePatternPlan))
+    kern = plan._parallel_kernel()
+    mgr.shutdown()
+    return ParallelChainKernel(kern.prog, kern.nfak, kern.family)
+
+
+def test_c4_makes_one_expiry_query_and_shares_it():
+    """The north star's chain (`every e1 -> e2 -> e3 within 10 sec`) asks
+    once, at hop 1, and hop 2 reads the answer: the plan says so, EXPLAIN's
+    counter says so, and the lowered lane block carries ONE `within_kill`
+    scope (two when every hop asks)."""
+    import dataclasses
+    import re
+    from siddhi_tpu.core.nfa_parallel import ExpiryAsk, _expiry_plan
+    kern = _c4_kernel()
+    assert _expiry_plan(kern.prog) == {1: ExpiryAsk(10_000, fresh=True),
+                                       2: ExpiryAsk(10_000, fresh=False)}
+    assert kern.expiry_queries == {"built": 1, "shared": 1}
+    lanes, F = 8, 64
+    ev = {"__flat.__ts__": np.zeros((lanes, F), np.int32),
+          "__flat.__seq__": np.zeros((lanes, F), np.int32),
+          "__flat.0.price": np.zeros((lanes, F), np.float32),
+          "__nev__": np.zeros((lanes,), np.int32),
+          "__prev_seq__": np.zeros((lanes,), np.int32),
+          "__base_ts__": np.int64(0), "__base_seq__": np.int64(0)}
+
+    def scopes(k):
+        txt = k.block_fn((lanes, F), F).lower({}, ev).as_text(
+            debug_info=True)
+        return sorted(set(re.findall(r"hop(\d+)\)?/within_kill", txt)))
+    assert scopes(kern) == ["1"]
+    kern.expiry_plan = {pi: dataclasses.replace(a, fresh=True)
+                        for pi, a in kern.expiry_plan.items()}
+    kern._block_cache.clear()
+    assert scopes(kern) == ["1", "2"]
+
+
+def _prog(*positions, sequence=False):
+    """Hand-built ParallelProgram: positions as (kind, within_ms)."""
+    from siddhi_tpu.core.nfa_parallel import HopNode, PPos, ParallelProgram
+    pp = [PPos(kind, [HopNode(f"e{i}", 0)] * (2 if kind == "logical" else 1),
+               within_ms=w) for i, (kind, w) in enumerate(positions)]
+    return ParallelProgram(pp, ["S"], {}, {}, sequence=sequence)
+
+
+@pytest.mark.parametrize("name,prog,want", [
+    ("one_within_3_hops",
+     _prog(("single", 5), ("single", 5), ("single", 5), ("single", 5)),
+     {1: (5, True), 2: (5, False), 3: (5, False)}),
+    ("shorter_then_longer",
+     _prog(("single", 9), ("single", 3), ("single", 9)),
+     {1: (3, True), 2: (9, True)}),
+    ("longer_then_shorter",
+     _prog(("single", 9), ("single", 9), ("single", 3)),
+     {1: (9, True), 2: (3, True)}),
+    ("back_to_the_first_horizon_is_fresh",
+     _prog(("single", 9), ("single", 9), ("single", 3), ("single", 9)),
+     {1: (9, True), 2: (3, True), 3: (9, True)}),
+    # a count's successor advances under the COUNT's within, whatever its
+    # own: it shares the head's query, and so does what follows under it
+    ("count_head_successor_takes_the_counts_within",
+     _prog(("count", 7), ("single", 2), ("single", 7)),
+     {0: (7, True), 1: (7, False), 2: (7, False)}),
+    ("count_mid_then_successor",
+     _prog(("single", 7), ("count", 7), ("single", 7)),
+     {1: (7, True), 2: (7, False)}),
+    ("count_mid_of_another_within",
+     _prog(("single", 7), ("count", 4), ("single", 7)),
+     {1: (4, True), 2: (4, False)}),
+    ("logical_pair",
+     _prog(("single", 6), ("logical", 6), ("single", 6)),
+     {1: (6, True), 2: (6, False)}),
+    ("final_count_shares",
+     _prog(("single", 6), ("single", 6), ("count", 6)),
+     {1: (6, True), 2: (6, False)}),
+    ("final_count_of_another_within",
+     _prog(("single", 6), ("single", 6), ("count", 2)),
+     {1: (6, True), 2: (2, True)}),
+    # strict succession tests ts[j + 1] directly: no query, none held
+    ("sequence_asks_nothing",
+     _prog(("single", 5), ("single", 5), ("single", 5), sequence=True),
+     {}),
+])
+def test_expiry_plan_function(name, prog, want):
+    from siddhi_tpu.core.nfa_parallel import ExpiryAsk, _expiry_plan
+    assert _expiry_plan(prog) == {
+        pi: ExpiryAsk(w, fresh=f) for pi, (w, f) in want.items()}, name
+
+
+def test_expiry_plan_does_not_share_past_a_hop_that_does_not_enforce(
+        monkeypatch):
+    """Reuse leans on `alive implies j < kl` after every hop since the
+    query: a hop kind that stops enforcing it makes its successor ask
+    afresh, under the same `within` too."""
+    from siddhi_tpu.core import nfa_parallel as npar
+    prog = _prog(("single", 5), ("logical", 5), ("single", 5), ("single", 5))
+    assert [a.fresh for a in npar._expiry_plan(prog).values()] \
+        == [True, False, False]
+    monkeypatch.setitem(npar._ENFORCES_EXPIRY, "logical", False)
+    assert [a.fresh for a in npar._expiry_plan(prog).values()] \
+        == [True, True, False]
