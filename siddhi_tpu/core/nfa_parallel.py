@@ -7,20 +7,25 @@ chain, not by math.  *Simultaneous Finite Automata* (arXiv
 compose per-event transition functions associatively, and the whole
 block collapses to log-depth scans.  First-match semantics make the
 composed transition function DETERMINISTIC given a head event, so the
-SFA composition factorizes into per-state primitives answered in
-O(log T) each:
+SFA composition factorizes into per-state primitives, none of which
+steps through events:
 
   * next-match pointers for statically-maskable transitions — a reverse
     `jax.lax.associative_scan` (min semiring) per chase node;
-  * a vectorized perfect-segment-tree descent for *threshold*
-    transitions — capture-dependent filters of the monotone comparison
-    form `attr > f(earlier captures)` (the BENCH config-3/4 shape
-    `e2.price > e1.price`), answered as "first index >= s whose masked
-    value beats v" in O(log T) gathers per hop, batched over every
-    pending instance at once;
+  * ONE first-hit question, "the first index >= s whose masked value
+    beats v", batched over every pending instance at once (_FirstHit),
+    for *threshold* transitions — capture-dependent filters of the
+    monotone comparison form `attr > f(earlier captures)` (the BENCH
+    config-3/4 shape `e2.price > e1.price`) — for the `within` expiry
+    ("the first event past the head's horizon") and for rank/select.
+    It has two forms, picked from the block's static F (DENSE_MAX_F):
+    a lane of a few hundred events reduces all (event, query) pairs in
+    one fused masked min, no gather and no loop; a long lane (the flat
+    P = 1 block, fused multi-query lanes) builds a perfect segment tree
+    and walks it in up to 2*log2(L)+1 rounds of dependent gathers;
   * rank/select over occurrence-count prefix sums for `<m:n>` count
-    quantifiers — "the min-th occurrence after entry" is one segment
-    tree query on the monotone cumulative-count array (the bit-packed
+    quantifiers — "the min-th occurrence after entry" is one first-hit
+    query on the monotone cumulative-count array (the bit-packed
     state-SET lowering of arXiv 2210.10077 collapsed onto the counter
     lattice: the u32 frontier word's reachable set is an interval, so
     its boundary IS the rank);
@@ -32,7 +37,8 @@ O(log T) each:
 
 Two plan families are built on these primitives:
 
-  * family "scan" — the SFA lowering above, O(S log T) depth.
+  * family "scan" — the SFA lowering above: O(S log T) depth on the
+    tree form, O(S) fused reductions on the dense one.
   * family "dfa"  — NFA->DFA/hybrid lowering (arXiv 2210.10077) with
     state-set compaction and bit-packed transitions: the per-event
     chase-node masks pack into one u32 *symbol word* (bit k = event
@@ -42,7 +48,7 @@ Two plan families are built on these primitives:
     next pointers ride ONE associative scan over T/4 elements — a
     multi-stride dense table walk instead of per-event stepping
     (cf. 2209.05686, CAMA 2112.00267).  Threshold and count hops share
-    the segment-tree machinery (the "hybrid" part).
+    the first-hit machinery (the "hybrid" part).
 
 Eligibility (classify_parallel) is strict and *sound*: anything outside
 the supported algebra reports a reason string and the planner keeps the
@@ -384,7 +390,7 @@ def classify_parallel(spec: ChainSpec, kernel: NFAKernel, strings,
 def _chase_lanes(prog: ParallelProgram) -> list:
     """Static chase nodes (pi, ni) that resolve via next-match pointers —
     the dfa family's bit-packable symbol lanes.  Count positions resolve
-    via rank/select and threshold hops via the segment tree; neither
+    via rank/select and threshold hops via a first-hit query; neither
     consumes a symbol bit."""
     lanes = []
     for pi, pos in enumerate(prog.positions):
@@ -576,30 +582,37 @@ def _build_heap(vals, mask, L: int, agg: str, dt):
                            + [lv for lv in reversed(levels)])
 
 
-def _first_hit(heap, L: int, s, v, op: str):
-    """First leaf index >= s whose value satisfies OP v; L when none.
-    Vectorized over query arrays s, v; 2*log2(L) gather rounds total
-    (up-walk decomposing [s, L) into aligned blocks visited left to
-    right, then a descent into the first qualifying subtree).
+_CMP = {"gt": lambda a, b: a > b, "ge": lambda a, b: a >= b,
+        "lt": lambda a, b: a < b, "le": lambda a, b: a <= b}
 
-    Hit checks are sentinel-safe: `>=`/`<=` rewrite to strict compares
-    against the adjacent representable value in the tree dtype (exact —
-    int32 trees are widened, floats use nextafter; an infinite rhs
-    meeting infinite data, or an int64 rhs of exactly INT64_MIN, are
-    the accepted pathological corners)."""
-    va = jnp.asarray(v, heap.dtype)
-    if op == "ge":
-        v = jnp.nextafter(va, jnp.array(-jnp.inf, heap.dtype)) \
-            if jnp.issubdtype(heap.dtype, jnp.floating) else va - 1
-        op = "gt"
+
+def _first_hit(heap, L: int, s, v, op: str):
+    """The tree form: first leaf index >= s whose value satisfies OP v; L
+    when none.  Vectorized over query arrays s, v; 2*log2(L) rounds of
+    data-dependent gathers (up-walk decomposing [s, L) into aligned blocks
+    visited left to right, then a descent into the first qualifying
+    subtree), each round a gather of one element a query.
+
+    Hit checks are sentinel-safe.  Integer `>=`/`<=` rewrite to strict
+    compares against the adjacent value (exact: int32 trees are widened;
+    an int64 rhs of exactly INT64_MIN/MAX is the accepted corner).
+    Floating `>=`/`<=` compare directly and refuse the sentinel itself, so
+    a masked-out leaf never answers an infinite rhs (real infinite data
+    meeting an infinite rhs of its own sign is the accepted corner).  NOT
+    through nextafter: the neighbour of 0.0 is a denormal, which the
+    backend's compare flushes to zero, and `price >= 0.0` lost its
+    `price == 0.0` rows."""
+    v = jnp.asarray(v, heap.dtype)
+    cmp = _CMP[op]
+    if jnp.issubdtype(heap.dtype, jnp.floating):
+        if op == "ge":
+            cmp = lambda a, b: (a >= b) & (a > -jnp.inf)    # noqa: E731
+        elif op == "le":
+            cmp = lambda a, b: (a <= b) & (a < jnp.inf)     # noqa: E731
+    elif op == "ge":
+        v, cmp = v - 1, _CMP["gt"]
     elif op == "le":
-        v = jnp.nextafter(va, jnp.array(jnp.inf, heap.dtype)) \
-            if jnp.issubdtype(heap.dtype, jnp.floating) else va + 1
-        op = "lt"
-    else:
-        v = va
-    cmp = {"gt": lambda a, b: a > b,
-           "lt": lambda a, b: a < b}[op]
+        v, cmp = v + 1, _CMP["lt"]
     P = max(L.bit_length() - 1, 0)
 
     # fori_loop (not an unrolled python loop): the round count is static
@@ -631,6 +644,114 @@ def _first_hit(heap, L: int, s, v, op: str):
 
     fnode = lax.fori_loop(0, P, down, fnode)
     return jnp.where(found, fnode - L, L).astype(_I32)
+
+
+def _first_hit_dense(vals, keep, L: int, s, v, op: str):
+    """The dense form of the same question: a masked min-reduction over
+    every (event, query) pair of the block,
+
+        out[h] = min over i of (i  if  i >= s[h] and keep[i]
+                                       and vals[i] OP v[h]   else  L)
+
+    No tree, no gather, no loop and no sentinel, so the compare runs in
+    the plain promotion of the two sides and `>=`/`<=` are themselves.
+    NaN values never hit (the sequential kernel's per-event compare is
+    False on them); a NaN rhs hits nothing.  Events lie along axis 0 and
+    queries along axis 1: the reduction then runs down the sublanes and
+    leaves the answers a query a lane, the layout every caller holds its
+    per-head arrays in.
+
+    An int32 column against an int64 rhs (the expiry query: ts offsets
+    reach +-2^30 and `ts + within` passes 2^31) compares in int32 against
+    the rhs SATURATED to int32, which is exact: a rhs above INT32_MAX is
+    beaten by nothing (`gt`/`ge`) or by everything (`lt`/`le`), one below
+    INT32_MIN the other way round, and both are settled a query."""
+    F = vals.shape[0]
+    s, v = jnp.broadcast_arrays(jnp.asarray(s, _I32), jnp.asarray(v))
+    if jnp.issubdtype(vals.dtype, jnp.floating):
+        keep = keep & ~jnp.isnan(vals)
+    every = None
+    if vals.dtype == jnp.int32 and v.dtype == jnp.int64:
+        info = jnp.iinfo(jnp.int32)
+        below, above = v < info.min, v > info.max
+        every, none = (below, above) if op in ("gt", "ge") \
+            else (above, below)
+        s = jnp.where(none, jnp.int32(L), s)
+        v = jnp.clip(v, info.min, info.max).astype(jnp.int32)
+    else:
+        dt = jnp.promote_types(vals.dtype, v.dtype)
+        vals, v = vals.astype(dt), v.astype(dt)
+    i = jnp.arange(F, dtype=_I32)[:, None]
+    beats = _CMP[op](vals[:, None], v[None, :])
+    if every is not None:
+        beats = beats | every[None, :]
+    hit = keep[:, None] & (i >= s[None, :]) & beats
+    return jnp.min(jnp.where(hit, i, jnp.int32(L)), axis=0)
+
+
+# Which form answers a block's first-hit queries.  Both forms cost the
+# same for every lane and every query, so the choice is a bound on F, the
+# events one lane of the block holds: its static shape, and nothing else
+# is consulted.  A query costs the tree log2(L) to 2*log2(L)+1 rounds of
+# one dependent gather an element, and costs the dense form F pairs.  Two
+# TPU v5e readings decide (PERF.md section 5, PR 30): a round takes
+# 10.25 ns an element at every shape from 1024 x 64 to 16 x 8192 (twice to
+# three times that on the emulated-int64 expiry tree); the fused
+# compare-select-min takes 2.0-2.6 ps a pair from 2e8 pairs a call up, at
+# F = 448 as at F = 8192, and never holds the (lanes, F, F) intermediate.
+# At F = 448 that is 1 ns against 92-195 ns a query; the forms cross where
+# F * 2.2 ps = rounds * 10.25 ns, between F = 80,000 (rounds = log2 L) and
+# 170,000 (2*log2(L)+1).  The bound sits a factor of twenty under that:
+# nothing was measured past F = 8192; pairs grow as F squared, so a lane
+# near the crossover costs seconds a call either way (1024 lanes x 16384^2
+# pairs are 0.6 s a query); and a backend that does not fuse the reduction
+# (the CPU test lane) holds F*F*4 bytes a lane, 64 MB at the bound.  4096
+# is also the smallest flat P = 1 block the plan ships (pattern_plan's
+# f_min): a small unpartitioned flush is dense, and one of 2^18 events, or
+# a fused multi-query lane that sees the whole stream, walks the tree.
+DENSE_MAX_F = 4096
+
+
+class _FirstHit:
+    """One block's entry for "the first index i >= s[h] with keep[i] and
+    vals[i] OP v[h]; L if none", asked by every query h at once: the
+    expiry query, a threshold hop and rank/select are this one question.
+    A lane of at most DENSE_MAX_F events reduces all (event, query) pairs
+    (_first_hit_dense); a longer one builds a segment tree over the
+    column, once per (column, mask, direction), and walks it
+    (_build_heap, _first_hit).  Counts what it was asked while the block
+    is traced: rt.explain()'s `first_hit`."""
+
+    def __init__(self, F: int, L: int):
+        self.F, self.L = F, L
+        self.dense = F <= DENSE_MAX_F
+        self.queries = 0
+        self.pairs = 0            # (event, query) pairs a lane, dense form
+        self._heaps: list = []    # (vals, keep, agg, dtype, heap)
+
+    def __call__(self, vals, keep, s, v, op: str):
+        self.queries += 1
+        if self.dense:
+            out = _first_hit_dense(vals, keep, self.L, s, v, op)
+            self.pairs += self.F * out.shape[0]
+            return out
+        agg = "max" if op in ("gt", "ge") else "min"
+        dt = _tree_dtype(vals.dtype, jnp.asarray(v).dtype)
+        for a, k, g, d, heap in self._heaps:
+            if a is vals and k is keep and g == agg and d == dt:
+                break
+        else:
+            with jax.named_scope("heap"):
+                heap = _build_heap(vals, keep, self.L, agg, dt)
+            self._heaps.append((vals, keep, agg, dt, heap))
+        return _first_hit(heap, self.L, s, v, op)
+
+    def asked(self, lanes: int) -> dict:
+        """What one call of the traced block asks, over all its lanes."""
+        return {"dense": self.queries if self.dense else 0,
+                "tree": 0 if self.dense else self.queries,
+                "pairs_per_call": lanes * self.pairs,
+                "lanes": lanes, "F": self.F}
 
 
 def _next_static_scan(mask, L: int):
@@ -686,26 +807,39 @@ class ParallelChainKernel:
         # compiled plan, shown by rt.explain()
         self.expiry_queries = {"built": fresh,
                                "shared": len(self.expiry_plan) - fresh}
+        # what each traced block asked of _FirstHit, by block key, and
+        # the key last asked for: rt.explain()'s `first_hit`
+        self._first_hit_of: dict = {}
+        self._last_key = None
+
+    @property
+    def first_hit(self) -> Optional[dict]:
+        """{'dense': n, 'tree': m, 'pairs_per_call': p, 'lanes': l,
+        'F': f}: the first-hit queries of the block last dispatched, by
+        the form that answers them (one form a block, by its F:
+        DENSE_MAX_F), and the (event, query) pairs the dense form reduces
+        in one call over all lanes.  None until a block has been traced."""
+        return self._first_hit_of.get(self._last_key)
 
     # NFAKernel-compatible surface consumed by _call_block / bench
     def block_fn(self, T, M: int):
-        key = (T, M)
+        key = self._last_key = (T, M)
         fn = self._block_cache.get(key)
         if fn is None:
             if isinstance(T, tuple):
-                fn = jax.jit(self._make_lane_block(M))
+                fn = jax.jit(self._make_lane_block(T, M))
             else:
-                fn = jax.jit(self._make_block(M))
+                fn = jax.jit(self._make_block(T, M))
             self._block_cache[key] = fn
         return fn
 
-    def _make_block(self, M: int):
+    def _make_block(self, T, M: int):
         def block(state, ev):
             with compute_dtypes(self._mode):
-                return state, self._block_impl(ev, M)
+                return state, self._block_impl(ev, M, T)
         return block
 
-    def _make_lane_block(self, M: int):
+    def _make_lane_block(self, T, M: int):
         """vmap the flat block over the lane axis: per-lane leaves (lane-
         major grids, per-lane scalars, params, qids) map on axis 0;
         shared leaves (bases, broadcast event arrays in fused mode)
@@ -723,7 +857,7 @@ class ParallelChainKernel:
 
             def one(e):
                 with compute_dtypes(self._mode):
-                    return self._block_impl(e, M)
+                    return self._block_impl(e, M, T)
             return state, jax.vmap(one, in_axes=(axes,))(ev)
         return lane_block
 
@@ -858,7 +992,7 @@ class ParallelChainKernel:
 
     # -- the block --------------------------------------------------------
 
-    def _block_impl(self, ev, M: int):
+    def _block_impl(self, ev, M: int, T):
         # every phase runs under a jax.named_scope, so each device
         # operation's `op_name` says which phase it belongs to and the
         # names survive a recompile (XLA's own `while.73` do not)
@@ -903,37 +1037,36 @@ class ParallelChainKernel:
                 return jnp.where(s < F, nx[jnp.clip(s, 0, F - 1)],
                                  jnp.int32(L))
 
-        # occurrence ranks per count position: inclusive cumulative match
-        # count + a segment tree over it — "the r-th occurrence after
-        # entry" is ONE monotone first-hit query (rank/select), so count
-        # minima and capture indices never iterate
+        # every "first event at or after s that ..." below is one entry,
+        # dense or tree by the block's F (DENSE_MAX_F)
+        first_hit = _FirstHit(F, L)
+
+        # occurrence ranks per count position: the inclusive cumulative
+        # match count — "the r-th occurrence after entry" is ONE monotone
+        # first-hit query on it (rank/select), so count minima and capture
+        # indices never iterate
         ranks: dict = {}
-        rank_heaps: dict = {}
         for pi, pos in enumerate(prog.positions):
             if pos.kind != "count":
                 continue
-            with scope("rank_heaps"):
-                r = jnp.cumsum(nmask[(pi, 0)].astype(_I32), dtype=_I32)
-                ranks[pi] = r
-                rank_heaps[pi] = _build_heap(r, valid, L, "max",
-                                             jnp.dtype(jnp.int64))
+            with scope("ranks"):
+                ranks[pi] = jnp.cumsum(nmask[(pi, 0)].astype(_I32),
+                                       dtype=_I32)
 
         def select(pi, s, r):
             """First index >= s whose inclusive occurrence rank >= r."""
             with scope("rank_select"):
-                return _first_hit(rank_heaps[pi], L, s, r, "ge")
+                return first_hit(ranks[pi], valid, s, r, "ge")
 
-        # expiry heap: the sequential kernel expires a waiting instance
-        # on the FIRST arriving event whose age exceeds the position's
-        # `within` horizon — matching or not (nfa_device._step computes
-        # `expired` before the match mask, over timey=valid).  With
-        # out-of-order timestamps a later event can carry a REGRESSED
-        # ts, so checking the matched event alone would resurrect
-        # instances the sequential kernel killed.  i64 aggregation:
-        # ts offsets reach ±2^30 and ts+W must not wrap i32.
-        with scope("expiry_heap"):
-            ts_heap = _build_heap(ts, valid, L, "max", jnp.dtype(jnp.int64))
-            ts64 = ts.astype(jnp.int64)
+        # expiry: the sequential kernel expires a waiting instance on the
+        # FIRST arriving event whose age exceeds the position's `within`
+        # horizon — matching or not (nfa_device._step computes `expired`
+        # before the match mask, over timey=valid).  With out-of-order
+        # timestamps a later event can carry a REGRESSED ts, so checking
+        # the matched event alone would resurrect instances the
+        # sequential kernel killed.  The horizon is int64: ts offsets
+        # reach ±2^30 and ts+W must not wrap i32.
+        ts64 = ts.astype(jnp.int64)
 
         asks = self.expiry_plan
         held_kl = None
@@ -941,32 +1074,27 @@ class ParallelChainKernel:
         def killer(pi, s):
             """First event at or after s past the head's `within` horizon
             (per-head v = head ts + W; queries indexed by head).  One
-            descent per distinct horizon: a position whose ask is not
+            query per distinct horizon: a position whose ask is not
             fresh reads the index the chain already holds
             (_expiry_plan)."""
             nonlocal held_kl
             if asks[pi].fresh:
                 with scope("within_kill"):
-                    held_kl = _first_hit(
-                        ts_heap, L, s,
+                    held_kl = first_hit(
+                        ts, valid, s,
                         ts64 + jnp.int64(asks[pi].within_ms), "gt")
             return held_kl
 
         def threshold_next(hop: HopNode, s, idx_of):
             with scope("threshold_next"):
                 th = hop.threshold
-                agg = "max" if th.op in ("gt", "ge") else "min"
                 own = ev[f"__flat.{hop.scode}."
                          f"{th.own_key.split('.', 1)[1]}"]
                 env = self._gather_env(ev, idx_of, th.rhs.reads, F,
                                        base_ts)
                 v = jnp.broadcast_to(th.rhs.fn(env), (F,))
-                dt = _tree_dtype(own.dtype, v.dtype)
-                with scope("heap"):
-                    heap = _build_heap(own,
-                                       nmask[self.prog.ref_of[hop.ref]],
-                                       L, agg, dt)
-                return _first_hit(heap, L, s, v, th.op)
+                return first_hit(own, nmask[self.prog.ref_of[hop.ref]],
+                                 s, v, th.op)
 
         # ---- the state chase: every event index is a candidate head ----
         j0 = jnp.arange(F, dtype=_I32)
@@ -1194,8 +1322,8 @@ class ParallelChainKernel:
                         if pos.max_count < UNBOUNDED else avail
 
                 def sel_q(r):
-                    return jnp.clip(_first_hit(rank_heaps[pi], L, s_m,
-                                               ra_m + r, "ge"), 0, F - 1)
+                    return jnp.clip(first_hit(ranks[pi], valid, s_m,
+                                              ra_m + r, "ge"), 0, F - 1)
                 for rp in sorted(rps):        # set of str: see _gather_env
                     _b, cidx = _base_ref(rp)
                     if cidx is None or cidx == "last":
@@ -1258,4 +1386,6 @@ class ParallelChainKernel:
             out = {"i": jnp.stack(irows, axis=0)}
             if frows:
                 out["f"] = jnp.stack(frows, axis=0)
+        self._first_hit_of[(T, M)] = first_hit.asked(
+            T[0] if isinstance(T, tuple) else 1)
         return out

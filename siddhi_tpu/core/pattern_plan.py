@@ -600,11 +600,21 @@ class DevicePatternPlan(QueryPlan):
 
     @property
     def expiry_queries(self) -> Optional[dict]:
-        """{'built': b, 'shared': s}: `within` expiry descents the parallel
+        """{'built': b, 'shared': s}: `within` expiry queries the parallel
         block makes per head, and positions that reuse one (EXPLAIN)."""
         if self.family not in ("scan", "dfa"):
             return None
         return dict(self._parallel_kernel().expiry_queries)
+
+    @property
+    def first_hit(self) -> Optional[dict]:
+        """The first-hit queries of the parallel block last dispatched, by
+        the form that answers them (ParallelChainKernel.first_hit;
+        EXPLAIN)."""
+        if self.family not in ("scan", "dfa"):
+            return None
+        asked = self._parallel_kernel().first_hit
+        return dict(asked) if asked else None
 
     def _rebase(self, min_ts: int, min_seq: int) -> None:
         """Shift the plan's ts/seq bases forward and adjust persistent slot

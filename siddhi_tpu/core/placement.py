@@ -235,6 +235,9 @@ def _query_entry(rt, plan) -> Optional[dict]:
         expiry = getattr(plan, "expiry_queries", None)
         if expiry:
             ent["expiry_queries"] = expiry
+        first_hit = getattr(plan, "first_hit", None)
+        if first_hit:
+            ent["first_hit"] = first_hit
         families = getattr(plan, "families", None)
         if families:
             rejected = {f: r for f, r in sorted(families.items())

@@ -391,6 +391,51 @@ def test_explain_shows_expiry_queries_of_parallel_families(force, want):
     mgr.shutdown()
 
 
+@pytest.mark.parametrize("part,events,side", [
+    (True, 40, "dense"),      # a lane grid of 64-slot lanes
+    (False, 40, "dense"),     # the flat block's capacity starts AT the bound
+    (False, 2100, "tree"),    # ... and its next bucket, 6144, is past it
+    (False, 0, "dense"),      # before any flush: the (2, 8) build validation
+])
+def test_explain_shows_first_hit_form_of_the_block_last_dispatched(
+        part, events, side):
+    """`first_hit` sits beside `expiry_queries`: the block's first-hit
+    queries by the form that answers them, chosen from the block's F alone
+    (nfa_parallel.DENSE_MAX_F), and the pairs the dense form reduces."""
+    from siddhi_tpu.core.nfa_parallel import DENSE_MAX_F
+    q = """@info(name='q')
+        from every e1=S[price > 100] -> e2=S[price > e1.price]
+            -> e3=S[price > e2.price] within 10 sec
+        select e1.price as p1, e2.price as p2, e3.price as p3
+        insert into Out;"""
+    head = "define stream S (sym string, price double);\n"
+    if part:
+        app = "@app:partitionCapacity(8)\n" + head \
+            + "partition with (sym of S)\nbegin\n" + q + "\nend;\n"
+    else:
+        app = "@app:devicePatterns('always')\n" + head + q
+    mgr, rt = _build(app)
+    rt.start()
+    ih = rt.input_handler("S")
+    for i in range(events):
+        ih.send((f"K{i % 3}", 101.0 + i % 7), timestamp=1_700_000_000_000 + i)
+    rt.flush()
+    ent = rt.explain()["queries"]["q"]
+    assert list(ent)[:6] == ["path", "plan", "kind", "family",
+                             "expiry_queries", "first_hit"], list(ent)
+    fh = ent["first_hit"]
+    assert sorted(fh) == ["F", "dense", "lanes", "pairs_per_call", "tree"]
+    assert (fh["F"] <= DENSE_MAX_F) == (side == "dense"), fh
+    if not part and events:
+        assert fh["F"] == (DENSE_MAX_F if side == "dense" else 6144), fh
+    # one expiry query (shared down the chain) and two threshold hops
+    assert (fh["dense"], fh["tree"]) == ((3, 0) if side == "dense"
+                                         else (0, 3)), fh
+    assert fh["pairs_per_call"] == (3 * fh["lanes"] * fh["F"] ** 2
+                                    if side == "dense" else 0), fh
+    mgr.shutdown()
+
+
 def test_placement_statistics_and_prometheus():
     from siddhi_tpu.core.telemetry import render_prometheus
     mgr, rt = _build("""

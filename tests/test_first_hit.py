@@ -1,0 +1,395 @@
+"""The lane block's one question, "the first index i >= s[h] with keep[i]
+and vals[i] OP v[h]; L if none", in its two forms: the dense all-pairs
+masked min (short lanes) and the segment tree (long ones), each against a
+plain numpy loop; then the whole block on both sides of the rule that
+picks the form (nfa_parallel.DENSE_MAX_F), forced by SHAPE: the same
+recorded block input padded past the bound must give the same bytes, and
+flushes longer than the bound must still equal the sequential kernel."""
+import warnings
+import zlib
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from siddhi_tpu import SiddhiManager
+from siddhi_tpu.core import nfa_parallel as npar
+from siddhi_tpu.core.nfa_device import LOCAL_SPAN, pow2_at_least
+from siddhi_tpu.core.pattern_plan import DevicePatternPlan
+
+import test_plan_families as pf
+
+OPS = {"gt": np.greater, "ge": np.greater_equal,
+       "lt": np.less, "le": np.less_equal}
+I32_MIN, I32_MAX = -2 ** 31, 2 ** 31 - 1
+F = 37                                   # not a power of two: L = 64
+L = pow2_at_least(F, lo=2)
+
+
+def _ftz(a):
+    """A floating compare on the backend reads a denormal as zero."""
+    if a.dtype.kind != "f":
+        return a
+    return np.where(np.abs(a) < np.finfo(a.dtype).tiny, a.dtype.type(0), a)
+
+
+def _loop(vals, keep, s, v, op):
+    """The question, one query and one event at a time."""
+    out = np.full(len(s), L, np.int32)
+    vals, v = _ftz(vals), _ftz(v)
+    for h in range(len(s)):
+        for i in range(max(int(s[h]), 0), len(vals)):
+            if keep[i] and vals[i] == vals[i] and OPS[op](vals[i], v[h]):
+                out[h] = i
+                break
+    return out
+
+
+def _dense(vals, keep, s, v, op):
+    return np.asarray(npar._first_hit_dense(
+        jnp.asarray(vals), jnp.asarray(keep), L, jnp.asarray(s),
+        jnp.asarray(v), op))
+
+
+def _tree(vals, keep, s, v, op):
+    dt = npar._tree_dtype(vals.dtype, v.dtype)
+    heap = npar._build_heap(jnp.asarray(vals), jnp.asarray(keep), L,
+                            "max" if op in ("gt", "ge") else "min", dt)
+    return np.asarray(npar._first_hit(heap, L, jnp.asarray(s),
+                                      jnp.asarray(v), op))
+
+
+def _columns(kind, rng):
+    """(vals, v per query): the column's special values as data AND as rhs."""
+    if kind == "f32":
+        pool = np.array([0.0, -0.0, 1.5, -2.0, np.nan, np.inf, -np.inf,
+                         3.25, 1e-40, -1e-40], np.float32)
+        return rng.choice(pool, F), rng.choice(pool, F)
+    if kind == "i32":
+        pool = np.array([0, 1, -1, I32_MAX, I32_MIN, 7, I32_MAX - 1,
+                         I32_MIN + 1], np.int32)
+        return rng.choice(pool, F), rng.choice(pool, F)
+    # the expiry query: int32 ts offsets out to +-LOCAL_SPAN against an
+    # int64 horizon `head ts + within_ms`, `within_ms` above 2^31 too
+    pool = np.array([0, LOCAL_SPAN, -LOCAL_SPAN, 5, 1000, LOCAL_SPAN - 1],
+                    np.int32)
+    ts = rng.choice(pool, F)
+    if kind == "ts":
+        ts = np.sort(ts)
+    within = rng.choice(np.array([0, 10, 2 ** 30, 2 ** 31 + 5, 2 ** 33],
+                                 np.int64), F)
+    return ts, ts.astype(np.int64) + within      # "ts_regressed": unsorted
+
+
+def _starts(rng):
+    """Every kind of s in one array: below 0, inside, = F, in (F, L], above L."""
+    return np.concatenate([
+        [-5, -1, 0, F - 1, F, F + 1, L, L + 3],
+        rng.integers(0, F, F - 8)]).astype(np.int32)
+
+
+@pytest.mark.parametrize("mask", ["none", "all", "random"])
+@pytest.mark.parametrize("kind", ["f32", "i32", "ts", "ts_regressed"])
+@pytest.mark.parametrize("op", list(OPS))
+def test_dense_tree_and_loop_agree(op, kind, mask):
+    rng = np.random.default_rng(zlib.crc32(f"{op}-{kind}-{mask}".encode()))
+    vals, v = _columns(kind, rng)
+    keep = {"none": np.zeros(F, bool), "all": np.ones(F, bool),
+            "random": rng.random(F) < 0.6}[mask]
+    s = _starts(rng)
+    want = _loop(vals, keep, s, v, op)
+    if mask != "none":
+        assert (want < L).any() and (want == L).any(), "a one-sided case"
+    assert _dense(vals, keep, s, v, op).tolist() == want.tolist()
+    got = _tree(vals, keep, s, v, op)
+    # the tree's accepted corner (its docstring): an infinite rhs, which
+    # masked-out leaves' sentinels would otherwise answer
+    sure = np.isfinite(v) if kind == "f32" else np.ones(F, bool)
+    assert got[sure].tolist() == want[sure].tolist()
+
+
+@pytest.mark.parametrize("s0,want", [(-7, 2), (3, 5), (F, L), (L + 9, L)])
+@pytest.mark.parametrize("form", ["dense", "tree"])
+def test_start_is_clipped_not_wrapped(form, s0, want):
+    """s below 0 reads from 0; s at or past F finds nothing (L)."""
+    vals = np.zeros(F, np.float32)
+    vals[[2, 5]] = 9.0
+    s = np.full(4, s0, np.int32)
+    v = np.full(4, 1.0, np.float32)
+    got = (_dense if form == "dense" else _tree)(
+        vals, np.ones(F, bool), s, v, "gt")
+    assert got.tolist() == [want] * 4
+
+
+@pytest.mark.parametrize("op,rhs,hit", [
+    ("ge", 0.0, 0.0), ("le", 0.0, 0.0), ("ge", -0.0, 0.0),
+    # the smallest normal's neighbour is the largest denormal
+    ("ge", float(np.finfo(np.float32).tiny), float(np.finfo(np.float32).tiny)),
+    ("le", -float(np.finfo(np.float32).tiny), -float(np.finfo(np.float32).tiny)),
+])
+@pytest.mark.parametrize("form", ["dense", "tree"])
+def test_ge_le_at_a_rhs_whose_neighbour_is_denormal(form, op, rhs, hit):
+    """`price >= 0.0` keeps its `price == 0.0` rows: the tree used to
+    rewrite `>= v` to `> nextafter(v, -inf)`, a denormal for v = 0.0 that
+    the compare flushes to zero, so it behaved as `> 0.0`."""
+    miss = -3.0 if op == "ge" else 3.0
+    vals = np.full(F, miss, np.float32)
+    vals[11] = hit
+    s = np.array([0, 11, 12], np.int32)
+    v = np.full(3, rhs, np.float32)
+    got = (_dense if form == "dense" else _tree)(
+        vals, np.ones(F, bool), s, v, op)
+    assert got.tolist() == [11, 11, L]
+
+
+@pytest.mark.parametrize("within,want", [
+    # every horizon past INT32_MAX: nothing expires anyone (a compare that
+    # wrapped int32 would expire every head at once)
+    (2 ** 31 + 5, [8, 8, 8, 8, 8]),
+    # the earliest head's horizon is 2^30 - 8: the event at 2^30 - 3 ends it
+    (2 ** 31 - 8, [3, 8, 8, 8, 8]),
+    (2 ** 33, [8, 8, 8, 8, 8]),
+])
+@pytest.mark.parametrize("form", ["dense", "tree"])
+def test_expiry_horizon_past_int32_does_not_wrap(form, within, want):
+    """ts offsets at +-2^30 (LOCAL_SPAN) against `head ts + within_ms`."""
+    ts = np.array([-LOCAL_SPAN, -LOCAL_SPAN + 10, 0, LOCAL_SPAN - 3,
+                   LOCAL_SPAN], np.int32)
+    v = ts.astype(np.int64) + within
+    s = np.arange(1, 6, dtype=np.int32)
+    keep = np.ones(5, bool)
+    if form == "dense":
+        got = npar._first_hit_dense(jnp.asarray(ts), jnp.asarray(keep), 8,
+                                    s, jnp.asarray(v), "gt")
+    else:
+        heap = npar._build_heap(jnp.asarray(ts), jnp.asarray(keep), 8,
+                                "max", jnp.dtype(jnp.int64))
+        got = npar._first_hit(heap, 8, jnp.asarray(s), jnp.asarray(v), "gt")
+    assert np.asarray(got).tolist() == want
+
+
+@pytest.mark.parametrize("op,want", [("gt", [1, 2, 3, 4, 8]),
+                                     ("ge", [1, 2, 3, 4, 8]),
+                                     ("lt", [8] * 5), ("le", [8] * 5)])
+def test_int64_rhs_below_every_int32_saturates_exactly(op, want):
+    """The dense form narrows an int64 rhs to int32 by saturation: a rhs
+    under INT32_MIN is beaten by every value, INT32_MIN itself included."""
+    vals = np.array([5, I32_MIN, 0, I32_MIN, 7], np.int32)
+    v = np.full(5, -2 ** 40, np.int64)
+    got = npar._first_hit_dense(jnp.asarray(vals), jnp.ones(5, bool), 8,
+                                np.arange(1, 6, dtype=np.int32),
+                                jnp.asarray(v), op)
+    assert np.asarray(got).tolist() == want
+
+
+# ---------------------------------------------------------------------------
+# the rule, and the block on both sides of it
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("lanes,F_,dense", [
+    (1024, 448, True),            # pattern1k.sat; a quarter of it per mesh chip
+    (256, 448, True),
+    (1024, 64, True),             # pattern1k.wire-paced
+    (1, npar.DENSE_MAX_F, True),  # the boundary itself
+    (1, npar.DENSE_MAX_F + 1, False),
+    (1, 2 ** 18, False),          # the flat P = 1 block of a 2^18-event batch
+    (1000, 2 ** 16, False),       # fused multi-query lanes see the whole stream
+])
+def test_rule_reads_the_blocks_static_shape(lanes, F_, dense):
+    """The form is a function of F alone, and the counter that says which
+    engaged is what tracing the block at that shape records (no run: the
+    block is only traced here, as `jax.eval_shape` does)."""
+    kern = pf._c4_kernel()
+    T = (lanes, F_) if lanes > 1 else F_
+    shape = (lanes, F_) if lanes > 1 else (F_,)
+    lead = (lanes,) if lanes > 1 else ()
+    ev = {"__flat.__ts__": jax.ShapeDtypeStruct(shape, jnp.int32),
+          "__flat.__seq__": jax.ShapeDtypeStruct(shape, jnp.int32),
+          "__flat.0.price": jax.ShapeDtypeStruct(shape, jnp.float32),
+          "__nev__": jax.ShapeDtypeStruct(lead, jnp.int32),
+          "__prev_seq__": jax.ShapeDtypeStruct(lead, jnp.int32),
+          "__base_ts__": jax.ShapeDtypeStruct((), jnp.int64),
+          "__base_seq__": jax.ShapeDtypeStruct((), jnp.int64)}
+    assert kern.first_hit is None
+    jax.eval_shape(kern.block_fn(T, F_), {}, ev)
+    # C4: one expiry query (shared down the chain) + two threshold hops
+    want = {"dense": 3, "tree": 0, "pairs_per_call": 3 * lanes * F_ * F_} \
+        if dense else {"dense": 0, "tree": 3, "pairs_per_call": 0}
+    assert kern.first_hit == {**want, "lanes": lanes, "F": F_}
+
+
+def _resize_block(ev, T, F2, lanes=2):
+    """The same block input with every event array cut or padded to F2
+    slots (`__nev__` says how many are events: the rest are never valid),
+    and a lane grid cut to its first `lanes` lanes."""
+    lane = isinstance(T, tuple)
+    F1 = T[1] if lane else T
+    assert int(np.max(ev["__nev__"])) <= F2
+    out = {}
+    for k, v in ev.items():
+        if lane and v.ndim and v.shape[0] == T[0]:
+            v = v[:lanes]
+        if k.startswith("__flat.") and v.shape[-1:] == (F1,):
+            v = v[..., :F2]
+            v = np.pad(v, [(0, 0)] * (v.ndim - 1) + [(0, F2 - v.shape[-1])])
+        out[k] = v
+    return out, ((min(lanes, T[0]), F2) if lane else F2)
+
+
+# name -> (query, partitioned, families that engage); per-element `within`s
+# that differ, a count head, a final count and a logical position among
+# them (PR 27's corpus)
+BLOCKS = {
+    "c4_lanes": (pf.C4_Q.replace("1 sec", "400 milliseconds"), True,
+                 ("scan",)),
+    "within_differs_lanes": (pf.EXPIRY["within_long_then_short"][0], True,
+                             ("scan", "dfa")),
+    "count_head_flat": (pf.EXPIRY["count_head_successor"][0], False,
+                        ("scan", "dfa")),
+    "logical_flat": (pf.EXPIRY["logical_pair_chain"][0], False,
+                     ("scan", "dfa")),
+    "final_count_lanes": (pf.EXPIRY["final_count_chain"][0], True,
+                          ("scan",)),
+    "le_threshold_flat": (pf.ELIGIBLE["le_threshold"][0], False, ("scan",)),
+}
+
+
+@pytest.mark.parametrize("name,fam", [(n, f) for n, b in BLOCKS.items()
+                                      for f in b[2]])
+def test_block_bytes_equal_across_the_rule(name, fam):
+    """The last block of a seeded run, cut to the 64-slot bucket its
+    events fill (dense), gives the same packed bytes when the SAME input
+    is padded to DENSE_MAX_F (still dense: the boundary) and to
+    DENSE_MAX_F + 1 (tree).  A third of the timestamps regress."""
+    q, part, _fams = BLOCKS[name]
+    force = f"@app:patternFamily('{fam}')\n"
+    if part:
+        app = force + "@app:partitionCapacity(8)\n" + pf.PART_HEAD \
+            + pf._lane_app(q)
+    else:
+        app = force + "@app:devicePatterns('always')\n" + pf.HEAD + q
+    mgr = SiddhiManager()
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", RuntimeWarning)
+        rt = mgr.create_app_runtime(app)
+    rt.start()
+    plan = next(p for p in rt._plans if isinstance(p, DevicePatternPlan))
+    assert plan.family == fam, plan.families
+    kern = plan._parallel_kernel()
+    calls: list = []
+    pf._record_lane_blocks(kern, calls)
+    rng = np.random.default_rng(5)
+    ih = rt.input_handler("S")
+    for b in range(2):
+        for j in range(300):
+            i = b * 300 + j
+            late = int(rng.integers(-300, 300)) if rng.random() < 0.3 else 0
+            ih.send((f"K{rng.integers(0, 4)}",
+                     float(np.round(rng.uniform(90, 130) * 4) / 4),
+                     int(rng.integers(1, 1000))),
+                    timestamp=1_700_000_000_000 + i * 7 + late)
+        rt.flush()
+    mgr.shutdown()
+    assert calls
+    fresh = npar.ParallelChainKernel(kern.prog, kern.nfak, kern.family)
+    T, M, ev = calls[-1]
+    F1 = 64 * -(-int(np.max(ev["__nev__"])) // 64)
+    assert F1 < npar.DENSE_MAX_F
+    ev1, T1 = _resize_block(ev, T, F1)
+    want = fresh.block_fn(T1, M)({}, ev1)[1]
+    assert fresh.first_hit["tree"] == 0 and fresh.first_hit["dense"] > 0
+    for F2, tree in ((npar.DENSE_MAX_F, False), (npar.DENSE_MAX_F + 1, True)):
+        ev2, T2 = _resize_block(ev, T, F2)
+        got = fresh.block_fn(T2, M)({}, ev2)[1]
+        assert (fresh.first_hit["dense"] == 0) == tree, fresh.first_hit
+        assert (fresh.first_hit["tree"] > 0) == tree, fresh.first_hit
+        assert sorted(got) == sorted(want)
+        for k in want:
+            assert np.asarray(got[k]).tobytes() \
+                == np.asarray(want[k]).tobytes(), (name, fam, F2, k)
+    assert int(np.asarray(want["i"])[..., 0, 0].sum()) > 0, \
+        f"{name}: no match in the block"
+
+
+# flushes the engine itself ships past the bound: a flat block holds
+# (N // 2048 + 2) * 2048 slots, so 2300 events a flush make F = 6144
+LONG_N = 2 * 2300
+LONG = {
+    "threshold3": ("scan",),
+    "hybrid": ("scan", "dfa"),
+    "le_threshold": ("scan",),
+    "count_head": ("scan", "dfa"),
+    "count_final": ("scan",),
+    "logical_and": ("scan", "dfa"),
+}
+
+
+@pytest.fixture(scope="module")
+def long_seq_rows():
+    cache = {}
+
+    def get(q):
+        if q not in cache:
+            cache[q] = pf._run("@app:patternFamily('seq')\n"
+                               "@app:devicePatterns('always')\n", q,
+                               n=LONG_N, batches=2)[2]
+        return cache[q]
+    return get
+
+
+@pytest.mark.parametrize("name,fam", [(n, f) for n, fs in LONG.items()
+                                      for f in fs])
+def test_long_flush_takes_the_tree_and_equals_the_sequential_kernel(
+        name, fam, long_seq_rows):
+    q = pf.ELIGIBLE[name][0]
+    info: dict = {}
+    used, _fams, dev = pf._run(
+        f"@app:patternFamily('{fam}')\n@app:devicePatterns('always')\n", q,
+        n=LONG_N, batches=2, plan_out=info)
+    assert used == fam
+    fh = info["explain"]["queries"]["q"]["first_hit"]
+    assert fh["dense"] == 0 and fh["tree"] > 0 and fh["pairs_per_call"] == 0 \
+        and fh["F"] > npar.DENSE_MAX_F, fh
+    assert len(dev) > 0
+    assert dev == long_seq_rows(q), (name, fam, len(dev))
+
+
+@pytest.mark.parametrize("op", ["ge", "le"])
+def test_zero_rhs_rows_survive_on_the_tree_side(op):
+    """`e2.price >= e1.price` with both 0.0, in a flush past the bound: the
+    tree form kept `>` semantics there (the denormal neighbour of 0.0) and
+    lost the rows the sequential kernel and the interpreter deliver."""
+    q = (f"from every e1=S[volume == 1] -> e2=S[price {'>=' if op == 'ge' else '<='} e1.price] "
+         "within 1 sec select e1.price as a, e2.price as b insert into Out;")
+    n = LONG_N // 2
+    rng = np.random.default_rng(9)
+    prices = rng.choice([0.0, 0.0, 2.5, -2.5], n)
+    rows = {}
+    for head in ("@app:devicePatterns('never')\n",
+                 "@app:patternFamily('seq')\n@app:devicePatterns('always')\n",
+                 "@app:patternFamily('scan')\n@app:devicePatterns('always')\n"):
+        mgr = SiddhiManager()
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore", RuntimeWarning)
+            rt = mgr.create_app_runtime(head + pf.HEAD + q)
+        out = []
+        rt.add_callback("Out", lambda evs, out=out: out.extend(
+            (e.timestamp, tuple(e.data)) for e in evs))
+        rt.start()
+        ih = rt.input_handler("S")
+        for i in range(n):
+            ih.send(("K", float(prices[i]), 1 if i % 5 == 0 else 0),
+                    timestamp=1_700_000_000_000 + i * 7)
+        rt.flush()
+        plan = next((p for p in rt._plans
+                     if isinstance(p, DevicePatternPlan)), None)
+        if "scan" in head:
+            assert plan.family == "scan" and plan.first_hit["tree"] > 0
+        mgr.shutdown()
+        rows[head] = out
+    host, seq, scan = rows.values()
+    assert any(a == 0.0 and b == 0.0 for _t, (a, b) in host)
+    assert seq == host
+    assert scan == host
