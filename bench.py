@@ -768,225 +768,6 @@ def _safe(label, fn, default=None):
         return default
 
 
-# ---------------------------------------------------------------------------
-# --autotune: tuner-driven frontier sweep + online SLO-controller demo
-# (core/autotune.py — see docs/AUTOTUNING.md)
-# ---------------------------------------------------------------------------
-
-def _autotune_tape(n, keys=8, dt_ms=1, seed=0):
-    """(cols, ts) recorded-tape form the Autotuner consumes: symbol as a
-    str array (the public send_batch path encodes it)."""
-    rng = np.random.default_rng(seed)
-    syms = np.asarray([f"K{i}" for i in rng.integers(0, keys, n)])
-    ts0 = 1_700_000_000_000
-    return ({"symbol": syms, "price": q4(rng.uniform(90.0, 130.0, n)),
-             "volume": rng.integers(1, 1000, n).astype(np.int32)},
-            ts0 + np.arange(n, dtype=np.int64) * dt_ms)
-
-
-def autotune_bench(smoke=False):
-    """Tuner-driven geometry sweep over configs 3/4/6 (the hand-tuned
-    BENCH geometries ride in every grid, so a warm winner matches or
-    beats them by construction) reporting before/after eps + p99 deltas,
-    plus the @app:latencySLO('25ms') controller demo under paced load.
-    The tuner asserts output-invariance across every candidate — a
-    geometry that changed results would raise, not win."""
-    from siddhi_tpu.core.autotune import Autotuner, Geometry
-
-    t0 = time.perf_counter()
-    tuner = Autotuner()
-    out = {"configs": {}}
-    if smoke:
-        specs = {"3_sequence": {
-            "app": DEV["patterns"] + C3, "keys": 8,
-            "hand": Geometry(batch=1 << 11, pipeline_depth=3),
-            "grid": [Geometry(batch=1 << 11, pipeline_depth=3),
-                     Geometry(batch=1 << 12, pipeline_depth=0)]}}
-    else:
-        specs = {
-            "3_sequence": {
-                "app": DEV["patterns"] + C3, "keys": 8,
-                "hand": Geometry(batch=1 << 17, pipeline_depth=3),
-                "grid": [Geometry(batch=1 << 15, pipeline_depth=0),
-                         Geometry(batch=1 << 15, pipeline_depth=3),
-                         Geometry(batch=1 << 17, pipeline_depth=0),
-                         Geometry(batch=1 << 17, pipeline_depth=3),
-                         Geometry(batch=1 << 17, pipeline_depth=3,
-                                  chunk_lanes=128),
-                         # plan-family axis: the sweep's output-invariance
-                         # check doubles as a cross-family differential
-                         Geometry(batch=1 << 17, pipeline_depth=3,
-                                  plan_family="chunk"),
-                         Geometry(batch=1 << 17, pipeline_depth=3,
-                                  plan_family="scan"),
-                         Geometry(batch=1 << 17, pipeline_depth=3,
-                                  plan_family="seq")]},
-            "4_partitioned_1k": {
-                "app": ("@app:partitionCapacity(1000)\n"
-                        "@app:deviceSlots(32)\n") + C4,
-                "keys": 1000,
-                "hand": Geometry(batch=1 << 18, pipeline_depth=0),
-                "grid": [Geometry(batch=1 << 16, pipeline_depth=0),
-                         Geometry(batch=1 << 17, pipeline_depth=0),
-                         Geometry(batch=1 << 18, pipeline_depth=0),
-                         # plan-family axis over the PARTITIONED lanes
-                         # (ISSUE 13): the lane-vmapped scan family vs
-                         # the per-key sequential state kernel — the
-                         # sweep's output-invariance check doubles as
-                         # the partitioned cross-family differential
-                         Geometry(batch=1 << 18, pipeline_depth=0,
-                                  plan_family="scan"),
-                         Geometry(batch=1 << 18, pipeline_depth=0,
-                                  plan_family="seq")]},
-            "6_join": {
-                "app": JOIN_APP, "keys": 1000,
-                "hand": Geometry(batch=2048, pipeline_depth=3),
-                "grid": [Geometry(batch=2048, pipeline_depth=0),
-                         Geometry(batch=2048, pipeline_depth=3),
-                         Geometry(batch=4096, pipeline_depth=3)]},
-        }
-    all_ok = True
-    for name, spec in specs.items():
-        keys = spec["keys"]
-        grid = list(spec["grid"])
-        if spec["hand"].to_dict() not in [g.to_dict() for g in grid]:
-            grid.append(spec["hand"])
-        # tape = 2x the LARGEST candidate batch, warm = that batch:
-        # every candidate (and the hand baseline) warms through at
-        # least one full batch of its own geometry, so the timed
-        # window is compile-free and the before/after comparison is
-        # warm-for-warm (not a warmup artifact)
-        maxb = max(g.batch for g in grid)
-        n, warm = 2 * maxb, maxb
-        if name == "6_join":
-            tapes = {"L": _autotape_join(n, keys, 0),
-                     "R": _autotape_join(n, keys, 1)}
-        else:
-            tapes = {STREAM: _autotune_tape(n, keys=keys)}
-        res = tuner.tune(spec["app"], tapes=tapes, grid=grid,
-                         warm_events=warm, force=False,
-                         log=lambda m: print(f"[autotune] {name}: {m}",
-                                             file=sys.stderr, flush=True))
-        # before/after come from the SWEEP's own candidate scores (hand
-        # rides in every grid): both sides measured under identical
-        # conditions, so the delta is geometry, not run-to-run noise.
-        # A warm cache skipped the sweep — re-measure both once, with a
-        # noise guard (the winner then usually IS the hand geometry).
-        by_geo = {json.dumps(c["geometry"], sort_keys=True): c
-                  for c in res.get("candidates", [])}
-        hand_key = json.dumps(spec["hand"].to_dict(), sort_keys=True)
-        win_key = json.dumps(res["winner"], sort_keys=True)
-        if hand_key in by_geo and win_key in by_geo:
-            before, after = by_geo[hand_key], by_geo[win_key]
-            ok = after["matches"] == before["matches"] and \
-                after["eps"] >= before["eps"]      # winner maximized eps
-        else:
-            before = tuner._measure(spec["app"], spec["hand"], tapes, n,
-                                    warm, None)
-            after = tuner._measure(spec["app"],
-                                   Geometry.from_dict(res["winner"]),
-                                   tapes, n, warm, None)
-            ok = after["matches"] == before["matches"] and \
-                after["eps"] >= 0.8 * before["eps"]   # noise guard
-        all_ok = all_ok and ok
-        out["configs"][name] = {
-            "winner": res["winner"], "from_cache": res["from_cache"],
-            "candidates": res.get("candidates", []),
-            "before": {"geometry": spec["hand"].to_dict(),
-                       "eps": before["eps"], "p99_ms": before["p99_ms"]},
-            "after": {"geometry": res["winner"], "eps": after["eps"],
-                      "p99_ms": after["p99_ms"]},
-            "eps_delta": round(after["eps"] / max(before["eps"], 1), 3),
-            "matches_identical": after["matches"] == before["matches"],
-            "pass": ok}
-        _mark(f"autotune {name}: x{out['configs'][name]['eps_delta']} "
-              f"({'cache' if res['from_cache'] else 'sweep'})", t0)
-    out["slo"] = slo_demo(target_ms=25, seconds=2.0 if smoke else 6.0,
-                          rate=2000 if smoke else 5000)
-    out["pass"] = all_ok and out["slo"]["pass"]
-    return out
-
-
-def _autotape_join(n, keys, seed):
-    rng = np.random.default_rng(seed)
-    syms = np.asarray([f"K{i}" for i in rng.integers(0, keys, n)])
-    ts0 = 1_700_000_000_000
-    return ({"symbol": syms, "price": q4(rng.uniform(90, 130, n)),
-             "volume": rng.integers(1, 9, n).astype(np.int32)},
-            ts0 + np.arange(n, dtype=np.int64))
-
-
-def slo_demo(target_ms=25, rate=5000, seconds=6.0, keys=8):
-    """@app:latencySLO under paced load: the AIMD controller must hold
-    the p99 detect-latency target within 2x while sustaining at least
-    the offered rate (the latency_demo host throughput anchor).  Same
-    producer harness as latency_demo; the controller adapts the
-    micro-batch/flush cadence itself — no hand-set batch knobs."""
-    from siddhi_tpu import SiddhiManager
-
-    app = (f"@app:latencySLO('{target_ms} ms')\n" + DEV["patterns"] + C3)
-    mgr = SiddhiManager()
-    rt = mgr.create_app_runtime(app)
-    lat: list = []
-    t0_batch = [0.0]
-    rt.add_batch_callback(
-        "Out", lambda b: lat.extend(
-            [(time.perf_counter() - t0_batch[0]) * 1e3] * b.n))
-    rt.start()
-    h = rt.input_handler(STREAM)
-    rng = np.random.default_rng(3)
-    syms = rng.integers(0, keys, size=1 << 16)
-    prices = q4(rng.uniform(90, 130, size=1 << 16))
-    ts0 = 1_700_000_000_000
-    i = 0
-    t_origin = time.perf_counter()
-
-    def send_one():
-        nonlocal i
-        # SLEEP-paced (not a busy spin): a hot spin loop starves the
-        # scheduler/flush threads of the GIL and the measured latency
-        # reads as engine tail when it is producer contention
-        while i > (time.perf_counter() - t_origin) * rate:
-            time.sleep(0.0005)
-        j = i % (1 << 16)
-        h.send((f"K{syms[j]}", float(prices[j]), 1), timestamp=ts0 + i * 25)
-        t0_batch[0] = rt._builder_t0.get(STREAM, t0_batch[0])
-        i += 1
-
-    # paced warmup in the SAME regime as the timed window: the
-    # controller converges and every flush-size shape bucket the
-    # steady state produces compiles here, not inside the measurement
-    warm_end = time.perf_counter() + max(2 * seconds, 8.0)
-    while time.perf_counter() < warm_end:
-        send_one()
-    rt.flush()
-    lat.clear()
-    rt.slo.total.reset()       # p99 over the timed window only
-    t_timed = time.perf_counter()
-    sent0 = i
-    t_origin = t_timed - i / rate          # keep the pacing continuous
-    while time.perf_counter() < t_timed + seconds:
-        send_one()
-    rt.flush()
-    eps = (i - sent0) / max(time.perf_counter() - t_timed, 1e-9)
-    # the headline p99 is the ENGINE-side per-batch end-to-end latency
-    # (first buffered event -> batch fully processed) the controller
-    # itself observes — measured inside the runtime, immune to the
-    # stale-t0 approximation of the callback clock (kept as a
-    # reference column)
-    p99_s = rt.slo.total.percentile(99)
-    slo_m = rt.slo.metrics()
-    mgr.shutdown()
-    p99 = round(p99_s * 1e3, 1) if p99_s is not None else None
-    cb_p99 = round(float(np.percentile(lat, 99)), 1) if lat else None
-    held = p99 is not None and p99 <= 2 * target_ms
-    return {"target_ms": target_ms, "offered_rate_eps": rate,
-            "eps": round(eps), "p99_ms": p99, "p99_callback_ms": cb_p99,
-            "held_within_2x": held, "sustained": eps >= 0.9 * rate,
-            "controller": slo_m,
-            "pass": bool(held and eps >= 0.9 * rate)}
-
-
 def harness_info() -> dict:
     """Provenance block recorded with every bench result (BENCH_DETAIL
     + summary): two runs whose harness blocks differ are not comparable
@@ -3070,18 +2851,6 @@ def main(argv=None):
         print(json.dumps({"metric": "chaos_recovery",
                           "value": 1 if res["pass"] else 0,
                           "unit": "all_recovery_paths_lossless", **res}))
-        if not res["pass"]:
-            sys.exit(1)
-        return
-    if "--autotune" in argv:
-        # tuner-driven frontier sweep (before/after eps + p99 per config)
-        # + the @app:latencySLO AIMD controller demo; --smoke shrinks it
-        # to one config for the CI budget (scripts/smoke.sh)
-        res = autotune_bench(smoke="--smoke" in argv)
-        print(json.dumps({"metric": "autotune_sweep",
-                          "value": 1 if res["pass"] else 0,
-                          "unit": "tuned_geometry_matches_or_beats_hand",
-                          **res}))
         if not res["pass"]:
             sys.exit(1)
         return

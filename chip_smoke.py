@@ -609,13 +609,7 @@ def main(argv=None) -> int:
           f"devices: {len(devs)}  jax: {jax.__version__}  "
           f"libtpu: {libtpu_version}", flush=True)
 
-    # plan geometry must come from what git commits, not from a tuning
-    # cache in somebody's home directory: start from an empty one
     os.makedirs(args.out, exist_ok=True)
-    tune = os.path.join(args.out, "tuning.json")
-    if os.path.exists(tune):
-        os.remove(tune)
-    os.environ["SIDDHI_TUNE_CACHE"] = tune
 
     import bench
     import siddhi_tpu  # noqa: F401  (fails here outside a checkout)

@@ -23,12 +23,6 @@ echo "== compileall =="
 # imported module must not wait for a request to surface
 python -m compileall -q siddhi_tpu
 
-echo "== tuning-cache schema lint =="
-# a malformed persisted tuning cache must never brick a deploy: the
-# loader quarantines corrupt files (core/autotune.py TuningCache), and
-# this lint step catches schema drift before it ships
-python -m siddhi_tpu.core.autotune --lint
-
 echo "== static analysis: self-lint =="
 # the no-silent-demotion CI gate (docs/ANALYSIS.md): an except handler
 # on a plan-lowering path that swallows without recording a Demotion
@@ -683,14 +677,6 @@ echo "== seeded chaos smoke =="
 # quarantine with byte-identical matches, sink retry/ErrorStore replay).
 # Exits nonzero if any recovery path loses or duplicates an event.
 python bench.py --chaos --seed 7
-
-echo "== autotune smoke =="
-# bench.py --autotune --smoke: one-config tuner sweep (output-invariance
-# asserted per candidate) + the @app:latencySLO AIMD controller under
-# paced load; the tuning cache is scoped to a throwaway path so CI never
-# pollutes (or trusts) the developer's persisted winners
-SIDDHI_TUNE_CACHE="$(mktemp -u /tmp/siddhi_tune_smoke.XXXXXX.json)" \
-    python bench.py --autotune --smoke
 
 echo "== plan-family parity smoke =="
 # bench.py --family-smoke: one eligible pattern per NFA plan family

@@ -54,7 +54,7 @@ _CHECKED_TYPES = {
     "Exception", "BaseException",
     "DeviceNFAUnsupported", "DeviceWindowUnsupported",
     "DeviceJoinUnsupported", "ParallelUnsupported",
-    "PlanError", "ExprError", "AutotuneError", "TableError",
+    "PlanError", "ExprError", "TableError",
 }
 
 _DEMOTE_CALLS = {"demote", "record_demotion"}

@@ -27,8 +27,8 @@ merge batch partials into standing state as `old op new`), so the two
 paths produce BYTE-IDENTICAL stores — `bench.py --matrix` and the
 forced-path differential tests assert exactly that.
 
-Slot lifecycle: the ring starts at `agg_capacity_for(rt)` slots
-(annotation > tuning cache > 1024) and doubles when full; @purge
+Slot lifecycle: the ring starts at `rt.geometry["agg_capacity"]` slots
+(@app:aggCapacity, else 1024) and doubles when full; @purge
 retention frees slots host-side only (the stale device row is simply
 overwritten on reuse), so eviction costs zero device traffic.
 """

@@ -301,9 +301,8 @@ class AggregationRuntime(QueryPlan):
             return
         try:
             from .agg_device import DeviceAggregationPlan
-            from .autotune import agg_capacity_for
-            cap = agg_capacity_for(rt, payload=None)
-            self.device_plan = DeviceAggregationPlan(self, cap)
+            self.device_plan = DeviceAggregationPlan(
+                self, rt.geometry["agg_capacity"][0])
         except Exception as e:          # jax missing / backend init failed
             rt.placement.demote(
                 ad.id, "D-AGG", "device aggregation plan unavailable",

@@ -41,20 +41,6 @@ def build_app(rt) -> None:
 def _build_app_scoped(rt) -> None:
     from .table import InMemoryTable, TableError
 
-    # `@app:patternFamily` names a pattern-kernel execution family
-    # (seq | chunk | scan | dfa | auto — docs/PERFORMANCE.md "Plan
-    # families").  Validate the NAME once here, loudly, so a typo is a
-    # PlanError on EVERY path (scoped, partitioned, and fused pattern
-    # plans) and never silently falls back to auto selection.  Whether
-    # the family is *eligible* for a given chain is decided later by
-    # each plan's eligibility analysis (ineligible -> warn + sound
-    # fallback).
-    from .autotune import AutotuneError, pattern_family_for
-    try:
-        pattern_family_for(rt)
-    except AutotuneError as e:
-        raise PlanError(str(e)) from None
-
     app = rt.app
     for tid, td in app.table_definitions.items():
         if tid in rt.schemas:
@@ -106,7 +92,6 @@ def _build_app_scoped(rt) -> None:
                 sig = query_signature(elem)
                 if sig is not None:
                     groups.setdefault(sig, []).append(i)
-        from .autotune import fused_lane_pack_for
         from .multi_query import plan_query_group
         from .nfa_device import DeviceNFAUnsupported
         for sig, idxs in groups.items():
@@ -123,10 +108,10 @@ def _build_app_scoped(rt) -> None:
                             f"individually",
                             alternative="fused-lanes")
                 continue
-            # fused-lane packing (@app:fusedLanes / tuning cache): cap the
-            # lane count per fused kernel — a group larger than the pack
-            # splits into several kernels (0 = unbounded, one kernel)
-            pack = fused_lane_pack_for(rt, sig)
+            # fused-lane packing (@app:fusedLanes): cap the lane count
+            # per fused kernel — a group larger than the pack splits
+            # into several kernels (0 = unbounded, one kernel)
+            pack = rt.geometry["lane_pack"][0]
             if pack and pack >= MIN_GROUP:
                 slices = [idxs[j:j + pack]
                           for j in range(0, len(idxs), pack)]
@@ -147,10 +132,6 @@ def _build_app_scoped(rt) -> None:
                             "for this group; queries planned individually",
                             cause=e, alternative="fused-lanes")
                     break
-                # the tuning cache keys fused plans by the GROUP shape
-                # signature (autotune.plan_signature) — the fused query
-                # AST never flows through attach_table_writer
-                plan._group_sig = sig
                 rt._register_plan(plan)
                 for i in sub:
                     fused[i] = plan
@@ -285,12 +266,11 @@ def _plan_query_scoped(rt, q: ast.Query, default_name: str):
                 and not any(isinstance(h, ast.StreamFunction) for h in inp.handlers)):
             try:
                 filters = [f.expr for f in inp.filters]
-                from .autotune import pipeline_depth_for
                 return attach_table_writer(rt, FilterProjectPlan(
                     name, schema, inp.alias, filters, q.selector, rt.strings,
                     target, q.selector.limit, q.selector.offset,
                     events_for=q.output.events_for,
-                    pipeline_depth=pipeline_depth_for(rt, "filter", q)),
+                    pipeline_depth=rt.geometry["pipeline_depth"][0]),
                     q, name)
             except PlanError:
                 raise

@@ -213,8 +213,7 @@ class DeviceJoinPlan(QueryPlan):
         # side filters force a sync per flush (the mirror update needs the
         # device-evaluated pass masks); filter-less joins pipeline
         self._can_pipeline = not (self.left.filters or self.right.filters)
-        from .autotune import pipeline_depth_for
-        self.pipeline_depth = pipeline_depth_for(rt, "join", q) \
+        self.pipeline_depth = rt.geometry["pipeline_depth"][0] \
             if self._can_pipeline else 0
         self._pipe = DispatchPipeline(name, self._materialize,
                                       depth=self.pipeline_depth)

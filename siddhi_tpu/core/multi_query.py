@@ -250,11 +250,8 @@ class MultiQueryDevicePatternPlan:
 
     # -- QueryPlan surface -------------------------------------------------
 
-    def regeometry(self, **knobs) -> None:
-        """Adaptive-geometry hook: delegate to the fused inner plan (the
-        lane PACKING itself is a build-time knob — @app:fusedLanes /
-        tuning cache — consulted in build.py before this plan exists)."""
-        self.inner.regeometry(**knobs)
+    def regeometry(self, batch_hint) -> None:
+        self.inner.regeometry(batch_hint)
 
     def device_metrics(self) -> dict:
         """Sampled gauges of the fused kernel (lane = query instance, so

@@ -189,10 +189,10 @@ class DevicePatternPlan(QueryPlan):
         #           whole-flush next-pointer composition, O(log T) depth;
         #   dfa   — bit-packed multi-stride hybrid lowering: u32 symbol
         #           words + stride-4 precomposed block tables.
-        # Eligibility analysis picks the cheapest sound family; the
-        # sequential kernel ("seq") is the universal fallback, and the
-        # autotuner sweeps the family as a geometry axis (@app:patternFamily
-        # / tuning-cache `plan_family` force one explicitly).
+        # Eligibility analysis picks the first sound family of
+        # FAMILY_ORDER; the sequential kernel ("seq") is the universal
+        # fallback, and @app:patternFamily asks for one by name.  The
+        # family is entered here, at build, and never changes after.
         self._chunk_cfg = None
         self._tail: Optional[dict] = None       # replayed raw events
         self._prev_last_seq = -1
@@ -224,9 +224,7 @@ class DevicePatternPlan(QueryPlan):
         elif not all(p.within_ms is not None for p in self.spec.positions):
             hard = "position without a `within` bound"
         self.families: dict = {"seq": True}
-        from .autotune import (chunk_lanes_for, pattern_family_for,
-                               pipeline_depth_for)
-        self._stateless_lanes = chunk_lanes_for(rt, q)
+        self._stateless_lanes = rt.geometry["chunk_lanes"][0]
         if hard is not None:
             self.families.update({"chunk": hard, "scan": hard, "dfa": hard})
         else:
@@ -264,13 +262,12 @@ class DevicePatternPlan(QueryPlan):
                         par[f] = ("multi-device mesh (flat block has no "
                                   "lane axis to shard)")
             self.families.update(par)
-        want = pattern_family_for(rt, q)
-        fam = self._choose_family(want)
+        fam = self._choose_family(rt.geometry["plan_family"][0])
         if fam != "seq":
             # fused groups route matches through finalize_multi, which
             # drains synchronously — no deferred-pull pipeline there
             self.pipeline_depth = 0 if broadcast_events \
-                else pipeline_depth_for(rt, "pattern", q)
+                else rt.geometry["pipeline_depth"][0]
             self._enter_stateless(fam)
         # device grids shipped per block: only attrs some predicate or
         # capture row reads, per scode
@@ -497,13 +494,12 @@ class DevicePatternPlan(QueryPlan):
 
     # -- plan families ---------------------------------------------------
 
-    # auto-selection preference: cheapest sound family first, measured —
-    # the associative-scan lowering beats the bit-packed multi-stride
-    # tables on the shipping backends (bench kernel_eps_by_family:
-    # static chain, scan ~3.4M eps vs dfa ~2.9M vs chunk ~57k on
-    # CPU), and both beat K sequential chunk lanes everywhere; "seq" is
-    # the universal fallback.  The autotuner's plan_family knob overrides
-    # per app when a sweep finds otherwise on a given device.
+    # auto-selection preference: the first eligible family wins, "seq"
+    # is the universal fallback.  The order is unmeasured on the chip:
+    # every benchmark cell runs scan, and the one reading of all four
+    # (PR 21's smoke, flat P = 1 block) ranked chunk < dfa < scan < seq
+    # before PRs 27 and 30 changed scan and dfa.  ROADMAP A9 (cells that
+    # run the others) and C2 (delete what loses) decide it.
     FAMILY_ORDER = ("scan", "dfa", "chunk")
 
     def _choose_family(self, want: Optional[str]) -> str:
@@ -548,41 +544,6 @@ class DevicePatternPlan(QueryPlan):
             nl = self.P if self.broadcast_events else 1
             self._arm_done = np.zeros(nl, dtype=bool)
         self.retryable_finalize = True
-
-    def _set_family(self, fam: str) -> None:
-        """Adaptive-geometry family switch (autotuner / regeometry).
-        Stateless<->stateless moves are flush-boundary output-invariant
-        (all three share the tail/dedup bookkeeping); seq<->stateless
-        switches only before the plan has touched data (the persistent
-        slot state and the replay tail don't interconvert)."""
-        import warnings
-        if fam == self.family:
-            return
-        if fam != "seq" and self.families.get(fam) is not True:
-            warnings.warn(
-                f"pattern {self.name!r}: plan family {fam!r} not eligible "
-                f"({self.families.get(fam)}); keeping {self.family!r}",
-                RuntimeWarning, stacklevel=2)
-            return
-        stateless = ("chunk", "scan", "dfa")
-        if self.family in stateless and fam in stateless:
-            self.family = fam
-            return
-        if self._ts_base is None and self._tail is None \
-                and self._lane_tail is None and not self._buffered:
-            if fam == "seq":
-                self.family = "seq"
-                self._chunk_cfg = None
-                self._pipe = None
-                self.retryable_finalize = False
-            else:
-                self._enter_stateless(fam)
-            return
-        warnings.warn(
-            f"pattern {self.name!r}: cannot switch plan family "
-            f"{self.family!r} -> {fam!r} mid-stream (device state and the "
-            f"replay tail do not interconvert)", RuntimeWarning,
-            stacklevel=2)
 
     def _parallel_kernel(self):
         """Build (and cache) the parallel-in-time kernel for the current
@@ -1349,20 +1310,6 @@ class DevicePatternPlan(QueryPlan):
         # bases are per-flush: _unpack_block must see THIS entry's
         self._ts_base, self._seq_base = e["ts_base"], e["seq_base"]
         return self._unpack_block(ipack, fpack, n)
-
-    def regeometry(self, batch_hint=None, depth=None, chunk_lanes=None,
-                   plan_family=None, **knobs) -> None:
-        """Pattern-family geometry: base knobs plus the chunked-halo lane
-        count K and the execution family.  A lane-count change only
-        affects how FUTURE flushes split into own-chunks (heads arm on
-        owned events regardless of K); a stateless family switch applies
-        to future flushes over the same tail/dedup bookkeeping — both
-        output-invariant like every other geometry move."""
-        super().regeometry(batch_hint=batch_hint, depth=depth, **knobs)
-        if chunk_lanes is not None and self._chunk_cfg is not None:
-            self._chunk_cfg["lanes"] = max(2, int(chunk_lanes))
-        if plan_family is not None:
-            self._set_family(str(plan_family))
 
     def flush_pending(self) -> list:
         # chunk results are raw columnar match tables, not OutputBatches:

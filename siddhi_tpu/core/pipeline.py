@@ -149,13 +149,6 @@ class DispatchPipeline:
         them all, then collects, so plans overlap on device."""
         self._held = True
 
-    def set_depth(self, depth: int) -> None:
-        """Retarget the in-flight depth (autotune regeometry).  Applied
-        at the next push/collect boundary: lowering the depth simply
-        materializes more entries there (FIFO, same delivery order), so
-        a mid-stream depth change is output-invariant."""
-        self.depth = max(0, int(depth))
-
     def collect(self) -> list:
         """Close a dispatch round: materialize entries beyond depth."""
         self._held = False

@@ -15,8 +15,8 @@ placement decision a first-class record:
     ``python -m siddhi_tpu.analysis`` CLI) reports, per query: the chosen
     execution path (device family vs interpreter), the chosen pattern
     plan family, where each geometry knob came from
-    (annotation / tuning-cache / default), and the full reason chain for
-    every rejected alternative;
+    (annotation / default), and the full reason chain for every rejected
+    alternative;
   * ``statistics()["placement"]`` + the ``siddhi_tpu_interp_demotions``
     Prometheus series keep the counts scrapeable, so a future silent
     demotion shows up in the bench trajectory (bench.py summary carries
@@ -160,51 +160,23 @@ def _knob(value, source: str) -> dict:
 
 
 def _geometry_entry(rt, plan, kind: str) -> dict:
-    """Each geometry knob the plan consulted at build, with its
-    provenance: annotation > tuning-cache > default (the same precedence
-    autotune.pipeline_depth_for & friends apply).  Uses the tuning
-    cache's peek() so an EXPLAIN scrape never skews hit/miss gauges."""
-    from ..query import ast as qast
-    from .autotune import signature_of
-    tn = getattr(rt, "tuner", None)
-    q = getattr(plan, "_q_ast", None)
-
-    def cached(family, payload):
-        if tn is None or not tn.enabled or payload is None:
-            return None
-        ent = tn.cache.peek(signature_of(family, payload))
-        if ent is None:
-            return None
-        from .autotune import Geometry
-        return Geometry.from_dict(ent.get("geometry", {}))
-
-    def source_of(ann_name, geo_attr, family, payload):
-        if qast.find_annotation(rt.app.annotations, ann_name) is not None:
-            return "annotation"
-        g = cached(family, payload)
-        if g is not None and getattr(g, geo_attr, None) is not None:
-            return "tuning-cache"
-        return "default"
+    """Each geometry knob the plan was built with: the value the PLAN
+    holds (a correctness pin — join side filters, fused groups — can
+    override an annotated depth) and the source rt.geometry resolved."""
+    def knob(name, value):
+        return _knob(value, rt.geometry[name][1])
 
     geo: dict = {}
-    fam_for_cache = "pattern" if kind in ("pattern", "multi_query") else kind
     if hasattr(plan, "pipeline_depth"):
-        geo["pipeline_depth"] = _knob(
-            int(getattr(plan, "pipeline_depth", 0) or 0),
-            source_of("app:devicePipeline", "pipeline_depth",
-                      fam_for_cache, q))
+        geo["pipeline_depth"] = knob(
+            "pipeline_depth", int(getattr(plan, "pipeline_depth", 0) or 0))
     if kind == "pattern":
-        geo["chunk_lanes"] = _knob(
-            int(getattr(plan, "_stateless_lanes", 0) or 0),
-            source_of("app:deviceChunkLanes", "chunk_lanes", "pattern", q))
-        geo["plan_family"] = _knob(
-            getattr(plan, "family", None),
-            source_of("app:patternFamily", "plan_family", "pattern", q))
+        geo["chunk_lanes"] = knob(
+            "chunk_lanes", int(getattr(plan, "_stateless_lanes", 0) or 0))
+        geo["plan_family"] = knob(
+            "plan_family", getattr(plan, "family", None))
     if kind == "multi_query":
-        gs = getattr(plan, "_group_sig", None)
-        geo["lane_pack"] = _knob(
-            int(getattr(plan, "lane_pack", 0) or 0) or None,
-            source_of("app:fusedLanes", "lane_pack", "multi_query", gs))
+        geo["lane_pack"] = _knob(*rt.geometry["lane_pack"])
     return geo
 
 
@@ -288,6 +260,9 @@ def explain(rt) -> dict:
         if ret:
             ent["retention_ms"] = {d.name: v for d, v in sorted(
                 ret.items(), key=lambda kv: kv[0].approx_millis)}
+        if ent["path"] == "device-resident":
+            ent["geometry"] = {"agg_capacity": _knob(
+                *rt.geometry["agg_capacity"])}
         ev = getattr(a, "evicted", None)
         if ev and any(ev.values()):
             ent["evicted"] = {d.name: n for d, n in ev.items() if n}

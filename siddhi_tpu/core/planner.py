@@ -169,27 +169,15 @@ class QueryPlan:
     def process(self, stream_id: str, batch: EventBatch) -> list:
         raise NotImplementedError
 
-    def regeometry(self, batch_hint=None, depth=None, **knobs) -> None:
-        """Adaptive-geometry hook (core/autotune.py): the tuner applies a
-        cached winner here after build, and the SLO controller applies
-        batch decisions at flush boundaries.  Every plan family derives
-        its device geometry (pad grids, chunk sizes) from batch.n at
+    def regeometry(self, batch_hint) -> None:
+        """The SLO controller's batch decision (core/slo.py), applied by
+        the runtime at a flush boundary.  Every plan family derives its
+        device geometry (pad grids, chunk sizes) from batch.n at
         dispatch, so a new hint only changes FUTURE dispatch shapes —
         batches already in flight are untouched, and batch-boundary moves
         are output-invariant (the PR-4 halving machinery's parity
         argument; asserted by the geometry differentials)."""
-        if batch_hint is not None:
-            self.batch_hint = int(batch_hint)
-        if depth is not None and getattr(self, "_can_pipeline", True):
-            # _can_pipeline: a plan that must sync per flush (join side
-            # filters feed the mirror update) pins depth 0 — geometry
-            # hints never override a correctness constraint.  The depth
-            # is recorded even without a live pipeline: a later
-            # plan-family switch (pattern plans) builds its pipeline
-            # from self.pipeline_depth and must not lose the knob.
-            self.pipeline_depth = int(depth)
-            if self._pipe is not None:
-                self._pipe.set_depth(int(depth))
+        self.batch_hint = int(batch_hint)
 
     def on_timer(self, now_ms: int) -> list:
         """Called by the scheduler tick (time windows, absent patterns...)."""

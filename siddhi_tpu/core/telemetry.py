@@ -795,18 +795,6 @@ def render_prometheus(reports: dict, openmetrics: bool = False) -> str:
             for key, name, help_ in _NET_GAUGES:
                 if key in m:
                     doc.add(name, "gauge", help_, nl, m[key])
-        # adaptive-geometry series (core/autotune.py)
-        tun = rep.get("tuning")
-        if tun:
-            doc.add("siddhi_tpu_tuning_cache_hits_total", "counter",
-                    "tuning-cache lookups that found a persisted geometry",
-                    al, tun.get("cache_hits", 0))
-            doc.add("siddhi_tpu_tuning_cache_misses_total", "counter",
-                    "tuning-cache lookups that fell back to defaults",
-                    al, tun.get("cache_misses", 0))
-            doc.add("siddhi_tpu_tuning_cache_entries", "gauge",
-                    "persisted geometry winners in the tuning cache",
-                    al, tun.get("tuning_cache_entries"))
         # queryable-state series (core/aggregation.py): per-duration
         # bucket/eviction gauges, group cardinality, and the store-query
         # latency histogram (exemplar-carrying, like the stream
@@ -1345,11 +1333,7 @@ class StatisticsManager:
             if self.store_query.batches:
                 ab["store_query"] = self.store_query.as_dict(buckets=True)
             rep["aggregation"] = ab
-        # adaptive execution geometry (core/autotune.py): tuning-cache
-        # hit/miss gauges + the SLO controller's state and decision log
-        tn = getattr(self.rt, "tuner", None)
-        if tn is not None and tn.enabled:
-            rep["tuning"] = tn.metrics()
+        # the SLO controller's state and decision log (core/slo.py)
         slo = getattr(self.rt, "slo", None)
         if slo is not None:
             rep["slo"] = slo.metrics()

@@ -56,7 +56,7 @@ from ..utils.locks import new_lock
 # fires it (all sites enqueue-only — the promotion/export work runs on
 # the siddhi-trace-export thread, never under an engine lock)
 TRIGGER_KINDS = (
-    "slo_breach",     # autotune.SLOController: decision-window p99 > target
+    "slo_breach",     # slo.SLOController: decision-window p99 > target
     "breaker_open",   # io.Sink: a per-sink circuit breaker opened
     "quarantine",     # runtime: a device plan quarantined onto the interpreter
     "shed_burst",     # net.admission: frames shed by rate limit / watermark

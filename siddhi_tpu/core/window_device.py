@@ -459,9 +459,8 @@ class DeviceWindowAggPlan(QueryPlan):
             raise DeviceWindowUnsupported(f"unresolved columns {unknown}")
         self.cols = sorted(k for k in reads if k in schema.types)
 
-        from .autotune import pipeline_depth_for
         from .pipeline import DispatchPipeline
-        self.pipeline_depth = pipeline_depth_for(rt, "window", q)
+        self.pipeline_depth = rt.geometry["pipeline_depth"][0]
         self._pipe = DispatchPipeline(name, self._materialize,
                                       depth=self.pipeline_depth)
 
@@ -1094,7 +1093,7 @@ class DeviceWindowAggPlan(QueryPlan):
         try:
             fill = int(np.asarray(self.state["valid"]).sum())
         except Exception:   # lint: allow-swallow (best-effort metrics
-            # sampling — a mid-regeometry scrape just skips the gauge)
+            # sampling — a scrape racing a state swap skips the gauge)
             return {}
         return {"window_capacity": int(self.C), "window_fill": fill,
                 "window_fill_ratio": round(fill / max(self.C, 1), 4)}

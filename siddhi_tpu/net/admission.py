@@ -23,7 +23,7 @@ Three shed policies once the bucket is empty:
              tokens refill.
 
 The PR-5 SLO controller lowers admission BEFORE latency collapses via
-`set_rate_factor` (autotune.SLOController.admission_factor): p99 over
+`set_rate_factor` (slo.SLOController.admission_factor): p99 over
 target scales every bucket's refill rate down, recovery raises it back
 to 1.0.
 """

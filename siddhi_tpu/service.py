@@ -71,10 +71,6 @@ Endpoints (JSON unless noted):
                                     profiling"): per-plan phase shares,
                                     host-dispatch share, windowed ring
                                     (last <n> snapshots)
-  GET  /siddhi/artifact/tuning[?siddhiApp=<name>]
-                                    the persisted execution-geometry tuning
-                                    cache (docs/AUTOTUNING.md): entries +
-                                    hit/miss gauges, or one app's view
   GET  /siddhi/net                  data-plane descriptor: frame port +
                                     per-stream admission/transport gauges
   GET  /siddhi/errors?siddhiApp=<name>[&stream=<id>]
@@ -318,13 +314,6 @@ class SiddhiService:
                             w = q.get("window", [None])[0]
                             self._reply(200, service.profile(
                                 app, window=None if w is None else int(w)))
-                    elif u.path == "/siddhi/artifact/tuning":
-                        app = q.get("siddhiApp", [None])[0]
-                        if app is not None and app not in service.runtimes:
-                            self._reply(404, {"error":
-                                              f"no deployed app {app!r}"})
-                        else:
-                            self._reply(200, service.tuning(app))
                     elif u.path == "/siddhi/net":
                         self._reply(200, service.net_info())
                     elif u.path == "/metrics":
@@ -785,19 +774,6 @@ class SiddhiService:
         names = [app] if app is not None else sorted(self.runtimes)
         return {"apps": {n: self.runtimes[n].profile(window=window)
                          for n in names}}
-
-    def tuning(self, app: Optional[str] = None) -> dict:
-        """The persisted execution-geometry tuning cache (autotune.py):
-        globally, or one deployed app's view of it (its hit/miss gauges
-        and the geometries its build resolved)."""
-        from .core.autotune import device_kind, jax_version, shared_cache
-        if app is not None:
-            rt = self.runtimes[app]
-            return {"app": app, **rt.tuner.metrics()}
-        c = shared_cache()
-        return {"path": c.path, "device": device_kind(),
-                "jax": jax_version(), "hits": c.hits, "misses": c.misses,
-                "corrupt": c.corrupt, "entries": c.entries()}
 
     def metrics(self, app: Optional[str] = None,
                 openmetrics: bool = False) -> str:

@@ -84,12 +84,5 @@ def _siddhi_thread_leak_gate():
         f"path stopped joining them): {sorted(t.name for t in leaked)}")
 
 
-# isolate the execution-geometry tuning cache (core/autotune.py): the
-# suite must neither trust nor pollute a developer's persisted winners
-if "SIDDHI_TUNE_CACHE" not in os.environ:
-    import tempfile
-    os.environ["SIDDHI_TUNE_CACHE"] = os.path.join(
-        tempfile.mkdtemp(prefix="siddhi_tune_test_"), "tuning.json")
-
 import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))

@@ -343,6 +343,127 @@ def test_geometry_provenance_annotation_vs_default():
     mgr.shutdown()
 
 
+_GEO_FILTER = """
+    define stream S (v double);
+    @info(name='q') from S[v > 1.0] select v insert into Out;
+"""
+_GEO_PATTERN = """
+    @app:devicePatterns('always')
+    define stream S (v double);
+    @info(name='q') from every e1=S[v > 1.0] -> e2=S[v > e1.v] within 1 sec
+    select e1.v as a, e2.v as b insert into Out;
+"""
+_GEO_FUSED = "define stream S (v double);\n" + "\n".join(
+    f"@info(name='q{i}') from every e1=S[v > {i}.5] -> e2=S[v > e1.v] "
+    f"within 1 sec select e1.v as a, e2.v as b insert into Out{i};"
+    for i in range(16))
+_GEO_AGG = """
+    define stream S (k string, v double, ts long);
+    define aggregation A from S select k, sum(v) as s group by k
+    aggregate by ts every sec;
+"""
+
+
+def _fused(rt):
+    return sorted(p.n_queries for p in rt._plans
+                  if type(p).__name__ == "MultiQueryDevicePatternPlan")
+
+
+# knob -> (annotation, app, where EXPLAIN reports it, what the built
+#          plan holds, (annotated text, value, held), (default, held))
+_GEO_KNOBS = {
+    "pipeline_depth": (
+        "devicePipeline", _GEO_FILTER,
+        lambda ex: ex["queries"]["q"]["geometry"],
+        lambda rt: rt._plans[0].pipeline_depth, ("2", 2, 2), (0, 0)),
+    "chunk_lanes": (
+        "deviceChunkLanes", _GEO_PATTERN,
+        lambda ex: ex["queries"]["q"]["geometry"],
+        lambda rt: rt._plans[0]._stateless_lanes, ("8", 8, 8), (64, 64)),
+    "plan_family": (
+        "patternFamily", _GEO_PATTERN,
+        lambda ex: ex["queries"]["q"]["geometry"],
+        lambda rt: rt._plans[0].family,
+        ("'seq'", "seq", "seq"), ("scan", "scan")),
+    "lane_pack": (
+        "fusedLanes", _GEO_FUSED,
+        lambda ex: min(ex["queries"].items())[1]["geometry"],
+        _fused, ("8", 8, [8, 8]), (0, [16])),
+    "agg_capacity": (
+        "aggCapacity", _GEO_AGG,
+        lambda ex: ex["aggregations"]["A"]["geometry"],
+        lambda rt: sorted({r.capacity for r in rt.aggregations["A"]
+                           .device_plan.rings.values()}),
+        ("4", 8, [8]), (1024, [1024])),     # the floor of 8 holds
+}
+
+
+@pytest.mark.parametrize("annotated", [True, False],
+                         ids=["annotation", "default"])
+@pytest.mark.parametrize("knob", sorted(_GEO_KNOBS))
+def test_geometry_knob_has_two_sources(knob, annotated):
+    """Each of the five geometry annotations, set and absent: the built
+    plan holds the value and EXPLAIN names where it came from."""
+    ann, app, where, held_by, set_, default = _GEO_KNOBS[knob]
+    if annotated:
+        text, value, held = set_
+        app = f"@app:{ann}({text})\n" + app
+    else:
+        value, held = default
+    mgr, rt = _build(app)
+    source = "annotation" if annotated else "default"
+    assert where(rt.explain())[knob] == {"value": value, "source": source}
+    assert held_by(rt) == held
+    mgr.shutdown()
+
+
+@pytest.mark.parametrize("where", ["env", "home"])
+def test_stale_tuning_file_changes_nothing(where, tmp_path, monkeypatch):
+    """Geometry has no source outside the app text: a file where the
+    deleted tuning cache used to live, keyed for this very app on this
+    very device and JAX version, must not move family, depth or batch
+    capacity.  (At the parent of PR 31 it moved all three.)"""
+    import hashlib
+
+    import jax
+
+    def key(family, payload):
+        sig = hashlib.sha1(f"{family}|{payload!r}".encode()).hexdigest()[:20]
+        return (f"{family}:{sig}|{jax.devices()[0].device_kind}"
+                f"|jax{jax.__version__}")
+
+    mgr, rt = _build(_GEO_PATTERN)
+    stale = {"batch": 64, "pipeline_depth": 3, "plan_family": "seq"}
+    entries = {
+        key("pattern", rt._plans[0]._q_ast): {
+            "geometry": stale, "family": "pattern"},
+        key("app", (tuple(sorted((sid, repr(sd)) for sid, sd in
+                                 rt.app.stream_definitions.items())),
+                    tuple(repr(e) for e in rt.app.execution_elements))): {
+            "geometry": {"batch": 64}, "family": "app"}}
+    mgr.shutdown()
+    if where == "env":
+        path = tmp_path / "tuning.json"
+        monkeypatch.setenv("SIDDHI_TUNE_CACHE", str(path))
+    else:
+        path = tmp_path / ".cache" / "siddhi_tpu" / "tuning.json"
+        path.parent.mkdir(parents=True)
+        monkeypatch.delenv("SIDDHI_TUNE_CACHE", raising=False)
+        monkeypatch.setenv("HOME", str(tmp_path))
+    path.write_text(json.dumps({"version": 1, "entries": entries}))
+    mgr, rt = _build(_GEO_PATTERN)
+    plan = rt._plans[0]
+    assert (plan.family, plan.pipeline_depth, rt.batch_capacity) \
+        == ("scan", 0, 2048)
+    mgr.shutdown()
+
+
+def test_unknown_pattern_family_is_a_plan_error():
+    from siddhi_tpu.core.planner import PlanError
+    with pytest.raises(PlanError, match="unknown family"):
+        _build("@app:patternFamily('nope')\n" + _GEO_FILTER)
+
+
 def test_ineligible_family_reasons_reach_explain():
     """Satellite: every classify_parallel reason string for the 5
     ineligible shapes is reachable through rt.explain() — both in the

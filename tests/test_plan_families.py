@@ -320,44 +320,6 @@ def test_cross_flush_tail_replay_many_small_flushes(host_rows):
         assert dev == host, (fam, used, len(dev), len(host))
 
 
-@pytest.mark.slow
-def test_family_switch_regeometry_between_flushes():
-    # stateless<->stateless family switches at flush boundaries are
-    # output-invariant: start on the default (scan), switch to dfa
-    # (eligible for the hybrid shape), then chunk, then back to scan,
-    # and compare the stitched output with the host oracle
-    q, _ = ELIGIBLE["hybrid"]
-    mgr = SiddhiManager()
-    rt = mgr.create_app_runtime(
-        "@app:devicePatterns('always')\n" + HEAD + q)
-    rows = []
-    rt.add_callback("Out", lambda evs: rows.extend(
-        (e.timestamp, tuple(round(float(x), 3) for x in e.data))
-        for e in evs))
-    rt.start()
-    plan = next(p for p in rt._plans if isinstance(p, DevicePatternPlan))
-    assert plan.family == "scan"
-    rng = np.random.default_rng(5)
-    ih = rt.input_handler("S")
-    ts0 = 1_700_000_000_000
-    switches = {1: "dfa", 2: "chunk", 3: "scan"}
-    for b in range(4):
-        if b in switches:
-            plan.regeometry(plan_family=switches[b])
-            assert plan.family == switches[b]
-        for j in range(400):
-            i = b * 400 + j
-            ih.send((f"K{rng.integers(0, 4)}",
-                     float(np.round(rng.uniform(90, 130) * 4) / 4),
-                     int(rng.integers(1, 1000))),
-                    timestamp=ts0 + i * 7)
-        rt.flush()
-    mgr.shutdown()
-    _f, _e, host = _run("@app:devicePatterns('never')\n", q,
-                        n=1600, batches=4, seed=5)
-    assert rows == host, (len(rows), len(host))
-
-
 def test_family_gauges_in_statistics():
     q, _ = ELIGIBLE["static2"]
     mgr = SiddhiManager()
@@ -760,25 +722,6 @@ def test_nonevery_single_arm_resolves_across_flushes():
                     "@app:devicePatterns('always')\n")
     assert dev == host, (dev, host)
     assert done is True
-
-
-def test_tuning_cache_plan_family_round_trip(tmp_path):
-    from siddhi_tpu.core.autotune import (Geometry, TuningCache,
-                                          validate_cache_data)
-    c = TuningCache(str(tmp_path / "t.json"))
-    c.put("pattern:abc", {"batch": 1024, "plan_family": "scan"},
-          family="pattern")
-    ent = c.peek("pattern:abc")
-    assert ent["geometry"]["plan_family"] == "scan"
-    g = Geometry.from_dict(ent["geometry"])
-    assert g.plan_family == "scan" and g.batch == 1024
-    import json
-    data = json.load(open(str(tmp_path / "t.json")))
-    assert validate_cache_data(data) == []
-    data2 = json.loads(json.dumps(data))
-    key = next(iter(data2["entries"]))
-    data2["entries"][key]["geometry"]["plan_family"] = "bogus"
-    assert validate_cache_data(data2)
 
 
 # ---------------------------------------------------------------------------

@@ -1,19 +1,14 @@
-"""Adaptive execution geometry (core/autotune.py): tuning-cache
-persistence, planner consult, the AIMD SLO controller, geometry-
-invariance differentials per device plan family, and the service/
-telemetry surfacing."""
-import json
-import os
+"""The AIMD SLO controller (core/slo.py) and its runtime wiring, plus
+the geometry-invariance differentials per device plan family: outputs
+must not depend on batch size, pipeline depth, chunk lanes or lane
+packing, each reached the way an app reaches it, by annotation."""
 import time
 
 import numpy as np
 import pytest
 
 from siddhi_tpu import SiddhiManager
-from siddhi_tpu.core.autotune import (Autotuner, Geometry, SLOController,
-                                      TuningCache, lint_path,
-                                      plan_signature, shared_cache,
-                                      signature_of, validate_cache_data)
+from siddhi_tpu.core.slo import SLOController
 
 
 def q4(x):
@@ -32,16 +27,17 @@ def tape(n, keys=8, seed=0, dt_ms=25):
 def run_geometry(app, feeds, batch, depth=None, chunk_lanes=None,
                  capacity_switch=None):
     """Feed `feeds` ({stream: (cols, ts)}) in fixed cross-stream quanta,
-    sub-chunked at `batch`, applying depth/chunk_lanes via the
-    regeometry hook; returns the full decoded output row/ts sequence.
+    sub-chunked at `batch`, with depth/chunk_lanes annotated onto the
+    app; returns the full decoded output row/ts sequence.
     `capacity_switch=(at_quantum, new_batch)` exercises a mid-stream
     SLO-controller decision (_apply_batch_target)."""
+    head = ""
+    if depth is not None:
+        head += f"@app:devicePipeline({depth})\n"
+    if chunk_lanes is not None:
+        head += f"@app:deviceChunkLanes({chunk_lanes})\n"
     mgr = SiddhiManager()
-    rt = mgr.create_app_runtime(app)
-    for p in rt._plans:
-        rg = getattr(p, "regeometry", None)
-        if rg is not None:
-            rg(batch_hint=batch, depth=depth, chunk_lanes=chunk_lanes)
+    rt = mgr.create_app_runtime(head + app)
     out = []
     rt.add_batch_callback("Out", lambda b: out.extend(
         (int(ts), row) for ts, row in zip(b.timestamps,
@@ -124,8 +120,9 @@ def test_geometry_invariance(app, two_streams, geos):
 
 def test_regeometry_respects_can_pipeline():
     """A join with side filters must sync per flush (_can_pipeline is
-    False): a tuner/controller depth hint never overrides that."""
+    False): an annotated depth never overrides that."""
     app = """
+    @app:devicePipeline(3)
     define stream S (sym string, p double, v int);
     define stream T (sym string, p double, v int);
     from S[p > 100]#window.length(8) as a join T#window.length(8) as b
@@ -135,10 +132,11 @@ def test_regeometry_respects_can_pipeline():
     rt = mgr.create_app_runtime(app)
     plan = next(p for p in rt._plans
                 if type(p).__name__ == "DeviceJoinPlan")
-    assert not plan._can_pipeline and plan.pipeline_depth == 0
-    plan.regeometry(batch_hint=512, depth=3)
+    assert rt.geometry["pipeline_depth"] == (3, "annotation")
+    assert not plan._can_pipeline
     assert plan.pipeline_depth == 0 and plan._pipe.depth == 0
-    assert plan.batch_hint == 512      # the safe knob still lands
+    rt._apply_batch_target(512)
+    assert plan.batch_hint == 512      # the controller's knob still lands
     mgr.shutdown()
 
 
@@ -150,63 +148,6 @@ def test_controller_decision_is_output_invariant():
     switched = run_geometry(FILTER_APP, feeds, 128,
                             capacity_switch=(4, 512))
     assert switched == ref
-
-
-# ---------------------------------------------------------------------------
-# tuning cache: persistence round-trip, corruption fallback, lint
-# ---------------------------------------------------------------------------
-
-def test_cache_round_trip(tmp_path):
-    path = str(tmp_path / "tuning.json")
-    c1 = TuningCache(path)
-    sig = signature_of("filter", "some-query-shape")
-    assert c1.get(sig) is None and c1.misses == 1
-    key = c1.put(sig, {"batch": 4096, "pipeline_depth": 2},
-                 family="filter", score={"eps": 1000, "p99_ms": 3.2})
-    assert "|" in key and os.path.exists(path)
-    # a FRESH instance (new process analog) reads the same winner back
-    c2 = TuningCache(path)
-    ent = c2.get(sig)
-    assert ent["geometry"] == {"batch": 4096, "pipeline_depth": 2}
-    assert ent["family"] == "filter" and c2.hits == 1
-    ok, msgs = lint_path(path)
-    assert ok, msgs
-
-
-def test_cache_corruption_falls_back(tmp_path):
-    path = str(tmp_path / "tuning.json")
-    with open(path, "w") as f:
-        f.write("{ not json at all")
-    with pytest.warns(RuntimeWarning, match="corrupt"):
-        c = TuningCache(path)
-        assert c.get(signature_of("filter", "x")) is None
-    assert c.corrupt
-    assert os.path.exists(path + ".corrupt")   # quarantined, not trusted
-    # the cache still WORKS after corruption: a put() re-creates a valid
-    # file (deploy is never bricked)
-    sig = signature_of("window", "y")
-    c.put(sig, {"batch": 1024})
-    ok, msgs = lint_path(path)
-    assert ok, msgs
-    assert TuningCache(path).get(sig)["geometry"] == {"batch": 1024}
-
-
-def test_cache_schema_lint_catches_malformed(tmp_path):
-    bad = {"version": 1, "entries": {
-        "sig|cpu|jax1": {"geometry": {"batch": "huge"}},
-        "sig2|cpu|jax1": {"geometry": {"warp_factor": 9}},
-        "sig3|cpu|jax1": {"geometry": {}}}}
-    assert len(validate_cache_data(bad)) == 3
-    assert validate_cache_data({"version": 99, "entries": {}})
-    assert validate_cache_data([1, 2, 3])
-    path = str(tmp_path / "t.json")
-    with open(path, "w") as f:
-        json.dump(bad, f)
-    ok, msgs = lint_path(path)
-    assert not ok and len(msgs) == 3
-    # missing file = cold cache = fine
-    ok, _ = lint_path(str(tmp_path / "nope.json"))
-    assert ok
 
 
 # ---------------------------------------------------------------------------
@@ -352,7 +293,7 @@ def test_max_batch_latency_rides_controller_non_adaptive():
 
 
 def test_latency_cadence_drains_pipelined_results():
-    """A depth-D dispatch pipeline (tuned or annotated) must not hold an
+    """A depth-D dispatch pipeline must not hold an
     aged-out micro-batch's results past the flush cadence: the scheduler
     pump drains in-flight entries, so latency targets and pipelining
     compose."""
@@ -370,112 +311,6 @@ def test_latency_cadence_drains_pipelined_results():
         time.sleep(0.01)     # NO explicit flush(): the pump must deliver
     mgr.shutdown()
     assert got == [("K1", 101.0, 2)]
-
-
-# ---------------------------------------------------------------------------
-# autotuner sweep + planner consult
-# ---------------------------------------------------------------------------
-
-def test_autotuner_sweep_persists_and_planner_consults(tmp_path,
-                                                       monkeypatch):
-    monkeypatch.setenv("SIDDHI_TUNE_CACHE", str(tmp_path / "tune.json"))
-    tuner = Autotuner()     # the shared per-path cache runtimes consult
-    res = tuner.tune(FILTER_APP, n_events=2048,
-                     grid=[Geometry(batch=256, pipeline_depth=0),
-                           Geometry(batch=512, pipeline_depth=2)],
-                     warm_events=256)
-    assert not res["from_cache"]
-    assert len(res["candidates"]) == 2
-    assert res["winner"]["batch"] in (256, 512)
-    # every candidate saw identical outputs (enforced inside tune())
-    ms = {c["matches"] for c in res["candidates"]}
-    assert len(ms) == 1 and ms.pop() > 0
-    # warm cache: the second tune() skips the sweep entirely
-    res2 = tuner.tune(FILTER_APP, n_events=2048)
-    assert res2["from_cache"] and res2["candidates"] == []
-    # a fresh runtime build consults the persisted winner: batch
-    # capacity + pipeline depth come from the cache, and the hit gauges
-    # show it
-    mgr = SiddhiManager()
-    rt = mgr.create_app_runtime(FILTER_APP)
-    assert rt.batch_capacity == res["winner"]["batch"]
-    plan = rt._plans[0]
-    assert plan.pipeline_depth == res["winner"]["pipeline_depth"]
-    assert rt.tuner.hits >= 2
-    rep_t = rt.statistics()["tuning"]
-    assert rep_t["cache_hits"] >= 2 and rep_t["tuning_cache_entries"] >= 2
-    prom = rt.stats.prometheus()
-    assert "siddhi_tpu_tuning_cache_hits_total" in prom
-    # explicit annotations still win over the cache
-    rt2 = mgr.create_app_runtime("@app:devicePipeline(7)\n" + FILTER_APP)
-    assert rt2._plans[0].pipeline_depth == 7
-    mgr.shutdown()
-
-
-def test_sweep_rejects_output_divergence(tmp_path):
-    """The invariance guard actually fires: doctor one candidate's
-    result path and the sweep must raise rather than persist."""
-    from siddhi_tpu.core.autotune import AutotuneError
-    tuner = Autotuner(TuningCache(str(tmp_path / "t.json")))
-    real = tuner._measure
-    calls = [0]
-
-    def crooked(app_text, g, tapes, n_events, warm_events, out_streams):
-        res = real(app_text, g, tapes, n_events, warm_events, out_streams)
-        calls[0] += 1
-        if calls[0] == 2:
-            res["out_crc"] ^= 1
-        return res
-
-    tuner._measure = crooked
-    with pytest.raises(AutotuneError, match="output-invariant"):
-        tuner.tune(FILTER_APP, n_events=1024,
-                   grid=[Geometry(batch=256), Geometry(batch=512)],
-                   warm_events=256, force=True)
-
-
-def test_plan_signature_stability():
-    mgr = SiddhiManager()
-    rt1 = mgr.create_app_runtime(FILTER_APP)
-    rt2 = mgr.create_app_runtime(FILTER_APP)
-    s1 = plan_signature(rt1._plans[0])
-    assert s1 is not None and s1.startswith("filter:")
-    assert s1 == plan_signature(rt2._plans[0])
-    rt3 = mgr.create_app_runtime(FILTER_APP.replace("p > 100", "p > 99"))
-    assert plan_signature(rt3._plans[0]) != s1
-    mgr.shutdown()
-
-
-# ---------------------------------------------------------------------------
-# service surfacing
-# ---------------------------------------------------------------------------
-
-def test_service_tuning_endpoint():
-    import urllib.request
-    from siddhi_tpu.service import SiddhiService
-    svc = SiddhiService(port=0).start()
-    base = f"http://127.0.0.1:{svc.port}"
-    try:
-        app = ("@app:name('TuneMe')\n" + FILTER_APP)
-        req = urllib.request.Request(f"{base}/siddhi/artifact/deploy",
-                                     data=app.encode(), method="POST")
-        assert json.loads(urllib.request.urlopen(req).read())["app"] \
-            == "TuneMe"
-        with urllib.request.urlopen(f"{base}/siddhi/artifact/tuning") as r:
-            body = json.loads(r.read())
-        assert body["path"] == shared_cache().path
-        assert "entries" in body and "hits" in body and "device" in body
-        with urllib.request.urlopen(
-                f"{base}/siddhi/artifact/tuning?siddhiApp=TuneMe") as r:
-            per_app = json.loads(r.read())
-        assert per_app["app"] == "TuneMe"
-        assert "cache_hits" in per_app and "cache_misses" in per_app
-        with pytest.raises(urllib.error.HTTPError) as ei:
-            urllib.request.urlopen(
-                f"{base}/siddhi/artifact/tuning?siddhiApp=Nope")
-        assert ei.value.code == 404
-    finally:
-        svc.stop()
 
 
 # ---------------------------------------------------------------------------
