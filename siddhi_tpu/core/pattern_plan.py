@@ -55,6 +55,47 @@ def _m_bucket_chunk(n: int) -> int:
     return -(-n // 65536) * 65536
 
 
+def _stable_lane_order(part: np.ndarray) -> np.ndarray:
+    """The permutation `np.lexsort((seq, part))` returns, for rows that are
+    ALREADY in seq order inside every lane (the caller's invariant): a
+    stable sort by the lane id alone keeps each lane's rows as they stand.
+    A lane id is a small non-negative integer, so the sort is numpy's
+    radix pass over 16-bit keys (what `kind="stable"` picks for them):
+    one pass while every id is under 2^16, the low half then the high half
+    (LSD, each pass stable) above that."""
+    if part.size == 0 or int(part.max()) < 1 << 16:
+        return np.argsort(part.astype(np.uint16), kind="stable")
+    by_low = np.argsort((part & 0xFFFF).astype(np.uint16), kind="stable")
+    high = (part >> 16).astype(np.uint16)
+    return by_low[np.argsort(high[by_low], kind="stable")]
+
+
+def _rises_in_lanes(a_l: np.ndarray, run_start: np.ndarray) -> bool:
+    """Whether lane-ordered `a_l` is non-decreasing inside every lane
+    (`run_start`: the row each lane's run begins at)."""
+    rises = a_l[1:] >= a_l[:-1]
+    rises[run_start[1:] - 1] = True         # a lane's first row
+    return bool(rises.all())
+
+
+def _tail_rows(t: dict, rows) -> dict:
+    """The rows `rows` (a mask or an index) of a lane tail, or of the
+    flush's columns laid out like one."""
+    return {"ts": t["ts"][rows], "seq": t["seq"][rows],
+            "scode": t["scode"][rows], "part": t["part"][rows],
+            "cols": {k: v[rows] for k, v in t["cols"].items()}}
+
+
+def _offsets32(a: np.ndarray, base: int, lo: int) -> np.ndarray:
+    """`a - base` as the i32 offsets the device reads, saturating at
+    +-LOCAL_SPAN.  `lo` is a's minimum; a base is never more than
+    LOCAL_SPAN under a's maximum, so only the low side can need the clip."""
+    off = a - base
+    if lo - base < -LOCAL_SPAN:
+        np.clip(off, -LOCAL_SPAN, LOCAL_SPAN, out=off)
+    return off.astype(_I32)
+
+
 class DevicePatternPlan(QueryPlan):
     """from [every] e1=A[...] -> e2=B[...] within T — batched device NFA."""
 
@@ -96,6 +137,14 @@ class DevicePatternPlan(QueryPlan):
         self.P = partitions
         self.part_key_fns = part_key_fns        # stream_id -> fn(batch)->codes
         self._key_to_part: dict = {}            # key value -> partition index
+        # dense cache of _key_to_part for integer key columns of narrow
+        # range: _key_table[key - _key_base] is the lane, -1 = not cached
+        self._key_table: Optional[np.ndarray] = None
+        self._key_base = 0
+        # flushes by the way their lane order was found (EXPLAIN /
+        # device_metrics `lane_pack_order`)
+        self._lane_pack_order = {"radix": 0, "lexsort": 0, "key_table": 0,
+                                 "key_unique": 0, "seq_sort_skipped": 0}
 
         # multi-chip mesh: shard the partition axis (last axis of every
         # state leaf / event grid) over jax.devices() — the production
@@ -420,27 +469,78 @@ class DevicePatternPlan(QueryPlan):
             return self._of_dropped
         return int(np.asarray(self.state["of_slots"]).sum())
 
-    def part_of(self, stream_id: str, batch: EventBatch) -> np.ndarray:
-        """Partition index per event; grows the key map (host side).
-        Vectorized: the python dict is consulted once per DISTINCT key."""
+    # An integer key column whose values span at most this many entries
+    # is mapped through a dense key -> lane table: 2^22 int32 entries are
+    # 16 MiB of host memory a plan, which holds a string attribute's
+    # dictionary codes (rt.strings hands out consecutive ints from 1) up
+    # to four million symbols, and any id range that narrow.  A wider
+    # span would trade the sort for a table mostly of holes.
+    KEY_TABLE_MAX = 1 << 22
+
+    def part_of(self, stream_id: str, batch: EventBatch) -> tuple:
+        """(partition index per event, whether the key table gave it);
+        grows the key map (host side).
+        The form is read off the key column: an integer dtype over a
+        narrow range goes through the table; float, wide-int and computed
+        object keys go through np.unique.  Either way the python dict is
+        consulted once per DISTINCT key it has to resolve, and new keys
+        are numbered in sorted order."""
         if self.part_key_fns is None:
-            return np.zeros(batch.n, dtype=_I32)
-        keys = self.part_key_fns[stream_id](batch)
+            return np.zeros(batch.n, dtype=_I32), False
+        keys = np.asarray(self.part_key_fns[stream_id](batch))
+        parts = self._lanes_by_table(keys)
+        if parts is not None:
+            return parts, True
         uniq, inv = np.unique(keys, return_inverse=True)
+        parts_u = np.fromiter((self._lane_of_key(k) for k in uniq.tolist()),
+                              dtype=_I32, count=len(uniq))
+        return parts_u[inv], False
+
+    def _lane_of_key(self, k) -> int:
         k2p = self._key_to_part
-        parts_u = np.empty(len(uniq), dtype=_I32)
-        for j, k in enumerate(uniq.tolist()):
-            p = k2p.get(k)
-            if p is None:
-                # stateless lane families size their (L, F) grid per
-                # flush: a hot-added key is just a new lane id — no
-                # device-state growth, no recompile below the next
-                # pow2 lane bucket
-                if self._chunk_cfg is None and len(k2p) >= self.P:
-                    self._grow(2 * self.P)
-                p = k2p[k] = len(k2p)
-            parts_u[j] = p
-        return parts_u[inv]
+        p = k2p.get(k)
+        if p is None:
+            # stateless lane families size their (L, F) grid per
+            # flush: a hot-added key is just a new lane id — no
+            # device-state growth, no recompile below the next
+            # pow2 lane bucket
+            if self._chunk_cfg is None and len(k2p) >= self.P:
+                self._grow(2 * self.P)
+            p = k2p[k] = len(k2p)
+        return p
+
+    def _lanes_by_table(self, keys: np.ndarray) -> Optional[np.ndarray]:
+        """One np.take on the dense key -> lane table, or None for a
+        column the table cannot hold.  The table is a cache of
+        _key_to_part: its misses (keys new to the plan, or to a table
+        rebuilt after a restore) go through the dict in sorted order, so
+        lane ids are assigned exactly as the np.unique walk assigns them."""
+        if not keys.size or keys.dtype.kind not in "iu" \
+                or keys.dtype == np.uint64:
+            return None
+        lo, hi = int(keys.min()), int(keys.max())
+        tab, base = self._key_table, self._key_base
+        if tab is None or lo < base or hi >= base + len(tab):
+            if tab is not None:
+                lo, hi = min(lo, base), max(hi, base + len(tab) - 1)
+            if 0 <= lo and hi < self.KEY_TABLE_MAX:
+                lo = 0          # codes and small ids index the table as is
+            if hi - lo >= self.KEY_TABLE_MAX:
+                return None
+            grown = np.full(pow2_at_least(hi - lo + 1, lo=1024), -1, _I32)
+            if tab is not None:
+                grown[base - lo:base - lo + len(tab)] = tab
+            self._key_table, self._key_base = tab, base = grown, lo
+        idx = keys.astype(np.intp, copy=False)
+        if base:
+            idx = idx - base
+        parts = tab.take(idx)
+        miss = parts < 0
+        if miss.any():
+            for k in np.unique(keys[miss]).tolist():
+                tab[k - base] = self._lane_of_key(k)
+            parts = tab.take(idx)
+        return parts
 
     def _grow(self, new_p: int) -> None:
         """Double the partition axis (last axis of every state leaf): pad,
@@ -577,6 +677,16 @@ class DevicePatternPlan(QueryPlan):
         asked = self._parallel_kernel().first_hit
         return dict(asked) if asked else None
 
+    @property
+    def lane_pack_order(self) -> Optional[dict]:
+        """Flushes by the way the host pack ordered them (EXPLAIN): lane
+        order by one `radix` pass or by the two-key `lexsort`; key -> lane
+        by the `key_table` or by `key_unique`; and `seq_sort_skipped`, the
+        flushes whose union was in arrival order as it stood.  Each form is
+        picked from the flush's own columns (dtype, range, order)."""
+        counted = self._lane_pack_order
+        return dict(counted) if any(counted.values()) else None
+
     def _rebase(self, min_ts: int, min_seq: int) -> None:
         """Shift the plan's ts/seq bases forward and adjust persistent slot
         offsets so i32 locals never overflow.  Ancient slots clamp to
@@ -655,6 +765,9 @@ class DevicePatternPlan(QueryPlan):
         inel = {f: r for f, r in self.families.items() if r is not True}
         if inel:
             d["family_ineligible"] = inel
+        counted = self.lane_pack_order
+        if counted:
+            d["lane_pack_order"] = counted
         return d
 
     # -- QueryPlan interface -------------------------------------------------
@@ -700,6 +813,7 @@ class DevicePatternPlan(QueryPlan):
             for si, attr, t in self._grid_attrs:
                 cols[f"{si}.{attr}"] = np.zeros(N, dtype=self._np_dtype(t))
             o = 0
+            tabled = True
             for sid, b in bufs:
                 si = self._scode[sid]
                 sl = slice(o, o + b.n)
@@ -707,27 +821,39 @@ class DevicePatternPlan(QueryPlan):
                 seq[sl] = b.seqs if b.seqs is not None \
                     else np.arange(o, o + b.n)
                 scode[sl] = si
-                part[sl] = self.part_of(sid, b)
+                part[sl], by_table = self.part_of(sid, b)
+                tabled &= by_table
                 for sj, attr, _t in self._grid_attrs:
                     if sj == si:
                         cols[f"{si}.{attr}"][sl] = b.columns[attr]
                 o += b.n
+            if self.part_key_fns is not None:
+                self._lane_pack_order[
+                    "key_table" if tabled else "key_unique"] += 1
 
-            # 2. order by arrival, compute index-within-partition (broadcast
-            # mode: every lane sees every event, so the grid is (T, 1))
-            order = np.lexsort((seq,))
-            ts, seq, scode, part = (ts[order], seq[order], scode[order],
-                                    part[order])
-            for k in cols:
-                cols[k] = cols[k][order]
+            # 2. order by arrival.  One send_batch's stamps, and batches
+            # buffered in the order they were stamped, are in order as
+            # they stand; streams flushed together interleave and sort.
+            if (seq[1:] >= seq[:-1]).all():
+                self._lane_pack_order["seq_sort_skipped"] += 1
+            else:
+                order = np.argsort(seq, kind="stable")
+                ts, seq, scode, part = (ts[order], seq[order], scode[order],
+                                        part[order])
+                for k in cols:
+                    cols[k] = cols[k][order]
         if self._chunk_cfg is not None:
             return self._run_chunked_flat(ts, seq, scode, cols, part)
         with self.rt.span("host_build", plan=self.name):
             if self.broadcast_events:
+                # every lane sees every event, so the grid is (T, 1)
                 idx_within = np.arange(N, dtype=np.int64)
                 part = np.zeros(N, dtype=_I32)
             else:
-                by_part = np.lexsort((seq, part))
+                # index-within-partition: step 2 left the rows in seq
+                # order, which is _stable_lane_order's invariant
+                by_part = _stable_lane_order(part)
+                self._lane_pack_order["radix"] += 1
                 idx_within = np.empty(N, dtype=np.int64)
                 sp = part[by_part]
                 run_start = np.flatnonzero(np.r_[True, sp[1:] != sp[:-1]])
@@ -1060,9 +1186,35 @@ class DevicePatternPlan(QueryPlan):
              self._lane_F) = saved
             raise
 
+    def _lane_order(self, part, seq, run_start) -> tuple:
+        """(order, seq[order]): the rows by (lane, seq).  `[tail | new]`
+        is in seq order inside every lane when (a) the tail keeps each
+        lane's rows in seq order (it is a mask over rows this function
+        ordered; `held` lanes are disjoint from the active ones), (b)
+        every tail seq is below every new seq of its lane (arrival stamps
+        only grow) and (c) the new rows are in seq order
+        (_finalize_chunks step 2): then one stable radix pass over the
+        lane id is the whole sort.  Whether it held is read off the
+        result, lane by lane (`run_start`: where each lane's run begins);
+        input that breaks it (unstamped batches, whose seqs restart at
+        every flush; a restored tail from elsewhere) takes the two-key
+        comparison sort."""
+        order = _stable_lane_order(part)
+        seq_l = seq[order]
+        if _rises_in_lanes(seq_l, run_start):
+            self._lane_pack_order["radix"] += 1
+        else:
+            self._lane_pack_order["lexsort"] += 1
+            order = np.lexsort((seq, part))
+            seq_l = seq[order]
+        return order, seq_l
+
     def _run_lanes_flat_inner(self, ts, seq, scode, cols, part) -> list:
         with self.rt.span("host_build", plan=self.name):
             W0 = int(self._chunk_cfg["W"])
+            # rows a lane, new then replayed; lane ids are dense in
+            # [0, len(_key_to_part)), so a count is a bincount
+            lane_n = np.bincount(part, minlength=len(self._key_to_part))
             tl = self._lane_tail
             held = None
             if tl is not None:
@@ -1073,20 +1225,12 @@ class DevicePatternPlan(QueryPlan):
                 # pin the shared i32 ts/seq bases forever (review
                 # finding: a long-quiet lane saturated every live
                 # lane's offsets at the 2^30 clip)
-                active = np.isin(tl["part"], np.unique(part))
+                active = lane_n[tl["part"]] > 0
                 if not active.all():
-                    inactive = ~active
-                    held = {"ts": tl["ts"][inactive],
-                            "seq": tl["seq"][inactive],
-                            "scode": tl["scode"][inactive],
-                            "part": tl["part"][inactive],
-                            "cols": {k: v[inactive]
-                                     for k, v in tl["cols"].items()}}
-                    tl = {"ts": tl["ts"][active], "seq": tl["seq"][active],
-                          "scode": tl["scode"][active],
-                          "part": tl["part"][active],
-                          "cols": {k: v[active]
-                                   for k, v in tl["cols"].items()}}
+                    held = _tail_rows(tl, ~active)
+                    tl = _tail_rows(tl, active)
+                lane_n = lane_n + np.bincount(tl["part"],
+                                              minlength=len(lane_n))
                 ts = np.concatenate([tl["ts"], ts])
                 seq = np.concatenate([tl["seq"], seq])
                 scode = np.concatenate([tl["scode"], scode])
@@ -1094,27 +1238,29 @@ class DevicePatternPlan(QueryPlan):
                 cols = {k: np.concatenate([tl["cols"][k], v])
                         for k, v in cols.items()}
             N = len(ts)
-            order = np.lexsort((seq, part))
-            ts, seq, scode, part = (ts[order], seq[order], scode[order],
-                                    part[order])
-            cols = {k: v[order] for k, v in cols.items()}
-            change = np.r_[True, part[1:] != part[:-1]]
-            run_id = np.cumsum(change) - 1
-            run_start = np.flatnonzero(change)
-            lane_ids = part[run_start].astype(np.int64)
-            counts = np.diff(np.r_[run_start, N])
-            idx_within = np.arange(N) - run_start[run_id]
+            # the flush's lanes in ascending id, each one run of the
+            # lane-ordered rows
+            lane_ids = np.flatnonzero(lane_n)
+            counts = lane_n[lane_ids]
             Lr = len(lane_ids)
+            run_start = np.cumsum(counts) - counts
             run_end = run_start + counts - 1
+            order, seq_l = self._lane_order(part, seq, run_start)
+            ts_l = ts[order]
 
-            # per-lane running-max ts in ONE pass (offset trick): feeds
-            # the tail-retention bound and the out-of-order `within`
-            # widening, exactly like the flat path's global cummax
-            span = int(ts.max()) - int(ts.min()) + 1
-            sh = ts.astype(np.int64) + run_id.astype(np.int64) * span
-            tsmono = np.maximum.accumulate(sh) \
-                - run_id.astype(np.int64) * span
-            W = W0 + int(np.max(tsmono - ts))
+            # per-lane running-max ts: feeds the tail-retention bound and
+            # the out-of-order `within` widening, exactly like the flat
+            # path's global cummax.  Timestamps that rise inside every
+            # lane are their own running max; else ONE cummax over all
+            # lanes, each lifted clear of the one before (offset trick)
+            ts_lo, ts_hi = int(ts.min()), int(ts.max())
+            if _rises_in_lanes(ts_l, run_start):
+                tsmono, W = ts_l, W0
+            else:
+                lift = np.repeat(np.arange(Lr, dtype=np.int64)
+                                 * (ts_hi - ts_lo + 1), counts)
+                tsmono = np.maximum.accumulate(ts_l + lift) - lift
+                W = W0 + int(np.max(tsmono - ts_l))
 
             # lane-grid geometry: the lane axis pads to pow2 (hot-adding
             # a key keeps the compiled (L, F) shape until the count
@@ -1141,49 +1287,55 @@ class DevicePatternPlan(QueryPlan):
             # host-identical outcome — instead of saturating every live
             # lane's offsets high
             budget = LOCAL_SPAN - (1 << 16)
-            ts_base = max(int(ts.min()), int(ts.max()) - budget)
-            seq_base = max(int(seq.min()), int(seq.max()) - budget)
-            self._last_seq = max(self._last_seq, int(seq.max()))
+            seq_lo, seq_hi = int(seq.min()), int(seq.max())
+            ts_base = max(ts_lo, ts_hi - budget)
+            seq_base = max(seq_lo, seq_hi - budget)
+            self._last_seq = max(self._last_seq, seq_hi)
             if len(self._lane_prev) < len(self._key_to_part):
                 grown = np.full(len(self._key_to_part), -(2 ** 62),
                                 dtype=np.int64)
                 grown[:len(self._lane_prev)] = self._lane_prev
                 self._lane_prev = grown
 
+            # cell (lane run r, index-within-lane i) of the (Lpad, F)
+            # grid is flat cell r * F + i: ascending in the lane-ordered
+            # rows, so each column is one in-order scatter
+            cell = np.repeat(np.arange(Lr) * F - run_start, counts)
+            cell += np.arange(N)
+
             def grid(a):
-                g = np.zeros((Lpad, F), dtype=a.dtype)
-                g[run_id, idx_within] = a
-                return g
+                g = np.zeros(Lpad * F, dtype=a.dtype)
+                g[cell] = a
+                return g.reshape(Lpad, F)
 
             nev = np.zeros(Lpad, _I32)
             nev[:Lr] = counts
             prev = np.full(Lpad, -LOCAL_SPAN, _I32)
             prev[:Lr] = np.clip(self._lane_prev[lane_ids] - seq_base,
                                 -LOCAL_SPAN, LOCAL_SPAN).astype(_I32)
-            ev = {"__flat.__ts__": grid(np.clip(
-                      ts - ts_base, -LOCAL_SPAN, LOCAL_SPAN).astype(_I32)),
-                  "__flat.__seq__": grid(np.clip(
-                      seq - seq_base, -LOCAL_SPAN, LOCAL_SPAN).astype(_I32)),
+            ev = {"__flat.__ts__": grid(_offsets32(ts_l, ts_base, ts_lo)),
+                  "__flat.__seq__": grid(_offsets32(seq_l, seq_base,
+                                                    seq_lo)),
                   "__nev__": nev, "__prev_seq__": prev,
                   "__base_ts__": np.int64(ts_base),
                   "__base_seq__": np.int64(seq_base)}
             if len(self.spec.stream_ids) > 1:
-                ev["__flat.__scode__"] = grid(scode)
+                ev["__flat.__scode__"] = grid(scode[order])
             for k, v in cols.items():
-                ev[f"__flat.{k}"] = grid(v)
+                ev[f"__flat.{k}"] = grid(v[order])
 
             # per-lane tail: the last `within` window of each lane's
             # events replays at that lane's next flush (lanes quiet this
-            # flush keep their stored tail untouched)
-            last_ts = tsmono[run_end]
-            keep = tsmono >= (last_ts[run_id] - W)
-            self._lane_tail = {
-                "ts": ts[keep], "seq": seq[keep], "scode": scode[keep],
-                "part": part[keep],
-                "cols": {k: v[keep] for k, v in cols.items()}}
+            # flush keep their stored tail untouched).  Only the kept
+            # rows are gathered, in lane order.
+            keep = order[tsmono >= np.repeat(tsmono[run_end] - W, counts)]
+            self._lane_tail = _tail_rows(
+                {"ts": ts, "seq": seq, "scode": scode, "part": part,
+                 "cols": cols}, keep)
             if held is not None:
-                # quiet lanes' tails ride along untouched (next flush
-                # re-sorts, so concatenation order is irrelevant)
+                # quiet lanes' tails ride along untouched: their lanes
+                # are none of the kept ones, so every lane's rows stay
+                # contiguous and in seq order (_lane_order's invariant)
                 self._lane_tail = {
                     k: (np.concatenate([self._lane_tail[k], held[k]])
                         if k != "cols" else
@@ -1191,7 +1343,7 @@ class DevicePatternPlan(QueryPlan):
                                             held["cols"][c]])
                          for c in held["cols"]})
                     for k in self._lane_tail}
-            self._lane_prev[lane_ids] = seq[run_end]
+            self._lane_prev[lane_ids] = seq_l[run_end]
 
         return self._pipe.push(self._dispatch_par(
             ev, F, F, ts_base, seq_base, lanes=Lpad))
@@ -1565,6 +1717,7 @@ class DevicePatternPlan(QueryPlan):
             self.P = p
         self.state = self._shard(st)
         self._key_to_part = dict(d["key_to_part"])
+        self._key_table = None      # a cache of the dict: refilled by misses
         self._ts_base = d.get("ts_base")
         self._seq_base = d.get("seq_base")
         self._start_anchor = d.get("start_anchor")

@@ -210,6 +210,9 @@ def _query_entry(rt, plan) -> Optional[dict]:
         first_hit = getattr(plan, "first_hit", None)
         if first_hit:
             ent["first_hit"] = first_hit
+        lane_pack_order = getattr(plan, "lane_pack_order", None)
+        if lane_pack_order:
+            ent["lane_pack_order"] = lane_pack_order
         families = getattr(plan, "families", None)
         if families:
             rejected = {f: r for f, r in sorted(families.items())
