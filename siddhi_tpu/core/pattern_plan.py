@@ -27,6 +27,7 @@ from .batch import EventBatch
 from .expr import ExprError, MultiStreamContext, compile_expression
 from .nfa_device import (ChainSpec, DeviceNFAUnsupported, LOCAL_SPAN,
                          NFAKernel, join64_np, lower_chain, pow2_at_least)
+from .nfa_parallel import DENSE_MAX_F
 from .planner import (AGGREGATOR_NAMES, OutputBatch, PlanError, QueryPlan,
                       selector_has_aggregators)
 from .schema import StreamSchema, TIMESTAMP_DTYPE, dtype_of
@@ -96,6 +97,64 @@ def _offsets32(a: np.ndarray, base: int, lo: int) -> np.ndarray:
     return off.astype(_I32)
 
 
+# The most events one row of the partitioned lane grid holds when a flush
+# is cut: a lane longer than this is laid out as several rows, each a
+# flush boundary its key never saw (_cut_rows).  Every lane pads to the
+# longest row and the block's first-hit queries stay dense only up to
+# DENSE_MAX_F events a row, so the cut is at most that.  Half of it, by two
+# readings of pattern1k-zipf.sat on the chip (one seed, 30 s; PERF.md
+# section 6, PR 35): at DENSE_MAX_F // 2 = 2048 a batch is 1,088 rows, a
+# 2048 x 2048 grid, 412,970 events/s; at DENSE_MAX_F = 4096 it is 1,025
+# rows, astride the lane axis' power of two, a 2048 x 4096 grid, 178,713
+# events/s.  A shorter row serves fewer new events behind its replayed
+# window (the top key's 1,294 of 2048), but the grid is rows x cut cells
+# and the dense queries cost cut^2 a row.
+LANE_CUT = DENSE_MAX_F // 2
+
+
+def _cut_rows(counts, run_start, tail_n, tsmono, W: int) -> Optional[tuple]:
+    """The grid rows of a flush in which some lane holds more than LANE_CUT
+    events, as (lane run of each row, its first lane-ordered event, its
+    length, its first NEW event), rows of one lane consecutive and in
+    order; None when some lane's replay window leaves under a quarter of
+    a row for new events: that lane is hotter than the cut can serve (the
+    rows it needs grow as LANE_CUT over the room left).
+
+    A lane run is [replayed tail | new events] in arrival order
+    (`tail_n` of them replayed).  Its first row starts where the run
+    starts; every next row starts with the events whose running-max
+    timestamp is within W of the last event BEFORE its first new one:
+    what the lane's tail would hold had a flush ended there, so the row
+    is what that lane's next flush would have been."""
+    cut = np.flatnonzero(counts > LANE_CUT)
+    room = LANE_CUT // 4
+    per_lane = []
+    for r in cut.tolist():
+        a, c = int(run_start[r]), int(counts[r])
+        mono = tsmono[a:a + c]
+        first, p, s = [], 0, int(tail_n[r])
+        while True:
+            if LANE_CUT - (s - p) < room:
+                return None
+            e = min(c, p + LANE_CUT)
+            first.append((a + p, e - p, a + s))
+            if e == c:
+                break
+            s = e
+            p = int(np.searchsorted(mono, mono[s - 1] - W, side="left"))
+        per_lane.append(first)
+    n_rows = np.ones(len(counts), dtype=np.int64)
+    n_rows[cut] = [len(f) for f in per_lane]
+    row_run = np.repeat(np.arange(len(counts)), n_rows)
+    row_at, row_n = run_start[row_run], counts[row_run]
+    row_new = row_at + tail_n[row_run]
+    at = np.cumsum(n_rows) - n_rows
+    for r, first in zip(cut.tolist(), per_lane):
+        sl = slice(int(at[r]), int(at[r]) + len(first))
+        row_at[sl], row_n[sl], row_new[sl] = np.array(first).T
+    return row_run, row_at, row_n, row_new
+
+
 class DevicePatternPlan(QueryPlan):
     """from [every] e1=A[...] -> e2=B[...] within T — batched device NFA."""
 
@@ -145,6 +204,10 @@ class DevicePatternPlan(QueryPlan):
         # device_metrics `lane_pack_order`)
         self._lane_pack_order = {"radix": 0, "lexsort": 0, "key_table": 0,
                                  "key_unique": 0, "seq_sort_skipped": 0}
+        # what cutting long lanes into grid rows did so far (EXPLAIN /
+        # device_metrics `lane_cut`)
+        self._lane_cut = {"flushes_cut": 0, "lanes_cut": 0, "rows_added": 0,
+                          "events_replayed": 0, "flushes_uncuttable": 0}
 
         # multi-chip mesh: shard the partition axis (last axis of every
         # state leaf / event grid) over jax.devices() — the production
@@ -687,6 +750,18 @@ class DevicePatternPlan(QueryPlan):
         counted = self._lane_pack_order
         return dict(counted) if any(counted.values()) else None
 
+    @property
+    def lane_cut(self) -> Optional[dict]:
+        """What the host pack's cut of long lanes did (EXPLAIN), for a
+        plan that packs partitioned lane grids: `flushes_cut`, and over
+        them the `lanes_cut`, the grid `rows_added` to one a lane and the
+        `events_replayed` at the head of those rows; `flushes_uncuttable`,
+        flushes with a lane past `cut_length` (LANE_CUT) that kept one row
+        a lane because some lane's replay window overfills a row."""
+        if not self._partitioned or self.family not in ("scan", "dfa"):
+            return None
+        return {**self._lane_cut, "cut_length": LANE_CUT}
+
     def _rebase(self, min_ts: int, min_seq: int) -> None:
         """Shift the plan's ts/seq bases forward and adjust persistent slot
         offsets so i32 locals never overflow.  Ancient slots clamp to
@@ -768,6 +843,9 @@ class DevicePatternPlan(QueryPlan):
         counted = self.lane_pack_order
         if counted:
             d["lane_pack_order"] = counted
+        cut = self.lane_cut
+        if cut:
+            d["lane_cut"] = cut
         return d
 
     # -- QueryPlan interface -------------------------------------------------
@@ -1209,6 +1287,34 @@ class DevicePatternPlan(QueryPlan):
             seq_l = seq[order]
         return order, seq_l
 
+    def _cut_lanes(self, counts, run_start, tail_n, tsmono, W: int,
+                   order, seq_l, ts_l, lane_prev) -> Optional[tuple]:
+        """The grid rows of a flush whose longest lane is past LANE_CUT
+        (_cut_rows), as what the grid is filled from: per cell, in row
+        order, the flush row, seq and ts it takes (`order`, `seq_l`, `ts_l`
+        gathered: a row's replayed head repeats events of the row before);
+        per row, its events, where its cells start, and its dedup bound: a
+        lane's first row at the lane's prev seq (`lane_prev`, per lane
+        run), a later one at the event before its first new one.  Counted.
+        None, and counted, when a lane is hotter than the cut serves: the
+        flush then keeps one row a lane, whatever its length."""
+        rows = _cut_rows(counts, run_start, tail_n, tsmono, W)
+        did = self._lane_cut
+        if rows is None:
+            did["flushes_uncuttable"] += 1
+            return None
+        row_run, row_at, row_n, row_new = rows
+        g_start = np.cumsum(row_n) - row_n
+        src = np.repeat(row_at - g_start, row_n)
+        src += np.arange(len(src))
+        first = np.r_[True, row_run[1:] != row_run[:-1]]
+        did["flushes_cut"] += 1
+        did["lanes_cut"] += int(np.count_nonzero(counts > LANE_CUT))
+        did["rows_added"] += len(row_n) - len(counts)
+        did["events_replayed"] += int((row_new - row_at)[~first].sum())
+        g_prev = np.where(first, lane_prev[row_run], seq_l[row_new - 1])
+        return order[src], seq_l[src], ts_l[src], row_n, g_start, g_prev
+
     def _run_lanes_flat_inner(self, ts, seq, scode, cols, part) -> list:
         with self.rt.span("host_build", plan=self.name):
             W0 = int(self._chunk_cfg["W"])
@@ -1216,7 +1322,7 @@ class DevicePatternPlan(QueryPlan):
             # [0, len(_key_to_part)), so a count is a bincount
             lane_n = np.bincount(part, minlength=len(self._key_to_part))
             tl = self._lane_tail
-            held = None
+            held = tail_n = None
             if tl is not None:
                 # only lanes with NEW events this flush replay their
                 # tail; a quiet lane cannot produce a new completion
@@ -1229,8 +1335,8 @@ class DevicePatternPlan(QueryPlan):
                 if not active.all():
                     held = _tail_rows(tl, ~active)
                     tl = _tail_rows(tl, active)
-                lane_n = lane_n + np.bincount(tl["part"],
-                                              minlength=len(lane_n))
+                tail_n = np.bincount(tl["part"], minlength=len(lane_n))
+                lane_n = lane_n + tail_n
                 ts = np.concatenate([tl["ts"], ts])
                 seq = np.concatenate([tl["seq"], seq])
                 scode = np.concatenate([tl["scode"], scode])
@@ -1269,11 +1375,35 @@ class DevicePatternPlan(QueryPlan):
             # finer than pow2 because every padded cell multiplies by
             # the lane count (pow2 wasted up to 2x the whole grid)
             fm = int(counts.max())
-            f_min = pow2_at_least(fm, lo=16) if fm <= 64 \
-                else (fm // 64 + 2) * 64
-            F = max(self._lane_F, f_min)
-            if F > 4 * f_min:
-                F = f_min
+            if len(self._lane_prev) < len(self._key_to_part):
+                grown = np.full(len(self._key_to_part), -(2 ** 62),
+                                dtype=np.int64)
+                grown[:len(self._lane_prev)] = self._lane_prev
+                self._lane_prev = grown
+            # what each grid row holds, in lane order: a lane's run as it
+            # stands, unless the flush is cut (rows of g_counts events
+            # from g_start on; g_prev: the seq before a row's new events)
+            g_order, g_seq, g_ts = order, seq_l, ts_l
+            g_counts, g_start = counts, run_start
+            g_prev = self._lane_prev[lane_ids]
+            cut = None
+            if fm > LANE_CUT:
+                with self.rt.span("lane_cut", plan=self.name):
+                    cut = self._cut_lanes(
+                        counts, run_start,
+                        np.zeros(Lr, dtype=np.int64) if tail_n is None
+                        else tail_n[lane_ids], tsmono, W,
+                        order, seq_l, ts_l, g_prev)
+            if cut is None:
+                f_min = pow2_at_least(fm, lo=16) if fm <= 64 \
+                    else (fm // 64 + 2) * 64
+                F = max(self._lane_F, f_min)
+                if F > 4 * f_min:
+                    F = f_min
+            else:
+                g_order, g_seq, g_ts, g_counts, g_start, g_prev = cut
+                Lr, N = len(g_counts), len(g_order)
+                F = LANE_CUT        # a cut lane's first row fills it
             self._lane_F = F
             Lpad = pow2_at_least(max(Lr, 1), lo=8)
             if self.mesh is not None:
@@ -1291,16 +1421,11 @@ class DevicePatternPlan(QueryPlan):
             ts_base = max(ts_lo, ts_hi - budget)
             seq_base = max(seq_lo, seq_hi - budget)
             self._last_seq = max(self._last_seq, seq_hi)
-            if len(self._lane_prev) < len(self._key_to_part):
-                grown = np.full(len(self._key_to_part), -(2 ** 62),
-                                dtype=np.int64)
-                grown[:len(self._lane_prev)] = self._lane_prev
-                self._lane_prev = grown
 
-            # cell (lane run r, index-within-lane i) of the (Lpad, F)
-            # grid is flat cell r * F + i: ascending in the lane-ordered
-            # rows, so each column is one in-order scatter
-            cell = np.repeat(np.arange(Lr) * F - run_start, counts)
+            # cell (grid row r, index-within-row i) of the (Lpad, F)
+            # grid is flat cell r * F + i: ascending in the row-ordered
+            # events, so each column is one in-order scatter
+            cell = np.repeat(np.arange(Lr) * F - g_start, g_counts)
             cell += np.arange(N)
 
             def grid(a):
@@ -1309,25 +1434,27 @@ class DevicePatternPlan(QueryPlan):
                 return g.reshape(Lpad, F)
 
             nev = np.zeros(Lpad, _I32)
-            nev[:Lr] = counts
+            nev[:Lr] = g_counts
             prev = np.full(Lpad, -LOCAL_SPAN, _I32)
-            prev[:Lr] = np.clip(self._lane_prev[lane_ids] - seq_base,
+            prev[:Lr] = np.clip(g_prev - seq_base,
                                 -LOCAL_SPAN, LOCAL_SPAN).astype(_I32)
-            ev = {"__flat.__ts__": grid(_offsets32(ts_l, ts_base, ts_lo)),
-                  "__flat.__seq__": grid(_offsets32(seq_l, seq_base,
+            ev = {"__flat.__ts__": grid(_offsets32(g_ts, ts_base, ts_lo)),
+                  "__flat.__seq__": grid(_offsets32(g_seq, seq_base,
                                                     seq_lo)),
                   "__nev__": nev, "__prev_seq__": prev,
                   "__base_ts__": np.int64(ts_base),
                   "__base_seq__": np.int64(seq_base)}
             if len(self.spec.stream_ids) > 1:
-                ev["__flat.__scode__"] = grid(scode[order])
+                ev["__flat.__scode__"] = grid(scode[g_order])
             for k, v in cols.items():
-                ev[f"__flat.{k}"] = grid(v[order])
+                ev[f"__flat.{k}"] = grid(v[g_order])
 
             # per-lane tail: the last `within` window of each lane's
             # events replays at that lane's next flush (lanes quiet this
             # flush keep their stored tail untouched).  Only the kept
-            # rows are gathered, in lane order.
+            # rows are gathered, in lane order.  By LANE (run_end,
+            # counts, lane_ids), never by grid row: a cut lane's tail is
+            # its last window whatever rows it was laid out as.
             keep = order[tsmono >= np.repeat(tsmono[run_end] - W, counts)]
             self._lane_tail = _tail_rows(
                 {"ts": ts, "seq": seq, "scode": scode, "part": part,
@@ -1391,6 +1518,11 @@ class DevicePatternPlan(QueryPlan):
             from .nfa_parallel import ARM_RESOLVED
             kern = self._parallel_kernel()
             if kern.prog.single_arm:
+                # indexed by grid ROW, which here is a lane id: only
+                # fused multi-query lanes reach this with `lanes` (row i
+                # is query lane i in every flush); partitioned lanes,
+                # whose rows are a flush's ACTIVE lanes or cut segments of
+                # them, refuse a non-`every` head (classify_parallel)
                 flags = np.asarray(ipack[:, 0, 4] if lanes
                                    else ipack[0, 4:5])
                 done = flags == ARM_RESOLVED

@@ -213,6 +213,9 @@ def _query_entry(rt, plan) -> Optional[dict]:
         lane_pack_order = getattr(plan, "lane_pack_order", None)
         if lane_pack_order:
             ent["lane_pack_order"] = lane_pack_order
+        lane_cut = getattr(plan, "lane_cut", None)
+        if lane_cut:
+            ent["lane_cut"] = lane_cut
         families = getattr(plan, "families", None)
         if families:
             rejected = {f: r for f, r in sorted(families.items())

@@ -57,7 +57,8 @@ SPANS = (
     "parse", "plan", "compile",                         # build
     "net.wait", "net.decode", "admit", "queue_wait",    # wire
     "ingest", "frame", "freeze", "wal.append",          # ingest
-    "dispatch", "host_build", "kernel", "transfer",     # dispatch
+    "dispatch", "host_build", "lane_cut", "kernel",     # dispatch
+    "transfer",
     "unpack", "scatter", "emit",
     "sink.publish", "sink.encode", "sink.send",         # egress
     "gc",                                               # process
