@@ -23,6 +23,12 @@ steps through events:
     one fused masked min, no gather and no loop; a long lane (the flat
     P = 1 block, fused multi-query lanes) builds a perfect segment tree
     and walks it in up to 2*log2(L)+1 rounds of dependent gathers;
+  * ONE indexed read, "this column's element at idx[m]" (_Read), for
+    whatever a resolved index then fetches: a hop's captured value a
+    head, the capture indices and the selected values of the M match
+    rows.  The same rule and constant pick its form: a short lane is
+    read by one fused one-hot sum over its (event, asker) pairs, bit
+    for bit and with no gather; a long one by the gather;
   * rank/select over occurrence-count prefix sums for `<m:n>` count
     quantifiers — "the min-th occurrence after entry" is one first-hit
     query on the monotone cumulative-count array (the bit-packed
@@ -709,6 +715,20 @@ def _first_hit_dense(vals, keep, L: int, s, v, op: str):
 # is also the smallest flat P = 1 block the plan ships (pattern_plan's
 # f_min): a small unpartitioned flush is dense, and one of 2^18 events, or
 # a fused multi-query lane that sees the whole stream, walks the tree.
+#
+# The same bound picks the form of an indexed read (_Read), and there it
+# sits AT the crossing, not a factor under it.  A gather of lanes x F
+# elements costs 10.25 ns an element at 1024 x 448, 256 x 448, 1024 x 64
+# and 2048 x 2048 alike, f32 or i32 (TPU v5e, PERF.md section 5, PR 36).
+# The fused one-hot sum costs F pairs an element: 0.84 ps a pair at
+# F = 64, 0.60 at F = 448 (0.27 ns an element, 38 times under the
+# gather), and at F = 2048 0.67 ps over 1088 lanes but 2.22 ps over 2048
+# (4.5 ns an element: the slowest reading, whose cause is the grid's size
+# and not F alone, PERF.md section 7.7); reads that share an index array
+# share the compare (two 1.13, five 0.87 ps a pair at 2048 x 2048).
+# 10.25 ns / 2.22 ps is F = 4,600.  Nothing was measured between 2048 and
+# the bound: a flat F = 4096 block reads 9.1 ns an element at the slowest
+# rate, even with the gather.
 DENSE_MAX_F = 4096
 
 
@@ -750,6 +770,80 @@ class _FirstHit:
         """What one call of the traced block asks, over all its lanes."""
         return {"dense": self.queries if self.dense else 0,
                 "tree": 0 if self.dense else self.queries,
+                "pairs_per_call": lanes * self.pairs,
+                "lanes": lanes, "F": self.F}
+
+
+def _read_dense(cols: list, idx) -> list:
+    """[col[clip(idx, 0, len(col) - 1)] for col in cols] without a gather:
+    a masked sum over every (event, asker) pair of the lane,
+
+        out[m] = sum over i of (bits[i]  if  i == clip(idx[m])  else  0)
+
+    where `bits` is a column as int32: float32 and uint32 bitcast, bool
+    and the narrower ints widened.  Exactly one term of each sum is
+    non-zero, so the answer is the gathered element bit for bit (NaN
+    payloads, -0.0, infinities and denormals pass through as integers:
+    nothing is compared or added as a float).  Events lie along axis 0
+    and askers along axis 1, _first_hit_dense's layout; the compiler
+    fuses compare, select and sum and never holds the (F, M) pairs.
+    Columns asked at ONE index array are summed by one variadic reduce:
+    the compare is made once for all of them."""
+    n = cols[0].shape[0]
+    i = jnp.arange(n, dtype=_I32)[:, None]
+    hit = i == jnp.clip(idx.reshape(-1), 0, n - 1).astype(_I32)[None, :]
+    cast = [c.dtype in (jnp.float32, jnp.uint32) for c in cols]
+    bits = [lax.bitcast_convert_type(c, _I32) if b else c.astype(_I32)
+            for c, b in zip(cols, cast)]
+    sums = lax.reduce(
+        [jnp.where(hit, b[:, None], jnp.int32(0)) for b in bits],
+        [jnp.int32(0)] * len(bits),
+        lambda x, y: tuple(p + q for p, q in zip(x, y)), (0,))
+    sums = [o.reshape(idx.shape) for o in sums]
+    return [lax.bitcast_convert_type(o, c.dtype) if b else o.astype(c.dtype)
+            for o, c, b in zip(sums, cols, cast)]
+
+
+class _Read:
+    """One block's entry for "the column's element at idx[m]", asked by
+    every m at once (idx clipped into the column, as every caller's
+    downstream mask expects): the chase's per-head reads, the capture
+    indices and the selected values of the M match rows.  The form follows
+    the block's F by _FirstHit's rule and constant: a lane of at most
+    DENSE_MAX_F events is read by one fused one-hot sum (_read_dense), a
+    longer one, or a column wider than four bytes (f64 mode), by a
+    gather.  A read at the block's own arange is the column itself.
+    Counts what it was asked while the block is traced: rt.explain()'s
+    `indexed_read`."""
+
+    def __init__(self, F: int, j0):
+        self.F, self.j0 = F, j0
+        self.dense = self.gather = self.identity = 0
+        self.pairs = 0            # (event, asker) pairs a lane, dense form
+
+    def __call__(self, col, idx):
+        return self.all([col], idx)[0]
+
+    def all(self, cols: list, idx) -> list:
+        """The columns (one length) at one index array: the dense form
+        then compares once for all of them."""
+        if idx is self.j0 and cols[0].shape[0] == self.F:
+            self.identity += len(cols)
+            return list(cols)
+        short = [self.F <= DENSE_MAX_F and c.dtype.itemsize <= 4
+                 for c in cols]
+        dense = iter(_read_dense([c for c, d in zip(cols, short) if d], idx)
+                     if any(short) else ())
+        self.dense += sum(short)
+        self.gather += len(cols) - sum(short)
+        self.pairs += sum(short) * cols[0].shape[0] * idx.size
+        at = jnp.clip(idx, 0, cols[0].shape[0] - 1)
+        return [next(dense) if d else c[at] for c, d in zip(cols, short)]
+
+    def asked(self, lanes: int) -> dict:
+        """What one call of the traced block reads, over all its lanes."""
+        return {"dense": self.dense, "gather": self.gather,
+                "identity": self.identity,
                 "pairs_per_call": lanes * self.pairs,
                 "lanes": lanes, "F": self.F}
 
@@ -810,6 +904,7 @@ class ParallelChainKernel:
         # what each traced block asked of _FirstHit, by block key, and
         # the key last asked for: rt.explain()'s `first_hit`
         self._first_hit_of: dict = {}
+        self._indexed_read_of: dict = {}
         self._last_key = None
 
     @property
@@ -820,6 +915,16 @@ class ParallelChainKernel:
         DENSE_MAX_F), and the (event, query) pairs the dense form reduces
         in one call over all lanes.  None until a block has been traced."""
         return self._first_hit_of.get(self._last_key)
+
+    @property
+    def indexed_read(self) -> Optional[dict]:
+        """{'dense': n, 'gather': m, 'identity': k, 'pairs_per_call': p,
+        'lanes': l, 'F': f}: the indexed reads of a lane's column in the
+        block last dispatched, by the form that answers them (_Read, the
+        same F and DENSE_MAX_F as `first_hit`), and the (event, asker)
+        pairs the dense form sums in one call over all lanes.  None until
+        a block has been traced."""
+        return self._indexed_read_of.get(self._last_key)
 
     # NFAKernel-compatible surface consumed by _call_block / bench
     def block_fn(self, T, M: int):
@@ -888,21 +993,22 @@ class ParallelChainKernel:
                 m = m & jnp.broadcast_to(ce.fn(env), m.shape)
         return m
 
-    def _gather_env(self, ev, idx_of: dict, keys, F: int, base_ts,
+    def _gather_env(self, ev, idx_of: dict, keys, read: "_Read", base_ts,
                     comp_j=None) -> dict:
-        """Capture env gathered at resolved indices: key "r.attr" (or
-        "r[i].attr") -> flat column at idx_of[refpart] (clipped; callers
-        mask validity downstream).  `keys` bounds the gathers to what's
-        read.  idx_of maps refpart -> index array (per-head or
-        per-match, caller's choice)."""
+        """Capture env read at resolved indices: key "r.attr" (or
+        "r[i].attr") -> flat column at idx_of[refpart] (clipped by `read`,
+        the block's _Read; callers mask validity downstream).  `keys`
+        bounds the reads to what's used.  idx_of maps refpart -> index
+        array (per-head or per-match, caller's choice)."""
         env = self._param_env(ev)
+        at: dict = {}       # id(idx) -> (idx, keys, columns): one shared read
         # sorted: `keys` is a set of strings, whose iteration order moves
         # with the process's hash seed — and with it the traced op order,
         # the HLO text and the persistent compile cache's key
         for k in sorted(keys):
             if k == "__timestamp__":
                 if comp_j is not None:
-                    env[k] = base_ts + ev["__flat.__ts__"][comp_j] \
+                    env[k] = base_ts + read(ev["__flat.__ts__"], comp_j) \
                         .astype(jnp.int64)
                 continue
             if "." not in k or k.startswith("__"):
@@ -919,7 +1025,11 @@ class ParallelChainKernel:
             col = ev.get(f"__flat.{scode}.{attr}")
             if col is None:
                 continue
-            env[k] = col[jnp.clip(idx, 0, F - 1)]
+            _idx, ks, cols = at.setdefault(id(idx), (idx, [], []))
+            ks.append(k)
+            cols.append(col)
+        for idx, ks, cols in at.values():
+            env.update(zip(ks, read.all(cols, idx)))
         return env
 
     # -- dfa family: bit-packed multi-stride static tables ----------------
@@ -973,18 +1083,19 @@ class ParallelChainKernel:
             nblk = {}
         return suffix, packed, nblk, NB
 
-    def _dfa_next(self, k: int, s, suffix, packed, nblk, NB: int, L: int):
+    def _dfa_next(self, k: int, s, suffix, packed, nblk, NB: int, L: int,
+                  read: "_Read"):
         """Multi-stride lookup: in-block suffix table, then the packed
         block-transition word of the next block containing a hit."""
         B = STRIDE
         Fp = NB * B
         sc = jnp.clip(s, 0, Fp - 1)
-        inb = suffix[k][sc]                      # first o >= s%B in block
+        inb = read(suffix[k], sc)                # first o >= s%B in block
         b = sc >> 2
         j_in = (b << 2) + inb
-        b2 = nblk[k][jnp.clip(b + 1, 0, NB - 1)]
+        b2 = read(nblk[k], b + 1)
         ok2 = (b + 1 < NB) & (b2 < NB)
-        f2 = ((packed[jnp.clip(b2, 0, NB - 1)]
+        f2 = ((read(packed, b2)
                >> (jnp.uint32(_OFF_BITS * k))) & jnp.uint32(7)).astype(_I32)
         j_blk = (b2 << 2) + f2
         j = jnp.where(inb < B, j_in, jnp.where(ok2, j_blk, jnp.int32(L)))
@@ -1029,17 +1140,19 @@ class ParallelChainKernel:
             with scope("next_hit"):
                 if chase and (pi, ni) in lane_of:
                     return self._dfa_next(lane_of[(pi, ni)], s, suffix,
-                                          packed, nblk, NB, L)
+                                          packed, nblk, NB, L, read)
                 key = (pi, ni)
                 if key not in scan_next:
                     scan_next[key] = _next_static_scan(nmask[key], L)
                 nx = scan_next[key]
-                return jnp.where(s < F, nx[jnp.clip(s, 0, F - 1)],
-                                 jnp.int32(L))
+                return jnp.where(s < F, read(nx, s), jnp.int32(L))
 
         # every "first event at or after s that ..." below is one entry,
-        # dense or tree by the block's F (DENSE_MAX_F)
+        # dense or tree by the block's F (DENSE_MAX_F); every "the column's
+        # element at idx" is the other, dense or gather by the same rule
         first_hit = _FirstHit(F, L)
+        j0 = jnp.arange(F, dtype=_I32)
+        read = _Read(F, j0)
 
         # occurrence ranks per count position: the inclusive cumulative
         # match count — "the r-th occurrence after entry" is ONE monotone
@@ -1090,14 +1203,13 @@ class ParallelChainKernel:
                 th = hop.threshold
                 own = ev[f"__flat.{hop.scode}."
                          f"{th.own_key.split('.', 1)[1]}"]
-                env = self._gather_env(ev, idx_of, th.rhs.reads, F,
+                env = self._gather_env(ev, idx_of, th.rhs.reads, read,
                                        base_ts)
                 v = jnp.broadcast_to(th.rhs.fn(env), (F,))
                 return first_hit(own, nmask[self.prog.ref_of[hop.ref]],
                                  s, v, th.op)
 
         # ---- the state chase: every event index is a candidate head ----
-        j0 = jnp.arange(F, dtype=_I32)
         head = prog.positions[0]
         ok = nmask[(0, 0)]
         dead = jnp.zeros((F,), bool)    # definitive failure (single-arm)
@@ -1117,7 +1229,7 @@ class ParallelChainKernel:
             # the arming event IS occurrence 1 (host _alloc_head): the
             # rank base excludes it, the select starts AT the head
             with scope("hop0"):
-                ra = ranks[0][j0] - 1
+                ra = read(ranks[0], j0) - 1
                 count_ctx[0] = (j0, ra)
                 jmin = select(0, j0, ra + jnp.int32(head.min_count))
                 kl = killer(0, j0 + 1)
@@ -1141,23 +1253,25 @@ class ParallelChainKernel:
                     if prog.sequence:
                         # strict succession: the hop consumes EXACTLY the
                         # next valid event — mask/filter/expiry all resolve
-                        # by direct gather at s
-                        sc = jnp.clip(s, 0, F - 1)
-                        m = nmask[(pi, 0)][sc]
+                        # by a direct read at s (the int32 ts, widened
+                        # after: an int64 column would take the gather)
+                        own = {f"{hop.ref}.{a.name}":
+                               ev[f"__flat.{hop.scode}.{a.name}"]
+                               for a in prog.schemas[hop.ref].attributes
+                               if hop.step_conjs
+                               and f"__flat.{hop.scode}.{a.name}" in ev}
+                        m, ts_s, *at_s = read.all(
+                            [nmask[(pi, 0)], ts, *own.values()], s)
+                        ts_s = ts_s.astype(jnp.int64)
                         if hop.step_conjs:
                             senv = self._gather_env(ev, idx_of, set().union(
-                                *[ce.reads for ce in hop.step_conjs]), F,
+                                *[ce.reads for ce in hop.step_conjs]), read,
                                 base_ts)
-                            for a in prog.schemas[hop.ref].attributes:
-                                col = ev.get(f"__flat.{hop.scode}.{a.name}")
-                                if col is not None:
-                                    senv[f"{hop.ref}.{a.name}"] = col[sc]
-                            senv["__timestamp__"] = base_ts \
-                                + ts64[sc]
+                            senv.update(zip(own, at_s))
+                            senv["__timestamp__"] = base_ts + ts_s
                             for ce in hop.step_conjs:
                                 m = m & jnp.broadcast_to(ce.fn(senv), m.shape)
-                        expired = ts64[sc] > ts64[j0] \
-                            + jnp.int64(pos.within_ms)
+                        expired = ts_s > ts64 + jnp.int64(pos.within_ms)
                         have = s < nev
                         jn = jnp.where(have & m & ~expired, s, jnp.int32(L))
                         dead = dead | (ok & have & (expired | ~m))
@@ -1197,12 +1311,13 @@ class ParallelChainKernel:
                             # emitted value is the LAST side match at or
                             # before the done event
                             pv = _prev_static_scan(nmask[(pi, ni)])
-                            idx_of[n.ref] = jnp.clip(pv[jdc], 0, F - 1)
+                            idx_of[n.ref] = jnp.clip(read(pv, jdc), 0, F - 1)
                             pres_of[n.ref] = jnp.ones((F,), bool)
                     j = jdc
                 else:                       # count (non-head entry)
                     entry = j
-                    ra = ranks[pi][entry]   # entry event is NOT an occurrence
+                    # the entry event is NOT an occurrence
+                    ra = read(ranks[pi], entry)
                     count_ctx[pi] = (entry + 1, ra)
                     if pi < S - 1:
                         jmin = select(pi, entry + 1,
@@ -1236,7 +1351,7 @@ class ParallelChainKernel:
 
             # dedup: completions at or before the previous flush's last seq
             # are tail replays — suppressed on device, per lane
-            lv_all = lv_all & (seq[comp_all] > prev_seq.astype(_I32))
+            lv_all = lv_all & (read(seq, comp_all) > prev_seq.astype(_I32))
 
             arm_flag = jnp.int32(0)
             if prog.single_arm:
@@ -1278,17 +1393,19 @@ class ParallelChainKernel:
             comp_m = compact(comp_all)
 
         with scope("capture"):
-            # per-match capture indices: single/logical refs gather their
+            # per-match capture indices: single/logical refs read their
             # per-head chase results; count refs rank/select at the match's
             # completion index (collection is station-independent in the
             # sequential kernel — occurrences keep absorbing until max or
             # the park freeze at completion)
-            midx: dict = {}
-            mpres: dict = {}
-            for rp, arr in idx_of.items():
-                midx[rp] = arr[hm_] if arr is not j0 else hm_
-            for rp, arr in pres_of.items():
-                mpres[rp] = arr[hm_]
+            # everything a match row takes at its head index is one read
+            heads = {("idx", rp): arr for rp, arr in idx_of.items()
+                     if arr is not j0}
+            heads.update({("pres", rp): arr for rp, arr in pres_of.items()})
+            heads["seq"] = seq
+            at_head = dict(zip(heads, read.all(list(heads.values()), hm_)))
+            midx = {rp: at_head.get(("idx", rp), hm_) for rp in idx_of}
+            mpres = {rp: at_head[("pres", rp)] for rp in pres_of}
 
             need = set()
             for ce in list(nfak.sel_fns.values()) \
@@ -1312,12 +1429,14 @@ class ParallelChainKernel:
                 if not rps:
                     continue
                 s_occ, ra = count_ctx[pi]
-                s_m = s_occ[hm_] if s_occ.ndim else s_occ
-                ra_m = ra[hm_]
+                if s_occ is j0:
+                    s_m, ra_m = hm_, read(ra, hm_)
+                else:
+                    s_m, ra_m = read.all([s_occ, ra], hm_)
                 if pi == S - 1:
                     q_m = jnp.int32(pos.min_count) + cm_
                 else:
-                    avail = ranks[pi][comp_m] - ra_m
+                    avail = read(ranks[pi], comp_m) - ra_m
                     q_m = jnp.minimum(avail, jnp.int32(pos.max_count)) \
                         if pos.max_count < UNBOUNDED else avail
 
@@ -1341,7 +1460,8 @@ class ParallelChainKernel:
                         mpres[rp] = q_m >= want
 
         with scope("select"):
-            env = self._gather_env(ev, midx, need, F, base_ts, comp_j=comp_m)
+            env = self._gather_env(ev, midx, need, read, base_ts,
+                                   comp_j=comp_m)
             sel = {name: jnp.broadcast_to(ce.fn(env), (M,))
                    for name, ce in nfak.sel_fns.items()}
             mvalid = jnp.arange(1, M + 1, dtype=_I32) <= n
@@ -1349,9 +1469,8 @@ class ParallelChainKernel:
                 henv = dict(env)
                 henv.update(sel)
                 mvalid = mvalid & jnp.broadcast_to(nfak.having.fn(henv), (M,))
-            sel["__timestamp__"] = ts[comp_m]
-            sel["__seq__"] = seq[comp_m]
-            sel["__head_seq__"] = seq[hm_]
+            sel["__timestamp__"], sel["__seq__"] = read.all([ts, seq], comp_m)
+            sel["__head_seq__"] = at_head["seq"]
             if nfak.emit_qid:
                 qid = ev.get("__lane_qid__", jnp.int32(0))
                 sel["__qid__"] = jnp.broadcast_to(qid.astype(_I32), (M,))
@@ -1386,6 +1505,7 @@ class ParallelChainKernel:
             out = {"i": jnp.stack(irows, axis=0)}
             if frows:
                 out["f"] = jnp.stack(frows, axis=0)
-        self._first_hit_of[(T, M)] = first_hit.asked(
-            T[0] if isinstance(T, tuple) else 1)
+        lanes = T[0] if isinstance(T, tuple) else 1
+        self._first_hit_of[(T, M)] = first_hit.asked(lanes)
+        self._indexed_read_of[(T, M)] = read.asked(lanes)
         return out

@@ -741,6 +741,15 @@ class DevicePatternPlan(QueryPlan):
         return dict(asked) if asked else None
 
     @property
+    def indexed_read(self) -> Optional[dict]:
+        """The indexed reads of a lane's column in the parallel block last
+        dispatched, by form (ParallelChainKernel.indexed_read; EXPLAIN)."""
+        if self.family not in ("scan", "dfa"):
+            return None
+        asked = self._parallel_kernel().indexed_read
+        return dict(asked) if asked else None
+
+    @property
     def lane_pack_order(self) -> Optional[dict]:
         """Flushes by the way the host pack ordered them (EXPLAIN): lane
         order by one `radix` pass or by the two-key `lexsort`; key -> lane

@@ -216,6 +216,9 @@ def _query_entry(rt, plan) -> Optional[dict]:
         lane_cut = getattr(plan, "lane_cut", None)
         if lane_cut:
             ent["lane_cut"] = lane_cut
+        indexed_read = getattr(plan, "indexed_read", None)
+        if indexed_read:
+            ent["indexed_read"] = indexed_read
         families = getattr(plan, "families", None)
         if families:
             rejected = {f: r for f, r in sorted(families.items())

@@ -1,7 +1,9 @@
 """The lane block's one question, "the first index i >= s[h] with keep[i]
 and vals[i] OP v[h]; L if none", in its two forms: the dense all-pairs
 masked min (short lanes) and the segment tree (long ones), each against a
-plain numpy loop; then the whole block on both sides of the rule that
+plain numpy loop; its other question, "the column's element at idx[m]", as
+one fused one-hot sum against numpy's own indexing, bit for bit; then the
+whole block on both sides of the rule that
 picks the form (nfa_parallel.DENSE_MAX_F), forced by SHAPE: the same
 recorded block input padded past the bound must give the same bytes, and
 flushes longer than the bound must still equal the sequential kernel."""
@@ -185,6 +187,119 @@ def test_int64_rhs_below_every_int32_saturates_exactly(op, want):
 
 
 # ---------------------------------------------------------------------------
+# the indexed read: a one-hot sum over the lane, not a gather
+# ---------------------------------------------------------------------------
+
+def _read_column(kind, rng, shape):
+    """A column whose every special value is there to be read."""
+    if kind == "f32":
+        pool = np.array([0x7FC00000, 0xFFC12345,      # NaN of two payloads
+                         0x80000000, 0x00000000,      # -0.0, 0.0
+                         0x7F800000, 0xFF800000,      # +inf, -inf
+                         0x00000001, 0x80012345,      # denormals
+                         0x3FC00000, 0xC2F70000], np.uint32).view(np.float32)
+    elif kind == "i32":
+        pool = np.array([I32_MIN, I32_MAX, 0, -1, 1, I32_MIN + 1, 12345],
+                        np.int32)
+    else:
+        pool = np.array([True, False])
+    return pool[rng.integers(0, len(pool), shape)]
+
+
+def _read_index(kind, rng, shape):
+    n = shape[-1]
+    return {"in_range": lambda: rng.integers(0, F, shape),
+            "repeated": lambda: np.full(shape, F - 1) * (np.arange(n) % 3 > 0),
+            "negative": lambda: rng.integers(-9, 3, shape),
+            "past_F": lambda: rng.integers(F - 3, F + 40, shape),
+            }[kind]().astype(np.int32)
+
+
+@pytest.mark.parametrize("lanes", [0, 5], ids=["flat", "lane_vmap"])
+@pytest.mark.parametrize("index", ["in_range", "repeated", "negative",
+                                   "past_F", "identity"])
+@pytest.mark.parametrize("kind", ["f32", "i32", "bool"])
+def test_dense_read_equals_the_gather_bit_for_bit(kind, index, lanes):
+    """`_Read` on a short lane: col[clip(idx, 0, F - 1)] as numpy reads it,
+    byte for byte (no float is compared or added: NaN payloads, -0.0 and
+    denormals pass through), for M askers that are not F; the block's own
+    arange is answered by the column itself and asks nothing."""
+    rng = np.random.default_rng(zlib.crc32(f"{kind}-{index}-{lanes}".encode()))
+    lead = (lanes,) if lanes else ()
+    M = F if index == "identity" else 53
+    col = _read_column(kind, rng, lead + (F,))
+    idx = None if index == "identity" else _read_index(index, rng, lead + (M,))
+    asked = []
+
+    def lane(col, idx):
+        j0 = jnp.arange(F, dtype=jnp.int32)
+        read = npar._Read(F, j0)
+        out = read(col, j0 if idx is None else idx)
+        if idx is None:
+            assert out is col
+        asked.append(read.asked(max(lanes, 1)))
+        return out
+
+    got = np.asarray((jax.vmap(lane, in_axes=(0, None if idx is None else 0))
+                      if lanes else lane)(jnp.asarray(col), idx))
+    want = col if idx is None else np.take_along_axis(
+        col, np.clip(idx, 0, F - 1), axis=-1)
+    assert got.dtype == col.dtype and got.shape == want.shape
+    assert got.tobytes() == want.tobytes()
+    dense = 0 if idx is None else 1
+    assert asked == [{"dense": dense, "gather": 0, "identity": 1 - dense,
+                      "pairs_per_call": dense * max(lanes, 1) * F * M,
+                      "lanes": max(lanes, 1), "F": F}]
+
+
+@pytest.mark.parametrize("lanes", [0, 3], ids=["flat", "lane_vmap"])
+def test_columns_read_at_one_index_share_one_reduction(lanes):
+    """`_Read.all`: columns of every narrow dtype at ONE index array come
+    back as each would alone (one variadic sum: the lowered text carries
+    one `reduce` and no gather); a wide one among them takes the gather."""
+    rng = np.random.default_rng(23 + lanes)
+    lead = (lanes,) if lanes else ()
+    kinds = ["f32", "i32", "bool", "f32"]
+    cols = [_read_column(k, rng, lead + (F,)) for k in kinds]
+    wide = rng.integers(-2 ** 40, 2 ** 40, lead + (F,))
+    idx = _read_index("past_F", rng, lead + (41,))
+    asked = []
+
+    def lane(idx, wide, *cols):
+        read = npar._Read(F, jnp.arange(F, dtype=jnp.int32))
+        out = read.all([*cols, wide], idx)
+        asked.append(read.asked(1))
+        return out
+
+    fn = jax.jit(jax.vmap(lane) if lanes else lane)
+    got = fn(idx, wide, *cols)
+    for g, c in zip(got, [*cols, wide]):
+        want = np.take_along_axis(c, np.clip(idx, 0, F - 1), axis=-1)
+        assert np.asarray(g).dtype == c.dtype
+        assert np.asarray(g).tobytes() == want.tobytes()
+    assert asked == [{"dense": 4, "gather": 1, "identity": 0,
+                      "pairs_per_call": 4 * F * 41, "lanes": 1, "F": F}]
+    txt = fn.lower(idx, wide, *cols).as_text()
+    assert txt.count("stablehlo.reduce") == 1, txt.count("stablehlo.reduce")
+    assert txt.count('"stablehlo.gather"(') == 1
+
+
+@pytest.mark.parametrize("F_,dtype", [(F, np.int64), (F, np.float64),
+                                      (npar.DENSE_MAX_F + 1, np.float32)])
+def test_wide_columns_and_long_lanes_keep_the_gather(F_, dtype):
+    """A column wider than four bytes has no int32 image, and past
+    DENSE_MAX_F the pairs cost more than the gather: both read as before."""
+    rng = np.random.default_rng(11)
+    col = rng.integers(-2 ** 40, 2 ** 40, F_).astype(dtype)
+    idx = rng.integers(-5, F_ + 5, 29).astype(np.int32)
+    read = npar._Read(F_, jnp.arange(F_, dtype=jnp.int32))
+    got = np.asarray(read(jnp.asarray(col), jnp.asarray(idx)))
+    assert got.tobytes() == col[np.clip(idx, 0, F_ - 1)].tobytes()
+    assert read.asked(1) == {"dense": 0, "gather": 1, "identity": 0,
+                             "pairs_per_call": 0, "lanes": 1, "F": F_}
+
+
+# ---------------------------------------------------------------------------
 # the rule, and the block on both sides of it
 # ---------------------------------------------------------------------------
 
@@ -192,15 +307,17 @@ def test_int64_rhs_below_every_int32_saturates_exactly(op, want):
     (1024, 448, True),            # pattern1k.sat; a quarter of it per mesh chip
     (256, 448, True),
     (1024, 64, True),             # pattern1k.wire-paced
+    (2048, 2048, True),           # pattern1k-zipf.sat: hot lanes cut at 2048
     (1, npar.DENSE_MAX_F, True),  # the boundary itself
     (1, npar.DENSE_MAX_F + 1, False),
     (1, 2 ** 18, False),          # the flat P = 1 block of a 2^18-event batch
     (1000, 2 ** 16, False),       # fused multi-query lanes see the whole stream
 ])
 def test_rule_reads_the_blocks_static_shape(lanes, F_, dense):
-    """The form is a function of F alone, and the counter that says which
-    engaged is what tracing the block at that shape records (no run: the
-    block is only traced here, as `jax.eval_shape` does)."""
+    """The form is a function of F alone, for the first-hit queries and
+    for the indexed reads alike, and the counters that say which engaged
+    are what tracing the block at that shape records (no run: the block is
+    only traced here, as `jax.eval_shape` does)."""
     kern = pf._c4_kernel()
     T = (lanes, F_) if lanes > 1 else F_
     shape = (lanes, F_) if lanes > 1 else (F_,)
@@ -212,12 +329,19 @@ def test_rule_reads_the_blocks_static_shape(lanes, F_, dense):
           "__prev_seq__": jax.ShapeDtypeStruct(lead, jnp.int32),
           "__base_ts__": jax.ShapeDtypeStruct((), jnp.int64),
           "__base_seq__": jax.ShapeDtypeStruct((), jnp.int64)}
-    assert kern.first_hit is None
+    assert kern.first_hit is None and kern.indexed_read is None
     jax.eval_shape(kern.block_fn(T, F_), {}, ev)
     # C4: one expiry query (shared down the chain) + two threshold hops
     want = {"dense": 3, "tree": 0, "pairs_per_call": 3 * lanes * F_ * F_} \
         if dense else {"dense": 0, "tree": 3, "pairs_per_call": 0}
     assert kern.first_hit == {**want, "lanes": lanes, "F": F_}
+    # C4: e2's price at hop 2, the dedup's seq, two capture indices, three
+    # selected prices and the three stamps of a match row; e1's price at
+    # hop 1 is read at the block's own arange
+    want = {"dense": 10, "gather": 0, "pairs_per_call": 10 * lanes * F_ * F_} \
+        if dense else {"dense": 0, "gather": 10, "pairs_per_call": 0}
+    assert kern.indexed_read == {**want, "identity": 1, "lanes": lanes,
+                                 "F": F_}
 
 
 def _resize_block(ev, T, F2, lanes=2):

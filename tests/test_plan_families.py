@@ -1007,6 +1007,36 @@ def test_c4_makes_one_expiry_query_and_shares_it():
     assert scopes(kern) == ["1", "2"]
 
 
+@pytest.mark.parametrize("F,gathers", [(64, False), (None, True)],
+                         ids=["short_lane", "past_DENSE_MAX_F"])
+def test_c4_lane_block_reads_a_short_lane_without_a_gather(F, gathers):
+    """The structure the one-hot read's gain rests on, checked without a
+    chip: the lowered C4 lane block of a short lane carries no `gather`
+    under the scopes that read a column by index (one hop, the dedup, the
+    capture indices, the selected values), and past DENSE_MAX_F it carries
+    them all."""
+    import re
+    from siddhi_tpu.core.nfa_parallel import DENSE_MAX_F
+    kern = _c4_kernel()
+    lanes, F = 8, F or DENSE_MAX_F + 1
+    ev = {"__flat.__ts__": np.zeros((lanes, F), np.int32),
+          "__flat.__seq__": np.zeros((lanes, F), np.int32),
+          "__flat.0.price": np.zeros((lanes, F), np.float32),
+          "__nev__": np.zeros((lanes,), np.int32),
+          "__prev_seq__": np.zeros((lanes,), np.int32),
+          "__base_ts__": np.int64(0), "__base_seq__": np.int64(0)}
+    txt = kern.block_fn((lanes, F), F).lower({}, ev).as_text(debug_info=True)
+    # loc("jit(lane_block)/vmap(hop2)/threshold_next/gather"): the scope
+    # path up to the op; a tree's own gathers sit under `heap` and the
+    # first-hit walk, which `[^"]*` would take for a read's
+    found = set(re.findall(
+        r'loc\("[^"]*?vmap\((select|capture|emit_candidates|hop\d+)\)'
+        r'(?:/threshold_next)?/gather"', txt))
+    assert found == ({"select", "capture", "emit_candidates", "hop2"}
+                     if gathers else set()), found
+    assert kern.indexed_read["gather"] == (10 if gathers else 0)
+
+
 def _prog(*positions, sequence=False):
     """Hand-built ParallelProgram: positions as (kind, within_ms)."""
     from siddhi_tpu.core.nfa_parallel import HopNode, PPos, ParallelProgram
