@@ -29,6 +29,11 @@ steps through events:
     rows.  The same rule and constant pick its form: a short lane is
     read by one fused one-hot sum over its (event, asker) pairs, bit
     for bit and with no gather; a long one by the gather;
+  * ONE compaction, "the candidates that matched, in order, as the M
+    match rows" (_Compact): a prefix count gives every live candidate
+    its row, and the same rule and constant pick how the rows are
+    filled: a short lane by one fused one-hot sum over its (candidate,
+    row) pairs, a long one by a scatter;
   * rank/select over occurrence-count prefix sums for `<m:n>` count
     quantifiers — "the min-th occurrence after entry" is one first-hit
     query on the monotone cumulative-count array (the bit-packed
@@ -729,6 +734,29 @@ def _first_hit_dense(vals, keep, L: int, s, v, op: str):
 # 10.25 ns / 2.22 ps is F = 4,600.  Nothing was measured between 2048 and
 # the bound: a flat F = 4096 block reads 9.1 ns an element at the slowest
 # rate, even with the gather.
+#
+# And it picks the form of the compaction (_Compact), again at the
+# crossing.  A scatter of lanes x F candidates into lanes x M rows costs
+# 4.4-4.7 ns a candidate a column (TPU v5e, PERF.md section 5, PR 38:
+# 2.12 ms at 1024 x 448, 84.97 at 72 x 250 x 1024 into M = 192), and once
+# its rows number 2^22 or more the compiler sorts the (row, value) pairs
+# first: 7.3 ns at 72 x 250 x 1024 into M = 256 or 384 (two scatters 89.8 ms
+# each and two `sort`s 44.7), 5.8 at 2048 x 2048 (20.45 and 3.79).  The
+# fused one-hot sum costs M pairs a candidate, both columns in one pass:
+# 0.92 ps a pair at 1024 x 448 x 448 (0.19 ms against 4.23), 1.5-2.9 at
+# 72 x 250 x 1024 x {192, 256, 384} (5.5 / 13.5 / 18.2 ms against 170 / 269 /
+# 269) and 2.2 at 2048 x 2048 x 2048 (19.0 against 48.5: the closest
+# reading).  Two columns by scatter are 9-15 ns a candidate, so the forms
+# cross at M of 4,000-6,500 at the slowest rate, and no cell sits between
+# F = 2048 and the bound.  The form is read off F alone though the cost is
+# C*F*M pairs: a first dispatch has M = F, or less from counts, but the
+# overflow's exact re-run (_materialize_par) raises M to the count's
+# bucket, up to C*F where a final position counts (C > 1) and 2*F for a
+# cut fused row.  At F near the bound that re-run is the accepted
+# exception: at F = 4096, C = 3, M = 12,288 the dense pass is ~0.33 ms a
+# lane against ~0.18 by scatter (computed from the rates above, not
+# measured), once, on a flush that already pays a second dispatch and a
+# compile; the rows are bit-equal either way.
 DENSE_MAX_F = 4096
 
 
@@ -774,24 +802,17 @@ class _FirstHit:
                 "lanes": lanes, "F": self.F}
 
 
-def _read_dense(cols: list, idx) -> list:
-    """[col[clip(idx, 0, len(col) - 1)] for col in cols] without a gather:
-    a masked sum over every (event, asker) pair of the lane,
-
-        out[m] = sum over i of (bits[i]  if  i == clip(idx[m])  else  0)
-
-    where `bits` is a column as int32: float32 and uint32 bitcast, bool
-    and the narrower ints widened.  Exactly one term of each sum is
-    non-zero, so the answer is the gathered element bit for bit (NaN
-    payloads, -0.0, infinities and denormals pass through as integers:
-    nothing is compared or added as a float).  Events lie along axis 0
-    and askers along axis 1, _first_hit_dense's layout; the compiler
-    fuses compare, select and sum and never holds the (F, M) pairs.
-    Columns asked at ONE index array are summed by one variadic reduce:
-    the compare is made once for all of them."""
-    n = cols[0].shape[0]
-    i = jnp.arange(n, dtype=_I32)[:, None]
-    hit = i == jnp.clip(idx.reshape(-1), 0, n - 1).astype(_I32)[None, :]
+def _one_hot_sum(cols: list, hit) -> list:
+    """[sum over i of (bits[i]  if  hit[i, m]  else  0) for each column],
+    where at most ONE i hits a given m and `bits` is the column as int32:
+    float32 and uint32 bitcast, bool and the narrower ints widened.  At
+    most one term of each sum is non-zero, so the answer is that element
+    bit for bit (NaN payloads, -0.0, infinities and denormals pass through
+    as integers: nothing is compared or added as a float), and 0 where no
+    i hits.  The summed axis is 0 and the answers lie along axis 1,
+    _first_hit_dense's layout; the compiler fuses compare, select and sum
+    and never holds the pairs.  The columns are summed by one variadic
+    reduce: `hit` is made once for all of them."""
     cast = [c.dtype in (jnp.float32, jnp.uint32) for c in cols]
     bits = [lax.bitcast_convert_type(c, _I32) if b else c.astype(_I32)
             for c, b in zip(cols, cast)]
@@ -799,9 +820,29 @@ def _read_dense(cols: list, idx) -> list:
         [jnp.where(hit, b[:, None], jnp.int32(0)) for b in bits],
         [jnp.int32(0)] * len(bits),
         lambda x, y: tuple(p + q for p, q in zip(x, y)), (0,))
-    sums = [o.reshape(idx.shape) for o in sums]
     return [lax.bitcast_convert_type(o, c.dtype) if b else o.astype(c.dtype)
             for o, c, b in zip(sums, cols, cast)]
+
+
+def _read_dense(cols: list, idx) -> list:
+    """[col[clip(idx, 0, len(col) - 1)] for col in cols] without a gather:
+    a one-hot sum (_one_hot_sum) over every (event, asker) pair of the
+    lane, hit[i, m] = i == clip(idx[m]): exactly one event a sum."""
+    n = cols[0].shape[0]
+    i = jnp.arange(n, dtype=_I32)[:, None]
+    hit = i == jnp.clip(idx.reshape(-1), 0, n - 1).astype(_I32)[None, :]
+    return [o.reshape(idx.shape) for o in _one_hot_sum(cols, hit)]
+
+
+def _compact_dense(cols: list, wpos, M: int) -> list:
+    """[zeros(M).at[wpos].set(col, mode="drop") for col in cols] without a
+    scatter, for a `wpos` that sends no two candidates to one row: a
+    one-hot sum (_one_hot_sum) over every (candidate, row) pair of the
+    lane, hit[i, m] = wpos[i] == m.  A row nobody writes sums nothing and
+    stays 0, as the scatter's zeros do; a candidate sent to M or past it
+    hits no row, as `mode="drop"` drops it."""
+    hit = wpos[:, None] == jnp.arange(M, dtype=_I32)[None, :]
+    return _one_hot_sum(cols, hit)
 
 
 class _Read:
@@ -846,6 +887,44 @@ class _Read:
                 "identity": self.identity,
                 "pairs_per_call": lanes * self.pairs,
                 "lanes": lanes, "F": self.F}
+
+
+class _Compact:
+    """One block's entry for "these columns of the live candidates, in
+    candidate order, as the M match rows": `live` over the C*F (slot,
+    head) candidates, a prefix count for the row each live one takes, and
+    the columns written there.  Rows past the live count are 0, live
+    candidates past M are dropped, and `n` counts every live one, so the
+    caller sees an overflow whole.  The form follows the block's F by
+    _FirstHit's rule and constant: a lane of at most DENSE_MAX_F events
+    fills the rows by one fused one-hot sum (_compact_dense), a longer one
+    by a scatter a column.  Counts what it was asked while the block is
+    traced: rt.explain()'s `compaction`."""
+
+    def __init__(self, F: int, M: int):
+        self.F, self.M = F, M
+        self.dense = self.scatter = 0
+        self.pairs = 0        # (candidate, row) pairs a lane, dense form
+
+    def __call__(self, cols: list, live):
+        """(the columns compacted, n).  Columns and `live` are (C*F,)."""
+        M = self.M
+        pos = jnp.cumsum(live.astype(_I32), dtype=_I32) - live
+        n = pos[-1] + live[-1]
+        wpos = jnp.where(live & (pos < M), pos, M)
+        if self.F <= DENSE_MAX_F:
+            self.dense += len(cols)
+            self.pairs += len(cols) * live.shape[0] * M
+            return _compact_dense(cols, wpos, M), n
+        self.scatter += len(cols)
+        return [jnp.zeros((M,), c.dtype).at[wpos].set(c, mode="drop")
+                for c in cols], n
+
+    def asked(self, lanes: int) -> dict:
+        """What one call of the traced block compacts, over all its lanes."""
+        return {"dense": self.dense, "scatter": self.scatter,
+                "pairs_per_call": lanes * self.pairs,
+                "lanes": lanes, "F": self.F, "M": self.M}
 
 
 def _next_static_scan(mask, L: int):
@@ -909,11 +988,14 @@ class ParallelChainKernel:
         # compiled plan, shown by rt.explain()
         self.expiry_queries = {"built": fresh,
                                "shared": len(self.expiry_plan) - fresh}
-        # what each traced block asked of _FirstHit, by block key, and
-        # the key last asked for: rt.explain()'s `first_hit`
-        self._first_hit_of: dict = {}
-        self._indexed_read_of: dict = {}
+        # what each traced block asked of _FirstHit, _Read and _Compact,
+        # by block key, and the key last asked for: rt.explain()'s
+        # `first_hit`, `indexed_read` and `compaction`
+        self._asked_of: dict = {}
         self._last_key = None
+
+    def _asked(self, entry: str) -> Optional[dict]:
+        return self._asked_of.get(self._last_key, {}).get(entry)
 
     @property
     def first_hit(self) -> Optional[dict]:
@@ -922,7 +1004,7 @@ class ParallelChainKernel:
         the form that answers them (one form a block, by its F:
         DENSE_MAX_F), and the (event, query) pairs the dense form reduces
         in one call over all lanes.  None until a block has been traced."""
-        return self._first_hit_of.get(self._last_key)
+        return self._asked("first_hit")
 
     @property
     def indexed_read(self) -> Optional[dict]:
@@ -932,7 +1014,17 @@ class ParallelChainKernel:
         same F and DENSE_MAX_F as `first_hit`), and the (event, asker)
         pairs the dense form sums in one call over all lanes.  None until
         a block has been traced."""
-        return self._indexed_read_of.get(self._last_key)
+        return self._asked("indexed_read")
+
+    @property
+    def compaction(self) -> Optional[dict]:
+        """{'dense': n, 'scatter': m, 'pairs_per_call': p, 'lanes': l,
+        'F': f, 'M': cap}: the columns the block last dispatched compacts
+        into its M match rows, by the form that fills the rows (_Compact,
+        the same F and DENSE_MAX_F as `first_hit`), and the (candidate,
+        row) pairs the dense form sums in one call over all lanes.  None
+        until a block has been traced."""
+        return self._asked("compaction")
 
     # NFAKernel-compatible surface consumed by _call_block / bench
     def block_fn(self, T, M: int):
@@ -1168,10 +1260,12 @@ class ParallelChainKernel:
 
         # every "first event at or after s that ..." below is one entry,
         # dense or tree by the block's F (DENSE_MAX_F); every "the column's
-        # element at idx" is the other, dense or gather by the same rule
+        # element at idx" is another, dense or gather by the same rule, and
+        # "the live candidates as the M match rows" the third
         first_hit = _FirstHit(F, L)
         j0 = jnp.arange(F, dtype=_I32)
         read = _Read(F, j0)
+        compact = _Compact(F, M)
 
         # occurrence ranks per count position: the inclusive cumulative
         # match count — "the r-th occurrence after entry" is ONE monotone
@@ -1396,20 +1490,14 @@ class ParallelChainKernel:
 
         with scope("compact"):
             # ---- compaction: (slot, head) candidates -> M match rows ------
-            lvf = lv_all.reshape(C * F)
-            pos_ = jnp.cumsum(lvf.astype(_I32), dtype=_I32) - lvf
-            n = pos_[-1] + lvf[-1]
-            wpos = jnp.where(lvf & (pos_ < M), pos_, M)
-
-            def compact(a):
-                return jnp.zeros((M,), a.dtype).at[wpos].set(
-                    a.reshape(C * F) if a.ndim == 2 else jnp.tile(a, C),
-                    mode="drop")
-
-            hm_ = compact(jnp.broadcast_to(j0[None, :], (C, F)))
-            cm_ = compact(jnp.broadcast_to(
-                jnp.arange(C, dtype=_I32)[:, None], (C, F)))
-            comp_m = compact(comp_all)
+            # a candidate's head, its completion and (where the final
+            # position counts: nothing else reads it) its slot
+            cols = [jnp.tile(j0, C), comp_all.reshape(C * F)]
+            if final_count:
+                cols.append(jnp.repeat(jnp.arange(C, dtype=_I32), F))
+            rows, n = compact(cols, lv_all.reshape(C * F))
+            hm_, comp_m = rows[:2]
+            cm_ = rows[2] if final_count else None
 
         with scope("capture"):
             # per-match capture indices: single/logical refs read their
@@ -1525,6 +1613,7 @@ class ParallelChainKernel:
             if frows:
                 out["f"] = jnp.stack(frows, axis=0)
         lanes = int(np.prod(T[:-1])) if isinstance(T, tuple) else 1
-        self._first_hit_of[(T, M)] = first_hit.asked(lanes)
-        self._indexed_read_of[(T, M)] = read.asked(lanes)
+        self._asked_of[(T, M)] = {"first_hit": first_hit.asked(lanes),
+                                  "indexed_read": read.asked(lanes),
+                                  "compaction": compact.asked(lanes)}
         return out

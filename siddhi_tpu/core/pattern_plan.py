@@ -785,24 +785,30 @@ class DevicePatternPlan(QueryPlan):
             return None
         return dict(self._parallel_kernel().expiry_queries)
 
-    @property
-    def first_hit(self) -> Optional[dict]:
-        """The first-hit queries of the parallel block last dispatched, by
-        the form that answers them (ParallelChainKernel.first_hit;
-        EXPLAIN)."""
+    def _block_form(self, entry: str) -> Optional[dict]:
+        """What the parallel block last dispatched asked of one of its
+        three questions, by the form that answers it
+        (ParallelChainKernel.first_hit / indexed_read / compaction)."""
         if self.family not in ("scan", "dfa"):
             return None
-        asked = self._parallel_kernel().first_hit
+        asked = getattr(self._parallel_kernel(), entry)
         return dict(asked) if asked else None
 
     @property
+    def first_hit(self) -> Optional[dict]:
+        """The block's first-hit queries, by form (EXPLAIN)."""
+        return self._block_form("first_hit")
+
+    @property
     def indexed_read(self) -> Optional[dict]:
-        """The indexed reads of a lane's column in the parallel block last
-        dispatched, by form (ParallelChainKernel.indexed_read; EXPLAIN)."""
-        if self.family not in ("scan", "dfa"):
-            return None
-        asked = self._parallel_kernel().indexed_read
-        return dict(asked) if asked else None
+        """The block's indexed reads of a lane's column, by form (EXPLAIN)."""
+        return self._block_form("indexed_read")
+
+    @property
+    def compaction(self) -> Optional[dict]:
+        """The columns the block compacts into its match rows, by form
+        (EXPLAIN)."""
+        return self._block_form("compaction")
 
     @property
     def lane_pack_order(self) -> Optional[dict]:
@@ -830,7 +836,7 @@ class DevicePatternPlan(QueryPlan):
     def fused(self) -> Optional[dict]:
         """What a fused (broadcast) plan ran (EXPLAIN `fused`, beside the
         rule count its MultiQueryDevicePatternPlan adds): the family, the
-        block's `first_hit` and `indexed_read` forms, `lane_cut`
+        block's `first_hit`, `indexed_read` and `compaction` forms, `lane_cut`
         {flushes_cut, rows, events_replayed: the cut of the shared
         stream into rows so far; cut_length: the row length in use, 0
         before a cut; flushes_uncuttable: flushes past a row that kept
@@ -843,6 +849,7 @@ class DevicePatternPlan(QueryPlan):
             else int(self._arm_done.sum())
         return {"family": self.family, "first_hit": self.first_hit,
                 "indexed_read": self.indexed_read,
+                "compaction": self.compaction,
                 "lane_cut": {**self._fused_cut_did,
                              "cut_length": self._fused_C},
                 "arms_resolved": arms,

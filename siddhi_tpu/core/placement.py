@@ -204,21 +204,11 @@ def _query_entry(rt, plan) -> Optional[dict]:
     fam = getattr(plan, "family", None)
     if kind == "pattern" and fam is not None:
         ent["family"] = fam
-        expiry = getattr(plan, "expiry_queries", None)
-        if expiry:
-            ent["expiry_queries"] = expiry
-        first_hit = getattr(plan, "first_hit", None)
-        if first_hit:
-            ent["first_hit"] = first_hit
-        lane_pack_order = getattr(plan, "lane_pack_order", None)
-        if lane_pack_order:
-            ent["lane_pack_order"] = lane_pack_order
-        lane_cut = getattr(plan, "lane_cut", None)
-        if lane_cut:
-            ent["lane_cut"] = lane_cut
-        indexed_read = getattr(plan, "indexed_read", None)
-        if indexed_read:
-            ent["indexed_read"] = indexed_read
+        for key in ("expiry_queries", "first_hit", "lane_pack_order",
+                    "lane_cut", "indexed_read", "compaction"):
+            rec = getattr(plan, key, None)
+            if rec:
+                ent[key] = rec
         families = getattr(plan, "families", None)
         if families:
             rejected = {f: r for f, r in sorted(families.items())

@@ -2,7 +2,9 @@
 and vals[i] OP v[h]; L if none", in its two forms: the dense all-pairs
 masked min (short lanes) and the segment tree (long ones), each against a
 plain numpy loop; its other question, "the column's element at idx[m]", as
-one fused one-hot sum against numpy's own indexing, bit for bit; then the
+one fused one-hot sum against numpy's own indexing, bit for bit; its
+third, "the live candidates' columns as the M match rows", as one fused
+one-hot sum against the scatter and a numpy loop; then the
 whole block on both sides of the rule that
 picks the form (nfa_parallel.DENSE_MAX_F), forced by SHAPE: the same
 recorded block input padded past the bound must give the same bytes, and
@@ -17,10 +19,11 @@ import jax
 import jax.numpy as jnp
 
 from siddhi_tpu import SiddhiManager
-from siddhi_tpu.core import nfa_parallel as npar
+from siddhi_tpu.core import nfa_parallel as npar, pattern_plan
 from siddhi_tpu.core.nfa_device import LOCAL_SPAN, pow2_at_least
 from siddhi_tpu.core.pattern_plan import DevicePatternPlan
 
+import test_fused_cut as fc
 import test_plan_families as pf
 
 OPS = {"gt": np.greater, "ge": np.greater_equal,
@@ -300,6 +303,110 @@ def test_wide_columns_and_long_lanes_keep_the_gather(F_, dtype):
 
 
 # ---------------------------------------------------------------------------
+# the compaction: a one-hot sum over the lane, not a scatter
+# ---------------------------------------------------------------------------
+
+def _compact_loop(cols, live, M):
+    """The rows, one candidate at a time: the first M live ones in order."""
+    out = [np.zeros(M, c.dtype) for c in cols]
+    n = 0
+    for i in np.flatnonzero(live):
+        if n < M:
+            for o, c in zip(out, cols):
+                o[n] = c[i]
+        n += 1
+    return out, n
+
+
+_COMPACT = npar._Compact      # the class itself: a test below patches the name
+
+
+def _scatter_form(F_, M):
+    """`_Compact` as a lane past DENSE_MAX_F takes it."""
+    return _COMPACT(npar.DENSE_MAX_F + 1, M)
+
+
+LIVE = {"empty": lambda rng, n: np.zeros(n, bool),
+        "all": lambda rng, n: np.ones(n, bool),
+        "random": lambda rng, n: rng.random(n) < 0.4,
+        "one_at_the_end": lambda rng, n: np.arange(n) == n - 1}
+
+
+@pytest.mark.parametrize("nest", [(), (5,), (3, 4)],
+                         ids=["flat", "lane_vmap", "row_lane_vmap"])
+@pytest.mark.parametrize("C", [1, 3], ids=["C1", "final_count_C3"])
+@pytest.mark.parametrize("M", [5, F, 128], ids=["M_lt_F", "M_eq_F", "M_gt_CF"])
+@pytest.mark.parametrize("live", list(LIVE))
+def test_dense_compaction_equals_the_scatter_bit_for_bit(live, M, C, nest):
+    """`_Compact` on a short lane against the scatter it replaces and a
+    numpy loop, byte for byte: the block's columns (head, completion, and
+    the slot when the final position counts) of C * F candidates, F = 37
+    (no multiple of 128); more live candidates than M keep the first M in
+    order and `n` counts them all; rows past the live count are 0; flat,
+    under one vmap and under `_make_lane_block`'s two (rows x lanes)."""
+    rng = np.random.default_rng(zlib.crc32(f"{live}-{M}-{C}-{nest}".encode()))
+    n_c = C * F
+    mask = np.stack([LIVE[live](rng, n_c) for _ in range(int(np.prod(nest)))]
+                    ).reshape(nest + (n_c,)) if nest else LIVE[live](rng, n_c)
+    comp = _read_column("i32", rng, nest + (n_c,))
+    asked = []
+
+    def lane(form, comp, mask):
+        compact = form(F, M)
+        cols = [jnp.tile(jnp.arange(F, dtype=jnp.int32), C), comp]
+        if C > 1:
+            cols.append(jnp.repeat(jnp.arange(C, dtype=jnp.int32), F))
+        out, n = compact(cols, mask)
+        asked.append(compact.asked(int(np.prod(nest))))
+        return tuple(out), n
+
+    got = {}
+    for name, form in (("dense", npar._Compact), ("scatter", _scatter_form)):
+        fn = lambda comp, mask, form=form: lane(form, comp, mask)  # noqa: E731
+        for _ in nest:
+            fn = jax.vmap(fn)
+        got[name] = jax.tree_util.tree_map(np.asarray, jax.jit(fn)(comp, mask))
+    cols_np = [np.tile(np.arange(F, dtype=np.int32), C), None] \
+        + ([np.repeat(np.arange(C, dtype=np.int32), F)] if C > 1 else [])
+    for at in np.ndindex(*nest):
+        cols_np[1] = comp[at]
+        want, n = _compact_loop(cols_np, mask[at], M)
+        for name, (out, n_got) in got.items():
+            assert int(n_got[at]) == n, (name, at)
+            for o, w in zip(out, want):
+                assert o[at].dtype == w.dtype
+                assert o[at].tobytes() == w.tobytes(), (name, at)
+    if live == "all":
+        assert n > min(M, n_c) or M >= n_c       # the overflow case is there
+    ncols, lanes = 2 + (C > 1), int(np.prod(nest))
+    assert asked == [
+        {"dense": ncols, "scatter": 0, "lanes": lanes, "F": F, "M": M,
+         "pairs_per_call": lanes * ncols * n_c * M},
+        {"dense": 0, "scatter": ncols, "lanes": lanes, "M": M,
+         "F": npar.DENSE_MAX_F + 1, "pairs_per_call": 0}]
+
+
+@pytest.mark.parametrize("F_,scatters", [(F, 0), (200, 0),
+                                         (npar.DENSE_MAX_F, 0),
+                                         (npar.DENSE_MAX_F + 1, 3)])
+def test_columns_compacted_at_one_prefix_share_one_reduction(F_, scatters):
+    """The form is read off F alone; the dense form's lowered text carries
+    ONE variadic `reduce` for all the columns and no scatter, the other a
+    scatter a column (only traced: a lane at the bound holds F * M pairs
+    on a backend that does not fuse them)."""
+    def lane(comp, mask):
+        compact = npar._Compact(F_, 64)
+        j0 = jnp.arange(F_, dtype=jnp.int32)
+        return compact([j0, comp, j0 // 2], mask)
+
+    txt = jax.jit(lane).lower(
+        jax.ShapeDtypeStruct((F_,), jnp.int32),
+        jax.ShapeDtypeStruct((F_,), jnp.bool_)).as_text()
+    assert txt.count('"stablehlo.scatter"(') == scatters
+    assert txt.count("stablehlo.reduce(") == (0 if scatters else 1)
+
+
+# ---------------------------------------------------------------------------
 # the rule, and the block on both sides of it
 # ---------------------------------------------------------------------------
 
@@ -314,8 +421,8 @@ def test_wide_columns_and_long_lanes_keep_the_gather(F_, dtype):
     (1000, 2 ** 16, False),       # fused multi-query lanes see the whole stream
 ])
 def test_rule_reads_the_blocks_static_shape(lanes, F_, dense):
-    """The form is a function of F alone, for the first-hit queries and
-    for the indexed reads alike, and the counters that say which engaged
+    """The form is a function of F alone, for the first-hit queries, the
+    indexed reads and the compaction alike, and the counters that say which engaged
     are what tracing the block at that shape records (no run: the block is
     only traced here, as `jax.eval_shape` does)."""
     kern = pf._c4_kernel()
@@ -329,7 +436,8 @@ def test_rule_reads_the_blocks_static_shape(lanes, F_, dense):
           "__prev_seq__": jax.ShapeDtypeStruct(lead, jnp.int32),
           "__base_ts__": jax.ShapeDtypeStruct((), jnp.int64),
           "__base_seq__": jax.ShapeDtypeStruct((), jnp.int64)}
-    assert kern.first_hit is None and kern.indexed_read is None
+    assert kern.first_hit is None and kern.indexed_read is None \
+        and kern.compaction is None
     jax.eval_shape(kern.block_fn(T, F_), {}, ev)
     # C4: one expiry query (shared down the chain) + two threshold hops
     want = {"dense": 3, "tree": 0, "pairs_per_call": 3 * lanes * F_ * F_} \
@@ -342,6 +450,10 @@ def test_rule_reads_the_blocks_static_shape(lanes, F_, dense):
         if dense else {"dense": 0, "gather": 10, "pairs_per_call": 0}
     assert kern.indexed_read == {**want, "identity": 1, "lanes": lanes,
                                  "F": F_}
+    # C4: a match row's head and its completion, at one prefix count
+    want = {"dense": 2, "scatter": 0, "pairs_per_call": 2 * lanes * F_ * F_} \
+        if dense else {"dense": 0, "scatter": 2, "pairs_per_call": 0}
+    assert kern.compaction == {**want, "lanes": lanes, "F": F_, "M": F_}
 
 
 def _resize_block(ev, T, F2, lanes=2):
@@ -424,11 +536,15 @@ def test_block_bytes_equal_across_the_rule(name, fam):
     ev1, T1 = _resize_block(ev, T, F1)
     want = fresh.block_fn(T1, M)({}, ev1)[1]
     assert fresh.first_hit["tree"] == 0 and fresh.first_hit["dense"] > 0
+    ncols = 3 if name.startswith("final_count") else 2
+    assert fresh.compaction["dense"] == ncols
     for F2, tree in ((npar.DENSE_MAX_F, False), (npar.DENSE_MAX_F + 1, True)):
         ev2, T2 = _resize_block(ev, T, F2)
         got = fresh.block_fn(T2, M)({}, ev2)[1]
         assert (fresh.first_hit["dense"] == 0) == tree, fresh.first_hit
         assert (fresh.first_hit["tree"] > 0) == tree, fresh.first_hit
+        assert fresh.compaction["scatter"] == (ncols if tree else 0)
+        assert fresh.compaction["dense"] == (0 if tree else ncols)
         assert sorted(got) == sorted(want)
         for k in want:
             assert np.asarray(got[k]).tobytes() \
@@ -517,3 +633,49 @@ def test_zero_rhs_rows_survive_on_the_tree_side(op):
     assert any(a == 0.0 and b == 0.0 for _t, (a, b) in host)
     assert seq == host
     assert scan == host
+
+
+# ---------------------------------------------------------------------------
+# whole plans, the scatter forced and the dense form
+# ---------------------------------------------------------------------------
+
+def _partitioned_rows():
+    """(the interpreter's rows, the lane grid's, its EXPLAIN entry): 37
+    keys through the partitioned scan plan, four flushes."""
+    _f, host = pf._run_part("@app:devicePatterns('never')\n")
+    info: dict = {}
+    fam, dev = pf._run_part("@app:partitionCapacity(64)\n", plan_out=info)
+    assert fam == "scan"
+    return host, dev, info["explain"]["queries"]["q"]
+
+
+def _cut_fused_rows():
+    """The same of a fused group of 12 rules whose every flush is cut into
+    rows (the row constants lowered by the caller: 64-event rows)."""
+    batches = fc.tape(21, 400, 4)
+    host, _e, _p, _s = fc.run(fc.HOST, fc.app_of(3), batches)
+    dev, ex, plans, _s = fc.run("", fc.app_of(3), batches)
+    fused = ex["queries"][plans[0].name]["fused"]
+    assert fused["lane_cut"]["flushes_cut"] == 4
+    inner = plans[0].inner
+    assert fused["compaction"]["lanes"] == inner._fused_R * inner.P
+    return fc.flat(host), fc.flat(dev), fused
+
+
+@pytest.mark.parametrize("form", ["dense", "scatter"])
+@pytest.mark.parametrize("rows_of", [_partitioned_rows, _cut_fused_rows],
+                         ids=["partitioned", "cut_fused_group"])
+def test_plans_rows_equal_the_interpreters_in_either_form(
+        rows_of, form, monkeypatch):
+    """Lanes of a few hundred events compact dense by themselves; the
+    scatter is forced by handing the block the `_Compact` of a lane past
+    the bound.  Both deliver the interpreter's rows."""
+    monkeypatch.setattr(pattern_plan, "FUSED_ROW_WINDOWS", 2)
+    monkeypatch.setattr(pattern_plan, "FUSED_ROW_MIN", 16)
+    if form == "scatter":
+        monkeypatch.setattr(npar, "_Compact", _scatter_form)
+    host, dev, ent = rows_of()
+    assert dev == host and sum(map(len, dev)) > 100
+    assert ent["first_hit"]["tree"] == 0 and ent["indexed_read"]["gather"] == 0
+    other = "scatter" if form == "dense" else "dense"
+    assert ent["compaction"][form] == 2 and ent["compaction"][other] == 0

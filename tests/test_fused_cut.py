@@ -156,6 +156,8 @@ def _assert_cut(fused, flushes, C):
     assert fused["first_hit"]["F"] == C and fused["first_hit"]["tree"] == 0
     assert fused["indexed_read"]["gather"] == 0
     assert fused["first_hit"]["dense"] > 0
+    assert fused["compaction"]["dense"] > 0
+    assert fused["compaction"]["scatter"] == 0 and fused["compaction"]["F"] == C
 
 
 # -- forced by SHAPE: the constants as they stand -------------------------------
@@ -432,7 +434,7 @@ def test_spans_route_and_lane_cut_and_the_fused_record():
     assert ent["kind"] == "multi_query" and "family" not in ent
     assert list(ent["fused"]) == [
         "queries", "padded_lanes", "family", "first_hit", "indexed_read",
-        "lane_cut", "arms_resolved", "dispatches_skipped"]
+        "compaction", "lane_cut", "arms_resolved", "dispatches_skipped"]
     assert sorted(ent["fused"]["lane_cut"]) == [
         "cut_length", "events_replayed", "flushes_cut",
         "flushes_uncuttable", "rows"]
