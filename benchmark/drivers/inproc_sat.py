@@ -62,18 +62,22 @@ def run(job: "harness.Run") -> dict:
         placement = engine.check_placement(rt, cell,
                                            job.devices[0].platform)
         job.spans.reset()
+        rows_warm = judge.rows
         before = engine.counters(rt) if job.trace_on else None
         c_setup = (job.compiles.n, job.compiles.secs)
 
         # ---- the timed window ------------------------------------------
         sent = warm
+        marks = []                  # when each batch of the window began
         t0 = time.perf_counter()
         while True:
             elapsed = time.perf_counter() - t0
             if elapsed >= job.seconds:
                 break
+            marks.append(elapsed)
             job.trace.tick(elapsed)
-            cols, ts = feed(sent)
+            with job.spans.span("feed"):    # the generator's own time
+                cols, ts = feed(sent)
             with job.spans.span("send_batch"):
                 handler.send_batch(cols, ts)
             sent += 1
@@ -86,6 +90,7 @@ def run(job: "harness.Run") -> dict:
         counted = engine.delta(engine.counters(rt), before) \
             if job.trace_on else None
         device = harness.device_block(job.devices)
+        rows_in_window = judge.rows - rows_warm
     finally:
         mgr.shutdown()
 
@@ -95,8 +100,10 @@ def run(job: "harness.Run") -> dict:
     n_batches = sent - warm
     events = n_batches * batch
     window_s = t1 - t0
+    periods = np.diff(np.array(marks + [window_s]))
     obs = job.close(device, {"events": events, "batches": n_batches,
-                             "window_s": window_s},
+                             "window_s": window_s, "batch": batch,
+                             "rows_delivered": rows_in_window},
                     counted, compiles_in_window, samples={})
     return {"t_window0": t0, "window_s": window_s, "events": events,
             "end_to_end": {"events_per_s": events / window_s},
@@ -108,4 +115,24 @@ def run(job: "harness.Run") -> dict:
             "counts": {"events": events, "batches": n_batches,
                        "rows_delivered": judge.rows, **judge.detail},
             "notes": {"placement": placement, "judge": judge.detail,
-                      "tape_batches_built_in_window": built_late}}
+                      "tape_batches_built_in_window": built_late,
+                      "batch_period_ms": _periods(periods)}}
+
+
+def _periods(periods: np.ndarray) -> dict:
+    """How evenly the window's batches followed one another (the last
+    one's period holds the closing flush): a printed note, not a metric.
+    `stalled_s` is the time batches took beyond twice the median period,
+    what a host that stood still now and then cost the window."""
+    if not len(periods):
+        return {}
+    med = float(np.median(periods))
+    q = np.quantile(periods, [0.05, 0.95])
+    thirds = [float(np.mean(t)) for t in np.array_split(periods, 3)
+              if len(t)]
+    return {"median": round(1e3 * med, 4), "p05": round(1e3 * float(q[0]), 4),
+            "p95": round(1e3 * float(q[1]), 4),
+            "max": round(1e3 * float(periods.max()), 4),
+            "mean_by_third": [round(1e3 * t, 4) for t in thirds],
+            "stalled_s": round(float(np.sum(np.maximum(
+                periods - 2 * med, 0.0))), 4)}

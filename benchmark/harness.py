@@ -72,8 +72,9 @@ class DeviceTrace:
     """jax.profiler around a few seconds of a traced run's window.  The
     driver calls `tick(elapsed)` between its calls into the engine."""
 
-    def __init__(self, on: bool, start_after_s: float, length_s: float):
-        self.on = on
+    def __init__(self, on: bool, start_after_s: float, length_s: float,
+                 keep: str = ""):
+        self.on, self.keep = on, keep
         self.start_after_s, self.length_s = start_after_s, length_s
         self.dir = None
         self.state = "idle" if on else "done"
@@ -110,6 +111,9 @@ class DeviceTrace:
                               recursive=True)
             if not files:
                 return None
+            if self.keep:       # --keep-trace: the raw file, for a look
+                os.makedirs(self.keep, exist_ok=True)
+                shutil.copy(files[0], self.keep)
             return xplane.summarize(xplane.load(files[0]))
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
@@ -142,14 +146,15 @@ def emit(result: dict, checks: list) -> None:
 class Run:
     """One run's settings and instruments, handed to the driver."""
 
-    def __init__(self, cell, seed, seconds, trace_on, devices):
+    def __init__(self, cell, seed, seconds, trace_on, devices,
+                 keep_trace=""):
         self.cell, self.seed, self.seconds = cell, int(seed), float(seconds)
         self.trace_on, self.devices = trace_on, devices
         self.compiles = CompileLog()
         self.spans = Spans(trace_on)
         t = cell["traffic"].get("trace", {})
         self.trace = DeviceTrace(trace_on, t.get("start_after_s", 3.0),
-                                 t.get("length_s", 4.0))
+                                 t.get("length_s", 4.0), keep_trace)
 
     def close(self, device: dict, window: dict, counted, compiles_in_window,
               samples: dict):

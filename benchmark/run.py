@@ -67,6 +67,10 @@ def main(argv=None) -> int:
                     "--set rate_events_per_s=200000 or --set "
                     "tape.price_step=0.01: for a sweep or an experiment; "
                     "the driver's check never passes it")
+    ap.add_argument("--keep-trace", default="", metavar="DIR",
+                    help="copy the traced run's raw .xplane.pb into DIR "
+                    "(inside the checkout), for a look by hand; the "
+                    "driver's check never passes it")
     args = ap.parse_args(argv)
 
     from benchmark import harness, manifest
@@ -85,15 +89,6 @@ def main(argv=None) -> int:
             raise SystemExit(f"--set {item}: no such key to override")
         where[key] = type(where[key])(float(value))
 
-    # plan geometry comes from what git commits, never from a tuning cache
-    # in somebody's home directory: start from an empty one in the checkout
-    state_dir = os.path.join(manifest.ROOT, ".bench_state")
-    os.makedirs(state_dir, exist_ok=True)
-    tune = os.path.join(state_dir, f"tuning-{cell['name']}.json")
-    if os.path.exists(tune):
-        os.remove(tune)
-    os.environ["SIDDHI_TUNE_CACHE"] = tune
-
     devices, attach_s = _attach(cell["chips"], args.rehearse_cpu)
     if devices is None:
         return 2
@@ -103,7 +98,8 @@ def main(argv=None) -> int:
 
     run = harness.Run(cell=cell, seed=args.seed, seconds=args.seconds,
                       trace_on=bool(args.trace),
-                      devices=devices[:cell["chips"]])
+                      devices=devices[:cell["chips"]],
+                      keep_trace=args.keep_trace)
     driver = manifest.module("drivers", cell["traffic"]["driver"])
     out = driver.run(run)
 
@@ -119,6 +115,10 @@ def main(argv=None) -> int:
     metrics = {}
     if args.trace:
         obs = out["obs"]
+        if obs["batches"]:      # the driver's own spans, whole window
+            print("driver_spans_ms_per_batch", {
+                k: round(1e3 * v / obs["batches"], 4)
+                for k, v in obs["spans"].items()}, flush=True)
         for m in mf.metrics_of(cell["name"], "per_layer"):
             spec = mf.metric_spec(m["name"])
             value = manifest.module("readers", spec["reader"]).read(spec, obs)
@@ -140,6 +140,9 @@ def main(argv=None) -> int:
         result["metrics"] = {}
     if args.trace and out["obs"].get("trace"):
         result["breakdown"] = out["obs"]["trace"]["breakdown"]
+        # false: the trace reader knew no operation's name-scope, and the
+        # names in device_ops are XLA's own
+        result["scopes"] = out["obs"]["trace"]["scopes"]
     harness.emit(result, out["checks"])
     return 0
 

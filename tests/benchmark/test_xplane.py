@@ -52,15 +52,24 @@ def test_busy_is_the_union_of_op_intervals_per_device():
     assert s["host_spans"]["send_batch"] == pytest.approx(0.155)
 
 
-def test_idle_gaps_go_to_the_host_span_that_covers_them():
+def test_idle_gaps_go_piece_by_piece_to_the_span_that_covers_them():
     s = xplane.summarize(_trace())
     gaps = dict(map(tuple, s["breakdown"]["idle_gaps"]))
-    # 50-100 ms: midpoint 75 lies in callback (the shortest covering span);
-    # 150-200 ms: midpoint 175 lies in flush
-    assert gaps == {"callback": pytest.approx(0.050),
-                    "flush": pytest.approx(0.050)}
+    # 50-100 ms is cut at the span boundaries: 50-55 and 85-90 send_batch,
+    # 55-85 callback (another thread's, begun later: the innermost), 90-95
+    # between the two calls, 95-100 send_batch; 150-200 ms: 150-160
+    # send_batch, 160-200 flush.  (Before PR 39 a gap went whole to the
+    # span over its midpoint: callback 50, flush 50.)
+    assert gaps == {"bench:callback": pytest.approx(0.030),
+                    "bench:send_batch": pytest.approx(0.025),
+                    "bench:flush": pytest.approx(0.040),
+                    xplane.BETWEEN: pytest.approx(0.005)}
     assert s["breakdown"]["device_ops"][0] == ["while.1",
                                                pytest.approx(0.090)]
+    assert s["scopes"] is False     # hand-made events carry no name-scope
+    assert s["breakdown"]["modules"] == [
+        ["jit_block(1) x2", pytest.approx(0.100)],
+        ["jit_small(2) x1", pytest.approx(0.001)]]
     assert xplane.short_name(
         "%while.73 = (u32[]{:T(128)}, s32[1024,640]{1,0:T(8,128)}) "
         "while((u32[]{:T(128)}) %tuple.1), condition=%c") == "%while.73 while"
@@ -128,6 +137,13 @@ def test_recorded_chip_trace_reduces_to_the_numbers_read_by_hand(tmp_path):
     assert sum(d["module_seconds"].values()) == pytest.approx(d["busy_s"],
                                                               rel=0.01)
     assert s["breakdown"]["device_ops"][0][0] == "%while.73 while"
-    assert [g[0] for g in s["breakdown"]["idle_gaps"]] == ["send_batch"]
+    # (names carry their prefix since PR 39; the trace predates the
+    # engine's `siddhi:` spans, and the callback and the closing flush hold
+    # a third of a millisecond each beside send_batch's 155)
+    assert [g[0] for g in s["breakdown"]["idle_gaps"]][:1] == [
+        "bench:send_batch"]
+    assert s["breakdown"]["idle_gaps"][0][1] == pytest.approx(
+        s["window_s"] - d["busy_s"], rel=0.01)
+    assert s["scopes"] is False     # PR 25's block had no named scope
     assert trace.read({"quantity": "idle_share"}, {"trace": s}) == \
         pytest.approx(100 * (1 - 1.139033706 / 1.29487575), rel=1e-6)
