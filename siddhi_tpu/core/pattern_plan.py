@@ -31,7 +31,7 @@ from .nfa_parallel import DENSE_MAX_F, varies_by_lane
 from .planner import (AGGREGATOR_NAMES, OutputBatch, PlanError, QueryPlan,
                       selector_has_aggregators)
 from .schema import StreamSchema, TIMESTAMP_DTYPE, dtype_of
-from .telemetry import call_kernel, env_nbytes
+from .telemetry import call_kernel, device_wait, env_nbytes
 
 _I32 = np.int32
 
@@ -898,10 +898,14 @@ class DevicePatternPlan(QueryPlan):
 
     def _pull(self, out: dict) -> tuple:
         """The blocking pull of one block's packed outputs: the wait for
-        the device, then ONE D2H transfer per pack; notes the bytes."""
-        with self.rt.span("transfer", plan=self.name):
-            ipack = np.asarray(out["i"])
-            fpack = np.asarray(out["f"]) if "f" in out else None
+        the device (a span of its own while a sink is on), then ONE D2H
+        transfer per pack; notes the bytes."""
+        span = self.rt.span
+        with span("transfer", plan=self.name):
+            device_wait(span, self.name, (out["i"], out.get("f")))
+            with span("transfer.copy", plan=self.name):
+                ipack = np.asarray(out["i"])
+                fpack = np.asarray(out["f"]) if "f" in out else None
         prof = self.rt.profiler
         if prof is not None:
             prof.note_bytes(self.name, "d2h", ipack.nbytes
@@ -1716,13 +1720,15 @@ class DevicePatternPlan(QueryPlan):
         lanes, rows = e.get("L"), e.get("R")
         while True:
             ipack, fpack = self._pull(e["out"])
-            if rows and self._lanes_real < lanes:
-                # lanes a mesh padded the group with: their rows are
-                # nobody's, so neither unpacked nor a reason to re-run
-                ipack = ipack[:, :self._lanes_real]
-                fpack = fpack if fpack is None \
-                    else fpack[:, :self._lanes_real]
-            n = int(ipack[..., 0, 0].max()) if lanes else int(ipack[0, 0])
+            with self.rt.span("unpack", plan=self.name):
+                if rows and self._lanes_real < lanes:
+                    # lanes a mesh padded the group with: their rows are
+                    # nobody's, so neither unpacked nor a reason to re-run
+                    ipack = ipack[:, :self._lanes_real]
+                    fpack = fpack if fpack is None \
+                        else fpack[:, :self._lanes_real]
+                n = int(ipack[..., 0, 0].max()) if lanes \
+                    else int(ipack[0, 0])
             if n > e["M"]:      # final-count emission burst: exact retry
                 e = self._dispatch_par(
                     e["ev"], e["F"],
@@ -1836,33 +1842,41 @@ class DevicePatternPlan(QueryPlan):
         """Columnar match table from one lane-vmapped block's packed
         output: (L, rows, M) transposes to (rows, L*M) and the per-lane
         match counts become one validity mask — the row decode is then
-        identical to the flat path (no per-lane python)."""
-        Ln, rows, Mm = ipack.shape
-        n_l = ipack[:, 0, 0]
-        ip2 = np.swapaxes(ipack, 0, 1).reshape(rows, Ln * Mm)
-        fp2 = (np.swapaxes(fpack, 0, 1).reshape(fpack.shape[1], Ln * Mm)
-               if fpack is not None else None)
-        base = (np.arange(Mm)[None, :] < n_l[:, None]).reshape(-1)
+        identical to the flat path (no per-lane python).  The transpose
+        is a copy of the whole result: span `unpack`, closed before
+        `_unpack_rows` opens `scatter`."""
+        with self.rt.span("unpack", plan=self.name):
+            Ln, rows, Mm = ipack.shape
+            n_l = ipack[:, 0, 0]
+            ip2 = np.swapaxes(ipack, 0, 1).reshape(rows, Ln * Mm)
+            fp2 = (np.swapaxes(fpack, 0, 1).reshape(fpack.shape[1], Ln * Mm)
+                   if fpack is not None else None)
+            base = (np.arange(Mm)[None, :] < n_l[:, None]).reshape(-1)
         return self._unpack_rows(ip2, fp2, base)
 
     def _unpack_lane_rows(self, ipack, fpack):
         """Columnar match table from a cut fused flush's packed output,
         (rows, lanes, words, M): each word is read at the filled cells
         alone (in row, lane, match order), so the capacity that stayed
-        empty is never copied, as _unpack_lanes' transpose would."""
-        n_l = ipack[:, :, 0, 0]
-        filled = np.arange(ipack.shape[-1]) < n_l[..., None]
-        # word 0 is the block's header (counts, flags): no match column
-        ip2 = [None] + [ipack[:, :, r, :][filled]
-                        for r in range(1, ipack.shape[2])]
-        fp2 = ([fpack[:, :, r, :][filled] for r in range(fpack.shape[2])]
-               if fpack is not None else None)
-        return self._unpack_rows(ip2, fp2, np.ones(len(ip2[1]), bool))
+        empty is never copied, as _unpack_lanes' transpose would (span
+        `unpack`, as there)."""
+        with self.rt.span("unpack", plan=self.name):
+            n_l = ipack[:, :, 0, 0]
+            filled = np.arange(ipack.shape[-1]) < n_l[..., None]
+            # word 0 is the block's header (counts, flags): no match column
+            ip2 = [None] + [ipack[:, :, r, :][filled]
+                            for r in range(1, ipack.shape[2])]
+            fp2 = ([fpack[:, :, r, :][filled]
+                    for r in range(fpack.shape[2])]
+                   if fpack is not None else None)
+            base = np.ones(len(ip2[1]), bool)
+        return self._unpack_rows(ip2, fp2, base)
 
     def _unpack_block(self, ipack, fpack, n: int):
         """Columnar match table from one flat block's packed output."""
-        return self._unpack_rows(ipack, fpack,
-                                 np.arange(ipack.shape[1]) < n)
+        with self.rt.span("unpack", plan=self.name):
+            base = np.arange(ipack.shape[1]) < n
+        return self._unpack_rows(ipack, fpack, base)
 
     def _unpack_rows(self, ipack, fpack, base_valid):
         with self.rt.span("scatter", plan=self.name):

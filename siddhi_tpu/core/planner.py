@@ -23,7 +23,7 @@ from .batch import EventBatch
 from .expr import (CompiledExpr, ExprError, MultiStreamContext,
                    SingleStreamContext, compile_expression, jnp_dtype)
 from .schema import TIMESTAMP_DTYPE, StreamSchema, StringTable, dtype_of
-from .telemetry import call_kernel, env_nbytes
+from .telemetry import call_kernel, device_wait, env_nbytes
 
 # aggregator function names recognized in selectors (reference:
 # core:query/selector/attribute/aggregator/*)
@@ -362,10 +362,13 @@ class FilterProjectPlan(QueryPlan):
     def _materialize(self, mask_w, outs, host_env, batch, mask) -> list:
         span = self.rt.span
         if mask is None:
-            # the pulls: the wait for the device and the D2H copies
+            # the pulls: the wait for the device (a span of its own
+            # while a sink is on) and the D2H copies
             with span("transfer", plan=self.name):
-                words = np.asarray(mask_w)
-                outs = [np.asarray(o) for o in outs]
+                device_wait(span, self.name, (mask_w, outs))
+                with span("transfer.copy", plan=self.name):
+                    words = np.asarray(mask_w)
+                    outs = [np.asarray(o) for o in outs]
         with span("unpack", plan=self.name, events=batch.n):
             if mask is None:
                 mask = ((words.view(np.uint32)[:, None]

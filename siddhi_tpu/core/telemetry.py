@@ -31,20 +31,31 @@ Layout:
   * `SiddhiDebugger` — micro-batch-boundary breakpoints (unchanged).
 
 `kernel` is the jitted dispatch call (async: it returns once the device
-has the work); `transfer` is block_until_ready + the D2H pull, so on the
-async path it includes the device execution wait.
+has the work); `transfer` is the blocking pull of its result.  While a
+sink is on, the pattern plans' and the filter's pulls split it into two
+children (`device_wait`): `transfer.wait`, the device time still owed
+when the host arrives (`block_until_ready`), and `transfer.copy`, what
+is left of the D2H copy and the host-side assembly.  With every sink off
+the pull makes no call it did not make before the split.  The result
+path's spans (`FAULT_SPANS`) also count the process's page faults.
 """
 from __future__ import annotations
 
 import gc
 import json
 import math
+import mmap
 import os
 import threading
 import time
 from collections import defaultdict
 from functools import partial
 from typing import Callable, Optional
+
+try:
+    import resource
+except ImportError:         # no getrusage on this platform: no fault counts
+    resource = None
 
 import jax.monitoring
 import jax.profiler
@@ -58,12 +69,45 @@ SPANS = (
     "net.wait", "net.decode", "admit", "queue_wait",    # wire
     "ingest", "frame", "freeze", "wal.append",          # ingest
     "dispatch", "host_build", "lane_cut", "kernel",     # dispatch
-    "transfer",
+    "transfer", "transfer.wait", "transfer.copy",
     "unpack", "scatter", "route", "emit",
     "sink.publish", "sink.encode", "sink.send",         # egress
     "gc",                                               # process
 )
 SPAN_PREFIX = "siddhi:"     # TraceAnnotation names in a jax.profiler trace
+
+
+def _page_faults() -> tuple:
+    ru = resource.getrusage(resource.RUSAGE_SELF)
+    return ru.ru_minflt, ru.ru_majflt
+
+
+def _kernel_counts_faults() -> bool:
+    """Does a first touch of fresh pages move `ru_minflt` here?  A
+    sandboxed kernel (gVisor, `runsc`: the machines the benchmark's chips
+    hang on) answers `getrusage` with 0 faults whatever the process
+    touches, at 6-14 us a call: there a count of 0 would read as "no
+    fresh pages", so no span counts at all."""
+    before = _page_faults()[0]
+    with mmap.mmap(-1, 16 * mmap.PAGESIZE) as fresh:
+        for i in range(16):
+            fresh[i * mmap.PAGESIZE] = 1
+    return _page_faults()[0] > before
+
+
+# the result path: where a pulled result lands and is copied again.  With
+# statistics on, a PLAN's span of these names reads the PROCESS's page-
+# fault counts at open and close (`stages[name]["minor_faults"]`,
+# `["major_faults"]`).  Process-wide on purpose: a D2H landing buffer is
+# touched by the runtime's transfer thread, not by the python thread that
+# waits for it, so RUSAGE_THREAD would miss the copy; faults other
+# threads take meanwhile fall in.  A plan's spans only: the runtime's own
+# `scatter` (one a delivered batch, 500 a flush of rules1k) lands no
+# result, and getrusage walks every thread of the process.  Empty where
+# there is no `resource` module or the kernel does not count.
+FAULT_SPANS = frozenset(
+    ("transfer.copy", "unpack", "scatter", "route")
+    if resource is not None and _kernel_counts_faults() else ())
 
 
 # ---------------------------------------------------------------------------
@@ -158,7 +202,7 @@ def _le_label(seconds: float) -> str:
 
 class Tracker:
     __slots__ = ("events", "batches", "seconds", "hist", "exemplars",
-                 "_lock")
+                 "faults", "_lock")
 
     def __init__(self):
         # spans close on serve threads and the scheduler pump as well
@@ -172,13 +216,23 @@ class Tracker:
         # TRACED sample per coarse bucket — OpenMetrics exemplars on
         # the /metrics histogram render (docs/OBSERVABILITY.md)
         self.exemplars: Optional[dict] = None
+        # [minor, major] page faults over this tracker's spans: a span
+        # of FAULT_SPANS only, else None
+        self.faults: Optional[list] = None
 
     def observe(self, seconds: float, events: int = 0,
-                trace_id: Optional[str] = None) -> None:
+                trace_id: Optional[str] = None,
+                faults: Optional[tuple] = None) -> None:
         """One timed batch; a traced frame's id becomes the bucket
-        exemplar linking the latency histogram back to its span tree."""
+        exemplar linking the latency histogram back to its span tree;
+        `faults` is the (minor, major) page faults the span saw."""
         with self._lock:
             self._observe_locked(seconds, events, trace_id)
+            if faults is not None:
+                if self.faults is None:
+                    self.faults = [0, 0]
+                self.faults[0] += faults[0]
+                self.faults[1] += faults[1]
 
     def _observe_locked(self, seconds: float, events: int, trace_id) -> None:
         # (the collector hook calls this bare: it is the only writer of
@@ -224,6 +278,8 @@ class Tracker:
 
     def _as_dict_locked(self, buckets: bool) -> dict:
         d = {"events": self.events, "batches": self.batches}
+        if self.faults is not None:
+            d["minor_faults"], d["major_faults"] = self.faults
         if self.seconds:
             d["seconds"] = self.seconds
             if self.events:
@@ -280,7 +336,8 @@ class _Span(Span):
     `StatisticsManager.span` only."""
 
     __slots__ = ("mgr", "name", "plan", "events", "handle", "args", "t0",
-                 "t_end", "seconds", "_pspan", "_ann", "_sid", "_parent")
+                 "t_end", "seconds", "_pspan", "_ann", "_sid", "_parent",
+                 "_faults")
 
     def __init__(self, mgr, name, plan, events, handle, pspan, t0, args):
         self.mgr = mgr
@@ -294,6 +351,7 @@ class _Span(Span):
         self.seconds = 0.0
         self._pspan = pspan
         self._ann = None
+        self._faults = None
 
     def note(self, **args) -> None:
         """Arguments known only inside the span (a WAL seq, an admission
@@ -315,6 +373,8 @@ class _Span(Span):
             self._ann = jax.profiler.TraceAnnotation(
                 SPAN_PREFIX + self.name, **kw)
             self._ann.__enter__()
+            if self.plan is not None and self.name in FAULT_SPANS:
+                self._faults = _page_faults()
         now = time.perf_counter()
         if self.t0 is None:
             self.t0 = now
@@ -324,8 +384,13 @@ class _Span(Span):
         self.t_end = now = time.perf_counter()
         self.seconds = dt = now - self.t0
         if self._ann is not None:
+            f0 = self._faults
+            if f0 is not None:
+                f1 = _page_faults()
+                f0 = (f1[0] - f0[0], f1[1] - f0[1])
             self._ann.__exit__(*exc)
-            self.mgr.stage_tracker(self.name).observe(dt, self.events)
+            self.mgr.stage_tracker(self.name).observe(dt, self.events,
+                                                      faults=f0)
         h = self.handle
         if h is not None:
             args = self.args
@@ -472,6 +537,19 @@ def call_kernel(stats, plan: str, fn, args: tuple, *, cache_hit: bool,
         with stats.span("kernel", plan=plan):
             return fn(*args)
     return prof.run_kernel(fn, args, span=partial(stats.span, plan=plan))
+
+
+def device_wait(span, plan: str, arrays) -> None:
+    """`transfer.wait`, the first child of a pull's `transfer` span: the
+    device time still owed when the host arrives, apart from the copy
+    that follows (`transfer.copy`).  It is a call the pull does not
+    otherwise make, so it exists only while a sink is on: with
+    statistics off and no traced frame the span is the no-op and nothing
+    blocks here."""
+    sp = span("transfer.wait", plan=plan)
+    if sp is not NOOP_SPAN:
+        with sp:
+            jax.block_until_ready(arrays)
 
 
 # ---------------------------------------------------------------------------
@@ -670,6 +748,11 @@ def render_prometheus(reports: dict, openmetrics: bool = False) -> str:
             _summary(doc, "siddhi_tpu_stage_latency_seconds",
                      "per-span latency per pipeline stage",
                      {**al, "stage": st}, td)
+            for kind in ("minor", "major"):     # the result path's spans
+                doc.add(f"siddhi_tpu_stage_{kind}_faults_total", "counter",
+                        f"{kind} page faults of the process while a "
+                        "result-path span was open", {**al, "stage": st},
+                        td.get(f"{kind}_faults"))
         for plan, m in rep.get("device", {}).items():
             pl = {**al, "plan": plan}
             for key, v in m.items():

@@ -103,6 +103,63 @@ def test_shares_sum_to_one_and_coverage(family):
     assert agg["rounds"] > 0 and agg["events"] > 0
 
 
+PARTITIONED = ("partition with (sym of S) begin\n@info(name='q') "
+               "from every e1=S[p > 10] -> e2=S[p > e1.p] "
+               "within 8 milliseconds select e1.p as a, e2.p as b "
+               "insert into Out;\nend;\n")
+
+
+@pytest.mark.parametrize("app,phase", [
+    # the seq family pulls and unpacks inside finalize(): the span's own
+    # phase takes the time
+    (FAMILIES["pattern"], "host_pack_unpack"),
+    # the lane path materialises under the pipeline's d2h_materialize
+    # wrap, the outermost phase, which holds its unpack as it always has
+    (PARTITIONED, "d2h_materialize")], ids=["seq", "lane"])
+def test_unpack_span_time_is_not_python_dispatch(app, phase):
+    """A pattern plan's `unpack` span maps to `host_pack_unpack`: its time
+    leaves the `python_dispatch` residual, and the shares still sum to 1."""
+    import time
+    mgr = SiddhiManager()
+    rt = mgr.create_app_runtime(
+        "@app:profile('all')\n@app:partitionCapacity(8)\n"
+        + PREFER + STOCK + app)
+    assert rt.stats._SPAN_PHASE["unpack"][0] == "host_pack_unpack"
+    assert "transfer.wait" not in rt.stats._SPAN_PHASE
+    assert "transfer.copy" not in rt.stats._SPAN_PHASE
+    real, slept = rt.span, [0]
+
+    class Slow:                 # the real span, 10 ms of work inside it
+        def __init__(self, inner):
+            self.inner = inner
+
+        def __enter__(self):
+            self.inner.__enter__()
+            time.sleep(0.01)
+            slept[0] += 1
+
+        def __exit__(self, *exc):
+            return self.inner.__exit__(*exc)
+
+    rt.start()
+    for k in range(8):
+        if k == 4:              # compiled: from here on the spans count
+            rt.profiler.reset()
+            rt.span = lambda name, **kw: Slow(real(name, **kw)) \
+                if name == "unpack" else real(name, **kw)
+        rt.input_handler("S").send_batch(_cols(64, seed=k),
+                                         np.arange(64) + 64 * k)
+        rt.flush()
+    prof = rt.profiler.metrics()
+    mgr.shutdown()
+    pv = prof["plans"]["q"]
+    assert slept[0] >= 4
+    assert pv["phases_s"][phase] >= 0.01 * slept[0]
+    assert pv["phases_s"].get("python_dispatch", 0.0) < 0.01 * slept[0]
+    assert abs(sum(pv["shares"].values()) - 1.0) < 5e-4
+    assert abs(sum(prof["aggregate"]["shares"].values()) - 1.0) < 5e-4
+
+
 def test_duty_cycle_counts_kernel_rounds():
     """sample=N probes ~1 in N KERNEL-carrying rounds: collect polls
     and scheduler pumps open kernel-less rounds and must not consume

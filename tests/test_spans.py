@@ -24,6 +24,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 STOCK = "define stream S (sym string, p double, v int);\n"
 FILTER = STOCK + ("@info(name='q') from S[p > 100] select sym, p "
                   "insert into Out;\n")
+T0_MS = 1_700_000_000_000
 PATTERN = ("@app:partitionCapacity(16)\n" + STOCK +
            "partition with (sym of S) begin\n"
            "@info(name='q') from every e1=S[p > 100] -> e2=S[p > e1.p] "
@@ -38,8 +39,7 @@ def _batch(k, n, keys=8):
     return ({"sym": np.array([f"K{i % keys}" for i in range(n)]),
              "p": np.round(r.uniform(90, 130, n) * 4) / 4,
              "v": r.integers(1, 100, n).astype(np.int32)},
-            1_700_000_000_000 + np.arange(k * n, (k + 1) * n,
-                                          dtype=np.int64))
+            T0_MS + np.arange(k * n, (k + 1) * n, dtype=np.int64))
 
 
 def _host_events(trace_dir):
@@ -318,6 +318,207 @@ def test_pattern_plan_notes_d2h_bytes():
         mgr.shutdown()
     assert rows[0] > 0
     assert plans["q"]["bytes"]["d2h"] > 0 and plans["q"]["bytes"]["h2d"] > 0
+
+
+# (g) the result path under names: `transfer` = wait + copy, `unpack`
+# before `scatter`, page faults where the result lands
+
+FLAT = ("@app:devicePatterns('prefer')\n" + STOCK +
+        "@info(name='q') from every e1=S[p > 100] -> e2=S[p > e1.p] "
+        "{within}select e1.p as a, e2.p as b insert into Out;\n")
+FUSED = STOCK + "".join(
+    f"@info(name='q{i}') from every e1=S[p > {120 + i}] -> e2=S[p > e1.p] "
+    "within 1 sec select e1.p as a, e2.p as b insert into Out;\n"
+    for i in range(8))
+# path -> (app, events a flush, ms an event, the unpack it takes, `scatter`
+# spans the plan opens a flush: what they were before `unpack` had a span)
+RESULT_PATHS = {
+    "lane": (PATTERN, 2048, 1, "_unpack_lanes", 3),
+    "fused-row": (FUSED, 512, 50, "_unpack_lane_rows", 1),
+    "flat-block": (FLAT.format(within="within 1 sec "), 256, 1,
+                   "_unpack_block", 3),
+    "seq-block": (FLAT.format(within=""), 256, 1, "_unpack_block", 2),
+    "filter": (FILTER, 4096, 1, None, 0),
+}
+PATTERN_PATHS = [k for k, v in RESULT_PATHS.items() if v[3]]
+
+
+def _run_result_path(path, monkeypatch, header="@app:trace('all')\n",
+                     stats=True):
+    """Three send_batch + flush rounds down one result path: (stage
+    statistics, the frames' trees, calls of each `_unpack_*`, rows out,
+    the runtime's Prometheus text)."""
+    from siddhi_tpu.core import pattern_plan
+    app, n, dt, _fn, _sc = RESULT_PATHS[path]
+    if path == "fused-row":     # rows of 64 events: a short flush is cut
+        monkeypatch.setattr(pattern_plan, "FUSED_ROW_WINDOWS", 2)
+        monkeypatch.setattr(pattern_plan, "FUSED_ROW_MIN", 16)
+    calls = {}
+    for fn in ("_unpack_lanes", "_unpack_lane_rows", "_unpack_block"):
+        def counted(self, *a, _o=getattr(pattern_plan.DevicePatternPlan, fn),
+                    _f=fn):
+            calls[_f] = calls.get(_f, 0) + 1
+            return _o(self, *a)
+        monkeypatch.setattr(pattern_plan.DevicePatternPlan, fn, counted)
+    mgr = SiddhiManager()
+    rt = mgr.create_app_runtime(header + app)
+    rows = [0]
+    rt.add_batch_callback("Out", lambda b: rows.__setitem__(0, rows[0] + b.n))
+    rt.enable_stats(stats)
+    rt.start()
+    try:
+        for k in range(3):
+            cols, ts = _batch(k, n)
+            rt.input_handler("S").send_batch(cols, T0_MS + dt * (ts - T0_MS))
+            rt.flush()
+        trees = list(rt.tracing.traces().values()) if rt.tracing else []
+        return (rt.statistics()["stages"], trees, calls, rows[0],
+                rt.stats.prometheus())
+    finally:
+        mgr.shutdown()
+
+
+@pytest.mark.parametrize("path", sorted(RESULT_PATHS))
+def test_transfer_is_its_wait_and_its_copy(path, monkeypatch):
+    stages, trees, _calls, rows, _prom = _run_result_path(path, monkeypatch)
+    assert rows > 0
+    wait, copy, whole = (stages[n] for n in
+                         ("transfer.wait", "transfer.copy", "transfer"))
+    # one of each a pull; the profiler's probe is a `transfer` with neither
+    assert 3 <= wait["batches"] == copy["batches"] <= whole["batches"]
+    assert wait["seconds"] + copy["seconds"] <= whole["seconds"]
+    pulls = 0
+    for spans in trees:
+        by_id = _one_tree(spans)
+        for sp in spans:
+            if sp["name"] in ("transfer.wait", "transfer.copy"):
+                # the wait hangs below `transfer`, the copy below the wait
+                # that closed just before it (as `sink.send` below
+                # `sink.encode`), and both lie inside the pull's interval
+                parent = by_id[sp["parent"]]
+                if sp["name"] == "transfer.copy":
+                    assert parent["name"] == "transfer.wait", (sp, parent)
+                    parent = by_id[parent["parent"]]
+                assert parent["name"] == "transfer", (sp, parent)
+                assert sp["t0_s"] >= parent["t0_s"] - 2e-6
+                assert sp["t0_s"] + sp["dur_s"] <= \
+                    parent["t0_s"] + parent["dur_s"] + 2e-6
+                pulls += sp["name"] == "transfer.copy"
+    assert pulls == copy["batches"]
+
+
+@pytest.mark.parametrize("path", PATTERN_PATHS)
+def test_unpack_closes_before_scatter_opens(path, monkeypatch):
+    _app, _n, _dt, fn, plan_scatters = RESULT_PATHS[path]
+    stages, trees, calls, rows, _prom = _run_result_path(path, monkeypatch)
+    assert rows > 0 and calls == {fn: 3}, calls
+    assert stages["unpack"]["batches"] >= 3
+    assert len(trees) == 3
+    for spans in trees:
+        mine = [s for s in spans if (s.get("args") or {}).get("plan")]
+        unpacks = [s for s in mine if s["name"] == "unpack"]
+        scatters = [s for s in mine if s["name"] == "scatter"]
+        assert unpacks and len(scatters) == plan_scatters, (path, mine)
+        for u in unpacks:
+            u_end = u["t0_s"] + u["dur_s"]
+            for sc in scatters:     # apart: `scatter` keeps its boundaries
+                assert u_end <= sc["t0_s"] + 2e-6 \
+                    or sc["t0_s"] + sc["dur_s"] <= u["t0_s"] + 2e-6, (u, sc)
+        # the flush's last unpack is over before its row decode begins
+        last = max(unpacks, key=lambda s: s["t0_s"])
+        assert any(sc["t0_s"] >= last["t0_s"] + last["dur_s"] - 2e-6
+                   for sc in scatters), (last, scatters)
+
+
+def test_fault_counters_ride_the_four_result_spans(monkeypatch):
+    from tests.test_tracing import assert_valid_exposition
+    stages, _t, _c, _r, prom = _run_result_path("fused-row", monkeypatch)
+    counted = {k for k, v in stages.items() if "minor_faults" in v}
+    assert counted == set(telemetry.FAULT_SPANS) == {
+        "transfer.copy", "unpack", "scatter", "route"}
+    assert counted == {k for k, v in stages.items() if "major_faults" in v}
+    assert_valid_exposition(prom)
+    for kind in ("minor", "major"):
+        got = re.findall(rf'^siddhi_tpu_stage_{kind}_faults_total'
+                         r'{app="[^"]*",stage="([a-z_.]+)"} \d+$', prom,
+                         flags=re.M)
+        assert sorted(got) == sorted(counted), (kind, got)
+
+
+@pytest.mark.parametrize("name,plan", [
+    (n, "q") for n in sorted(telemetry.FAULT_SPANS)] + [
+    ("transfer.wait", "q"), ("emit", "q"),
+    ("scatter", None)])         # the runtime's own: it lands no result
+def test_fault_counts_rise_with_a_fresh_buffer(name, plan):
+    """64 MB is past the allocator's mmap ceiling, so the buffer is pages
+    the process has never touched: 16,384 of them, or 32 huge ones."""
+    mgr = SiddhiManager()
+    rt = mgr.create_app_runtime(FILTER)
+    rt.enable_stats()
+    try:
+        with rt.span(name, plan=plan):
+            pass
+        before = rt.statistics()["stages"][name]
+        with rt.span(name, plan=plan):
+            buf = np.empty(64 << 20, np.uint8)
+            buf[::4096] = 1
+        after = rt.statistics()["stages"][name]
+    finally:
+        mgr.shutdown()
+    if plan is None or name not in telemetry.FAULT_SPANS:
+        assert "minor_faults" not in after and "major_faults" not in after
+        return
+    assert after["minor_faults"] - before["minor_faults"] >= 16
+    assert after["major_faults"] >= before["major_faults"] >= 0
+
+
+@pytest.mark.parametrize("header", [
+    "@app:trace('off')\n@app:profile('off')\n",   # every sink off
+    "@app:trace('off')\n"])                        # the default profiler
+def test_transfer_children_are_noops_with_no_sink_on(header):
+    mgr = SiddhiManager()
+    rt = mgr.create_app_runtime(header + FILTER)
+    try:
+        for name in ("transfer.wait", "transfer.copy"):
+            assert rt.span(name, plan="q") is NOOP_SPAN, name
+    finally:
+        mgr.shutdown()
+
+
+@pytest.mark.parametrize("path", ["filter", "lane", "seq-block"])
+def test_pull_does_not_block_with_no_sink_on(path, monkeypatch):
+    """Statistics off, no traced frame: the pull makes the calls it made
+    before `transfer` had children, so no `block_until_ready`."""
+    def boom(*a, **kw):
+        raise AssertionError("block_until_ready on the off path")
+    monkeypatch.setattr(jax, "block_until_ready", boom)
+    stages, _t, _calls, rows, _p = _run_result_path(
+        path, monkeypatch, stats=False,
+        header="@app:trace('off')\n@app:profile('off')\n")
+    assert rows > 0 and stages == {}
+
+
+@pytest.mark.parametrize("counts", [True, False],
+                         ids=["linux", "sandboxed-kernel"])
+def test_a_kernel_that_counts_no_faults_is_found_out(counts, monkeypatch):
+    """gVisor answers getrusage with 0 faults whatever is touched (the
+    chip machines, PERF.md 7.10): a count of 0 there would read as "no
+    fresh pages", so FAULT_SPANS is empty on such a kernel."""
+    if not counts:
+        monkeypatch.setattr(telemetry, "_page_faults", lambda: (0, 0))
+    elif not telemetry.FAULT_SPANS:
+        pytest.skip("this kernel counts no page faults")
+    assert telemetry._kernel_counts_faults() is counts
+
+
+def test_fault_counts_need_the_resource_module(monkeypatch):
+    """A platform without `resource`, or whose kernel does not count: no
+    span counts, nothing is read."""
+    monkeypatch.setattr(telemetry, "FAULT_SPANS", frozenset())
+    monkeypatch.setattr(telemetry, "resource", None)
+    stages, _t, _c, rows, _p = _run_result_path("filter", monkeypatch)
+    assert rows > 0 and "unpack" in stages
+    assert not [k for k, v in stages.items() if "minor_faults" in v]
 
 
 # (f) the taxonomy is the documentation's
