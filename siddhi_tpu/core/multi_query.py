@@ -13,9 +13,10 @@ family group ships (T, 1) grids that broadcast on device; a scan / dfa
 group ships the flat flush, and a flush longer than a row of a few
 `within` windows ships it as (rows, C) grids `[the last window | new
 events]`, the block then running over rows x lanes (pattern_plan
-`_fused_cut`).  Each emitted match carries its lane id, and the host
-routes the flush's matches by one stable sort over (lane, completion,
-head) into one slice a rule (span `route`).
+`_fused_cut`).  A lane is a rule: the host puts the flush's matches in
+delivery order (rule, completion, head) by one stable sort on one composite
+key, a cut flush's while it decodes the result (pattern_plan `_decode_cut`,
+`_rule_order`), and hands each rule one slice (span `route`).
 
 Grouping is automatic: >= MIN_GROUP StateInputStream queries with equal
 shape signatures (and no rate/having/limit) fuse; everything else plans
@@ -305,36 +306,31 @@ class MultiQueryDevicePatternPlan:
         return self._route(self.inner.finalize_multi())
 
     def _route(self, outs):
-        """The flush's match table to one OutputBatch a rule."""
+        """The flush's matches to one OutputBatch a rule: a batch is a
+        slice of the columns in delivery order (pattern_plan RuleRuns),
+        which a cut flush's decode has made already and a flat flush's
+        table is put into here."""
         from .batch import EventBatch
+        from .pattern_plan import RuleRuns
         from .planner import OutputBatch
-        from .schema import TIMESTAMP_DTYPE
 
         if not outs:
             return []
-        tss, seqs, hseqs, data, qids = outs
         res = []
         with self.rt.span("route", plan=self.name):
-            # ONE stable sort by (rule, completion, head arrival): a
-            # rule's rows are then one run, in the order they are owed
-            # (completion order, same-event ties by head arrival), and a
-            # batch is a slice of the sorted columns, not a mask over them
-            order = np.lexsort((hseqs, seqs, qids))
-            tss, seqs, qids = (
-                tss[order].astype(TIMESTAMP_DTYPE, copy=False),
-                seqs[order], qids[order])
-            data = {k: v[order] for k, v in data.items()}
-            starts = np.flatnonzero(np.r_[True, qids[1:] != qids[:-1]])
-            ends = np.r_[starts[1:], len(qids)]
-            for a, b in zip(starts.tolist(), ends.tolist()):
-                qi = int(qids[a])
+            runs = outs if isinstance(outs, RuleRuns) \
+                else self.inner._rule_runs(outs)
+            starts = runs.starts.tolist()
+            for qi, a, b in zip(runs.lanes.tolist(), starts,
+                                starts[1:] + [len(runs.tss)]):
                 if qi >= self.n_queries:      # defensive: padding lanes
                     continue
                 names = self.per_q_names[qi]
-                cols = {nm: data[src][a:b] for nm, src
+                cols = {nm: runs.data[src][a:b] for nm, src
                         in zip(names, self.inner._names)}
                 ob = OutputBatch(self.targets[qi], EventBatch(
-                    self._schema_of(qi), tss[a:b], cols, b - a, seqs[a:b]))
+                    self._schema_of(qi), runs.tss[a:b], cols, b - a,
+                    runs.seqs[a:b]))
                 ob.callback_name = self.query_names[qi]
                 res.append(ob)
         return res

@@ -17,7 +17,8 @@ rebases the persistent slot state host-side before offsets can overflow.
 """
 from __future__ import annotations
 
-from typing import Callable, Optional
+from functools import partial
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import numpy as np
@@ -93,6 +94,80 @@ def _rises_in_lanes(a_l: np.ndarray, run_start: np.ndarray) -> bool:
     rises = a_l[1:] >= a_l[:-1]
     rises[run_start[1:] - 1] = True         # a lane's first row
     return bool(rises.all())
+
+
+class CutResult(NamedTuple):
+    """A cut fused flush's packed result as pulled, (rows, lanes, words, M),
+    not yet decoded (`_decode_cut`): `counts` is its header's match counts
+    by (row, lane), a mesh's padding lanes dropped; the bases are the
+    flush's own."""
+    ipack: np.ndarray
+    fpack: Optional[np.ndarray]
+    counts: np.ndarray
+    ts_base: int
+    seq_base: int
+
+
+class RuleRuns(NamedTuple):
+    """A fused flush's matches in the order they are owed: by rule, inside a
+    rule by completion seq, same-event ties by head arrival.  Rule
+    `lanes[j]` holds the rows from `starts[j]` to the next rule's start
+    of every column."""
+    tss: np.ndarray
+    seqs: np.ndarray
+    data: dict
+    lanes: np.ndarray
+    starts: np.ndarray
+
+
+class _Scratch:
+    """The buffers a plan decodes its results through (index, key, the
+    words of one column on their way to the delivered dtype): kept by
+    the plan, grown geometrically, reused flush to flush and NEVER handed
+    out.  A fresh page costs the chip machines ~1 ms a MB (PERF.md 7.10),
+    so what is not delivered is not allocated a flush; what IS delivered
+    is allocated for its batch, always (a callback may keep it)."""
+
+    def __init__(self):
+        self._bufs: dict = {}
+
+    def __call__(self, name: str, n: int, dtype) -> np.ndarray:
+        """`n` uninitialised items of buffer `name`."""
+        buf = self._bufs.get(name)
+        if buf is None or len(buf) < n or buf.dtype != dtype:
+            buf = self._bufs[name] = np.empty(
+                max(n, 2 * (0 if buf is None else len(buf))), dtype)
+        return buf[:n]
+
+
+# `np.take` into scratch: `mode="raise"`, the default, buffers `out`; what
+# it would have checked, _decode_cut checks once a result (`index`)
+_take = partial(np.take, mode="clip")
+
+
+def _flat_words(a: np.ndarray) -> tuple:
+    """(`a`'s memory as ONE flat array, `a`'s strides in items).  A pulled
+    result comes in the axis order the device laid it out in (PERF.md
+    7.8), so a cell's flat position is read off the strides, not assumed;
+    a result that is no permutation of one contiguous block is copied."""
+    by_stride = sorted(range(a.ndim), key=lambda i: -a.strides[i])
+    if not a.transpose(by_stride).flags.c_contiguous:
+        a = np.ascontiguousarray(a)
+        by_stride = range(a.ndim)
+    return (a.transpose(by_stride).reshape(-1),
+            tuple(s // a.itemsize for s in a.strides))
+
+
+def _ramp(out: np.ndarray, starts, lens, first, step: int) -> np.ndarray:
+    """out[k] = first[j] + (k - starts[j]) * step inside run j (the runs
+    non-empty, one after the other, covering `out`): a fill, the jumps
+    between runs written at the run starts, one running sum in place;
+    no temporary of `out`'s length, as `np.repeat` would make."""
+    out.fill(step)
+    jump = np.array(first, dtype=out.dtype)
+    jump[1:] -= jump[:-1] + (lens[:-1] - 1).astype(out.dtype) * step
+    out[starts] = jump
+    return np.cumsum(out, out=out)
 
 
 def _tail_rows(t: dict, rows) -> dict:
@@ -258,6 +333,12 @@ class DevicePatternPlan(QueryPlan):
         self._fused_C = self._fused_R = 0
         self._lanes_real = partitions    # before a mesh pads the lane axis
         self._fused_M: Optional[int] = None
+        # a fused plan's results by the form their decode took, and its
+        # flushes by the way their rows were ordered for delivery
+        # (EXPLAIN / device_metrics `fused.result_decode`, `.route_order`)
+        self._result_decode = {"indexed": 0, "masked": 0}
+        self._route_order = {"keyed": 0, "lexsort": 0}
+        self._scratch = _Scratch()
         # seq-family single-arm lanes (fused): every arm fired or died
         self._seq_spent = False
         self._arms_resolved = 0
@@ -841,8 +922,15 @@ class DevicePatternPlan(QueryPlan):
         stream into rows so far; cut_length: the row length in use, 0
         before a cut; flushes_uncuttable: flushes past a row that kept
         the flat form}, `arms_resolved` (lanes of single-arm rules whose
-        arm is spent) and `dispatches_skipped` (flushes not dispatched
-        because every arm was)."""
+        arm is spent), `dispatches_skipped` (flushes not dispatched
+        because every arm was), `result_decode` (pulled results that held
+        rows, by the form of their decode: `indexed`, a cut flush's, read
+        through one index over its filled cells, _decode_cut; `masked`,
+        a flat flush's or a tick's, under a validity mask, _unpack_rows)
+        and `route_order` (flushes by the way their rows were put in
+        delivery order: `keyed`, one stable sort on one composite key;
+        `lexsort`, the three-key sort, where the key cannot serve:
+        _rule_order)."""
         if not self.broadcast_events:
             return None
         arms = self._arms_resolved if self._arm_done is None \
@@ -853,7 +941,9 @@ class DevicePatternPlan(QueryPlan):
                 "lane_cut": {**self._fused_cut_did,
                              "cut_length": self._fused_C},
                 "arms_resolved": arms,
-                "dispatches_skipped": self._dispatches_skipped}
+                "dispatches_skipped": self._dispatches_skipped,
+                "result_decode": dict(self._result_decode),
+                "route_order": dict(self._route_order)}
 
     def _rebase(self, min_ts: int, min_seq: int) -> None:
         """Shift the plan's ts/seq bases forward and adjust persistent slot
@@ -1721,14 +1811,14 @@ class DevicePatternPlan(QueryPlan):
         while True:
             ipack, fpack = self._pull(e["out"])
             with self.rt.span("unpack", plan=self.name):
-                if rows and self._lanes_real < lanes:
+                if rows:
                     # lanes a mesh padded the group with: their rows are
-                    # nobody's, so neither unpacked nor a reason to re-run
-                    ipack = ipack[:, :self._lanes_real]
-                    fpack = fpack if fpack is None \
-                        else fpack[:, :self._lanes_real]
-                n = int(ipack[..., 0, 0].max()) if lanes \
-                    else int(ipack[0, 0])
+                    # nobody's, so neither decoded nor a reason to re-run
+                    counts = ipack[:, :self._lanes_real, 0, 0]
+                    n = int(counts.max())
+                else:
+                    n = int(ipack[..., 0, 0].max()) if lanes \
+                        else int(ipack[0, 0])
             if n > e["M"]:      # final-count emission burst: exact retry
                 e = self._dispatch_par(
                     e["ev"], e["F"],
@@ -1761,7 +1851,10 @@ class DevicePatternPlan(QueryPlan):
         # bases are per-flush: _unpack_block must see THIS entry's
         self._ts_base, self._seq_base = e["ts_base"], e["seq_base"]
         if rows:
-            return self._unpack_lane_rows(ipack, fpack)
+            # decoded in _multi_table, which knows whether the flush's
+            # rows are this result's alone
+            return CutResult(ipack, fpack, counts, e["ts_base"],
+                             e["seq_base"]) if n else None
         if lanes:
             return self._unpack_lanes(ipack, fpack)
         return self._unpack_block(ipack, fpack, n)
@@ -1854,23 +1947,165 @@ class DevicePatternPlan(QueryPlan):
             base = (np.arange(Mm)[None, :] < n_l[:, None]).reshape(-1)
         return self._unpack_rows(ip2, fp2, base)
 
-    def _unpack_lane_rows(self, ipack, fpack):
-        """Columnar match table from a cut fused flush's packed output,
-        (rows, lanes, words, M): each word is read at the filled cells
-        alone (in row, lane, match order), so the capacity that stayed
-        empty is never copied, as _unpack_lanes' transpose would (span
-        `unpack`, as there)."""
-        with self.rt.span("unpack", plan=self.name):
-            n_l = ipack[:, :, 0, 0]
-            filled = np.arange(ipack.shape[-1]) < n_l[..., None]
-            # word 0 is the block's header (counts, flags): no match column
-            ip2 = [None] + [ipack[:, :, r, :][filled]
-                            for r in range(1, ipack.shape[2])]
-            fp2 = ([fpack[:, :, r, :][filled]
-                    for r in range(fpack.shape[2])]
-                   if fpack is not None else None)
-            base = np.ones(len(ip2[1]), bool)
-        return self._unpack_rows(ip2, fp2, base)
+    def _out_words(self) -> dict:
+        """Where the pack holds each output of `kernel.out_names`, as
+        (pack, first word, word dtype): `i` words from 1 (word 0 is the
+        block's header; a `having` flag would come first and fused plans
+        have none), f32 bit-cast into one, i64 as a hi / lo pair; f64 in
+        the `f` pack.  The order _unpack_rows reads them in."""
+        words, ii, fi = {}, 1, 0
+        for nm in self.kernel.out_names:
+            dt = np.dtype(self.kernel.out_dtypes[nm])
+            if dt == np.float64:
+                words[nm] = ("f", fi, dt)
+                fi += 1
+            else:
+                words[nm] = ("i", ii, dt)
+                ii += 2 if dt == np.int64 else 1
+        return words
+
+    def _decode_cut(self, res: CutResult, alone: bool):
+        """A cut fused flush's packed result to host columns, every word
+        read ONCE, through one index over the filled cells.
+
+        `unpack`: the index, from the count header alone, in lane-major
+        order (lane, row, match), so that a rule's rows are one run of
+        nearly sorted rows; and the two key words, completion and head
+        seq, fetched through it.  `route` (when the flush's rows are this
+        result's `alone`): the delivery order (_rule_order), composed
+        into the index.  `scatter`: every delivered column allocated for
+        this batch and written once, in its delivered dtype, from the
+        words fetched through that index.  The fetch goes through the
+        COMPOSED index, not lane-major and then through the order:
+        composed reads stay inside the lane-row they reorder, so they
+        cost what sequential ones do, and the second pass a column is
+        saved (PERF.md section 6, PR 41).
+
+        Alone: RuleRuns.  Beside other chunks (a tick's): the flat table
+        _unpack_rows returns, in lane-major order, for _multi_table to
+        join and _rule_runs to order."""
+        S, span, take = self._scratch, self.rt.span, _take
+        words = self._out_words()
+        with span("unpack", plan=self.name):
+            n_rows = len(res.counts)
+            cnt = res.counts.T.reshape(-1)          # lane-major cells
+            cells = np.flatnonzero(cnt)
+            lens = cnt[cells].astype(np.intp)
+            at = np.cumsum(lens) - lens
+            n = int(lens.sum())
+            lane_of, row_of = np.divmod(cells, n_rows)
+
+            def index(name, pack):
+                flat, (s_row, s_lane, s_word, s_m) = _flat_words(pack)
+                idx = _ramp(S(name, n, np.intp), at, lens,
+                            row_of * s_row + lane_of * s_lane, s_m)
+                # the takes clip: every word's cell must lie in the pack
+                if idx.min() < 0 or int(idx.max()) \
+                        + (pack.shape[2] - 1) * s_word >= len(flat):
+                    raise IndexError(
+                        f"{self.name}: decode index past the result "
+                        f"{pack.shape} (strides {pack.strides})")
+                return flat, s_word, idx
+            iflat, i_word, idx = index("idx", res.ipack)
+            if res.fpack is not None:
+                fflat, f_word, fidx = index("fidx", res.fpack)
+
+            def word(nm, through, buf="word", k=0):
+                """Word `k` of output `nm` at the cells of index
+                `through`, as it stands in the pack, in scratch `buf`."""
+                return take(iflat[(words[nm][1] + k) * i_word:], through,
+                            out=S(buf, n, _I32))
+            seq = word("__seq__", idx, "seq")
+            hseq = word("__head_seq__", idx, "hseq")
+            # a lane IS a rule (`__lane_qid__` is arange(P)): its id is
+            # read off the layout, the `__qid__` word never fetched
+            lane_n = res.counts.sum(axis=0, dtype=np.intp)
+            lanes = np.flatnonzero(lane_n)
+            starts = np.cumsum(lane_n[lanes]) - lane_n[lanes]
+            lane = _ramp(S("lane", n, _I32), starts, lane_n[lanes], lanes, 0)
+        if alone:
+            with span("route", plan=self.name):
+                order = self._rule_order(lane, seq, hseq)
+                idx = take(idx, order, out=S("idx.ordered", n, np.intp))
+                if res.fpack is not None:
+                    fidx = take(fidx, order,
+                                out=S("fidx.ordered", n, np.intp))
+        with span("scatter", plan=self.name):
+            tss = np.add(word("__timestamp__", idx),
+                         TIMESTAMP_DTYPE(res.ts_base),
+                         out=np.empty(n, TIMESTAMP_DTYPE))
+            # (the completions are on the host already, in lane-major order)
+            seqs = np.add(take(seq, order, out=S("word", n, _I32))
+                          if alone else seq, np.int64(res.seq_base),
+                          out=np.empty(n, np.int64))
+            data = {}
+            for nm, t in zip(self._names, self._types):
+                pack, w, dt = words[nm]
+                if pack == "f":
+                    src = take(fflat[w * f_word:], fidx,
+                               out=S("f64", n, np.float64))
+                elif dt == np.int64:                    # join64_np
+                    src = np.left_shift(word(nm, idx), 32, dtype=np.int64,
+                                        out=S("i64", n, np.int64))
+                    src |= word(nm, idx, k=1).view(np.uint32)
+                elif dt == np.float32:
+                    src = word(nm, idx).view(np.float32)
+                else:
+                    src = word(nm, idx)
+                col = data[nm] = np.empty(n, dtype_of(t))
+                if t == ast.AttrType.BOOL:
+                    np.not_equal(src, 0, out=col)
+                else:
+                    col[...] = src
+        if alone:
+            return RuleRuns(tss, seqs, data, lanes, starts)
+        return (tss, seqs, hseq.copy(), data, {}, lane.copy())
+
+    def _rule_order(self, lane, seq, hseq) -> np.ndarray:
+        """The permutation `np.lexsort((hseq, seq, lane))` returns, for
+        rows that stand as a fused result's do: lane-major, a lane's grid
+        rows in rising and disjoint completion ranges (each row's dedup
+        bound is the last event before it), a cell's matches in head
+        order (the block compacts them by head index).  Then the rows are
+        a run a lane of nearly sorted rows, and ONE stable sort of ONE
+        key, `lane * span + seq - seq.min()`, orders them.  Whether that
+        IS the lexsort is read off the result: rows of equal key must
+        come with their head seqs rising.  Where they do not (unstamped
+        batches whose seqs restart, a final-count burst that emits a
+        head's rows out of head order), or the key has no room (lanes x
+        span past 31 bits), the three-key sort serves.  Counted
+        (`fused.route_order`)."""
+        S, n = self._scratch, len(seq)
+        lo = int(seq.min())
+        span = int(seq.max()) - lo + 1
+        if (int(lane.max()) + 1) * span < 1 << 31:
+            key = np.multiply(lane, span, out=S("key", n, _I32),
+                              casting="unsafe")
+            key += np.subtract(seq, lo, out=S("rel", n, _I32),
+                               casting="unsafe")
+            order = np.argsort(key, kind="stable")
+            keys = _take(key, order, out=S("key.sorted", n, _I32))
+            heads = _take(hseq, order, out=S("heads", n, hseq.dtype))
+            tie = np.equal(keys[1:], keys[:-1], out=S("tie", n - 1, bool))
+            tie &= np.less(heads[1:], heads[:-1],
+                           out=S("falls", n - 1, bool))
+            if not tie.any():
+                self._route_order["keyed"] += 1
+                return order
+        self._route_order["lexsort"] += 1
+        return np.lexsort((hseq, seq, lane))
+
+    def _rule_runs(self, table: tuple) -> RuleRuns:
+        """A flat fused flush's match table (_multi_table) in delivery
+        order: its table is lane-major as a cut one's is (_unpack_lanes),
+        a lane one cell."""
+        tss, seqs, hseqs, data, qids = table
+        order = self._rule_order(qids, seqs, hseqs)
+        qids = qids[order]
+        starts = np.flatnonzero(np.r_[True, qids[1:] != qids[:-1]])
+        return RuleRuns(tss[order].astype(TIMESTAMP_DTYPE, copy=False),
+                        seqs[order], {k: v[order] for k, v in data.items()},
+                        qids[starts], starts)
 
     def _unpack_block(self, ipack, fpack, n: int):
         """Columnar match table from one flat block's packed output."""
@@ -1974,9 +2209,19 @@ class DevicePatternPlan(QueryPlan):
         return self._multi_table(self._pipe.collect())
 
     def _multi_table(self, chunks: list):
+        """The flush's matches from its results: a cut result alone is
+        decoded straight into delivery order (RuleRuns); anything else
+        becomes one flat table for _rule_runs to order."""
         chunks = [c for c in chunks if c is not None]
         if not chunks:
             return None
+        cut = [isinstance(c, CutResult) for c in chunks]
+        self._result_decode["indexed"] += sum(cut)
+        self._result_decode["masked"] += len(cut) - sum(cut)
+        if len(chunks) == 1 and cut[0]:
+            return self._decode_cut(chunks[0], alone=True)
+        chunks = [self._decode_cut(c, alone=False) if is_cut else c
+                  for c, is_cut in zip(chunks, cut)]
         if len(chunks) == 1:
             tss, seqs, hseqs, data, _nulls, qids = chunks[0]
             return (tss, seqs, hseqs, data, qids)

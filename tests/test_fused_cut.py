@@ -372,6 +372,316 @@ def test_rows_of_one_rule_arrive_in_the_order_of_their_last_event(rows64):
         assert [r[0] for r in b] == sorted(r[0] for r in b)
 
 
+# -- the decode of a cut flush's result ---------------------------------------------
+
+TYPED = ("@app:playback\n@app:devicePrecision('f64')\n"
+         "define stream T (price double, vol long, qty int);\n")
+
+
+def typed_app(n=10, head=""):
+    """Rules whose outputs take every form the pack has: f64 in the `f`
+    pack, an i64 hi / lo pair, an i32 word, a BOOL."""
+    return head + TYPED + "".join(
+        f"@info(name='q{i}') from every e1=T[price > {123 + i % 6}] -> "
+        f"e2=T[price > e1.price] within 1 sec select e1.price as p1, "
+        f"e2.vol as v, e2.qty as q, e2.price > e1.price + 1.0 as hot, "
+        f"e2.price as p2 insert into Out{i % 4};\n" for i in range(n))
+
+
+def fused_plan(app):
+    mgr = SiddhiManager()
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", RuntimeWarning)
+        rt = mgr.create_app_runtime(app)
+    plan, = [p for p in rt._plans
+             if isinstance(p, MultiQueryDevicePatternPlan)]
+    return mgr, plan
+
+
+def packed(plan, rng, counts, M, row_events=1000, seqs_of=None):
+    """A cut fused flush's result as the block packs it, (rows, lanes,
+    words, M) i32 (+ f64 `f` pack): `counts[r, l]` matches in lane-row
+    (r, l), heads rising inside it, completions in row r's own range, a
+    few events after the head (so many rows tie on one completion); every
+    other word, and every cell past a count, random."""
+    inner = plan.inner
+    R, L = counts.shape
+    words = inner._out_words()
+    n_i = 1 + sum(2 if dt == np.int64 else 1
+                  for pack, _w, dt in words.values() if pack == "i")
+    n_f = sum(pack == "f" for pack, _w, _dt in words.values())
+    ipack = rng.integers(-2 ** 20, 2 ** 20, (R, L, n_i, M)).astype(np.int32)
+    fpack = rng.random((R, L, n_f, M)) if n_f else None
+    ipack[:, :, 0, :] = 0
+    ipack[:, :, 0, 0] = counts
+    at = {nm: words[nm][1] for nm in
+          ("__timestamp__", "__seq__", "__head_seq__", "__qid__")}
+    for r in range(R):
+        for ln in range(L):
+            c = min(int(counts[r, ln]), M)
+            head = np.sort(rng.integers(r * row_events,
+                                        (r + 1) * row_events, c))
+            seq = np.minimum(head + rng.integers(0, 4, c) * 5,
+                             (r + 1) * row_events - 1)
+            if seqs_of is not None:
+                head, seq = seqs_of(r, ln, head, seq)
+            ipack[r, ln, at["__seq__"], :c] = seq
+            ipack[r, ln, at["__timestamp__"], :c] = seq * 50
+            ipack[r, ln, at["__head_seq__"], :c] = head
+            ipack[r, ln, at["__qid__"], :] = ln
+    return ipack, fpack
+
+
+BASES = {"ts_base": T0, "seq_base": 7_000_000_000}
+
+
+def new_form(plan, results, tick=()):
+    """The production path from the pulled results on: `_materialize_par`
+    (given numpy arrays where the device's would be), `_multi_table`,
+    `_route`."""
+    inner = plan.inner
+    chunks = list(tick)
+    for ipack, fpack in results:
+        out = {"i": ipack} if fpack is None else {"i": ipack, "f": fpack}
+        chunks.append(inner._materialize_par(
+            {"out": out, "M": ipack.shape[-1], "L": ipack.shape[1],
+             "R": ipack.shape[0], **BASES}))
+    return plan._route(inner._multi_table(chunks))
+
+
+def old_form(plan, results, tick=()):
+    """The decode and the routing as they were before PR 41, kept as the
+    reference: every word read under a capacity-sized mask, `_unpack_rows`
+    (untouched, the partitioned plans' own), one three-key lexsort, a
+    slice a rule."""
+    inner = plan.inner
+    chunks = list(tick)
+    for ipack, fpack in results:
+        ipack = ipack[:, :inner._lanes_real]
+        filled = np.arange(ipack.shape[-1]) < ipack[:, :, 0, 0][..., None]
+        ip2 = [None] + [ipack[:, :, r, :][filled]
+                        for r in range(1, ipack.shape[2])]
+        fp2 = None if fpack is None else [
+            fpack[:, :inner._lanes_real, r, :][filled]
+            for r in range(fpack.shape[2])]
+        inner._ts_base, inner._seq_base = BASES["ts_base"], BASES["seq_base"]
+        chunks.append(inner._unpack_rows(ip2, fp2,
+                                         np.ones(len(ip2[1]), bool)))
+    chunks = [c for c in chunks if c is not None]
+    tss, seqs, hseqs, qids = (np.concatenate([c[k] for c in chunks])
+                              for k in (0, 1, 2, 5))
+    data = {nm: np.concatenate([c[3][nm] for c in chunks])
+            for nm in inner._names}
+    order = np.lexsort((hseqs, seqs, qids))
+    tss, seqs, qids = tss[order], seqs[order], qids[order]
+    data = {k: v[order] for k, v in data.items()}
+    starts = np.flatnonzero(np.r_[True, qids[1:] != qids[:-1]])
+    res = []
+    for a, b in zip(starts.tolist(), np.r_[starts[1:], len(qids)].tolist()):
+        qi = int(qids[a])
+        res.append((plan.targets[qi], plan.query_names[qi], tss[a:b],
+                    seqs[a:b], {nm: data[src][a:b] for nm, src in zip(
+                        plan.per_q_names[qi], inner._names)}))
+    return res
+
+
+def assert_same_batches(got, want):
+    assert len(got) == len(want)
+    for ob, (target, name, tss, sq, cols) in zip(got, want):
+        assert (ob.target, ob.callback_name) == (target, name)
+        assert ob.batch.n == len(tss)
+        for mine, theirs in ((ob.batch.timestamps, tss), (ob.batch.seqs, sq)):
+            assert mine.dtype == theirs.dtype
+            assert np.array_equal(mine, theirs)
+        assert list(ob.batch.columns) == list(cols)
+        for nm in cols:
+            assert ob.batch.columns[nm].dtype == cols[nm].dtype, nm
+            assert np.array_equal(      # random words are NaNs as well
+                ob.batch.columns[nm], cols[nm],
+                equal_nan=cols[nm].dtype.kind == "f"), nm
+
+
+def _restart(r, ln, head, seq):
+    """Unstamped batches: every row's seqs start again, so two rows of a
+    lane hold the same completions and the later row's heads come first."""
+    return head % 1000, seq % 1000
+
+
+def _wide(r, ln, head, seq):
+    """Completions across 2^28 seqs: 12 lanes x the span is past 31 bits."""
+    return head, seq + r * (1 << 26)
+
+
+def _heads_fall(r, ln, head, seq):
+    """A final-count burst: a head's rows come out of head order."""
+    return head[::-1], np.full_like(seq, seq.max(initial=0))
+
+
+# name -> (app, counts(rng, R, L, M), seqs_of, the order it must take)
+DECODES = {
+    "uniform": (None, lambda rng, R, L, M: rng.integers(0, M // 2, (R, L)),
+                None, "keyed"),
+    "mostly_empty_lane_rows": (
+        None, lambda rng, R, L, M: rng.integers(0, M // 2, (R, L))
+        * (rng.random((R, L)) < 0.2), None, "keyed"),
+    "a_lane_row_at_capacity": (
+        None, lambda rng, R, L, M: np.where(
+            rng.random((R, L)) < 0.3, M, rng.integers(0, 3, (R, L))),
+        None, "keyed"),
+    "one_rule_holds_every_row": (
+        None, lambda rng, R, L, M: rng.integers(1, M, (R, L))
+        * (np.arange(L) == 7), None, "keyed"),
+    "one_row_in_all": (
+        None, lambda rng, R, L, M: (np.arange(R * L).reshape(R, L) == 17)
+        .astype(np.int64), None, "keyed"),
+    "f64_i64_i32_bool_outputs": (
+        typed_app(), lambda rng, R, L, M: rng.integers(0, M, (R, L)),
+        None, "keyed"),
+    "lanes_padded_by_a_mesh": (
+        typed_app(10, "@app:deviceMesh('always')\n"),
+        lambda rng, R, L, M: rng.integers(0, M, (R, L)), None, "keyed"),
+    "seqs_that_restart": (
+        None, lambda rng, R, L, M: rng.integers(M // 2, M, (R, L)),
+        _restart, "lexsort"),
+    "a_span_past_the_key": (
+        None, lambda rng, R, L, M: rng.integers(0, M // 2, (R, L)),
+        _wide, "lexsort"),
+    "heads_out_of_order_in_a_cell": (
+        None, lambda rng, R, L, M: rng.integers(2, M // 2, (R, L)),
+        _heads_fall, "lexsort"),
+}
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("case", list(DECODES))
+def test_the_decode_equals_the_mask_form_row_for_row(case, seed):
+    app, counts_of, seqs_of, order = DECODES[case]
+    mgr, plan = fused_plan(app or app_of(0))
+    inner = plan.inner
+    rng = np.random.default_rng(100 * seed + len(case))
+    R, M = 6, 32
+    counts = np.zeros((R, inner.P), np.int64)
+    counts[:, :inner._lanes_real] = counts_of(rng, R, inner._lanes_real, M)
+    if "mesh" in case:
+        if inner._lanes_real == inner.P:
+            pytest.skip("needs a mesh that pads the lane axis")
+        counts[:, inner._lanes_real:] = M + 5   # nobody's, and no overflow
+    ipack, fpack = packed(plan, rng, counts, M, seqs_of=seqs_of)
+    got = new_form(plan, [(ipack, fpack)])
+    assert_same_batches(got, old_form(plan, [(ipack, fpack)]))
+    assert sum(ob.batch.n for ob in got) \
+        == counts[:, :inner._lanes_real].sum() > 0
+    fused = plan.fused
+    assert fused["result_decode"] == {"indexed": 1, "masked": 0}
+    assert fused["route_order"] == {
+        "keyed": int(order == "keyed"), "lexsort": int(order == "lexsort")}
+    mgr.shutdown()
+
+
+def test_the_decode_reads_a_result_in_whatever_axis_order_it_was_laid():
+    """The pulled array's strides are the device's to choose (on the chip
+    the partitioned result comes words-major): the index follows them."""
+    mgr, plan = fused_plan(typed_app())
+    rng = np.random.default_rng(5)
+    counts = rng.integers(0, 24, (4, plan.inner.P))
+    ipack, fpack = packed(plan, rng, counts, 24)
+    want = old_form(plan, [(ipack, fpack)])
+    for axes in ((2, 0, 1, 3), (1, 0, 3, 2), (3, 2, 1, 0)):
+        back = np.argsort(axes)
+        laid = [np.ascontiguousarray(a.transpose(axes)).transpose(back)
+                for a in (ipack, fpack)]
+        assert laid[0].shape == ipack.shape
+        assert not laid[0].flags.c_contiguous
+        assert_same_batches(new_form(plan, [laid]), want)
+    mgr.shutdown()
+
+
+def test_an_index_past_the_result_raises_and_reads_no_neighbour(monkeypatch):
+    """The decode's takes clip (they write into scratch), so what
+    `mode="raise"` would have checked is checked once a result: strides
+    that put a cell outside the pack stop the flush."""
+    mgr, plan = fused_plan(app_of(0))
+    rng = np.random.default_rng(3)
+    result = packed(plan, rng, rng.integers(1, 16, (4, plan.inner.P)), 16)
+    flat_words = pattern_plan._flat_words
+
+    def doubled(a):
+        flat, strides = flat_words(a)
+        return flat, tuple(2 * s for s in strides)
+    monkeypatch.setattr(pattern_plan, "_flat_words", doubled)
+    with pytest.raises(IndexError, match="decode index past the result"):
+        new_form(plan, [result])
+    mgr.shutdown()
+
+
+@pytest.mark.parametrize("tick_first", [True, False])
+def test_a_tick_chunk_beside_the_cut_result_is_one_batch_a_rule(tick_first):
+    """A flush of several chunks: the rows a timer tick produced (a flat
+    table under a mask) and the cut result's join into one table, ordered
+    as one: a rule still gets ONE batch."""
+    mgr, plan = fused_plan(app_of(0))
+    inner = plan.inner
+    rng = np.random.default_rng(8)
+    counts = rng.integers(0, 16, (5, inner.P))
+    result = packed(plan, rng, counts, 32)
+    n = 40
+    seqs = np.sort(rng.integers(0, 900, n)) + BASES["seq_base"]
+    tick = (T0 + seqs, seqs, (seqs - BASES["seq_base"] - 3).astype(np.int32),
+            {nm: rng.random(n) for nm in inner._names}, {},
+            rng.integers(0, plan.n_queries, n).astype(np.int32))
+    ticks = [tick] if tick_first else []
+    got = new_form(plan, [result], tick=ticks)
+    assert_same_batches(got, old_form(plan, [result], tick=ticks))
+    assert len({ob.callback_name for ob in got}) == len(got)
+    fused = plan.fused
+    assert fused["result_decode"] == {"indexed": 1,
+                                      "masked": int(tick_first)}
+    assert fused["route_order"] == {"keyed": 1, "lexsort": 0}
+    # two cut results in one collect (a drained pipeline) join the same way
+    got = new_form(plan, [result, result])
+    assert_same_batches(got, old_form(plan, [result, result]))
+    mgr.shutdown()
+
+
+def test_delivered_memory_is_the_batch_s_own():
+    """Flush n's arrays are kept (a callback may); flushes n+1 (larger)
+    and n+2 (smaller) decode through the same scratch: the kept arrays
+    stay as they were, and nothing delivered is the plan's scratch or the
+    pulled result."""
+    mgr, plan = fused_plan(typed_app())
+    inner = plan.inner
+    rng = np.random.default_rng(12)
+    kept = []
+    for hi in (12, 32, 4):
+        ipack, fpack = packed(
+            plan, rng, rng.integers(0, hi, (6, inner.P)), 32)
+        obs = new_form(plan, [(ipack, fpack)])
+        arrays = [a for ob in obs for a in (
+            ob.batch.timestamps, ob.batch.seqs, *ob.batch.columns.values())]
+        scratch = [*inner._scratch._bufs.values()]
+        assert len(scratch) > 8
+        for a in arrays:
+            assert not any(np.shares_memory(a, b)
+                           for b in (*scratch, ipack, fpack))
+        kept.append((arrays, [a.copy() for a in arrays]))
+        # the flat form's batches too (the order it gathers through is
+        # scratch)
+        n = 50
+        seqs = np.sort(rng.integers(0, 900, n)) + BASES["seq_base"]
+        flat = plan._route((T0 + seqs, seqs, (seqs % 7).astype(np.int32),
+                            {nm: rng.random(n) for nm in inner._names},
+                            rng.integers(0, plan.n_queries, n)
+                            .astype(np.int32)))
+        for ob in flat:
+            for a in (ob.batch.timestamps, ob.batch.seqs,
+                      *ob.batch.columns.values()):
+                assert not any(np.shares_memory(a, b) for b in scratch)
+    for arrays, copies in kept:
+        for a, c in zip(arrays, copies):
+            assert np.array_equal(a, c)
+    mgr.shutdown()
+
+
 # -- the seq family: single arms that are spent ----------------------------------
 
 @pytest.mark.parametrize("kind", [1, 2])
@@ -427,16 +737,31 @@ def test_spans_route_and_lane_cut_and_the_fused_record():
     batches = tape(19, 2500, 2)
     _d, ex, plans, st = run("", app_of(0), batches, stats=True)
     stages = st["stages"]
-    assert stages["route"]["batches"] == 2
+    # a cut flush opens `route` twice: the order, then a slice a rule
+    # around `scatter`, the fetch of the payload; `unpack` for the counts
+    # and for the index over the filled cells and the key words
+    assert stages["route"]["batches"] == 4
+    assert stages["unpack"]["batches"] == 4
+    delivered = sum(len(g) for g in _d)
+    assert stages["scatter"]["batches"] == 2 + delivered
     assert stages["lane_cut"]["batches"] == 2
     assert stages["lane_cut"]["seconds"] <= stages["host_build"]["seconds"]
     ent = ex["queries"][plans[0].name]
     assert ent["kind"] == "multi_query" and "family" not in ent
     assert list(ent["fused"]) == [
         "queries", "padded_lanes", "family", "first_hit", "indexed_read",
-        "compaction", "lane_cut", "arms_resolved", "dispatches_skipped"]
+        "compaction", "lane_cut", "arms_resolved", "dispatches_skipped",
+        "result_decode", "route_order"]
     assert sorted(ent["fused"]["lane_cut"]) == [
         "cut_length", "events_replayed", "flushes_cut",
         "flushes_uncuttable", "rows"]
-    _d, _ex, _p, st = run("", app_of(0), tape(19, 200, 2), stats=True)
-    assert "lane_cut" not in st["stages"] and "route" in st["stages"]
+    assert ent["fused"]["result_decode"] == {"indexed": 2, "masked": 0}
+    assert ent["fused"]["route_order"] == {"keyed": 2, "lexsort": 0}
+    assert plans[0].device_metrics()["fused"] == ent["fused"]
+    # a flush within a row: the flat form, under a mask, the same keyed order
+    _d, ex, plans, st = run("", app_of(0), tape(19, 200, 2), stats=True)
+    assert "lane_cut" not in st["stages"]
+    assert st["stages"]["route"]["batches"] == 2
+    fused = ex["queries"][plans[0].name]["fused"]
+    assert fused["result_decode"] == {"indexed": 0, "masked": 2}
+    assert fused["route_order"] == {"keyed": 2, "lexsort": 0}

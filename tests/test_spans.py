@@ -334,7 +334,7 @@ FUSED = STOCK + "".join(
 # spans the plan opens a flush: what they were before `unpack` had a span)
 RESULT_PATHS = {
     "lane": (PATTERN, 2048, 1, "_unpack_lanes", 3),
-    "fused-row": (FUSED, 512, 50, "_unpack_lane_rows", 1),
+    "fused-row": (FUSED, 512, 50, "_decode_cut", 1),
     "flat-block": (FLAT.format(within="within 1 sec "), 256, 1,
                    "_unpack_block", 3),
     "seq-block": (FLAT.format(within=""), 256, 1, "_unpack_block", 2),
@@ -354,11 +354,11 @@ def _run_result_path(path, monkeypatch, header="@app:trace('all')\n",
         monkeypatch.setattr(pattern_plan, "FUSED_ROW_WINDOWS", 2)
         monkeypatch.setattr(pattern_plan, "FUSED_ROW_MIN", 16)
     calls = {}
-    for fn in ("_unpack_lanes", "_unpack_lane_rows", "_unpack_block"):
+    for fn in ("_unpack_lanes", "_decode_cut", "_unpack_block"):
         def counted(self, *a, _o=getattr(pattern_plan.DevicePatternPlan, fn),
-                    _f=fn):
+                    _f=fn, **kw):
             calls[_f] = calls.get(_f, 0) + 1
-            return _o(self, *a)
+            return _o(self, *a, **kw)
         monkeypatch.setattr(pattern_plan.DevicePatternPlan, fn, counted)
     mgr = SiddhiManager()
     rt = mgr.create_app_runtime(header + app)
