@@ -202,6 +202,17 @@ def _offsets32(a: np.ndarray, base: int, lo: int) -> np.ndarray:
 # and the dense queries cost cut^2 a row.
 LANE_CUT = DENSE_MAX_F // 2
 
+# What `lane_fill` counts of a partitioned lane-grid flush, in this order:
+# the lanes with new events, the grid rows they were padded to (a cut lane
+# is several) and the quiet lanes whose tails were held apart; the new
+# events and the events replayed in front of them from the lanes' tails;
+# the grid's cells that hold an event and all of them (rows x F); the cells
+# of the packed result as pulled (rows x words x M, every pull of a flush)
+# and the match rows it carried.
+LANE_FILL = ("lanes_active", "lanes_padded", "lanes_held", "events_new",
+             "events_replayed", "cells_filled", "cells_total",
+             "result_cells", "rows_delivered")
+
 
 # The row length of a cut FUSED flush (_fused_cut).  Every fused lane sees
 # the one shared stream, so a flush longer than a row is laid out ONCE as
@@ -323,6 +334,11 @@ class DevicePatternPlan(QueryPlan):
         # device_metrics `lane_cut`)
         self._lane_cut = {"flushes_cut": 0, "lanes_cut": 0, "rows_added": 0,
                           "events_replayed": 0, "flushes_uncuttable": 0}
+        # how full the partitioned lane grids and their results were
+        # (EXPLAIN / device_metrics `lane_fill`): the sums over the
+        # flushes materialised, the last of them, and flushes by grid
+        self._lane_fill = {"flushes": 0, "total": dict.fromkeys(LANE_FILL, 0),
+                           "last": None, "grids": {}}
 
         # what cutting a fused (broadcast) flush into rows did so far
         # (EXPLAIN / device_metrics `fused.lane_cut`), and the sticky
@@ -914,6 +930,24 @@ class DevicePatternPlan(QueryPlan):
         return {**self._lane_cut, "cut_length": LANE_CUT}
 
     @property
+    def lane_fill(self) -> Optional[dict]:
+        """How full the partitioned lane grids were (EXPLAIN), once a
+        flush has been materialised: `total`, the sums over `flushes`, and
+        `last`, the newest flush alone, of LANE_FILL's counts (`last` also
+        has the grid's `F` and the result's `M`); `grids`, flushes by
+        `"<rows>x<F>x<M>"`: more than one entry is a geometry that moved
+        (each new one a compilation).  What the ratios say: `events_replayed`
+        over `events_new`, the tail replay the host pays for keeping no
+        pattern state on the device; `cells_total` over `cells_filled`, the
+        padding uploaded; `result_cells` over `rows_delivered`, the capacity
+        pulled for every row carried."""
+        did = self._lane_fill
+        if not did["flushes"]:
+            return None
+        return {"flushes": did["flushes"], "total": dict(did["total"]),
+                "last": dict(did["last"]), "grids": dict(did["grids"])}
+
+    @property
     def fused(self) -> Optional[dict]:
         """What a fused (broadcast) plan ran (EXPLAIN `fused`, beside the
         rule count its MultiQueryDevicePatternPlan adds): the family, the
@@ -1033,6 +1067,9 @@ class DevicePatternPlan(QueryPlan):
         cut = self.lane_cut
         if cut:
             d["lane_cut"] = cut
+        fill = self.lane_fill
+        if fill:
+            d["lane_fill"] = fill
         return d
 
     # -- QueryPlan interface -------------------------------------------------
@@ -1620,6 +1657,8 @@ class DevicePatternPlan(QueryPlan):
             lane_n = np.bincount(part, minlength=len(self._key_to_part))
             tl = self._lane_tail
             held = tail_n = None
+            fill = {"events_new": len(ts), "events_replayed": 0,
+                    "lanes_held": 0}
             if tl is not None:
                 # only lanes with NEW events this flush replay their
                 # tail; a quiet lane cannot produce a new completion
@@ -1628,24 +1667,29 @@ class DevicePatternPlan(QueryPlan):
                 # pin the shared i32 ts/seq bases forever (review
                 # finding: a long-quiet lane saturated every live
                 # lane's offsets at the 2^30 clip)
-                active = lane_n[tl["part"]] > 0
-                if not active.all():
-                    held = _tail_rows(tl, ~active)
-                    tl = _tail_rows(tl, active)
-                tail_n = np.bincount(tl["part"], minlength=len(lane_n))
-                lane_n = lane_n + tail_n
-                ts = np.concatenate([tl["ts"], ts])
-                seq = np.concatenate([tl["seq"], seq])
-                scode = np.concatenate([tl["scode"], scode])
-                part = np.concatenate([tl["part"], part])
-                cols = {k: np.concatenate([tl["cols"][k], v])
-                        for k, v in cols.items()}
+                with self.rt.span("lane_tail", plan=self.name):
+                    active = lane_n[tl["part"]] > 0
+                    if not active.all():
+                        held = _tail_rows(tl, ~active)
+                        tl = _tail_rows(tl, active)
+                        # a lane's rows are contiguous in a tail
+                        fill["lanes_held"] = 1 + int(np.count_nonzero(
+                            held["part"][1:] != held["part"][:-1]))
+                    tail_n = np.bincount(tl["part"], minlength=len(lane_n))
+                    lane_n = lane_n + tail_n
+                    fill["events_replayed"] = len(tl["ts"])
+                    ts = np.concatenate([tl["ts"], ts])
+                    seq = np.concatenate([tl["seq"], seq])
+                    scode = np.concatenate([tl["scode"], scode])
+                    part = np.concatenate([tl["part"], part])
+                    cols = {k: np.concatenate([tl["cols"][k], v])
+                            for k, v in cols.items()}
             N = len(ts)
             # the flush's lanes in ascending id, each one run of the
             # lane-ordered rows
             lane_ids = np.flatnonzero(lane_n)
             counts = lane_n[lane_ids]
-            Lr = len(lane_ids)
+            Lr = fill["lanes_active"] = len(lane_ids)
             run_start = np.cumsum(counts) - counts
             run_end = run_start + counts - 1
             order, seq_l = self._lane_order(part, seq, run_start)
@@ -1706,6 +1750,8 @@ class DevicePatternPlan(QueryPlan):
             if self.mesh is not None:
                 nd = self.mesh.devices.size
                 Lpad = -(-Lpad // nd) * nd      # even lane shards
+            fill.update(lanes_padded=Lpad, cells_filled=N,
+                        cells_total=Lpad * F, F=F)
 
             # bases anchor at the flush MAX with i32 headroom (like the
             # dense path): a lane resuming after a >2^30 ms / seq gap
@@ -1752,25 +1798,28 @@ class DevicePatternPlan(QueryPlan):
             # rows are gathered, in lane order.  By LANE (run_end,
             # counts, lane_ids), never by grid row: a cut lane's tail is
             # its last window whatever rows it was laid out as.
-            keep = order[tsmono >= np.repeat(tsmono[run_end] - W, counts)]
-            self._lane_tail = _tail_rows(
-                {"ts": ts, "seq": seq, "scode": scode, "part": part,
-                 "cols": cols}, keep)
-            if held is not None:
-                # quiet lanes' tails ride along untouched: their lanes
-                # are none of the kept ones, so every lane's rows stay
-                # contiguous and in seq order (_lane_order's invariant)
-                self._lane_tail = {
-                    k: (np.concatenate([self._lane_tail[k], held[k]])
-                        if k != "cols" else
-                        {c: np.concatenate([self._lane_tail["cols"][c],
-                                            held["cols"][c]])
-                         for c in held["cols"]})
-                    for k in self._lane_tail}
+            with self.rt.span("lane_tail", plan=self.name):
+                keep = order[tsmono >= np.repeat(tsmono[run_end] - W,
+                                                 counts)]
+                self._lane_tail = _tail_rows(
+                    {"ts": ts, "seq": seq, "scode": scode, "part": part,
+                     "cols": cols}, keep)
+                if held is not None:
+                    # quiet lanes' tails ride along untouched: their lanes
+                    # are none of the kept ones, so every lane's rows stay
+                    # contiguous and in seq order (_lane_order's invariant)
+                    self._lane_tail = {
+                        k: (np.concatenate([self._lane_tail[k], held[k]])
+                            if k != "cols" else
+                            {c: np.concatenate([self._lane_tail["cols"][c],
+                                                held["cols"][c]])
+                             for c in held["cols"]})
+                        for k in self._lane_tail}
             self._lane_prev[lane_ids] = seq_l[run_end]
 
-        return self._pipe.push(self._dispatch_par(
-            ev, F, F, ts_base, seq_base, lanes=Lpad))
+        entry = self._dispatch_par(ev, F, F, ts_base, seq_base, lanes=Lpad)
+        entry["fill"] = fill        # counted when the flush materialises
+        return self._pipe.push(entry)
 
     def _dispatch_par(self, ev, F, M, ts_base, seq_base,
                       lanes=None, rows=None) -> dict:
@@ -1808,8 +1857,10 @@ class DevicePatternPlan(QueryPlan):
 
     def _materialize_par(self, e: dict):
         lanes, rows = e.get("L"), e.get("R")
+        fill, pulled = e.get("fill"), 0
         while True:
             ipack, fpack = self._pull(e["out"])
+            pulled += ipack.size + (0 if fpack is None else fpack.size)
             with self.rt.span("unpack", plan=self.name):
                 if rows:
                     # lanes a mesh padded the group with: their rows are
@@ -1856,8 +1907,26 @@ class DevicePatternPlan(QueryPlan):
             return CutResult(ipack, fpack, counts, e["ts_base"],
                              e["seq_base"]) if n else None
         if lanes:
-            return self._unpack_lanes(ipack, fpack)
+            table = self._unpack_lanes(ipack, fpack)
+            if fill is not None:
+                self._note_fill(fill, e["M"], pulled,
+                                0 if table is None else len(table[0]))
+            return table
         return self._unpack_block(ipack, fpack, n)
+
+    def _note_fill(self, fill: dict, M: int, result_cells: int,
+                   rows: int) -> None:
+        """Count one materialised partitioned flush into `lane_fill`:
+        what the pack noted at dispatch (`fill`) with the result's side."""
+        did = self._lane_fill
+        last = {**dict.fromkeys(LANE_FILL, 0), **fill, "M": M,
+                "result_cells": result_cells, "rows_delivered": rows}
+        for k in LANE_FILL:
+            did["total"][k] += last[k]
+        grid = f"{last['lanes_padded']}x{last['F']}x{M}"
+        did["grids"][grid] = did["grids"].get(grid, 0) + 1
+        did["last"] = last
+        did["flushes"] += 1
 
     def _dispatch_chunk(self, ev, K, T, M, ts_base, seq_base) -> dict:
         with self.rt.span("host_build", plan=self.name):

@@ -68,7 +68,8 @@ SPANS = (
     "parse", "plan", "compile",                         # build
     "net.wait", "net.decode", "admit", "queue_wait",    # wire
     "ingest", "frame", "freeze", "wal.append",          # ingest
-    "dispatch", "host_build", "lane_cut", "kernel",     # dispatch
+    "dispatch", "host_build", "lane_cut", "lane_tail",  # dispatch
+    "kernel",
     "transfer", "transfer.wait", "transfer.copy",
     "unpack", "scatter", "route", "emit",
     "sink.publish", "sink.encode", "sink.send",         # egress

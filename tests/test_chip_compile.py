@@ -74,3 +74,57 @@ def test_lane_block_compiles_for_the_chip_with_no_indexed_operation(
     # lanes * F * M * 4 bytes
     assert compiled.memory_analysis().temp_size_in_bytes \
         < lanes * F_ * F_ * 4 / 64
+
+
+def test_pattern200ks_block_compiles_for_the_chip_at_its_stated_size(topo):
+    """`pattern200k.sat`'s flush: 262,144 lanes of 64 events, the key
+    captured (a fourth column in, an eighth word out).  One chip holds it:
+    what a call uploads and returns is the H2D and D2H the cell reports
+    (1,032 and 2,048 bytes an event of a 2^18-event batch)."""
+    import os
+    import warnings
+    from siddhi_tpu import SiddhiManager
+    from siddhi_tpu.core.nfa_parallel import ParallelChainKernel
+    from siddhi_tpu.core.pattern_plan import DevicePatternPlan
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "apps",
+                           "pattern200k.siddhi")) as f:
+        app = f.read().replace("{source}", "").replace("{sink}", "")
+    mgr = SiddhiManager()
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", RuntimeWarning)
+        rt = mgr.create_app_runtime("@app:partitionCapacity(16)\n" + app)
+    plan = next(p for p in rt._plans if isinstance(p, DevicePatternPlan))
+    kern = plan._parallel_kernel()
+    mgr.shutdown()
+    kern = ParallelChainKernel(kern.prog, kern.nfak, kern.family)
+    lanes, F_ = 1 << 18, 64
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def of(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    ev = {"__flat.__ts__": of((lanes, F_), jnp.int32),
+          "__flat.__seq__": of((lanes, F_), jnp.int32),
+          "__nev__": of((lanes,), jnp.int32),
+          "__prev_seq__": of((lanes,), jnp.int32),
+          "__base_ts__": of((), jnp.int64),
+          "__base_seq__": of((), jnp.int64),
+          "__flat.0.price": of((lanes, F_), jnp.float32),
+          "__flat.0.symbol": of((lanes, F_), jnp.int32)}
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = kern.block_fn((lanes, F_), F_).lower({}, ev).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+    ops = Counter(re.findall(r"= \S+ ([a-z\-]+)\(", compiled.as_text()))
+    assert ops["fusion"] > 0
+    assert {k: ops[k] for k in INDEXED + COLLECTIVES if ops[k]} == {}
+    assert kern.first_hit["dense"] == 3 and kern.first_hit["tree"] == 0
+    assert kern.compaction == {"dense": 2, "scatter": 0,
+                               "pairs_per_call": 2 * lanes * F_ * F_,
+                               "lanes": lanes, "F": F_, "M": F_}
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes // lanes == 4 * F_ * 4 + 8     # 1,032
+    assert mem.output_size_in_bytes == lanes * 8 * F_ * 4   # 2,048 an event
+    assert mem.temp_size_in_bytes < 1 << 30     # 0.67 GB: the chip has 16

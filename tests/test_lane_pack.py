@@ -290,7 +290,7 @@ class Rig:
     def _record(self, ev, F, M, ts_base, seq_base, lanes=None):
         self.got = (ev, F, ts_base, seq_base, lanes)
         assert M == F
-        return None
+        return {}       # the entry: the pack notes its `lane_fill` on it
 
     def _compare(self, want):
         assert self.got is not None, "the flush never reached _dispatch_par"
