@@ -15,8 +15,10 @@ group ships the flat flush, and a flush longer than a row of a few
 events]`, the block then running over rows x lanes (pattern_plan
 `_fused_cut`).  A lane is a rule: the host puts the flush's matches in
 delivery order (rule, completion, head) by one stable sort on one composite
-key, a cut flush's while it decodes the result (pattern_plan `_decode_cut`,
-`_rule_order`), and hands each rule one slice (span `route`).
+key (pattern_plan `_rule_order`), and hands each rule one slice (span
+`route`).  Every result is decoded through one index over its filled cells
+(`_Filled`); a cut flush's alone with that order composed into the index
+(`_decode_cut`), so its columns are written once, in delivery order.
 
 Grouping is automatic: >= MIN_GROUP StateInputStream queries with equal
 shape signatures (and no rate/having/limit) fuse; everything else plans

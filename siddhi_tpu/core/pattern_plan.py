@@ -27,7 +27,7 @@ from ..query import ast
 from .batch import EventBatch
 from .expr import ExprError, MultiStreamContext, compile_expression
 from .nfa_device import (ChainSpec, DeviceNFAUnsupported, LOCAL_SPAN,
-                         NFAKernel, join64_np, lower_chain, pow2_at_least)
+                         NFAKernel, lower_chain, pow2_at_least)
 from .nfa_parallel import DENSE_MAX_F, varies_by_lane
 from .planner import (AGGREGATOR_NAMES, OutputBatch, PlanError, QueryPlan,
                       selector_has_aggregators)
@@ -141,7 +141,7 @@ class _Scratch:
 
 
 # `np.take` into scratch: `mode="raise"`, the default, buffers `out`; what
-# it would have checked, _decode_cut checks once a result (`index`)
+# it would have checked, _Filled checks once a result (`_index`)
 _take = partial(np.take, mode="clip")
 
 
@@ -168,6 +168,82 @@ def _ramp(out: np.ndarray, starts, lens, first, step: int) -> np.ndarray:
     jump[1:] -= jump[:-1] + (lens[:-1] - 1).astype(out.dtype) * step
     out[starts] = jump
     return np.cumsum(out, out=out)
+
+
+class _Filled:
+    """A block's packed result, `(lanes, words, M)` or a cut flush's
+    `(rows, lanes, words, M)`, under ONE index over its filled cells,
+    built from the count header alone (`counts`: matches by lane, or by
+    (row, lane)), lane-major `(lane, [row,] match)`.  Every word is fetched
+    once through it into the plan's scratch: no copy and no mask of the
+    capacity, so a decode costs what its rows cost, at any fill."""
+
+    def __init__(self, plan, counts, ipack, fpack):
+        self.S, self.words, self.plan = plan._scratch, plan._out_words(), plan
+        cnt = counts.T.reshape(-1)              # lane-major cells
+        cells = np.flatnonzero(cnt)
+        self.lens = cnt[cells].astype(np.intp)
+        self.at = np.cumsum(self.lens) - self.lens
+        self.n = int(self.lens.sum())
+        self.cell = np.unravel_index(cells, counts.shape[::-1])
+        self.i = self._index("idx", ipack)
+        self.f = None if fpack is None else self._index("fidx", fpack)
+
+    def _index(self, name: str, pack: np.ndarray) -> list:
+        """[`pack` flat, its word stride, the cells' places in word 0]."""
+        flat, (*s_cell, s_word, s_m) = _flat_words(pack)
+        first = sum(c * s for c, s in zip(self.cell, reversed(s_cell)))
+        idx = _ramp(self.S(name, self.n, np.intp), self.at, self.lens,
+                    first, s_m)
+        # the takes clip: every word's cell must lie in the pack
+        if idx.min() < 0 or int(idx.max()) \
+                + (pack.shape[-2] - 1) * s_word >= len(flat):
+            raise IndexError(
+                f"{self.plan.name}: decode index past the result "
+                f"{pack.shape} (strides {pack.strides})")
+        return [flat, s_word, idx]
+
+    def reindex(self, order: np.ndarray) -> None:
+        """Compose `order` (a permutation, or the cells kept); once."""
+        self.n = len(order)
+        for pack, name in ((self.i, "idx.ordered"), (self.f, "fidx.ordered")):
+            if pack is not None:
+                pack[2] = _take(pack[2], order,
+                                out=self.S(name, self.n, np.intp))
+
+    def word(self, nm: str, buf: str = "word", k: int = 0) -> np.ndarray:
+        """Word `k` of output `nm` as the pack has it, in scratch `buf`."""
+        flat, s_word, idx = self.i
+        return _take(flat[(self.words[nm][1] + k) * s_word:], idx,
+                     out=self.S(buf, self.n, _I32))
+
+    def column(self, nm: str, t) -> np.ndarray:
+        """Output `nm` as the delivered column of type `t`: allocated for
+        this batch, written once (f64 from the `f` pack, its own index)."""
+        pack, w, dt = self.words[nm]
+        if pack == "f":
+            flat, s_word, idx = self.f
+            src = _take(flat[w * s_word:], idx,
+                        out=self.S("f64", self.n, np.float64))
+        elif dt == np.int64:                            # join64_np
+            src = np.left_shift(self.word(nm), 32, dtype=np.int64,
+                                out=self.S("i64", self.n, np.int64))
+            src |= self.word(nm, k=1).view(np.uint32)
+        elif dt == np.float32:
+            src = self.word(nm).view(np.float32)
+        else:
+            src = self.word(nm)
+        col = np.empty(self.n, dtype_of(t))
+        if t == ast.AttrType.BOOL:
+            np.not_equal(src, 0, out=col)
+        else:
+            col[...] = src
+        return col
+
+
+def _on_base(off: np.ndarray, base: int, dtype=np.int64) -> np.ndarray:
+    """The i32 offsets `off` on their flush's `base`, allocated."""
+    return np.add(off, dtype(base), out=np.empty(len(off), dtype))
 
 
 def _tail_rows(t: dict, rows) -> dict:
@@ -349,9 +425,9 @@ class DevicePatternPlan(QueryPlan):
         self._fused_C = self._fused_R = 0
         self._lanes_real = partitions    # before a mesh pads the lane axis
         self._fused_M: Optional[int] = None
-        # a fused plan's results by the form their decode took, and its
-        # flushes by the way their rows were ordered for delivery
-        # (EXPLAIN / device_metrics `fused.result_decode`, `.route_order`)
+        # the plan's results by the form their decode took (EXPLAIN /
+        # device_metrics `result_decode`), and a fused plan's flushes by
+        # the way their rows were ordered for delivery (`fused.route_order`)
         self._result_decode = {"indexed": 0, "masked": 0}
         self._route_order = {"keyed": 0, "lexsort": 0}
         self._scratch = _Scratch()
@@ -948,6 +1024,17 @@ class DevicePatternPlan(QueryPlan):
                 "last": dict(did["last"]), "grids": dict(did["grids"])}
 
     @property
+    def result_decode(self) -> Optional[dict]:
+        """Pulled results that held rows, once one has, by their decode
+        (EXPLAIN; a fused plan's inside `fused`): `indexed`, through one
+        index over the filled cells (_Filled: every lane, cut and one-
+        block result); `masked`, under a mask as wide as the capacity:
+        none is since PR 43, it stays 0."""
+        did = self._result_decode
+        return dict(did) if any(did.values()) and not self.broadcast_events \
+            else None
+
+    @property
     def fused(self) -> Optional[dict]:
         """What a fused (broadcast) plan ran (EXPLAIN `fused`, beside the
         rule count its MultiQueryDevicePatternPlan adds): the family, the
@@ -957,14 +1044,11 @@ class DevicePatternPlan(QueryPlan):
         before a cut; flushes_uncuttable: flushes past a row that kept
         the flat form}, `arms_resolved` (lanes of single-arm rules whose
         arm is spent), `dispatches_skipped` (flushes not dispatched
-        because every arm was), `result_decode` (pulled results that held
-        rows, by the form of their decode: `indexed`, a cut flush's, read
-        through one index over its filled cells, _decode_cut; `masked`,
-        a flat flush's or a tick's, under a validity mask, _unpack_rows)
-        and `route_order` (flushes by the way their rows were put in
-        delivery order: `keyed`, one stable sort on one composite key;
-        `lexsort`, the three-key sort, where the key cannot serve:
-        _rule_order)."""
+        because every arm was), `result_decode` (as the property of that
+        name, which a fused plan shows here) and `route_order` (flushes by
+        the way their rows were put in delivery order: `keyed`, one stable
+        sort on one composite key; `lexsort`, the three-key sort, where
+        the key cannot serve: _rule_order)."""
         if not self.broadcast_events:
             return None
         arms = self._arms_resolved if self._arm_done is None \
@@ -1070,6 +1154,9 @@ class DevicePatternPlan(QueryPlan):
         fill = self.lane_fill
         if fill:
             d["lane_fill"] = fill
+        decode = self.result_decode
+        if decode:
+            d["result_decode"] = decode
         return d
 
     # -- QueryPlan interface -------------------------------------------------
@@ -1862,14 +1949,14 @@ class DevicePatternPlan(QueryPlan):
             ipack, fpack = self._pull(e["out"])
             pulled += ipack.size + (0 if fpack is None else fpack.size)
             with self.rt.span("unpack", plan=self.name):
-                if rows:
-                    # lanes a mesh padded the group with: their rows are
-                    # nobody's, so neither decoded nor a reason to re-run
-                    counts = ipack[:, :self._lanes_real, 0, 0]
-                    n = int(counts.max())
-                else:
-                    n = int(ipack[..., 0, 0].max()) if lanes \
-                        else int(ipack[0, 0])
+                # the count header, read ONCE (the overflow check, then
+                # the decode's index); a cut result's without the lanes a
+                # mesh padded the group with: their rows are nobody's,
+                # neither decoded nor a reason to re-run
+                counts = np.ascontiguousarray(
+                    ipack[:, :self._lanes_real, 0, 0] if rows
+                    else ipack[..., 0, 0])
+                n = int(counts.max())
             if n > e["M"]:      # final-count emission burst: exact retry
                 e = self._dispatch_par(
                     e["ev"], e["F"],
@@ -1906,13 +1993,14 @@ class DevicePatternPlan(QueryPlan):
             # rows are this result's alone
             return CutResult(ipack, fpack, counts, e["ts_base"],
                              e["seq_base"]) if n else None
-        if lanes:
-            table = self._unpack_lanes(ipack, fpack)
-            if fill is not None:
-                self._note_fill(fill, e["M"], pulled,
-                                0 if table is None else len(table[0]))
-            return table
-        return self._unpack_block(ipack, fpack, n)
+        if not lanes:
+            return self._unpack_block(ipack, fpack, n)
+        table = self._decode_lanes(ipack, fpack, counts, e["ts_base"],
+                                   e["seq_base"])
+        if fill is not None:
+            self._note_fill(fill, e["M"], pulled,
+                            0 if table is None else len(table[0]))
+        return table
 
     def _note_fill(self, fill: dict, M: int, result_cells: int,
                    rows: int) -> None:
@@ -2000,29 +2088,14 @@ class DevicePatternPlan(QueryPlan):
         chunks = self._pipe.collect()
         return self._rows_to_batches(chunks) if chunks else []
 
-    def _unpack_lanes(self, ipack, fpack):
-        """Columnar match table from one lane-vmapped block's packed
-        output: (L, rows, M) transposes to (rows, L*M) and the per-lane
-        match counts become one validity mask — the row decode is then
-        identical to the flat path (no per-lane python).  The transpose
-        is a copy of the whole result: span `unpack`, closed before
-        `_unpack_rows` opens `scatter`."""
-        with self.rt.span("unpack", plan=self.name):
-            Ln, rows, Mm = ipack.shape
-            n_l = ipack[:, 0, 0]
-            ip2 = np.swapaxes(ipack, 0, 1).reshape(rows, Ln * Mm)
-            fp2 = (np.swapaxes(fpack, 0, 1).reshape(fpack.shape[1], Ln * Mm)
-                   if fpack is not None else None)
-            base = (np.arange(Mm)[None, :] < n_l[:, None]).reshape(-1)
-        return self._unpack_rows(ip2, fp2, base)
-
     def _out_words(self) -> dict:
         """Where the pack holds each output of `kernel.out_names`, as
-        (pack, first word, word dtype): `i` words from 1 (word 0 is the
-        block's header; a `having` flag would come first and fused plans
-        have none), f32 bit-cast into one, i64 as a hi / lo pair; f64 in
-        the `f` pack.  The order _unpack_rows reads them in."""
+        (pack, first word, word dtype), for _Filled: `i` words from 1
+        (word 0 is the block's header), a `having` flag first, as
+        `__having__`; f32 bit-cast, i64 a hi / lo pair; f64 in the `f` pack."""
         words, ii, fi = {}, 1, 0
+        if self.kernel.having is not None:
+            words["__having__"], ii = ("i", 1, np.dtype(_I32)), 2
         for nm in self.kernel.out_names:
             dt = np.dtype(self.kernel.out_dtypes[nm])
             if dt == np.float64:
@@ -2033,102 +2106,83 @@ class DevicePatternPlan(QueryPlan):
                 ii += 2 if dt == np.int64 else 1
         return words
 
-    def _decode_cut(self, res: CutResult, alone: bool):
-        """A cut fused flush's packed result to host columns, every word
-        read ONCE, through one index over the filled cells.
-
-        `unpack`: the index, from the count header alone, in lane-major
-        order (lane, row, match), so that a rule's rows are one run of
-        nearly sorted rows; and the two key words, completion and head
-        seq, fetched through it.  `route` (when the flush's rows are this
-        result's `alone`): the delivery order (_rule_order), composed
-        into the index.  `scatter`: every delivered column allocated for
-        this batch and written once, in its delivered dtype, from the
-        words fetched through that index.  The fetch goes through the
-        COMPOSED index, not lane-major and then through the order:
-        composed reads stay inside the lane-row they reorder, so they
-        cost what sequential ones do, and the second pass a column is
-        saved (PERF.md section 6, PR 41).
-
-        Alone: RuleRuns.  Beside other chunks (a tick's): the flat table
-        _unpack_rows returns, in lane-major order, for _multi_table to
-        join and _rule_runs to order."""
-        S, span, take = self._scratch, self.rt.span, _take
-        words = self._out_words()
+    def _decode_cut(self, res: CutResult) -> RuleRuns:
+        """A cut fused flush's packed result, the flush's rows its alone,
+        to host columns in delivery order (_Filled).  `unpack`: the index,
+        lane-major (lane, row, match), so that a rule's rows are one run
+        of nearly sorted rows; and the two key words, completion and head
+        seq, fetched through it.  `route`: the delivery order
+        (_rule_order), composed into the index.  `scatter`: every
+        delivered column written once through the COMPOSED index, not
+        lane-major and then through the order: composed reads stay inside
+        the lane-row they reorder, so they cost what sequential ones do,
+        and the second pass a column is saved (PERF.md section 6, PR 41)."""
+        S, span = self._scratch, self.rt.span
+        self._result_decode["indexed"] += 1
         with span("unpack", plan=self.name):
-            n_rows = len(res.counts)
-            cnt = res.counts.T.reshape(-1)          # lane-major cells
-            cells = np.flatnonzero(cnt)
-            lens = cnt[cells].astype(np.intp)
-            at = np.cumsum(lens) - lens
-            n = int(lens.sum())
-            lane_of, row_of = np.divmod(cells, n_rows)
-
-            def index(name, pack):
-                flat, (s_row, s_lane, s_word, s_m) = _flat_words(pack)
-                idx = _ramp(S(name, n, np.intp), at, lens,
-                            row_of * s_row + lane_of * s_lane, s_m)
-                # the takes clip: every word's cell must lie in the pack
-                if idx.min() < 0 or int(idx.max()) \
-                        + (pack.shape[2] - 1) * s_word >= len(flat):
-                    raise IndexError(
-                        f"{self.name}: decode index past the result "
-                        f"{pack.shape} (strides {pack.strides})")
-                return flat, s_word, idx
-            iflat, i_word, idx = index("idx", res.ipack)
-            if res.fpack is not None:
-                fflat, f_word, fidx = index("fidx", res.fpack)
-
-            def word(nm, through, buf="word", k=0):
-                """Word `k` of output `nm` at the cells of index
-                `through`, as it stands in the pack, in scratch `buf`."""
-                return take(iflat[(words[nm][1] + k) * i_word:], through,
-                            out=S(buf, n, _I32))
-            seq = word("__seq__", idx, "seq")
-            hseq = word("__head_seq__", idx, "hseq")
+            got = _Filled(self, res.counts, res.ipack, res.fpack)
+            seq = got.word("__seq__", "seq")
+            hseq = got.word("__head_seq__", "hseq")
             # a lane IS a rule (`__lane_qid__` is arange(P)): its id is
             # read off the layout, the `__qid__` word never fetched
             lane_n = res.counts.sum(axis=0, dtype=np.intp)
             lanes = np.flatnonzero(lane_n)
             starts = np.cumsum(lane_n[lanes]) - lane_n[lanes]
-            lane = _ramp(S("lane", n, _I32), starts, lane_n[lanes], lanes, 0)
-        if alone:
-            with span("route", plan=self.name):
-                order = self._rule_order(lane, seq, hseq)
-                idx = take(idx, order, out=S("idx.ordered", n, np.intp))
-                if res.fpack is not None:
-                    fidx = take(fidx, order,
-                                out=S("fidx.ordered", n, np.intp))
+            lane = _ramp(S("lane", got.n, _I32), starts, lane_n[lanes],
+                         lanes, 0)
+        with span("route", plan=self.name):
+            order = self._rule_order(lane, seq, hseq)
+            got.reindex(order)
         with span("scatter", plan=self.name):
-            tss = np.add(word("__timestamp__", idx),
-                         TIMESTAMP_DTYPE(res.ts_base),
-                         out=np.empty(n, TIMESTAMP_DTYPE))
+            tss = _on_base(got.word("__timestamp__"), res.ts_base,
+                           TIMESTAMP_DTYPE)
             # (the completions are on the host already, in lane-major order)
-            seqs = np.add(take(seq, order, out=S("word", n, _I32))
-                          if alone else seq, np.int64(res.seq_base),
-                          out=np.empty(n, np.int64))
-            data = {}
-            for nm, t in zip(self._names, self._types):
-                pack, w, dt = words[nm]
-                if pack == "f":
-                    src = take(fflat[w * f_word:], fidx,
-                               out=S("f64", n, np.float64))
-                elif dt == np.int64:                    # join64_np
-                    src = np.left_shift(word(nm, idx), 32, dtype=np.int64,
-                                        out=S("i64", n, np.int64))
-                    src |= word(nm, idx, k=1).view(np.uint32)
-                elif dt == np.float32:
-                    src = word(nm, idx).view(np.float32)
-                else:
-                    src = word(nm, idx)
-                col = data[nm] = np.empty(n, dtype_of(t))
-                if t == ast.AttrType.BOOL:
-                    np.not_equal(src, 0, out=col)
-                else:
-                    col[...] = src
-        if alone:
-            return RuleRuns(tss, seqs, data, lanes, starts)
-        return (tss, seqs, hseq.copy(), data, {}, lane.copy())
+            seqs = _on_base(_take(seq, order, out=S("word", got.n, _I32)),
+                            res.seq_base)
+            data = {nm: got.column(nm, t)
+                    for nm, t in zip(self._names, self._types)}
+        return RuleRuns(tss, seqs, data, lanes, starts)
+
+    def _decode_lanes(self, ipack, fpack, counts, ts_base, seq_base):
+        """A lane block's packed result `(lanes, words, M)`, or a cut one
+        that is not its flush's alone, to the match table `(tss, seqs,
+        hseqs, data, nulls, qids)`, lane-major; None when it holds no
+        row.  `unpack`: the index over the filled cells (_Filled) from
+        `counts`, the header as _materialize_par read it; a `having` flag
+        thins the INDEX before any other word is read.  `scatter`: every
+        word fetched once through it, into columns of the batch's own."""
+        if not counts.any():
+            return None
+        with self.rt.span("unpack", plan=self.name):
+            got = _Filled(self, counts, ipack, fpack)
+            if self.kernel.having is not None:
+                got.reindex(np.flatnonzero(got.word("__having__")))
+        if not got.n:
+            return None
+        self._result_decode["indexed"] += 1
+        with self.rt.span("scatter", plan=self.name):
+            tss = _on_base(got.word("__timestamp__"), ts_base,
+                           TIMESTAMP_DTYPE)
+            seqs = _on_base(got.word("__seq__"), seq_base)
+            hseqs = got.word("__head_seq__").copy()
+            qids = got.word("__qid__").copy() if self.kernel.emit_qid \
+                else None
+            data = {nm: got.column(nm, t)
+                    for nm, t in zip(self._names, self._types)}
+            nulls = {}
+            for nm, ref in self.kernel.null_outputs.items():
+                if f"__present__.{ref}" in got.words:
+                    absent = got.word(f"__present__.{ref}") == 0
+                    if absent.any():
+                        nulls[nm] = absent
+        return (tss, seqs, hseqs, data, nulls, qids)
+
+    def _unpack_block(self, ipack, fpack, n: int):
+        """One flat block's packed output `(words, M)`: the one-lane
+        case of _decode_lanes, its filled cells the prefix `[:n]`."""
+        return self._decode_lanes(
+            ipack[None], None if fpack is None else fpack[None],
+            np.array([n]), self._ts_base, self._seq_base)
 
     def _rule_order(self, lane, seq, hseq) -> np.ndarray:
         """The permutation `np.lexsort((hseq, seq, lane))` returns, for
@@ -2166,7 +2220,7 @@ class DevicePatternPlan(QueryPlan):
 
     def _rule_runs(self, table: tuple) -> RuleRuns:
         """A flat fused flush's match table (_multi_table) in delivery
-        order: its table is lane-major as a cut one's is (_unpack_lanes),
+        order: its table is lane-major as a cut one's is (_decode_lanes),
         a lane one cell."""
         tss, seqs, hseqs, data, qids = table
         order = self._rule_order(qids, seqs, hseqs)
@@ -2175,57 +2229,6 @@ class DevicePatternPlan(QueryPlan):
         return RuleRuns(tss[order].astype(TIMESTAMP_DTYPE, copy=False),
                         seqs[order], {k: v[order] for k, v in data.items()},
                         qids[starts], starts)
-
-    def _unpack_block(self, ipack, fpack, n: int):
-        """Columnar match table from one flat block's packed output."""
-        with self.rt.span("unpack", plan=self.name):
-            base = np.arange(ipack.shape[1]) < n
-        return self._unpack_rows(ipack, fpack, base)
-
-    def _unpack_rows(self, ipack, fpack, base_valid):
-        with self.rt.span("scatter", plan=self.name):
-            if self.kernel.having is not None:
-                valid = base_valid & (ipack[1] != 0)
-                ii = 2
-            else:
-                valid = base_valid
-                ii = 1
-            if not valid.any():
-                return None
-            # unpack columns in out_names order (columnar, no per-row python):
-            # f32 rows are bitcast into the i32 pack, f64 rows (f64 mode) come
-            # from the float pack, i64 as hi/lo row pairs
-            row = {}
-            fi = 0
-            for nm in self.kernel.out_names:
-                dt = np.dtype(self.kernel.out_dtypes[nm])
-                if dt == np.float64:
-                    row[nm] = fpack[fi]; fi += 1
-                elif dt == np.float32:
-                    row[nm] = ipack[ii].view(np.float32); ii += 1
-                elif dt == np.int64:
-                    row[nm] = join64_np(ipack[ii], ipack[ii + 1]); ii += 2
-                else:
-                    row[nm] = ipack[ii]; ii += 1
-            tss = row["__timestamp__"][valid].astype(np.int64) + self._ts_base
-            seqs = row["__seq__"][valid].astype(np.int64) + self._seq_base
-            hseqs = row["__head_seq__"][valid]
-            self._last_qids = (row["__qid__"][valid]
-                               if self.kernel.emit_qid else None)
-            data = {}
-            for nm, t in zip(self._names, self._types):
-                col = row[nm][valid]
-                if t == ast.AttrType.BOOL:
-                    col = col != 0
-                data[nm] = col.astype(dtype_of(t))
-            nulls = {}
-            for nm, ref in self.kernel.null_outputs.items():
-                pres = row.get(f"__present__.{ref}")
-                if pres is not None:
-                    mask = pres[valid] == 0
-                    if mask.any():
-                        nulls[nm] = mask
-            return (tss, seqs, hseqs, data, nulls, self._last_qids)
 
     def _rows_to_batches(self, chunks: list) -> list:
         """chunks: list of (tss, seqs, hseqs, data) columnar match tables."""
@@ -2278,19 +2281,16 @@ class DevicePatternPlan(QueryPlan):
         return self._multi_table(self._pipe.collect())
 
     def _multi_table(self, chunks: list):
-        """The flush's matches from its results: a cut result alone is
-        decoded straight into delivery order (RuleRuns); anything else
-        becomes one flat table for _rule_runs to order."""
+        """The flush's matches: a cut result alone decoded straight into
+        delivery order (RuleRuns); beside other chunks (a tick's) into a
+        table like theirs, the tables joined for _rule_runs to order."""
         chunks = [c for c in chunks if c is not None]
         if not chunks:
             return None
-        cut = [isinstance(c, CutResult) for c in chunks]
-        self._result_decode["indexed"] += sum(cut)
-        self._result_decode["masked"] += len(cut) - sum(cut)
-        if len(chunks) == 1 and cut[0]:
-            return self._decode_cut(chunks[0], alone=True)
-        chunks = [self._decode_cut(c, alone=False) if is_cut else c
-                  for c, is_cut in zip(chunks, cut)]
+        if len(chunks) == 1 and isinstance(chunks[0], CutResult):
+            return self._decode_cut(chunks[0])
+        chunks = [self._decode_lanes(*c) if isinstance(c, CutResult) else c
+                  for c in chunks]
         if len(chunks) == 1:
             tss, seqs, hseqs, data, _nulls, qids = chunks[0]
             return (tss, seqs, hseqs, data, qids)

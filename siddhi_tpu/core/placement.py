@@ -205,7 +205,8 @@ def _query_entry(rt, plan) -> Optional[dict]:
     if kind == "pattern" and fam is not None:
         ent["family"] = fam
         for key in ("expiry_queries", "first_hit", "lane_pack_order",
-                    "lane_cut", "lane_fill", "indexed_read", "compaction"):
+                    "lane_cut", "lane_fill", "result_decode", "indexed_read",
+                    "compaction"):
             rec = getattr(plan, key, None)
             if rec:
                 ent[key] = rec

@@ -31,6 +31,7 @@ from benchmark.reference import rules_mixed               # noqa: E402
 from siddhi_tpu import SiddhiManager                      # noqa: E402
 from siddhi_tpu.core import pattern_plan                  # noqa: E402
 from siddhi_tpu.core.multi_query import MultiQueryDevicePatternPlan  # noqa: E402
+from tests.test_lane_decode import masked_rows            # noqa: E402
 
 T0 = 1_700_000_000_000
 STREAM = "define stream S (symbol string, price double, volume int);\n"
@@ -451,9 +452,9 @@ def new_form(plan, results, tick=()):
 
 def old_form(plan, results, tick=()):
     """The decode and the routing as they were before PR 41, kept as the
-    reference: every word read under a capacity-sized mask, `_unpack_rows`
-    (untouched, the partitioned plans' own), one three-key lexsort, a
-    slice a rule."""
+    reference: every word read under a capacity-sized mask (`masked_rows`,
+    the partitioned plans' own decode until PR 43, kept in
+    tests/test_lane_decode.py), one three-key lexsort, a slice a rule."""
     inner = plan.inner
     chunks = list(tick)
     for ipack, fpack in results:
@@ -464,9 +465,8 @@ def old_form(plan, results, tick=()):
         fp2 = None if fpack is None else [
             fpack[:, :inner._lanes_real, r, :][filled]
             for r in range(fpack.shape[2])]
-        inner._ts_base, inner._seq_base = BASES["ts_base"], BASES["seq_base"]
-        chunks.append(inner._unpack_rows(ip2, fp2,
-                                         np.ones(len(ip2[1]), bool)))
+        chunks.append(masked_rows(inner, ip2, fp2,
+                                  np.ones(len(ip2[1]), bool), **BASES))
     chunks = [c for c in chunks if c is not None]
     tss, seqs, hseqs, qids = (np.concatenate([c[k] for c in chunks])
                               for k in (0, 1, 2, 5))
@@ -617,8 +617,8 @@ def test_an_index_past_the_result_raises_and_reads_no_neighbour(monkeypatch):
 @pytest.mark.parametrize("tick_first", [True, False])
 def test_a_tick_chunk_beside_the_cut_result_is_one_batch_a_rule(tick_first):
     """A flush of several chunks: the rows a timer tick produced (a flat
-    table under a mask) and the cut result's join into one table, ordered
-    as one: a rule still gets ONE batch."""
+    table, decoded when it was pulled) and the cut result's join into one
+    table, ordered as one: a rule still gets ONE batch."""
     mgr, plan = fused_plan(app_of(0))
     inner = plan.inner
     rng = np.random.default_rng(8)
@@ -634,8 +634,8 @@ def test_a_tick_chunk_beside_the_cut_result_is_one_batch_a_rule(tick_first):
     assert_same_batches(got, old_form(plan, [result], tick=ticks))
     assert len({ob.callback_name for ob in got}) == len(got)
     fused = plan.fused
-    assert fused["result_decode"] == {"indexed": 1,
-                                      "masked": int(tick_first)}
+    # (the tick's table is handed in ready made: no decode of it here)
+    assert fused["result_decode"] == {"indexed": 1, "masked": 0}
     assert fused["route_order"] == {"keyed": 1, "lexsort": 0}
     # two cut results in one collect (a drained pipeline) join the same way
     got = new_form(plan, [result, result])
@@ -758,10 +758,11 @@ def test_spans_route_and_lane_cut_and_the_fused_record():
     assert ent["fused"]["result_decode"] == {"indexed": 2, "masked": 0}
     assert ent["fused"]["route_order"] == {"keyed": 2, "lexsort": 0}
     assert plans[0].device_metrics()["fused"] == ent["fused"]
-    # a flush within a row: the flat form, under a mask, the same keyed order
+    # a flush within a row: the flat form, a lane result of its own, decoded
+    # through the same index (no mask since PR 43), the same keyed order
     _d, ex, plans, st = run("", app_of(0), tape(19, 200, 2), stats=True)
     assert "lane_cut" not in st["stages"]
     assert st["stages"]["route"]["batches"] == 2
     fused = ex["queries"][plans[0].name]["fused"]
-    assert fused["result_decode"] == {"indexed": 0, "masked": 2}
+    assert fused["result_decode"] == {"indexed": 2, "masked": 0}
     assert fused["route_order"] == {"keyed": 2, "lexsort": 0}

@@ -270,6 +270,13 @@ def test_lane_fill_counts_what_the_flushes_held(ran):
             held = len(seen - now)
         seen |= now
     assert fill["last"]["lanes_held"] == held > 1000
+    # every flush that held rows was decoded through the one index
+    with_rows = sum(e["lane_fill"]["total"]["rows_delivered"] > (
+        p["lane_fill"]["total"]["rows_delivered"] if p else 0)
+        for p, e in zip([None] + entries, entries))
+    assert 0 < with_rows <= FLUSHES
+    assert entries[-1]["result_decode"] == metrics["result_decode"] \
+        == plan.result_decode == {"indexed": with_rows, "masked": 0}
 
 
 def _small(prices_by_flush):
@@ -311,9 +318,12 @@ def test_lane_fill_on_a_flush_worked_by_hand():
     assert fill["total"] == {k: first["last"][k] + fill["last"][k]
                              for k in pattern_plan.LANE_FILL}
     assert fill["flushes"] == 2 and fill["grids"] == {"8x16x16": 2}
-    assert list(entries[1])[:9] == [
+    assert list(entries[1])[:10] == [
         "path", "plan", "kind", "family", "expiry_queries", "first_hit",
-        "lane_pack_order", "lane_cut", "lane_fill"]
+        "lane_pack_order", "lane_cut", "lane_fill", "result_decode"]
+    # flush 0 held no row: nothing decoded, no record yet
+    assert "result_decode" not in entries[0]
+    assert entries[1]["result_decode"] == {"indexed": 1, "masked": 0}
 
 
 def test_lane_fill_is_a_partitioned_scan_plans_alone():
@@ -334,6 +344,8 @@ def test_lane_fill_is_a_partitioned_scan_plans_alone():
     mgr.shutdown()
     assert "lane_fill" not in ent and plan.lane_fill is None
     assert "lane_fill" not in plan.device_metrics()
+    # its one flat block held a row: decoded as the one-lane case
+    assert ent["result_decode"] == {"indexed": 1, "masked": 0}
 
 
 # -- span lane_tail -------------------------------------------------------------

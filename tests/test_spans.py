@@ -330,15 +330,17 @@ FUSED = STOCK + "".join(
     f"@info(name='q{i}') from every e1=S[p > {120 + i}] -> e2=S[p > e1.p] "
     "within 1 sec select e1.p as a, e2.p as b insert into Out;\n"
     for i in range(8))
-# path -> (app, events a flush, ms an event, the unpack it takes, `scatter`
-# spans the plan opens a flush: what they were before `unpack` had a span)
+# path -> (app, events a flush, ms an event, the decode it takes (a flat
+# block's is the one-lane case of a lane result's), `scatter` spans the
+# plan opens a flush: what they were before `unpack` had a span)
+DECODES = ("_decode_lanes", "_decode_cut", "_unpack_block")
 RESULT_PATHS = {
-    "lane": (PATTERN, 2048, 1, "_unpack_lanes", 3),
-    "fused-row": (FUSED, 512, 50, "_decode_cut", 1),
+    "lane": (PATTERN, 2048, 1, DECODES[:1], 3),
+    "fused-row": (FUSED, 512, 50, DECODES[1:2], 1),
     "flat-block": (FLAT.format(within="within 1 sec "), 256, 1,
-                   "_unpack_block", 3),
-    "seq-block": (FLAT.format(within=""), 256, 1, "_unpack_block", 2),
-    "filter": (FILTER, 4096, 1, None, 0),
+                   DECODES[::2], 3),
+    "seq-block": (FLAT.format(within=""), 256, 1, DECODES[::2], 2),
+    "filter": (FILTER, 4096, 1, (), 0),
 }
 PATTERN_PATHS = [k for k, v in RESULT_PATHS.items() if v[3]]
 
@@ -346,7 +348,7 @@ PATTERN_PATHS = [k for k, v in RESULT_PATHS.items() if v[3]]
 def _run_result_path(path, monkeypatch, header="@app:trace('all')\n",
                      stats=True):
     """Three send_batch + flush rounds down one result path: (stage
-    statistics, the frames' trees, calls of each `_unpack_*`, rows out,
+    statistics, the frames' trees, calls of each of DECODES, rows out,
     the runtime's Prometheus text)."""
     from siddhi_tpu.core import pattern_plan
     app, n, dt, _fn, _sc = RESULT_PATHS[path]
@@ -354,7 +356,7 @@ def _run_result_path(path, monkeypatch, header="@app:trace('all')\n",
         monkeypatch.setattr(pattern_plan, "FUSED_ROW_WINDOWS", 2)
         monkeypatch.setattr(pattern_plan, "FUSED_ROW_MIN", 16)
     calls = {}
-    for fn in ("_unpack_lanes", "_decode_cut", "_unpack_block"):
+    for fn in DECODES:
         def counted(self, *a, _o=getattr(pattern_plan.DevicePatternPlan, fn),
                     _f=fn, **kw):
             calls[_f] = calls.get(_f, 0) + 1
@@ -409,9 +411,9 @@ def test_transfer_is_its_wait_and_its_copy(path, monkeypatch):
 
 @pytest.mark.parametrize("path", PATTERN_PATHS)
 def test_unpack_closes_before_scatter_opens(path, monkeypatch):
-    _app, _n, _dt, fn, plan_scatters = RESULT_PATHS[path]
+    _app, _n, _dt, fns, plan_scatters = RESULT_PATHS[path]
     stages, trees, calls, rows, _prom = _run_result_path(path, monkeypatch)
-    assert rows > 0 and calls == {fn: 3}, calls
+    assert rows > 0 and calls == dict.fromkeys(fns, 3), calls
     assert stages["unpack"]["batches"] >= 3
     assert len(trees) == 3
     for spans in trees:
@@ -428,6 +430,51 @@ def test_unpack_closes_before_scatter_opens(path, monkeypatch):
         last = max(unpacks, key=lambda s: s["t0_s"])
         assert any(sc["t0_s"] >= last["t0_s"] + last["dur_s"] - 2e-6
                    for sc in scatters), (last, scatters)
+
+
+@pytest.mark.parametrize("path", PATTERN_PATHS)
+def test_the_index_under_unpack_the_columns_under_scatter(path, monkeypatch):
+    """What `materialise_ms_per_batch` (span `scatter`) and the idle gap
+    `siddhi:unpack` mean: the index over a result's filled cells is built
+    while `unpack` is the innermost open span, every delivered column is
+    fetched while `scatter` is."""
+    from contextlib import contextmanager
+    from siddhi_tpu.core import pattern_plan
+    app, n, dt, _fns, _sc = RESULT_PATHS[path]
+    if path == "fused-row":
+        monkeypatch.setattr(pattern_plan, "FUSED_ROW_WINDOWS", 2)
+        monkeypatch.setattr(pattern_plan, "FUSED_ROW_MIN", 16)
+    open_spans, seen = [], {"_index": [], "column": []}
+    for fn in seen:
+        def noted(self, *a, _o=getattr(pattern_plan._Filled, fn), _f=fn):
+            seen[_f].append(open_spans[-1] if open_spans else None)
+            return _o(self, *a)
+        monkeypatch.setattr(pattern_plan._Filled, fn, noted)
+    mgr = SiddhiManager()
+    rt = mgr.create_app_runtime(app)
+    span = rt.span
+
+    @contextmanager
+    def logged(name, **kw):
+        open_spans.append(name)
+        try:
+            with span(name, **kw) as sp:
+                yield sp
+        finally:
+            open_spans.pop()
+    rt.span = logged
+    rows = [0]
+    rt.add_batch_callback("Out", lambda b: rows.__setitem__(0, rows[0] + b.n))
+    rt.start()
+    try:
+        cols, ts = _batch(0, n)
+        rt.input_handler("S").send_batch(cols, T0_MS + dt * (ts - T0_MS))
+        rt.flush()
+    finally:
+        mgr.shutdown()
+    assert rows[0] > 0
+    assert seen["_index"] and set(seen["_index"]) == {"unpack"}, seen
+    assert seen["column"] and set(seen["column"]) == {"scatter"}, seen
 
 
 def test_fault_counters_ride_the_four_result_spans(monkeypatch):
