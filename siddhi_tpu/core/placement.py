@@ -220,6 +220,8 @@ def _query_entry(rt, plan) -> Optional[dict]:
         # what the fused group ran, nested: a top-level `family` would
         # read as a pattern plan's
         ent["fused"] = plan.fused
+    if kind == "window":
+        ent["window"] = plan.window
     if kind == "partition-group":
         ent["queries"] = sorted(
             q.name(f"query_p{plan.index}_{qi}")

@@ -13,8 +13,9 @@ loop becomes closed-form range reductions over the concatenated
   * sliding windows — each event's aggregate is a contiguous-range
     reduction ending at that event.  The left edge is rank arithmetic
     for length(L) and a vectorized `searchsorted` for time(D);
-    sums/counts/avgs read prefix-sum differences (O(T)), min/max read
-    a log2 sparse table (O(T log T) build, O(1) per query).
+    sums/counts/avgs read a range of ONE compensated prefix sum (O(T);
+    `_range_sum`: exact to the rounding of the range's own contents),
+    min/max read a log2 sparse table (O(T log T) build, O(1) per query).
   * group-by — per-group prefixes come from one sort by (segment,
     position) + segmented cumsum + two searchsorted rank lookups; no
     per-group state is kept at all for sliding windows.
@@ -45,7 +46,7 @@ from .expr import (CompiledExpr, ExprError, SingleStreamContext,
 from .planner import (AGGREGATOR_NAMES, OutputBatch, PlanError, QueryPlan,
                       selector_has_aggregators)
 from .schema import StreamSchema, TIMESTAMP_DTYPE, dtype_of
-from .telemetry import call_kernel, env_nbytes
+from .telemetry import call_kernel, device_wait, env_nbytes
 
 
 class DeviceWindowUnsupported(Exception):
@@ -106,43 +107,87 @@ def _range_reduce(table: jnp.ndarray, l: jnp.ndarray, r: jnp.ndarray,
     return op(table[j, l], table[j, r - half + 1])
 
 
-def _segmented_prefix(seg: jnp.ndarray, v: jnp.ndarray) -> tuple:
-    """Inclusive per-segment prefix sums over arrival order.
+# One primitive serves every windowed, segmented or running SUM of this
+# module: inclusive prefix sums carried as unevaluated (hi, lo) pairs of the
+# plan's float type, hi + lo holding twice its precision, and a range taken
+# as the component-wise difference of two of them.  (A difference of two
+# plain f32 prefixes errs by the rounding of the PREFIXES, 2.9e7 and one ulp
+# of 2 at 2^18 events of ~110, whatever the size of the window.)
+#
+# Bound, u = 2^-24 in f32.  `_pair_add` loses at most 2u^2 (|x| + |y|): one
+# rounding of lo + lo, one of e + t, both of numbers under u (|x| + |y|);
+# the two `_two_sum`s are error-free.  `associative_scan` builds a prefix
+# from at most 2 log2 N of them, so a prefix over N <= 2^20 entries is off
+# by at most 80 u^2 S = 2^-41.6 S, S the sequence's sum of |v|, and a
+# difference of two by 2^-40.6 S.  `_range_sum` adds one rounding of the
+# result, u R <= 1 ulp of R, R the RANGE's own sum of |v|.  Hence
+#
+#     |error| <= 16 ulps of R   wherever S <= 2^20 R
+#
+# (k = 16: 1 + 2^-40.6 x 2^20 x 2^24 = 1 + 2^3.4 < 16), which holds for
+# every range, a single entry included, of up to 2^20 entries of one
+# magnitude, wherever in the sequence it lies and whatever T and C are;
+# measured on 0.01-step prices: half an ulp, the result's own rounding
+# (tests/test_window_exact.py).  On values that are multiples of a step q
+# with S under 2^46 q every pair is exact (hi holds the sum's leading 24
+# bits, lo the rest, nothing rounds), so a range whose sum is under 2^24 q
+# comes out EXACT: quarter-step prices, counts.
 
-    seg: (N,) int64 segment id (invalid entries: large id, zero value).
-    Returns (ks, segpfx): sorted (seg*N + pos) keys and the per-segment
-    inclusive prefix at each sorted slot."""
+def _two_sum(a, b):
+    """(s, e) with s = fl(a + b) and s + e == a + b exactly (Knuth)."""
+    s = a + b
+    bb = s - a
+    return s, (a - (s - bb)) + (b - bb)
+
+
+def _pair_add(x, y):
+    (xh, xl), (yh, yl) = x, y
+    s, e = _two_sum(xh, yh)
+    return _two_sum(s, e + (xl + yl))
+
+
+def _prefix_pairs(v: jnp.ndarray) -> tuple:
+    """Inclusive prefix sums of `v` as (hi, lo) pairs."""
+    return jax.lax.associative_scan(_pair_add, (v, jnp.zeros_like(v)))
+
+
+def _range_sum(pfx: tuple, lo: jnp.ndarray, hi=None) -> jnp.ndarray:
+    """Sum of the scanned values over positions (lo, hi]; lo == -1 takes
+    the range from the start, hi None ends each range at its own index."""
+    ph, pl = pfx
+    at = jnp.maximum(lo, 0)
+    bh = jnp.where(lo >= 0, ph[at], 0.0)
+    bl = jnp.where(lo >= 0, pl[at], 0.0)
+    if hi is not None:
+        ph, pl = ph[hi], pl[hi]
+    dh, de = _two_sum(ph, -bh)
+    return dh + (de + (pl - bl))
+
+
+def _segment_start(seg: jnp.ndarray) -> jnp.ndarray:
+    """Index of the first entry of each entry's run of equal `seg`."""
     n = seg.shape[0]
+    is_start = jnp.concatenate([jnp.array([True]), seg[1:] != seg[:-1]])
+    return jax.lax.associative_scan(
+        jnp.maximum, jnp.where(is_start, jnp.arange(n), 0))
+
+
+def _by_segment(seg: jnp.ndarray, n: int) -> tuple:
+    """(order, ks): arrival order sorted by (segment, position) and the
+    sorted seg * n + pos keys (invalid entries: a large segment id)."""
     key = seg * n + jnp.arange(n, dtype=jnp.int64)
     order = jnp.argsort(key)
-    ks = key[order]
-    ss = seg[order]
-    cs = jnp.cumsum(v[order])
-    is_start = jnp.concatenate([jnp.array([True]), ss[1:] != ss[:-1]])
-    start_idx = jax.lax.associative_scan(
-        jnp.maximum, jnp.where(is_start, jnp.arange(n), 0))
-    base = jnp.where(start_idx > 0, cs[jnp.maximum(start_idx - 1, 0)], 0.0)
-    return ks, cs - base
-
-
-def _seg_prefix_at(ks, segpfx, seg, pos, n):
-    """Inclusive prefix at an existing (seg, pos) entry."""
-    r = jnp.searchsorted(ks, seg * n + pos)
-    return segpfx[r]
-
-
-def _seg_prefix_before(ks, segpfx, seg, bound, n):
-    """Prefix over entries of `seg` with position < bound (0.0 if none)."""
-    lo = jnp.searchsorted(ks, seg * n)
-    p = jnp.searchsorted(ks, seg * n + bound)
-    return jnp.where(p > lo, segpfx[jnp.maximum(p - 1, 0)], 0.0)
+    return order, key[order]
 
 
 def _seg_window_sum(seg, v, left, gpos, n):
-    """Per-entry sum over its segment's members in positions [left, gpos]."""
-    ks, segpfx = _segmented_prefix(seg, v)
-    incl = _seg_prefix_at(ks, segpfx, seg, gpos, n)
-    return incl - _seg_prefix_before(ks, segpfx, seg, left, n)
+    """Per-entry sum over its segment's members in positions [left, gpos]:
+    a range of the (segment, position) order, from the segment's first
+    member at or after `left` to the entry itself."""
+    order, ks = _by_segment(seg, n)
+    first = jnp.searchsorted(ks, seg * n + left)
+    own = jnp.searchsorted(ks, seg * n + gpos)
+    return _range_sum(_prefix_pairs(v[order]), first - 1, own)
 
 
 def _seg_window_minmax(seg, v, left, gpos, n, is_max):
@@ -161,8 +206,11 @@ def _seg_window_minmax(seg, v, left, gpos, n, is_max):
 
 
 def _seg_running_sum(seg, v, n):
-    ks, segpfx = _segmented_prefix(seg, v)
-    return _seg_prefix_at(ks, segpfx, seg, jnp.arange(n, dtype=jnp.int64), n)
+    """Per-entry running sum within its segment, arrival order."""
+    order, ks = _by_segment(seg, n)
+    run = _range_sum(_prefix_pairs(v[order]),
+                     _segment_start(seg[order]) - 1)
+    return run[jnp.searchsorted(ks, seg * n + jnp.arange(n, dtype=jnp.int64))]
 
 
 def _seg_running_minmax(seg, v, is_max, n):
@@ -187,13 +235,7 @@ def _seg_running_minmax(seg, v, is_max, n):
 # order (no group-by, or bucket-only keys) the sort is a no-op — skip it
 
 def _mono_running_sum(seg, v):
-    n = seg.shape[0]
-    cs = jnp.cumsum(v)
-    is_start = jnp.concatenate([jnp.array([True]), seg[1:] != seg[:-1]])
-    start_idx = jax.lax.associative_scan(
-        jnp.maximum, jnp.where(is_start, jnp.arange(n), 0))
-    base = jnp.where(start_idx > 0, cs[jnp.maximum(start_idx - 1, 0)], 0.0)
-    return cs - base
+    return _range_sum(_prefix_pairs(v), _segment_start(seg) - 1)
 
 
 def _mono_running_minmax(seg, v, is_max):
@@ -256,7 +298,7 @@ class DeviceWindowAggPlan(QueryPlan):
         wh = inp.window
         if wh is None:
             raise DeviceWindowUnsupported("no window")
-        wname = wh.name.lower()
+        wname = self._wname = wh.name.lower()
         if wh.namespace is not None:
             raise DeviceWindowUnsupported(f"namespaced window {wname}")
 
@@ -472,8 +514,28 @@ class DeviceWindowAggPlan(QueryPlan):
         from .planner import mesh_for
         self.mesh = mesh_for(rt, "t")
 
+        # what `window` (EXPLAIN / device_metrics) counts: each is a
+        # recompile that a steady window of traffic may not hold
+        self.counters = {"carry_overflow_reruns": 0, "carry_grows": 0}
+        self._T = None              # the last dispatch's padded length
+
         self.state = self._init_state()
         jax.eval_shape(self._step_fn(8, self.C), self.state, self._dummy(8))
+
+    @property
+    def window(self) -> dict:
+        """The form this plan took: static but for `T` and
+        `carry_capacity`, which move with a recompile, and the counters."""
+        size = {"length": self.L} if hasattr(self, "L") \
+            else {"duration_ms": self.D}
+        return {"kind": self._wname, **size,
+                "grouped": bool(self.group_keys),
+                "sites": [nm for nm, _arg, _t in self.sites],
+                "T": self._T, "carry_capacity": int(self.C),
+                # sums are ranges of one (hi, lo) pair prefix
+                # (`_range_sum`), which never restarts: no block
+                "sum_form": "pair_prefix", "block": None,
+                **self.counters}
 
     # -- state ---------------------------------------------------------------
 
@@ -557,6 +619,9 @@ class DeviceWindowAggPlan(QueryPlan):
         N = C + T
         FDT = self.fdt
         out_types = [a.type for a in self.out_schema.attributes]
+        # every phase runs under a jax.named_scope, so each device
+        # operation of a trace names the phase it belongs to
+        scope = jax.named_scope
 
         def site_vals(env_all, n):
             out = []
@@ -590,19 +655,66 @@ class DeviceWindowAggPlan(QueryPlan):
             seg = jnp.zeros(n, dtype=jnp.int64).at[order].set(seg_sorted)
             return jnp.where(gvalid, seg, n)
 
+        def bucket_seg(brel, env_all, all_valid):
+            """The tumbling kinds' segment id per entry: its bucket, or
+            (bucket, group) under a group-by."""
+            if not group_keys:
+                return brel
+            seg = group_seg(env_all, all_valid, N)
+            return jnp.where(all_valid, brel * (N + 1) + seg,
+                             jnp.int64((N + 2) * (N + 1)))
+
         def finish(env_all, aggs, row_ok):
             """Select + having over an aligned env; returns (outs, ok)."""
-            env2 = dict(env_all)
-            for i, a in enumerate(aggs):
-                _nm, _arg, ot = sites[i]
-                env2[f"__agg{i}"] = _cast_site(a, ot)
-            outs = [ce.fn(env2) for ce in out_fns]
-            if having is not None:
-                henv = dict(env2)
-                for nm2, col in zip(out_names, outs):
-                    henv[nm2] = col
-                row_ok = row_ok & having.fn(henv)
-            return outs, row_ok
+            with scope("window/select"):
+                env2 = dict(env_all)
+                for i, a in enumerate(aggs):
+                    _nm, _arg, ot = sites[i]
+                    env2[f"__agg{i}"] = _cast_site(a, ot)
+                outs = [ce.fn(env2) for ce in out_fns]
+                if having is not None:
+                    henv = dict(env2)
+                    for nm2, col in zip(out_names, outs):
+                        henv[nm2] = col
+                    row_ok = row_ok & having.fn(henv)
+                return outs, row_ok
+
+        def carry(seen, all_ts, pending, env_all, k):
+            """The next state: the C entries that end at C + k."""
+            sl = lambda a: jax.lax.dynamic_slice(a, (k,), (C,))
+            nst = {"seen": seen, "ts": sl(all_ts), "valid": sl(pending)}
+            for c in carry_cols:
+                nst[f"c.{c}"] = sl(env_all[c])
+            return nst
+
+        def running(segk, all_valid, vals):
+            """The tumbling kinds' per-entry running aggregates within
+            (bucket[, group]) segments `segk`.  No group-by: bucket ids are
+            nondecreasing over [carry | batch] (the carry holds only the
+            lowest incomplete bucket), so the sort inside the segmented
+            scans is a no-op and is skipped."""
+            if group_keys:
+                rsum = lambda v_: _seg_running_sum(segk, v_, N)
+                rmm = lambda v_, mx: _seg_running_minmax(segk, v_, mx, N)
+            else:
+                rsum = lambda v_: _mono_running_sum(segk, v_)
+                rmm = lambda v_, mx: _mono_running_minmax(segk, v_, mx)
+            aggs = []
+            for i, (nm, _arg, _ot) in enumerate(sites):
+                if nm in ("min", "max"):
+                    neutral = NEG if nm == "max" else POS
+                    vv = jnp.where(all_valid, vals[i], neutral)
+                    with scope("window/minmax"):
+                        aggs.append(rmm(vv, nm == "max"))
+                    continue
+                v = (all_valid.astype(FDT) if nm == "count"
+                     else jnp.where(all_valid, vals[i], 0.0))
+                with scope("window/sum"):
+                    s = rsum(v)
+                    if nm == "avg":
+                        s = s / jnp.maximum(rsum(all_valid.astype(FDT)), 1.0)
+                aggs.append(s)
+            return aggs
 
         def step_sliding(state, bts, bvalid, bcols, k):
             raw_bts = bts
@@ -613,36 +725,39 @@ class DeviceWindowAggPlan(QueryPlan):
                        for c in carry_cols}
             env_all["__timestamp__"] = all_ts
             gpos = jnp.arange(N, dtype=jnp.int64)
-            vcnt = jnp.cumsum(all_valid.astype(jnp.int64))
-            if kind == "length":
-                want = jnp.maximum(vcnt - L, 0)
-                left = jnp.searchsorted(vcnt, want, side="right")
-            else:
-                left = jnp.searchsorted(all_ts, all_ts - D, side="right")
-            seg = group_seg(env_all, all_valid, N) if group_keys else None
+            with scope("window/left_edge"):
+                vcnt = jnp.cumsum(all_valid.astype(jnp.int64))
+                if kind == "length":
+                    want = jnp.maximum(vcnt - L, 0)
+                    left = jnp.searchsorted(vcnt, want, side="right")
+                else:
+                    left = jnp.searchsorted(all_ts, all_ts - D, side="right")
+                seg = group_seg(env_all, all_valid, N) if group_keys else None
             vals = site_vals(env_all, N)
 
             def wsum(v):
                 """Windowed sum over [left, gpos] — per-group via the
-                segmented machinery, else one prefix-difference (no sort)."""
-                if group_keys:
-                    return _seg_window_sum(seg, v, left, gpos, N)
-                c = jnp.cumsum(v)
-                before = jnp.where(left > 0, c[jnp.maximum(left - 1, 0)], 0.0)
-                return c - before
+                segmented machinery, else a range of arrival order (no
+                sort)."""
+                with scope("window/sum"):
+                    if group_keys:
+                        return _seg_window_sum(seg, v, left, gpos, N)
+                    return _range_sum(_prefix_pairs(v), left - 1)
 
             aggs_full = []
             for i, (nm, _arg, _ot) in enumerate(sites):
                 if nm in ("min", "max"):
                     neutral = NEG if nm == "max" else POS
                     vv = jnp.where(all_valid, vals[i], neutral)
-                    if group_keys:
-                        aggs_full.append(_seg_window_minmax(
-                            seg, vv, left, gpos, N, nm == "max"))
-                        continue
-                    table = _sparse_table(vv, nm == "max")
-                    aggs_full.append(_range_reduce(
-                        table, jnp.minimum(left, gpos), gpos, nm == "max"))
+                    with scope("window/minmax"):
+                        if group_keys:
+                            aggs_full.append(_seg_window_minmax(
+                                seg, vv, left, gpos, N, nm == "max"))
+                            continue
+                        table = _sparse_table(vv, nm == "max")
+                        aggs_full.append(_range_reduce(
+                            table, jnp.minimum(left, gpos), gpos,
+                            nm == "max"))
                     continue
                 v = (all_valid.astype(FDT) if nm == "count"
                      else jnp.where(all_valid, vals[i], 0.0))
@@ -660,21 +775,18 @@ class DeviceWindowAggPlan(QueryPlan):
             row_ts = raw_bts
 
             # carry = last C entries ending at C+k, minus departed ones
-            if kind == "length":
-                total_v = vcnt[N - 1]
-                start_k = jnp.searchsorted(
-                    vcnt, jnp.maximum(total_v - L, 0), side="right")
-            else:
-                last_ts = all_ts[jnp.maximum(C + k - 1, 0)]
-                start_k = jnp.searchsorted(all_ts, last_ts - D, side="right")
-            keep = (gpos >= start_k) & all_valid
-            sl = lambda a: jax.lax.dynamic_slice(a, (k,), (C,))
-            nst = {"seen": state["seen"] + k,
-                   "ts": sl(all_ts),
-                   "valid": sl(keep)}
-            for c in carry_cols:
-                nst[f"c.{c}"] = sl(env_all[c])
-            overflow = (jnp.sum(keep) > C).astype(jnp.int32)
+            with scope("window/carry"):
+                if kind == "length":
+                    total_v = vcnt[N - 1]
+                    start_k = jnp.searchsorted(
+                        vcnt, jnp.maximum(total_v - L, 0), side="right")
+                else:
+                    last_ts = all_ts[jnp.maximum(C + k - 1, 0)]
+                    start_k = jnp.searchsorted(all_ts, last_ts - D,
+                                               side="right")
+                keep = (gpos >= start_k) & all_valid
+                nst = carry(state["seen"] + k, all_ts, keep, env_all, k)
+                overflow = (jnp.sum(keep) > C).astype(jnp.int32)
             return nst, outs, row_ok, row_ts, overflow
 
         def step_lengthbatch(state, bts, bvalid, bcols, k):
@@ -684,52 +796,21 @@ class DeviceWindowAggPlan(QueryPlan):
                        for c in carry_cols}
             env_all["__timestamp__"] = all_ts
             # admission index: carried events resume their old positions
-            base = state["seen"] - jnp.sum(state["valid"])   # multiple of L
-            vrank = jnp.cumsum(all_valid.astype(jnp.int64)) - 1
-            gidx = base + vrank
-            brel = jnp.where(all_valid, (gidx - base) // L, -1)
-            if group_keys:
-                seg = group_seg(env_all, all_valid, N)
-                segb = jnp.where(all_valid, brel * (N + 1) + seg,
-                                 jnp.int64((N + 2) * (N + 1)))
-            else:
-                segb = None
-            vals = site_vals(env_all, N)
-            # no group-by: bucket ids are nondecreasing over [carry | batch]
-            # (the carry holds only the lowest incomplete bucket), so the
-            # sort inside the segmented scans is a no-op — skip it
-            rsum = ((lambda s_, v_: _mono_running_sum(s_, v_))
-                    if not group_keys else
-                    (lambda s_, v_: _seg_running_sum(s_, v_, N)))
-            rmm = ((lambda s_, v_, mx: _mono_running_minmax(s_, v_, mx))
-                   if not group_keys else
-                   (lambda s_, v_, mx: _seg_running_minmax(s_, v_, mx, N)))
-            segk = brel if not group_keys else segb
-            aggs = []
-            for i, (nm, _arg, _ot) in enumerate(sites):
-                if nm in ("min", "max"):
-                    neutral = NEG if nm == "max" else POS
-                    vv = jnp.where(all_valid, vals[i], neutral)
-                    aggs.append(rmm(segk, vv, nm == "max"))
-                else:
-                    v = (all_valid.astype(FDT) if nm == "count"
-                         else jnp.where(all_valid, vals[i], 0.0))
-                    s = rsum(segk, v)
-                    if nm == "avg":
-                        s = s / jnp.maximum(rsum(segk, all_valid.astype(FDT)),
-                                            1.0)
-                    aggs.append(s)
+            with scope("window/left_edge"):
+                base = state["seen"] - jnp.sum(state["valid"])  # multiple of L
+                vrank = jnp.cumsum(all_valid.astype(jnp.int64)) - 1
+                gidx = base + vrank
+                brel = jnp.where(all_valid, (gidx - base) // L, -1)
+                segk = bucket_seg(brel, env_all, all_valid)
+            aggs = running(segk, all_valid, site_vals(env_all, N))
             total = base + jnp.sum(all_valid)
             completed = (total // L) * L
             emit = all_valid & (gidx < completed)
             outs, row_ok = finish(env_all, aggs, emit)
-            row_ts = all_ts
-            pend = all_valid & (gidx >= completed)
-            sl = lambda a: jax.lax.dynamic_slice(a, (k,), (C,))
-            nst = {"seen": total, "ts": sl(all_ts), "valid": sl(pend)}
-            for c in carry_cols:
-                nst[f"c.{c}"] = sl(env_all[c])
-            return nst, outs, row_ok, row_ts, jnp.int32(0)
+            with scope("window/carry"):
+                nst = carry(total, all_ts, all_valid & (gidx >= completed),
+                            env_all, k)
+            return nst, outs, row_ok, all_ts, jnp.int32(0)
 
         def step_extbatch(state, bts, bvalid, bcols, k):
             """externalTimeBatch: lengthBatch's per-bucket segmented scans
@@ -743,61 +824,33 @@ class DeviceWindowAggPlan(QueryPlan):
             env_all = {c: jnp.concatenate([state[f"c.{c}"], bcols[c]])
                        for c in carry_cols}
             env_all["__timestamp__"] = all_ts
-            ets = env_all[ext_ts].astype(jnp.int64)
-            idx0 = jnp.argmax(all_valid)          # first valid entry
-            first_e = ets[idx0]
-            # latch the bucket anchor only when the block actually holds
-            # a valid event: argmax over an all-False mask is 0, and a
-            # fully-filtered first micro-batch would otherwise latch a
-            # garbage carry-slot timestamp, permanently shifting every
-            # bucket boundary vs the host path
-            start = jnp.where((state["start"] == SENT)
-                              & jnp.any(all_valid),
-                              first_e, state["start"])
-            Dj = jnp.int64(D)
-            b = jnp.where(all_valid, (ets - start) // Dj, jnp.int64(-1))
-            bfirst = b[idx0]
-            brel = jnp.where(all_valid, b - bfirst, jnp.int64(-1))
-            blast = jnp.max(b)                    # monotone ts: current
-            if group_keys:
-                seg = group_seg(env_all, all_valid, N)
-                segb = jnp.where(all_valid, brel * (N + 1) + seg,
-                                 jnp.int64((N + 2) * (N + 1)))
-            else:
-                segb = None
-            vals = site_vals(env_all, N)
-            rsum = ((lambda s_, v_: _mono_running_sum(s_, v_))
-                    if not group_keys else
-                    (lambda s_, v_: _seg_running_sum(s_, v_, N)))
-            rmm = ((lambda s_, v_, mx: _mono_running_minmax(s_, v_, mx))
-                   if not group_keys else
-                   (lambda s_, v_, mx: _seg_running_minmax(s_, v_, mx, N)))
-            segk = brel if not group_keys else segb
-            aggs = []
-            for i, (nm, _arg, _ot) in enumerate(sites):
-                if nm in ("min", "max"):
-                    neutral = NEG if nm == "max" else POS
-                    vv = jnp.where(all_valid, vals[i], neutral)
-                    aggs.append(rmm(segk, vv, nm == "max"))
-                else:
-                    v = (all_valid.astype(FDT) if nm == "count"
-                         else jnp.where(all_valid, vals[i], 0.0))
-                    s = rsum(segk, v)
-                    if nm == "avg":
-                        s = s / jnp.maximum(rsum(segk, all_valid.astype(FDT)),
-                                            1.0)
-                    aggs.append(s)
+            with scope("window/left_edge"):
+                ets = env_all[ext_ts].astype(jnp.int64)
+                idx0 = jnp.argmax(all_valid)          # first valid entry
+                first_e = ets[idx0]
+                # latch the bucket anchor only when the block actually
+                # holds a valid event: argmax over an all-False mask is 0,
+                # and a fully-filtered first micro-batch would otherwise
+                # latch a garbage carry-slot timestamp, permanently
+                # shifting every bucket boundary vs the host path
+                start = jnp.where((state["start"] == SENT)
+                                  & jnp.any(all_valid),
+                                  first_e, state["start"])
+                Dj = jnp.int64(D)
+                b = jnp.where(all_valid, (ets - start) // Dj, jnp.int64(-1))
+                bfirst = b[idx0]
+                brel = jnp.where(all_valid, b - bfirst, jnp.int64(-1))
+                blast = jnp.max(b)                    # monotone ts: current
+                segk = bucket_seg(brel, env_all, all_valid)
+            aggs = running(segk, all_valid, site_vals(env_all, N))
             emit = all_valid & (b < blast)
             outs, row_ok = finish(env_all, aggs, emit)
-            row_ts = all_ts
-            pend = all_valid & (b == blast)
-            sl = lambda a: jax.lax.dynamic_slice(a, (k,), (C,))
-            nst = {"seen": state["seen"] + k, "ts": sl(all_ts),
-                   "valid": sl(pend), "start": start}
-            for c in carry_cols:
-                nst[f"c.{c}"] = sl(env_all[c])
-            overflow = (jnp.sum(pend) > C).astype(jnp.int32)
-            return nst, outs, row_ok, row_ts, overflow
+            with scope("window/carry"):
+                pend = all_valid & (b == blast)
+                nst = carry(state["seen"] + k, all_ts, pend, env_all, k)
+                nst["start"] = start
+                overflow = (jnp.sum(pend) > C).astype(jnp.int32)
+            return nst, outs, row_ok, all_ts, overflow
 
         def compact(mask, arr, fill):
             pos = jnp.cumsum(mask.astype(jnp.int32), dtype=jnp.int32) - mask
@@ -831,17 +884,19 @@ class DeviceWindowAggPlan(QueryPlan):
                 # compact filtered events to the front: one i32 cumsum + one
                 # scatter per column (a stable argsort here cost 244s of
                 # XLA compile at T=16K and dominated runtime)
-                k = jnp.sum(mask, dtype=jnp.int32)
-                bvalid = jnp.arange(T, dtype=jnp.int32) < k
-                bts = compact(mask, ts64, _TS_PAD)
-                bcols = {c: compact(mask, env[c], 0) for c in cols}
+                with scope("window/compact"):
+                    k = jnp.sum(mask, dtype=jnp.int32)
+                    bvalid = jnp.arange(T, dtype=jnp.int32) < k
+                    bts = compact(mask, ts64, _TS_PAD)
+                    bcols = {c: compact(mask, env[c], 0) for c in cols}
                 if kind == "lengthbatch":
                     res = step_lengthbatch(state, bts, bvalid, bcols, k)
                 elif kind == "externaltimebatch":
                     res = step_extbatch(state, bts, bvalid, bcols, k)
                 else:
                     res = step_sliding(state, bts, bvalid, bcols, k)
-                return pack(res, mask, k)
+                with scope("window/pack"):
+                    return pack(res, mask, k)
 
         def bits32(m):
             """(T,) bool -> (ceil(T/32),) i32 word stream, little-bit order."""
@@ -961,6 +1016,7 @@ class DeviceWindowAggPlan(QueryPlan):
         # plan retryable (the runtime's degradation ladder re-dispatches
         # with a split batch — half the pad footprint)
         self.rt.inject("dispatch", self.name)
+        self._T = T
         pre = self.state
         prof = self.rt.profiler
         if not self.rt.stats.enabled and prof is None:
@@ -975,31 +1031,43 @@ class DeviceWindowAggPlan(QueryPlan):
         self.state = res["nst"]
         return {"pre": pre, "env": env, "batch": batch, "T": T, "res": res}
 
+    def _pull(self, res: dict) -> tuple:
+        """The blocking pull of one step's packed outputs: the wait for
+        the device (a span of its own while a sink is on), then one D2H
+        copy a pack; notes the bytes."""
+        span = self.rt.span
+        packs = [res.get(k) for k in ("b", "i", "f")]
+        with span("transfer", plan=self.name):
+            device_wait(span, self.name, packs)
+            with span("transfer.copy", plan=self.name):
+                packs = [None if p is None else np.asarray(p) for p in packs]
+        prof = self.rt.profiler
+        if prof is not None:
+            prof.note_bytes(self.name, "d2h", sum(
+                p.nbytes for p in packs if p is not None))
+        return packs
+
     def _materialize(self, entry: dict) -> list:
         slim = self.kind not in ("lengthbatch", "externaltimebatch")
-        bpack = None
         while True:
-            res = entry["res"]
-            with self.rt.span("transfer", plan=self.name):
-                if slim:
-                    bpack = np.asarray(res["b"])
-                    overflow = int(bpack[0])
-                else:
-                    overflow = int(np.asarray(res["i"])[0, 0])
-            if not overflow:
+            bpack, ipack, fpack = self._pull(entry["res"])
+            if not int(bpack[0] if slim else ipack[0, 0]):
                 break
             # carry overflow: grow C and replay this entry plus everything
             # dispatched after it (their pre-states are now invalid)
             chain = [entry] + self._pipe.take_all()
             self.state = entry["pre"]
             self._grow(2 * self.C)
+            self.counters["carry_grows"] += 1
+            self.counters["carry_overflow_reruns"] += len(chain)
             redone = [self._dispatch(e["env"], e["batch"], e["T"])
                       for e in chain]
             entry = redone[0]
             self._pipe.requeue(redone[1:])
-        with self.rt.span("transfer", plan=self.name):
-            ipack = np.asarray(res["i"]) if "i" in res else None
-            fpack = np.asarray(res["f"]) if "f" in res else None
+        with self.rt.span("unpack", plan=self.name, events=entry["batch"].n):
+            return self._unpack(entry, slim, bpack, ipack, fpack)
+
+    def _unpack(self, entry: dict, slim: bool, bpack, ipack, fpack) -> list:
         batch = entry["batch"]
         T = entry["T"]
         from .nfa_device import join64_np
@@ -1096,7 +1164,8 @@ class DeviceWindowAggPlan(QueryPlan):
             # sampling — a scrape racing a state swap skips the gauge)
             return {}
         return {"window_capacity": int(self.C), "window_fill": fill,
-                "window_fill_ratio": round(fill / max(self.C, 1), 4)}
+                "window_fill_ratio": round(fill / max(self.C, 1), 4),
+                "window": self.window}
 
     def state_dict(self) -> dict:
         return {"state": {k: np.asarray(v) for k, v in self.state.items()},

@@ -5,8 +5,9 @@ F alone (nfa_parallel.DENSE_MAX_F; the cells' real shapes are held in
 tests/test_first_hit.py `test_rule_reads_the_blocks_static_shape`), so what
 EXPLAIN says here is what it says on the chip unless a configuration's
 lanes outgrow the bound.  The configurations are read from the manifest,
-not named here: one a later PR adds is held to the same, and one that runs
-no scan/dfa block (a seq-family plan, a window) is skipped.  This file is
+not named here: one a later PR adds is held to the same; one whose plan is
+no pattern plan (a filter, a window) is not collected, and one that runs
+no scan/dfa block (a seq-family plan) is skipped.  This file is
 outside BENCHMARK.json's `paths` on purpose, so that such a PR may adapt
 it (a configuration whose flush cannot be cut under the bound keeps the
 scatter by design)."""
@@ -20,7 +21,8 @@ from benchmark import engine, manifest
 MF = manifest.Manifest()
 PATTERN_CELLS = {}          # configuration -> its first cell
 for _w in MF.data["workloads"]:
-    if MF.cell(_w["name"])["config"]["expect"]["kind"] != "filter":
+    if MF.cell(_w["name"])["config"]["expect"]["kind"] in ("pattern",
+                                                           "multi_query"):
         PATTERN_CELLS.setdefault(_w["config"], _w["name"])
 
 

@@ -128,3 +128,47 @@ def test_pattern200ks_block_compiles_for_the_chip_at_its_stated_size(topo):
     assert mem.argument_size_in_bytes // lanes == 4 * F_ * 4 + 8     # 1,032
     assert mem.output_size_in_bytes == lanes * 8 * F_ * 4   # 2,048 an event
     assert mem.temp_size_in_bytes < 1 << 30     # 0.67 GB: the chip has 16
+
+
+def test_window1ks_step_compiles_for_the_chip_at_its_stated_size(topo):
+    """`window1k.sat`'s step: 2^18 events on a 1024-entry carry, the price
+    in and one f32 word a row out (the H2D and D2H the cell reports, 4.0
+    and 4.0 bytes an event), the window's sum a range of the (hi, lo) pair
+    prefix.  What is counted here is what a `perf_opt` on this step takes
+    out: the per-element indexed operations the lane block no longer has."""
+    import os
+    from siddhi_tpu import SiddhiManager
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "apps",
+                           "window1k.siddhi")) as f:
+        app = f.read().replace("{source}", "").replace("{sink}", "")
+    mgr = SiddhiManager()
+    rt = mgr.create_app_runtime("@app:deviceMesh('never')\n" + app)
+    plan = rt._plan_by_name["q"]
+    mgr.shutdown()
+    T, C = 1 << 18, plan.C
+    assert C == 1024 and plan.cols == ["price"] and not plan._needs_ts
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def of(a):
+        a = jnp.asarray(a)
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)
+    state = {k: of(v) for k, v in plan.state.items()}
+    # as `process` uploads it: the DOUBLE price padded as f32
+    env = {"__nvalid__": of(np.int32(0)), "price": of(np.zeros(T, np.float32))}
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = plan._build_step_fn(T, C).lower(state, env).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+    ops = Counter(re.findall(r"= \S+ ([a-z\-]+)\(", compiled.as_text()))
+    assert ops["fusion"] > 0
+    # the reads of a prefix at each window's left edge, hi and lo of the
+    # price's and of the count's, and the searches for the edge; no sort
+    assert 0 < ops["gather"] <= 8 and ops["sort"] == 0
+    assert {k: ops[k] for k in COLLECTIVES if ops[k]} == {}
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes // T == 4         # the price as f32
+    assert mem.output_size_in_bytes // T == 4           # one f32 word a row
+    assert mem.temp_size_in_bytes < 1 << 28             # 25 MB: the chip has 16 GB

@@ -156,6 +156,51 @@ def test_carry_overflow_grows():
     m.shutdown()
 
 
+def test_window_record_says_the_plans_form_and_counts_its_reruns():
+    """`rt.explain()["queries"][q]["window"]` and `device_metrics()`: the
+    static form, and the two counters of a carry that overflowed (each a
+    recompile); the pull notes its D2H bytes with the profiler."""
+    app = ("@app:deviceWindows('always') @app:playback\n"
+           "define stream S (sym string, p double, v long);\n"
+           "@info(name='q') from S#window.time(1 hour) select sym, "
+           "sum(p) as s, count() as c group by sym insert into O;")
+    m = SiddhiManager()
+    rt = m.create_app_runtime(app)
+    plan = rt._plan_by_name["q"]
+    want = {"kind": "time", "duration_ms": 3_600_000, "grouped": True,
+            "sites": ["sum", "count"], "T": None, "carry_capacity": 1024,
+            "sum_form": "pair_prefix", "block": None,
+            "carry_overflow_reruns": 0, "carry_grows": 0}
+    assert rt.explain()["queries"]["q"]["window"] == want
+    plan.C = 8
+    plan.state = plan._init_state()
+    out = []
+    rt.add_callback("O", lambda evs: out.extend(e.data for e in evs))
+    h = rt.input_handler("S")
+    for i in range(50):
+        h.send(("x", 1.0, 1), timestamp=1000 + 10 * i)
+    rt.flush()
+    assert out[-1] == ("x", 50.0, 50)
+    rec = rt.explain()["queries"]["q"]["window"]
+    # 8 -> 16 -> 32 -> 64: three grows, the one flush re-run each time
+    assert rec == {**want, "T": 64, "carry_capacity": 64,
+                   "carry_overflow_reruns": 3, "carry_grows": 3}
+    assert rt.statistics()["device"]["q"]["window"] == rec
+    assert rt.statistics()["profile"]["plans"]["q"]["bytes"]["d2h"] > 0
+    m.shutdown()
+    # a length window says its length
+    m = SiddhiManager()
+    rt = m.create_app_runtime(
+        "define stream S (sym string, p double, v long);\n"
+        "@info(name='q') from S#window.lengthBatch(4) select max(p) as hi "
+        "insert into O;")
+    rec = rt.explain()["queries"]["q"]["window"]
+    assert (rec["kind"], rec["length"], rec["sites"]) == (
+        "lengthbatch", 4, ["max"])
+    assert "duration_ms" not in rec
+    m.shutdown()
+
+
 def test_f64_all_double_outputs():
     """Slim pack with every output column DOUBLE in f64 mode: the i-pack
     is empty and must be omitted, not stacked (r4 review finding)."""
