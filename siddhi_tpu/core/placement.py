@@ -222,6 +222,7 @@ def _query_entry(rt, plan) -> Optional[dict]:
         ent["fused"] = plan.fused
     if kind == "window":
         ent["window"] = plan.window
+        ent["window_step"] = dict(plan.window_step)
     if kind == "partition-group":
         ent["queries"] = sorted(
             q.name(f"query_p{plan.index}_{qi}")

@@ -11,8 +11,9 @@ loop becomes closed-form range reductions over the concatenated
 [carry | batch] sequence:
 
   * sliding windows — each event's aggregate is a contiguous-range
-    reduction ending at that event.  The left edge is rank arithmetic
-    for length(L) and a vectorized `searchsorted` for time(D);
+    reduction ending at that event.  The left edge is position
+    arithmetic for length(L) (the valid entries are one contiguous run:
+    `_length_left`) and a vectorized `searchsorted` for time(D);
     sums/counts/avgs read a range of ONE compensated prefix sum (O(T);
     `_range_sum`: exact to the rounding of the range's own contents),
     min/max read a log2 sparse table (O(T log T) build, O(1) per query).
@@ -151,17 +152,37 @@ def _prefix_pairs(v: jnp.ndarray) -> tuple:
     return jax.lax.associative_scan(_pair_add, (v, jnp.zeros_like(v)))
 
 
+def _pair_diff(top: tuple, base: tuple) -> jnp.ndarray:
+    """top - base of two (hi, lo) prefix pairs, rounded once."""
+    (th, tl), (bh, bl) = top, base
+    dh, de = _two_sum(th, -bh)
+    return dh + (de + (tl - bl))
+
+
 def _range_sum(pfx: tuple, lo: jnp.ndarray, hi=None) -> jnp.ndarray:
     """Sum of the scanned values over positions (lo, hi]; lo == -1 takes
     the range from the start, hi None ends each range at its own index."""
     ph, pl = pfx
     at = jnp.maximum(lo, 0)
-    bh = jnp.where(lo >= 0, ph[at], 0.0)
-    bl = jnp.where(lo >= 0, pl[at], 0.0)
-    if hi is not None:
-        ph, pl = ph[hi], pl[hi]
-    dh, de = _two_sum(ph, -bh)
-    return dh + (de + (pl - bl))
+    base = (jnp.where(lo >= 0, ph[at], 0.0), jnp.where(lo >= 0, pl[at], 0.0))
+    return _pair_diff(pfx if hi is None else (ph[hi], pl[hi]), base)
+
+
+def _trailing_sum(pfx: tuple, L: int) -> jnp.ndarray:
+    """Sum of the scanned values over each index's last L positions,
+    (i - L, i], 0 < L < n: `_range_sum(pfx, arange(n) - L)` with the base
+    pair read as a static shift by L (zeros in front), not a gather."""
+    back = lambda p: jnp.concatenate([jnp.zeros(L, p.dtype), p[:-L]])
+    return _pair_diff(pfx, (back(pfx[0]), back(pfx[1])))
+
+
+def _length_left(gpos: jnp.ndarray, first_valid, L: int) -> jnp.ndarray:
+    """Left edge of each position's length(L) window where the valid
+    entries are ONE contiguous run that starts at `first_valid`: what
+    `searchsorted(vcnt, maximum(vcnt - L, 0), side="right")` finds over
+    the running count of valid entries, at every position of a run that
+    is not empty and before it."""
+    return jnp.maximum(gpos - (L - 1), first_valid)
 
 
 def _segment_start(seg: jnp.ndarray) -> jnp.ndarray:
@@ -518,6 +539,19 @@ class DeviceWindowAggPlan(QueryPlan):
         # recompile that a steady window of traffic may not hold
         self.counters = {"carry_overflow_reruns": 0, "carry_grows": 0}
         self._T = None              # the last dispatch's padded length
+        # the form each indexed pass of the step takes (`window_step` in
+        # EXPLAIN / device_metrics): `_build_step_fn` traces what this
+        # says, and it says what the query lets the plan see.  A length
+        # window's edges and a tumbling kind's buckets are arithmetic on
+        # positions, a time window's a search; an ungrouped length sum
+        # reads its base prefix L entries back, a grouped one through the
+        # (segment, position) order; no filter, nothing to compact.
+        self.window_step = {
+            "left_edge": "search" if self.kind == "time" else "arithmetic",
+            "prefix_read": ("segmented" if self.group_keys else
+                            "shift" if self.kind == "length" else "gather"),
+            "compaction": "scatter" if self._filter is not None
+            else "identity"}
 
         self.state = self._init_state()
         jax.eval_shape(self._step_fn(8, self.C), self.state, self._dummy(8))
@@ -618,6 +652,7 @@ class DeviceWindowAggPlan(QueryPlan):
         D = getattr(self, "D", 0)
         N = C + T
         FDT = self.fdt
+        forms = self.window_step
         out_types = [a.type for a in self.out_schema.attributes]
         # every phase runs under a jax.named_scope, so each device
         # operation of a trace names the phase it belongs to
@@ -679,10 +714,21 @@ class DeviceWindowAggPlan(QueryPlan):
                     row_ok = row_ok & having.fn(henv)
                 return outs, row_ok
 
-        def carry(seen, all_ts, pending, env_all, k):
-            """The next state: the C entries that end at C + k."""
+        def carry(state, seen, all_ts, pending, env_all, k):
+            """The next state: the C entries that end at C + k.
+
+            INVARIANT (the sliding kinds' closed forms rest on it): the
+            carry is packed RIGHT, its `valid` a suffix.  The batch is
+            compacted left (`bvalid` a prefix of k), so the valid entries
+            of [carry | batch] are the one run [C - sum(valid), C + k);
+            `pending` keeps a suffix of that run, and the slice that ends
+            at C + k puts it at the right edge again.  `_grow` pads on the
+            left; a snapshot restores the arrays as they were.
+            (`all_ts` None: a window that reads no time carries its
+            column through untouched.)"""
             sl = lambda a: jax.lax.dynamic_slice(a, (k,), (C,))
-            nst = {"seen": seen, "ts": sl(all_ts), "valid": sl(pending)}
+            nst = {"seen": seen, "valid": sl(pending),
+                   "ts": state["ts"] if all_ts is None else sl(all_ts)}
             for c in carry_cols:
                 nst[f"c.{c}"] = sl(env_all[c])
             return nst
@@ -717,19 +763,23 @@ class DeviceWindowAggPlan(QueryPlan):
             return aggs
 
         def step_sliding(state, bts, bvalid, bcols, k):
-            raw_bts = bts
-            all_ts = jnp.concatenate([state["ts"], bts])
-            all_ts = jax.lax.associative_scan(jnp.maximum, all_ts)  # monotone
+            """`bts` None: a length window no expression of which reads
+            time (`_needs_ts`) builds, scans and carries no timestamps."""
             all_valid = jnp.concatenate([state["valid"], bvalid])
             env_all = {c: jnp.concatenate([state[f"c.{c}"], bcols[c]])
                        for c in carry_cols}
-            env_all["__timestamp__"] = all_ts
+            all_ts = None
+            if bts is not None:
+                all_ts = jax.lax.associative_scan(      # monotone
+                    jnp.maximum, jnp.concatenate([state["ts"], bts]))
+                env_all["__timestamp__"] = all_ts
             gpos = jnp.arange(N, dtype=jnp.int64)
             with scope("window/left_edge"):
-                vcnt = jnp.cumsum(all_valid.astype(jnp.int64))
-                if kind == "length":
-                    want = jnp.maximum(vcnt - L, 0)
-                    left = jnp.searchsorted(vcnt, want, side="right")
+                if forms["left_edge"] == "arithmetic":
+                    # the valid run of [carry | batch] (see `carry`)
+                    first_valid = C - jnp.sum(state["valid"],
+                                              dtype=jnp.int64)
+                    left = _length_left(gpos, first_valid, L)
                 else:
                     left = jnp.searchsorted(all_ts, all_ts - D, side="right")
                 seg = group_seg(env_all, all_valid, N) if group_keys else None
@@ -738,11 +788,21 @@ class DeviceWindowAggPlan(QueryPlan):
             def wsum(v):
                 """Windowed sum over [left, gpos] — per-group via the
                 segmented machinery, else a range of arrival order (no
-                sort)."""
+                sort): of a length window the last L entries, invalid
+                ones holding zeros."""
                 with scope("window/sum"):
-                    if group_keys:
+                    if forms["prefix_read"] == "segmented":
                         return _seg_window_sum(seg, v, left, gpos, N)
+                    if forms["prefix_read"] == "shift":
+                        return _trailing_sum(_prefix_pairs(v), L)
                     return _range_sum(_prefix_pairs(v), left - 1)
+
+            def wcount():
+                """Valid entries in [left, gpos], in the float type."""
+                if forms["prefix_read"] == "shift":
+                    # exact: an integer under 2^24
+                    return jnp.clip(gpos - first_valid + 1, 0, L).astype(FDT)
+                return wsum(all_valid.astype(FDT))
 
             aggs_full = []
             for i, (nm, _arg, _ot) in enumerate(sites):
@@ -759,35 +819,35 @@ class DeviceWindowAggPlan(QueryPlan):
                             table, jnp.minimum(left, gpos), gpos,
                             nm == "max"))
                     continue
-                v = (all_valid.astype(FDT) if nm == "count"
-                     else jnp.where(all_valid, vals[i], 0.0))
-                s = wsum(v)
+                if nm == "count":
+                    aggs_full.append(wcount())
+                    continue
+                s = wsum(jnp.where(all_valid, vals[i], 0.0))
                 if nm == "avg":
-                    s = s / jnp.maximum(wsum(all_valid.astype(FDT)), 1.0)
+                    s = s / jnp.maximum(wcount(), 1.0)
                 aggs_full.append(s)
 
             # rows align with the compacted batch part (raw timestamps:
             # the monotonic clamp is internal to expiry math only)
             aggs = [a[C:] for a in aggs_full]
             benv = {c: bcols[c] for c in cols}
-            benv["__timestamp__"] = raw_bts
+            if bts is not None:
+                benv["__timestamp__"] = bts
             outs, row_ok = finish(benv, aggs, bvalid)
-            row_ts = raw_bts
 
             # carry = last C entries ending at C+k, minus departed ones
             with scope("window/carry"):
-                if kind == "length":
-                    total_v = vcnt[N - 1]
-                    start_k = jnp.searchsorted(
-                        vcnt, jnp.maximum(total_v - L, 0), side="right")
+                if forms["left_edge"] == "arithmetic":
+                    start_k = jnp.maximum(C + k - L, first_valid)
                 else:
                     last_ts = all_ts[jnp.maximum(C + k - 1, 0)]
                     start_k = jnp.searchsorted(all_ts, last_ts - D,
                                                side="right")
                 keep = (gpos >= start_k) & all_valid
-                nst = carry(state["seen"] + k, all_ts, keep, env_all, k)
+                nst = carry(state, state["seen"] + k, all_ts, keep, env_all,
+                            k)
                 overflow = (jnp.sum(keep) > C).astype(jnp.int32)
-            return nst, outs, row_ok, row_ts, overflow
+            return nst, outs, row_ok, bts, overflow
 
         def step_lengthbatch(state, bts, bvalid, bcols, k):
             all_ts = jnp.concatenate([state["ts"], bts])
@@ -808,8 +868,8 @@ class DeviceWindowAggPlan(QueryPlan):
             emit = all_valid & (gidx < completed)
             outs, row_ok = finish(env_all, aggs, emit)
             with scope("window/carry"):
-                nst = carry(total, all_ts, all_valid & (gidx >= completed),
-                            env_all, k)
+                nst = carry(state, total, all_ts,
+                            all_valid & (gidx >= completed), env_all, k)
             return nst, outs, row_ok, all_ts, jnp.int32(0)
 
         def step_extbatch(state, bts, bvalid, bcols, k):
@@ -847,12 +907,17 @@ class DeviceWindowAggPlan(QueryPlan):
             outs, row_ok = finish(env_all, aggs, emit)
             with scope("window/carry"):
                 pend = all_valid & (b == blast)
-                nst = carry(state["seen"] + k, all_ts, pend, env_all, k)
+                nst = carry(state, state["seen"] + k, all_ts, pend, env_all,
+                            k)
                 nst["start"] = start
                 overflow = (jnp.sum(pend) > C).astype(jnp.int32)
             return nst, outs, row_ok, all_ts, overflow
 
         def compact(mask, arr, fill):
+            """`arr`'s entries under `mask` moved to the front, `fill`
+            behind them.  With no filter the mask is a prefix already."""
+            if forms["compaction"] == "identity":
+                return jnp.where(mask, arr, fill)
             pos = jnp.cumsum(mask.astype(jnp.int32), dtype=jnp.int32) - mask
             wpos = jnp.where(mask, pos, T)
             return jnp.full((T,), fill, arr.dtype).at[wpos].set(
@@ -875,19 +940,23 @@ class DeviceWindowAggPlan(QueryPlan):
                     ts64 = env["__ts_base__"] \
                         + env["__ts_off__"].astype(jnp.int64)
                 else:
-                    ts64 = jnp.zeros(T, jnp.int64)
+                    ts64 = None         # sliding length: time is unread
                 mask = jnp.arange(T, dtype=jnp.int32) < env["__nvalid__"]
                 if filt is not None:
                     fenv = dict(env)
-                    fenv["__timestamp__"] = ts64
+                    if ts64 is not None:
+                        fenv["__timestamp__"] = ts64
                     mask = mask & filt.fn(fenv)
                 # compact filtered events to the front: one i32 cumsum + one
                 # scatter per column (a stable argsort here cost 244s of
                 # XLA compile at T=16K and dominated runtime)
                 with scope("window/compact"):
-                    k = jnp.sum(mask, dtype=jnp.int32)
+                    k = env["__nvalid__"].astype(jnp.int32) \
+                        if forms["compaction"] == "identity" \
+                        else jnp.sum(mask, dtype=jnp.int32)
                     bvalid = jnp.arange(T, dtype=jnp.int32) < k
-                    bts = compact(mask, ts64, _TS_PAD)
+                    bts = None if ts64 is None \
+                        else compact(mask, ts64, _TS_PAD)
                     bcols = {c: compact(mask, env[c], 0) for c in cols}
                 if kind == "lengthbatch":
                     res = step_lengthbatch(state, bts, bvalid, bcols, k)
@@ -1165,7 +1234,8 @@ class DeviceWindowAggPlan(QueryPlan):
             return {}
         return {"window_capacity": int(self.C), "window_fill": fill,
                 "window_fill_ratio": round(fill / max(self.C, 1), 4),
-                "window": self.window}
+                "window": self.window,
+                "window_step": dict(self.window_step)}
 
     def state_dict(self) -> dict:
         return {"state": {k: np.asarray(v) for k, v in self.state.items()},

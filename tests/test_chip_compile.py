@@ -134,8 +134,9 @@ def test_window1ks_step_compiles_for_the_chip_at_its_stated_size(topo):
     """`window1k.sat`'s step: 2^18 events on a 1024-entry carry, the price
     in and one f32 word a row out (the H2D and D2H the cell reports, 4.0
     and 4.0 bytes an event), the window's sum a range of the (hi, lo) pair
-    prefix.  What is counted here is what a `perf_opt` on this step takes
-    out: the per-element indexed operations the lane block no longer has."""
+    prefix.  Since PR 45 the step has, as the lane block, NO per-element
+    indexed operation: the left edge is arithmetic, the base prefix a static
+    shift, the compaction the identity (`window_step`)."""
     import os
     from siddhi_tpu import SiddhiManager
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -164,11 +165,12 @@ def test_window1ks_step_compiles_for_the_chip_at_its_stated_size(topo):
         jax.config.update("jax_enable_compilation_cache", prev)
     ops = Counter(re.findall(r"= \S+ ([a-z\-]+)\(", compiled.as_text()))
     assert ops["fusion"] > 0
-    # the reads of a prefix at each window's left edge, hi and lo of the
-    # price's and of the count's, and the searches for the edge; no sort
-    assert 0 < ops["gather"] <= 8 and ops["sort"] == 0
-    assert {k: ops[k] for k in COLLECTIVES if ops[k]} == {}
+    assert plan.window_step == {"left_edge": "arithmetic",
+                                "prefix_read": "shift",
+                                "compaction": "identity"}
+    assert {k: ops[k] for k in INDEXED + COLLECTIVES if ops[k]} == {}
+    assert "while" not in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes // T == 4         # the price as f32
     assert mem.output_size_in_bytes // T == 4           # one f32 word a row
-    assert mem.temp_size_in_bytes < 1 << 28             # 25 MB: the chip has 16 GB
+    assert mem.temp_size_in_bytes < 1 << 24             # 2.5 MB (25 before PR 45)

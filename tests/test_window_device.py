@@ -1,12 +1,57 @@
 """Device window-aggregation plans: differential equality against the
 sequential host interpreter on randomized streams (the device kernel's
 claim is exact reference semantics — SURVEY §4 differential strategy)."""
+import functools
 import random
 
+import jax
+import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from siddhi_tpu import SiddhiManager
+from siddhi_tpu.core import window_device as wd
 from siddhi_tpu.core.window_device import DeviceWindowAggPlan
+
+
+def cents(rng, n):
+    """0.01-step prices: sums that ROUND, so the order of the arithmetic
+    shows in the bits."""
+    return np.round(rng.uniform(90.0, 130.0, n) * 100) / 100
+
+
+# the file's lightest cases come first (the tier-1 run hands the files with
+# the most cases out first, beside tests/test_spans.py's timing test)
+
+@pytest.mark.parametrize("fill", [0, 3, 1024])
+@pytest.mark.parametrize("k", [0, 5, 64])
+@pytest.mark.parametrize("L", [1, 37, 1000, 1024])
+def test_length_left_and_trailing_sum_against_search_and_gather(fill, k, L):
+    """The three identities on the primitives, over a contiguous valid run
+    [C - fill, C + k) of a 1024 + 64 sequence: at every position of the run
+    and before it the arithmetic left IS the searched one, and on the run the
+    shifted base and the integer count give the gathered forms' bits."""
+    C, T = 1024, 64
+    g = np.arange(C + T)
+    valid = (g >= C - fill) & (g < C + k)
+    v = np.where(valid, cents(np.random.default_rng([fill, k, L]), C + T),
+                 0.0).astype(np.float32)
+    vcnt = jnp.cumsum(jnp.asarray(valid).astype(jnp.int64))
+    searched = np.asarray(jnp.searchsorted(
+        vcnt, jnp.maximum(vcnt - L, 0), side="right"))
+    left = np.asarray(wd._length_left(jnp.asarray(g), C - fill, L))
+    if fill + k:        # no entry valid: no run, no row, and nothing kept
+        assert np.array_equal(left[:C + k], searched[:C + k])
+    pfx = wd._prefix_pairs(jnp.asarray(v))
+    gathered = np.asarray(wd._range_sum(pfx, jnp.asarray(searched) - 1))
+    shifted = np.asarray(wd._trailing_sum(pfx, L))
+    assert np.array_equal(shifted[valid].view(np.uint32),
+                          gathered[valid].view(np.uint32))
+    counted = np.asarray(wd._range_sum(
+        wd._prefix_pairs(jnp.asarray(valid).astype(jnp.float32)),
+        jnp.asarray(searched) - 1))
+    assert np.array_equal(np.clip(g - (C - fill) + 1, 0, L)[valid],
+                          counted[valid])
 
 
 def run_app(app, rows, batch_sizes=None, rng=None):
@@ -396,3 +441,303 @@ def test_external_time_batch_device_engaged():
         "insert into O;")
     assert any(isinstance(p, DeviceWindowAggPlan) for p in rt._plans)
     m.shutdown()
+
+
+# -- the step's closed forms (PR 45) -----------------------------------------
+# A length window's left edge by arithmetic, its base prefix read as a static
+# shift, its count an integer, and no compaction scatter where no filter is:
+# each against the searched / gathered form it replaced, bit for bit on every
+# delivered row.  What they rest on: the valid entries of [carry | batch] are
+# ONE contiguous run (the carry packed right, the batch compacted left).  (The
+# identities on the primitives alone open the file.)
+
+W_HEAD = ("@app:playback @app:deviceWindows('always')\n"
+          "define stream S (symbol string, price double, volume int);\n"
+          "@info(name='q') from S")
+
+
+@functools.partial(jax.jit, static_argnums=(4,))
+def _searched(cvalid, cprice, price, k, L):
+    valid = jnp.concatenate([cvalid, jnp.arange(len(price)) < k])
+    v = jnp.where(valid, jnp.concatenate([cprice, price]), 0.0)
+    vcnt = jnp.cumsum(valid.astype(jnp.int64))
+    left = jnp.searchsorted(vcnt, jnp.maximum(vcnt - L, 0), side="right")
+    s = wd._range_sum(wd._prefix_pairs(v), left - 1)
+    c = wd._range_sum(wd._prefix_pairs(valid.astype(v.dtype)), left - 1)
+    return s, s / jnp.maximum(c, 1.0), c
+
+
+def searched_step(state, price, k, L):
+    """The general forms written out, as the step traced them before the
+    closed forms: running valid counts, the left edge by `searchsorted`,
+    each prefix pair GATHERED at `left - 1`, the count a second pair scan.
+    -> (sum, avg, count) of the batch's k rows."""
+    C = len(state["valid"])
+    sac = _searched(state["valid"], state["c.price"], price, k, L)
+    return [np.asarray(x)[C:C + k].astype(np.float64) for x in sac]
+
+
+def is_suffix(valid) -> bool:
+    v = np.asarray(valid).astype(np.int8)
+    return bool((np.diff(v) >= 0).all())
+
+
+class Driven:
+    """One window query fed batch by batch, its plan's state at hand."""
+
+    def __init__(self, query, head=W_HEAD):
+        self.mgr = SiddhiManager()
+        self.rt = self.mgr.create_app_runtime(head + query)
+        self.plan = self.rt._plan_by_name["q"]
+        assert isinstance(self.plan, DeviceWindowAggPlan)
+        self.got = []
+        self.rt.add_batch_callback("O", lambda b: self.got.append(
+            {k: np.array(v) for k, v in b.columns.items()}))
+        self.rt.start()
+        self.sent = 0
+
+    def send(self, price, volume=None, symbol=None):
+        """-> the delivered columns of this batch (None: no row)."""
+        n = len(price)
+        cols = {"symbol": np.zeros(n, np.int32) if symbol is None else symbol,
+                "price": np.asarray(price, np.float64),
+                "volume": np.ones(n, np.int32) if volume is None else volume}
+        self.got.clear()
+        self.rt.input_handler("S").send_batch(
+            cols, 1000 + self.sent + np.arange(n, dtype=np.int64))
+        self.rt.flush()
+        self.sent += n
+        assert len(self.got) <= 1
+        return self.got[0] if self.got else None
+
+    def state(self):
+        return {k: np.asarray(v) for k, v in self.plan.state.items()}
+
+    def close(self):
+        self.mgr.shutdown()
+
+
+def padded32(x, T):
+    out = np.zeros(T, np.float32)
+    out[:len(x)] = x
+    return out
+
+
+SAC = " select sum(price) as s, avg(price) as a, count() as c insert into O;"
+# (window length, batch sizes): the carry empty, part-filled and full, batches
+# under their padded T, a batch longer and shorter than the window
+CLOSED_FORM_CASES = {
+    "length1": (1, [1, 3, 9, 2]),
+    "part_filled_carry": (5, [3, 1, 7, 8, 2, 16]),
+    "L_equals_C": (8, [5, 8, 3, 20, 1]),
+    "L_not_a_power_of_two": (1000, [300, 600, 1500, 7, 2048, 999]),
+    "L_equals_C_1024": (1024, [1000, 100, 3000, 24]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CLOSED_FORM_CASES))
+@pytest.mark.parametrize("filtered", [False, True], ids=["all", "thinned"])
+def test_closed_forms_deliver_the_searched_forms_bits(case, filtered):
+    L, sizes = CLOSED_FORM_CASES[case]
+    rng = np.random.default_rng([L, filtered])
+    d = Driven(("[volume >= 400]" if filtered else "")
+               + f"#window.length({L})" + SAC)
+    try:
+        assert d.plan.window_step == {
+            "left_edge": "arithmetic", "prefix_read": "shift",
+            "compaction": "scatter" if filtered else "identity"}
+        for n in sizes:
+            price = cents(rng, n)
+            volume = rng.integers(1, 1000, n).astype(np.int32)
+            keep = volume >= 400 if filtered else np.ones(n, bool)
+            before = d.state()
+            T = wd.pow2_at_least(n)
+            want = searched_step(before, padded32(price[keep], T),
+                                 int(keep.sum()), L)
+            out = d.send(price, volume)
+            if not keep.any():
+                assert out is None
+                continue
+            for name, w in zip("sac", want):
+                assert np.array_equal(out[name].astype(np.float64), w), \
+                    (case, n, name)
+            after = d.state()
+            assert is_suffix(after["valid"])
+            assert after["valid"].sum() == min(
+                L, before["valid"].sum() + keep.sum())
+    finally:
+        d.close()
+
+
+def test_closed_forms_a_filter_that_passes_nothing():
+    """k = 0: no row, the carry as it was; then traffic again."""
+    d = Driven("[volume >= 400]#window.length(5)" + SAC)
+    try:
+        rng = np.random.default_rng(5)
+        d.send(cents(rng, 3), np.full(3, 500, np.int32))
+        before = d.state()
+        assert d.send(cents(rng, 6), np.zeros(6, np.int32)) is None
+        after = d.state()
+        for k in ("valid", "c.price"):
+            assert np.array_equal(before[k], after[k]), k
+        price = cents(rng, 4)
+        want = searched_step(after, padded32(price, 8), 4, 5)
+        out = d.send(price, np.full(4, 999, np.int32))
+        for name, w in zip("sac", want):
+            assert np.array_equal(out[name].astype(np.float64), w), name
+    finally:
+        d.close()
+
+
+def test_closed_forms_after_a_grow():
+    """`_grow` pads the carry on the LEFT: the valid run stays one run."""
+    L = 5
+    d = Driven(f"#window.length({L})" + SAC)
+    try:
+        rng = np.random.default_rng(11)
+        d.send(cents(rng, 3))
+        d.plan._grow(4 * d.plan.C)
+        assert d.plan.C == 32 and is_suffix(d.state()["valid"])
+        for n in (1, 9, 4):
+            price = cents(rng, n)
+            want = searched_step(d.state(), padded32(price, wd.pow2_at_least(n)),
+                                 n, L)
+            out = d.send(price)
+            for name, w in zip("sac", want):
+                assert np.array_equal(out[name].astype(np.float64), w), name
+            assert is_suffix(d.state()["valid"])
+    finally:
+        d.close()
+
+
+# `state_dict()` of `#window.length(5)` + SAC after ONE 3-event batch, as the
+# step of PR 44 wrote it (its tree, run here): a length window's unread
+# timestamp column holds 0 where that step had carried an entry
+PARENT_STATE = {"C": 8, "state": {
+    "c.price": np.array([0, 0, 0, 0, 0, 101.25, 99.5, 120.75], np.float32),
+    "seen": np.int64(3),
+    "ts": np.array([-(2 ** 62)] * 5 + [0] * 3, np.int64),
+    "valid": np.array([False] * 5 + [True] * 3)}}
+
+
+def test_closed_forms_restore_a_state_the_parents_step_wrote():
+    """The state's layout is unchanged: an old snapshot loads, and the
+    window goes on from it."""
+    d = Driven("#window.length(5)" + SAC)
+    try:
+        fresh = d.plan.state_dict()
+        assert fresh["C"] == PARENT_STATE["C"]
+        assert {k: (v.shape, v.dtype) for k, v in fresh["state"].items()} == {
+            k: (np.shape(v), np.asarray(v).dtype)
+            for k, v in PARENT_STATE["state"].items()}
+        d.plan.load_state_dict(PARENT_STATE)
+        out = d.send([100.5, 90.25, 110.0])
+        assert out["s"].tolist() == [422.0, 512.25, 521.0]
+        assert out["c"].tolist() == [4, 5, 5]
+        # ONE f32 division: correctly rounded on the CPU, within 2.26 ulps
+        # on a TPU v5e (PERF.md section 6, PR 44)
+        mean = np.array([422.0 / 4, 512.25 / 5, 521.0 / 5])
+        assert (np.abs(out["a"] - mean)
+                <= 3 * np.spacing(mean.astype(np.float32))).all()
+        snap = d.plan.state_dict()
+    finally:
+        d.close()
+    d = Driven("#window.length(5)" + SAC)      # and through its own snapshot
+    try:
+        d.plan.load_state_dict(snap)
+        assert d.send([95.0])["s"].tolist() == [516.5]
+    finally:
+        d.close()
+
+
+def test_closed_forms_grouped_length_takes_the_arithmetic_left():
+    """Grouped `length`: the sums stay segmented and only receive the
+    arithmetic left edge.  Quarter-step prices, so every sum is exact and
+    float64 arithmetic over each window's members owes the same bits."""
+    L = 7
+    d = Driven(f"#window.length({L}) select symbol, sum(price) as s, "
+               "count() as c, max(price) as hi group by symbol "
+               "insert into O;")
+    try:
+        assert d.plan.window_step == {
+            "left_edge": "arithmetic", "prefix_read": "segmented",
+            "compaction": "identity"}
+        rng = np.random.default_rng(3)
+        codes = np.array([d.rt.strings.encode(f"K{i}") for i in range(3)],
+                         np.int32)
+        hist_p, hist_g = [], []
+        for n in (4, 1, 9, 16, 2):
+            price = np.round(rng.uniform(90, 130, n) * 4) / 4
+            group = rng.integers(0, 3, n)
+            out = d.send(price, symbol=codes[group])
+            for j in range(n):
+                hist_p.append(price[j]); hist_g.append(group[j])
+                win = [(p, g) for p, g in zip(hist_p[-L:], hist_g[-L:])
+                       if g == group[j]]
+                assert out["s"][j] == sum(p for p, _g in win)
+                assert out["c"][j] == len(win)
+                assert out["hi"][j] == max(p for p, _g in win)
+            assert is_suffix(d.state()["valid"])
+    finally:
+        d.close()
+
+
+INVARIANT_QUERIES = {
+    "length": "#window.length(6)" + SAC,
+    "length_filtered": "[volume >= 400]#window.length(6)" + SAC,
+    "length_grouped": "#window.length(6) select symbol, sum(price) as s "
+                      "group by symbol insert into O;",
+    "time": "#window.time(5 milliseconds)" + SAC,
+    "lengthBatch": "#window.lengthBatch(6)" + SAC,
+}
+
+
+@pytest.mark.parametrize("kind", sorted(INVARIANT_QUERIES))
+def test_the_carrys_valid_is_a_suffix_after_every_step(kind):
+    """The invariant itself (`carry()` in `_build_step_fn`): the carry is
+    packed right, whatever the kind, the batch and the filter."""
+    d = Driven(INVARIANT_QUERIES[kind])
+    try:
+        rng = np.random.default_rng(len(kind))
+        for n in (1, 4, 9, 2, 30, 3, 1):
+            d.send(cents(rng, n), rng.integers(1, 1000, n).astype(np.int32),
+                   rng.integers(0, 3, n).astype(np.int32))
+            st = d.state()
+            assert is_suffix(st["valid"]), (kind, n, st["valid"])
+            assert st["valid"].shape == (d.plan.C,)
+    finally:
+        d.close()
+
+
+WINDOW_STEP_RECORDS = {
+    "length": ("#window.length(9) select avg(price) as a insert into O;",
+               ("arithmetic", "shift", "identity")),
+    "length_filter": ("[volume > 3]#window.length(9) select avg(price) as a "
+                      "insert into O;", ("arithmetic", "shift", "scatter")),
+    "length_grouped": ("#window.length(9) select symbol, avg(price) as a "
+                       "group by symbol insert into O;",
+                       ("arithmetic", "segmented", "identity")),
+    "time": ("#window.time(1 sec) select avg(price) as a insert into O;",
+             ("search", "gather", "identity")),
+    "lengthBatch": ("#window.lengthBatch(9) select avg(price) as a "
+                    "insert into O;", ("arithmetic", "gather", "identity")),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(WINDOW_STEP_RECORDS))
+def test_window_step_record_says_the_form_of_each_indexed_pass(kind):
+    """`window_step`, a SIBLING of `window` in `rt.explain()` and
+    `device_metrics()`: what the step traced, static for the plan's life."""
+    query, (left, read, compaction) = WINDOW_STEP_RECORDS[kind]
+    d = Driven(query)
+    try:
+        want = {"left_edge": left, "prefix_read": read,
+                "compaction": compaction}
+        ent = d.rt.explain()["queries"]["q"]
+        assert ent["window_step"] == want
+        assert "left_edge" not in ent["window"]
+        d.send([100.0, 101.0], np.array([5, 5], np.int32))
+        assert d.plan.device_metrics()["window_step"] == want
+        assert d.rt.explain()["queries"]["q"]["window_step"] == want
+    finally:
+        d.close()
