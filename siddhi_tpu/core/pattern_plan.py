@@ -73,6 +73,19 @@ def _fused_capacity(n: int) -> int:
     return max(128, -(-2 * n // 128) * 128)
 
 
+def _sticky_sixteenth(n: int, held: int, lo: int = 1) -> int:
+    """`n` rows of a grid's major axis, padded: up to a granule of a
+    sixteenth of n's power of two (never under `lo`), so that a padded row,
+    which costs what a real one does, is under an eighth of the count; and
+    sticky, since every distinct count is a compile: `held`, the count in
+    use, stays while it serves (a count that drifts inside its granule moves
+    nothing), grows when n passes it, and is dropped for what n needs once
+    it is over four times that."""
+    g = max(lo, pow2_at_least(n, lo=1) // 16)
+    need = -(-n // g) * g
+    return need if held < need or held > 4 * need else held
+
+
 def _stable_lane_order(part: np.ndarray) -> np.ndarray:
     """The permutation `np.lexsort((seq, part))` returns, for rows that are
     ALREADY in seq order inside every lane (the caller's invariant): a
@@ -548,7 +561,7 @@ class DevicePatternPlan(QueryPlan):
         # the per-lane single-arm resolution flags for non-`every` heads
         self._lane_tail: Optional[dict] = None
         self._lane_prev = np.zeros(0, dtype=np.int64)
-        self._lane_F = 0
+        self._lane_F = self._lane_L = 0     # the lane grid in use, sticky
         self._arm_done: Optional[np.ndarray] = None
         self.family = "seq"
         self._partitioned = part_key_fns is not None or \
@@ -794,7 +807,7 @@ class DevicePatternPlan(QueryPlan):
             # stateless lane families size their (L, F) grid per
             # flush: a hot-added key is just a new lane id — no
             # device-state growth, no recompile below the next
-            # pow2 lane bucket
+            # step of the lane axis (_sticky_sixteenth)
             if self._chunk_cfg is None and len(k2p) >= self.P:
                 self._grow(2 * self.P)
             p = k2p[k] = len(k2p)
@@ -1012,7 +1025,10 @@ class DevicePatternPlan(QueryPlan):
         `last`, the newest flush alone, of LANE_FILL's counts (`last` also
         has the grid's `F` and the result's `M`); `grids`, flushes by
         `"<rows>x<F>x<M>"`: more than one entry is a geometry that moved
-        (each new one a compilation).  What the ratios say: `events_replayed`
+        (each new one a compilation).  `lanes_padded` is `lanes_active`
+        (grid rows, once a hot lane is cut) up to a sticky sixteenth of its
+        power of two, at least 8, then to the mesh's device count: under
+        9/8 of it past 128 rows.  What the ratios say: `events_replayed`
         over `events_new`, the tail replay the host pays for keeping no
         pattern state on the device; `cells_total` over `cells_filled`, the
         padding uploaded; `result_cells` over `rows_delivered`, the capacity
@@ -1654,14 +1670,8 @@ class DevicePatternPlan(QueryPlan):
             at = np.arange(int(row_n.sum()))
             src = np.repeat(row_at - g_start, row_n) + at
             cell = np.repeat(np.arange(R) * C - g_start, row_n) + at
-            # rows pad to a sticky granule of a sixteenth of their power
-            # of two (a tail that drifts moves the count by one; every
-            # distinct count is a compile; a padded row costs a real one)
-            g = max(1, pow2_at_least(R) // 16)
-            r_min = -(-R // g) * g
-            Rp = max(self._fused_R, r_min)
-            if Rp > 4 * r_min:
-                Rp = r_min
+            # a tail that drifts moves the row count by one
+            Rp = _sticky_sixteenth(R, self._fused_R)
             if C != self._fused_C:
                 self._fused_M = None     # capacity was sized for the old row
             self._fused_C, self._fused_R = C, Rp
@@ -1677,12 +1687,12 @@ class DevicePatternPlan(QueryPlan):
         dispatch failure rolls the per-lane bookkeeping back so the
         degradation ladder can re-run the flush."""
         saved = (self._lane_tail, self._lane_prev.copy(), self._last_seq,
-                 self._lane_F)
+                 self._lane_F, self._lane_L)
         try:
             return self._run_lanes_flat_inner(ts, seq, scode, cols, part)
         except Exception:
             (self._lane_tail, self._lane_prev, self._last_seq,
-             self._lane_F) = saved
+             self._lane_F, self._lane_L) = saved
             raise
 
     def _lane_order(self, part, seq, run_start) -> tuple:
@@ -1796,12 +1806,15 @@ class DevicePatternPlan(QueryPlan):
                 tsmono = np.maximum.accumulate(ts_l + lift) - lift
                 W = W0 + int(np.max(tsmono - ts_l))
 
-            # lane-grid geometry: the lane axis pads to pow2 (hot-adding
-            # a key keeps the compiled (L, F) shape until the count
-            # crosses the next pow2 — no per-key recompile), and F rides
-            # a sticky 64-granule bucket so tail drift never recompiles:
-            # finer than pow2 because every padded cell multiplies by
-            # the lane count (pow2 wasted up to 2x the whole grid)
+            # lane-grid geometry, both axes sticky so that drift never
+            # recompiles.  The lane axis pads to a sixteenth of its power
+            # of two (_sticky_sixteenth, never under the 8 sublanes of an
+            # uploaded (L, F) grid): hot-adding a key keeps the compiled
+            # shape until the count crosses a sixteenth, and a padded row
+            # is worked, uploaded and pulled like a real one (the power
+            # of two wasted up to half of every grid and result).  F rides
+            # a 64-granule bucket: every padded cell multiplies by the
+            # lane count
             fm = int(counts.max())
             if len(self._lane_prev) < len(self._key_to_part):
                 grown = np.full(len(self._key_to_part), -(2 ** 62),
@@ -1833,7 +1846,8 @@ class DevicePatternPlan(QueryPlan):
                 Lr, N = len(g_counts), len(g_order)
                 F = LANE_CUT        # a cut lane's first row fills it
             self._lane_F = F
-            Lpad = pow2_at_least(max(Lr, 1), lo=8)
+            Lpad = self._lane_L = _sticky_sixteenth(
+                max(Lr, 1), self._lane_L, lo=8)
             if self.mesh is not None:
                 nd = self.mesh.devices.size
                 Lpad = -(-Lpad // nd) * nd      # even lane shards

@@ -38,7 +38,7 @@ def topo():
     (1024, 448, 1),               # pattern1k.sat
     (1024, 448, 4),               # pattern1k-mesh4.sat: 256 lanes a chip
     (1024, 64, 1),                # pattern1k.wire-paced
-    (2048, 2048, 1),              # pattern1k-zipf.sat
+    (1152, 2048, 1),              # pattern1k-zipf.sat (2048 until PR 46)
 ])
 def test_lane_block_compiles_for_the_chip_with_no_indexed_operation(
         topo, lanes, F_, chips):
@@ -77,10 +77,12 @@ def test_lane_block_compiles_for_the_chip_with_no_indexed_operation(
 
 
 def test_pattern200ks_block_compiles_for_the_chip_at_its_stated_size(topo):
-    """`pattern200k.sat`'s flush: 262,144 lanes of 64 events, the key
-    captured (a fourth column in, an eighth word out).  One chip holds it:
-    what a call uploads and returns is the H2D and D2H the cell reports
-    (1,032 and 2,048 bytes an event of a 2^18-event batch)."""
+    """`pattern200k.sat`'s flush: ~146,000 active lanes of 64 events on
+    147,456 grid rows (nine sixteenths of the 262,144 they rode until
+    PR 46), the key captured (a fourth column in, an eighth word out).
+    One chip holds it: what a call uploads and returns is the H2D and D2H
+    the cell reports (580.5 and 1,152 bytes an event of a 2^18-event
+    batch)."""
     import os
     import warnings
     from siddhi_tpu import SiddhiManager
@@ -98,7 +100,9 @@ def test_pattern200ks_block_compiles_for_the_chip_at_its_stated_size(topo):
     kern = plan._parallel_kernel()
     mgr.shutdown()
     kern = ParallelChainKernel(kern.prog, kern.nfak, kern.family)
-    lanes, F_ = 1 << 18, 64
+    from siddhi_tpu.core.pattern_plan import _sticky_sixteenth
+    lanes, F_ = _sticky_sixteenth(146_080, 0, lo=8), 64
+    assert lanes == 147_456
     one = SingleDeviceSharding(topo.devices[0])
 
     def of(shape, dtype):
@@ -125,9 +129,10 @@ def test_pattern200ks_block_compiles_for_the_chip_at_its_stated_size(topo):
                                "pairs_per_call": 2 * lanes * F_ * F_,
                                "lanes": lanes, "F": F_, "M": F_}
     mem = compiled.memory_analysis()
-    assert mem.argument_size_in_bytes // lanes == 4 * F_ * 4 + 8     # 1,032
-    assert mem.output_size_in_bytes == lanes * 8 * F_ * 4   # 2,048 an event
-    assert mem.temp_size_in_bytes < 1 << 30     # 0.67 GB: the chip has 16
+    assert mem.argument_size_in_bytes // lanes == 4 * F_ * 4 + 8   # a lane
+    assert mem.argument_size_in_bytes / (1 << 18) == 580.5      # an event
+    assert mem.output_size_in_bytes / (1 << 18) == 1152.0       # an event
+    assert mem.temp_size_in_bytes < 1 << 30     # the chip has 16 GB
 
 
 def test_window1ks_step_compiles_for_the_chip_at_its_stated_size(topo):

@@ -6,9 +6,9 @@ Held here against the plain form it replaced, kept below as the reference
 (`RefPack`: np.unique for key -> lane, lexsort((seq,)) for the union,
 lexsort((seq, part)) for the lanes): the same input must give the same
 permutation, the same lane ids and, flush after flush, byte for byte the
-same (Lpad, F) grids, tails, prev seqs and F bucket, so the jitted block
-and everything it emits are the parent's.  `lane_pack_order` (EXPLAIN,
-device_metrics) says which form each flush took."""
+same (Lpad, F) grids, tails, prev seqs and sticky F and L, so the jitted
+block and everything it emits are the plain form's.  `lane_pack_order`
+(EXPLAIN, device_metrics) says which form each flush took."""
 import numpy as np
 import pytest
 
@@ -81,14 +81,15 @@ class RefPack:
     """`part_of`, `_finalize_chunks` steps 1-2 and `_run_lanes_flat_inner`
     in the plain form: two comparison sorts and an np.unique a flush.  It
     reads the plan's static shape only (stream codes, gridded attributes,
-    `within`) and keeps its own key map, tails, prev seqs and F."""
+    `within`) and keeps its own key map, tails, prev seqs and the sticky
+    grid, F and L."""
 
     def __init__(self, plan):
         self.plan = plan
         self.k2p: dict = {}
         self.tail = None
         self.prev = np.zeros(0, dtype=np.int64)
-        self.F = 0
+        self.F = self.L = 0
 
     def part_of(self, sid, b):
         keys = self.plan.part_key_fns[sid](b)
@@ -172,7 +173,18 @@ class RefPack:
         if F > 4 * f_min:
             F = f_min
         self.F = F
-        Lpad = pow2_at_least(max(Lr, 1), lo=8)
+        # the lane axis: up to a sixteenth of the count's power of two, a
+        # granule of at least 8; what is in use stays while it serves and
+        # is dropped once it is over four times what the flush needs
+        p = 8
+        while p < Lr:
+            p *= 2
+        g = max(8, p // 16)
+        l_min = (Lr + g - 1) // g * g
+        Lpad = max(self.L, l_min)
+        if Lpad > 4 * l_min:
+            Lpad = l_min
+        self.L = Lpad
         budget = LOCAL_SPAN - (1 << 16)
         ts_base = max(int(ts.min()), int(ts.max()) - budget)
         seq_base = max(int(seq.min()), int(seq.max()) - budget)
@@ -305,7 +317,7 @@ class Rig:
         pl, ref = self.plan, self.ref
         assert pl._key_to_part == ref.k2p
         assert list(pl._key_to_part) == list(ref.k2p)
-        assert pl._lane_F == ref.F
+        assert (pl._lane_F, pl._lane_L) == (ref.F, ref.L)
         assert pl._lane_prev.dtype == ref.prev.dtype
         assert np.array_equal(pl._lane_prev, ref.prev)
         for k in ("ts", "seq", "scode", "part"):
@@ -319,9 +331,11 @@ class Rig:
 
     def restored(self):
         """A new runtime restored from this one's snapshot; the reference
-        carries on as it is."""
+        carries on as it is, but for the sticky grid, which no snapshot
+        holds: a restored plan sizes its first flush anew."""
         snap = self.rt.snapshot()
         self.mgr.shutdown()
+        self.ref.F = self.ref.L = 0
         return Rig(self.app, ref=self.ref)._restore(snap)
 
     def _restore(self, snap):
@@ -436,8 +450,8 @@ FLOWS = {
 
 @pytest.mark.parametrize("flow", list(FLOWS))
 def test_every_flush_packs_what_the_plain_reference_packs(flow):
-    """(c): ev, _lane_tail, _lane_prev, _lane_F and the lane ids, flush
-    after flush, are the plain lexsort + unique implementation's."""
+    """(c): ev, _lane_tail, _lane_prev, _lane_F, _lane_L and the lane ids,
+    flush after flush, are the plain lexsort + unique implementation's."""
     rig = _flows(flow)
     try:
         assert rig.flushes == FLOWS[flow][0]

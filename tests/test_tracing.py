@@ -278,6 +278,10 @@ def test_slo_breach_trigger_exports_dump(tmp_path):
         # the dump's slowest span names the breaching stage
         assert md["slowest"]["name"] in SPANS
         assert rt.tracing.metrics()["triggers"].get("slo_breach")
+        # the exporter writes the file BEFORE it publishes the dump
+        # (FrameTracer._promote), so the file can be seen a moment early
+        while not rt.tracing.dump_summaries() and time.monotonic() < deadline:
+            time.sleep(0.01)
         assert rt.tracing.dump_summaries()
     finally:
         rt.shutdown()
