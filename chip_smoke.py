@@ -365,7 +365,7 @@ HOST_HEAD = ("@app:devicePatterns('never')\n@app:deviceFilters('never')\n"
 
 
 # C4 warm-up: the lane grid's flat capacity F is a sticky 64-granule bucket
-# (core/pattern_plan.py _run_lanes_flat_inner) that grows whenever the
+# (core/lane_grid.py LaneGrid.pack) that grows whenever the
 # busiest key's event count crosses a multiple of 64.  At 2^18 events over
 # 1000 keys that count sits at 313..332 per flush — astride 320 — so F
 # settles (384 -> 448, one recompile) at the first flush that crosses,

@@ -15,10 +15,11 @@ group ships the flat flush, and a flush longer than a row of a few
 events]`, the block then running over rows x lanes (pattern_plan
 `_fused_cut`).  A lane is a rule: the host puts the flush's matches in
 delivery order (rule, completion, head) by one stable sort on one composite
-key (pattern_plan `_rule_order`), and hands each rule one slice (span
-`route`).  Every result is decoded through one index over its filled cells
-(`_Filled`); a cut flush's alone with that order composed into the index
-(`_decode_cut`), so its columns are written once, in delivery order.
+key (lane_grid `ResultDecoder.rule_order`), and hands each rule one slice
+(span `route`).  Every result is decoded through one index over its filled
+cells (`_Filled`); a cut flush's alone with that order composed into the
+index (`ResultDecoder.cut`), so its columns are written once, in delivery
+order.
 
 Grouping is automatic: >= MIN_GROUP StateInputStream queries with equal
 shape signatures (and no rate/having/limit) fuse; everything else plans
@@ -259,9 +260,6 @@ class MultiQueryDevicePatternPlan:
 
     # -- QueryPlan surface -------------------------------------------------
 
-    def regeometry(self, batch_hint) -> None:
-        self.inner.regeometry(batch_hint)
-
     def device_metrics(self) -> dict:
         """Sampled gauges of the fused kernel (lane = query instance, so
         occupancy here reads as per-query pending-match population)."""
@@ -309,11 +307,11 @@ class MultiQueryDevicePatternPlan:
 
     def _route(self, outs):
         """The flush's matches to one OutputBatch a rule: a batch is a
-        slice of the columns in delivery order (pattern_plan RuleRuns),
+        slice of the columns in delivery order (lane_grid RuleRuns),
         which a cut flush's decode has made already and a flat flush's
         table is put into here."""
         from .batch import EventBatch
-        from .pattern_plan import RuleRuns
+        from .lane_grid import RuleRuns
         from .planner import OutputBatch
 
         if not outs:
@@ -321,7 +319,7 @@ class MultiQueryDevicePatternPlan:
         res = []
         with self.rt.span("route", plan=self.name):
             runs = outs if isinstance(outs, RuleRuns) \
-                else self.inner._rule_runs(outs)
+                else self.inner.decoder.rule_runs(outs)
             starts = runs.starts.tolist()
             for qi, a, b in zip(runs.lanes.tolist(), starts,
                                 starts[1:] + [len(runs.tss)]):

@@ -204,12 +204,7 @@ def _query_entry(rt, plan) -> Optional[dict]:
     fam = getattr(plan, "family", None)
     if kind == "pattern" and fam is not None:
         ent["family"] = fam
-        for key in ("expiry_queries", "first_hit", "lane_pack_order",
-                    "lane_cut", "lane_fill", "result_decode", "indexed_read",
-                    "compaction"):
-            rec = getattr(plan, key, None)
-            if rec:
-                ent[key] = rec
+        ent.update(plan.explain_records())
         families = getattr(plan, "families", None)
         if families:
             rejected = {f: r for f, r in sorted(families.items())

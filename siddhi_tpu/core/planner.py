@@ -163,21 +163,10 @@ class QueryPlan:
     retryable_process = False
     retryable_finalize = False
     _finalize_retry_ok = True
-    batch_hint = None             # SLO controller's current batch target
     pipeline_depth = 0
 
     def process(self, stream_id: str, batch: EventBatch) -> list:
         raise NotImplementedError
-
-    def regeometry(self, batch_hint) -> None:
-        """The SLO controller's batch decision (core/slo.py), applied by
-        the runtime at a flush boundary.  Every plan family derives its
-        device geometry (pad grids, chunk sizes) from batch.n at
-        dispatch, so a new hint only changes FUTURE dispatch shapes —
-        batches already in flight are untouched, and batch-boundary moves
-        are output-invariant (the PR-4 halving machinery's parity
-        argument; asserted by the geometry differentials)."""
-        self.batch_hint = int(batch_hint)
 
     def on_timer(self, now_ms: int) -> list:
         """Called by the scheduler tick (time windows, absent patterns...)."""

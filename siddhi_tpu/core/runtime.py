@@ -1250,9 +1250,9 @@ class SiddhiAppRuntime:
 
     def _apply_batch_target(self, n: int) -> None:
         """Apply an SLO-controller batch decision AT A FLUSH BOUNDARY:
-        future builders freeze at the new capacity and plans learn the
-        hint through their regeometry() hook.  Batches already frozen or
-        in flight are untouched — only where future batch boundaries
+        future builders freeze at the new capacity (plans size their
+        device geometry from batch.n at dispatch).  Batches already frozen
+        or in flight are untouched — only where future batch boundaries
         fall changes, which the geometry-invariance differentials prove
         is output-invariant (faults.split_batch parity, PR 4)."""
         n = max(1, int(n))
@@ -1260,10 +1260,6 @@ class SiddhiAppRuntime:
         # lint: allow (called from _drain at a flush boundary: lock held)
         for b in self._builders.values():
             b.capacity = n
-        for p in self._plans:
-            rg = getattr(p, "regeometry", None)
-            if rg is not None:
-                rg(batch_hint=n)
 
     def flush(self) -> None:
         """Drain all pending builders through the compiled plans.  In

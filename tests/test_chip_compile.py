@@ -100,7 +100,7 @@ def test_pattern200ks_block_compiles_for_the_chip_at_its_stated_size(topo):
     kern = plan._parallel_kernel()
     mgr.shutdown()
     kern = ParallelChainKernel(kern.prog, kern.nfak, kern.family)
-    from siddhi_tpu.core.pattern_plan import _sticky_sixteenth
+    from siddhi_tpu.core.lane_grid import _sticky_sixteenth
     lanes, F_ = _sticky_sixteenth(146_080, 0, lo=8), 64
     assert lanes == 147_456
     one = SingleDeviceSharding(topo.devices[0])

@@ -1,6 +1,6 @@
 """The cut of a fused multi-query flush (pattern_plan.py `_fused_cut`,
-`FUSED_ROW_WINDOWS`, `FUSED_ROW_MIN`): every fused lane sees the ONE shared
-stream, so a flush longer than a row is laid out once as rows `[the last
+`FUSED_ROW_WINDOWS`, `FUSED_ROW_MIN`; its decode: lane_grid.py
+`ResultDecoder.cut`): every fused lane sees the ONE shared stream, so a flush longer than a row is laid out once as rows `[the last
 within-window | new events]` by `_cut_rows`' rule, each row a flush boundary
 the rules never saw, and the block runs over rows x lanes with event grids
 that vary by row only and lifted constants that vary by lane only.  Held
@@ -29,7 +29,7 @@ import jax
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from benchmark.reference import rules_mixed               # noqa: E402
 from siddhi_tpu import SiddhiManager                      # noqa: E402
-from siddhi_tpu.core import pattern_plan                  # noqa: E402
+from siddhi_tpu.core import lane_grid, pattern_plan       # noqa: E402
 from siddhi_tpu.core.multi_query import MultiQueryDevicePatternPlan  # noqa: E402
 from tests.test_lane_decode import masked_rows            # noqa: E402
 
@@ -166,7 +166,7 @@ def _assert_cut(fused, flushes, C):
 @pytest.mark.parametrize("kind", [0, 3])
 def test_cut_by_shape_equals_interpreter_and_reference(kind):
     C = pattern_plan.FUSED_ROW_MIN
-    assert 8 * 41 <= C <= pattern_plan.LANE_CUT     # the floor sets the row
+    assert 8 * 41 <= C <= lane_grid.LANE_CUT     # the floor sets the row
     batches = tape(7, 2600, 3)
     host, _e, _p, _s = run(HOST, app_of(kind), batches)
     dev, ex, plans, _s = run("@app:deviceMesh('never')\n", app_of(kind),
@@ -407,7 +407,7 @@ def packed(plan, rng, counts, M, row_events=1000, seqs_of=None):
     other word, and every cell past a count, random."""
     inner = plan.inner
     R, L = counts.shape
-    words = inner._out_words()
+    words = inner.decoder.words
     n_i = 1 + sum(2 if dt == np.int64 else 1
                   for pack, _w, dt in words.values() if pack == "i")
     n_f = sum(pack == "f" for pack, _w, _dt in words.values())
@@ -572,7 +572,7 @@ def test_the_decode_equals_the_mask_form_row_for_row(case, seed):
     assert sum(ob.batch.n for ob in got) \
         == counts[:, :inner._lanes_real].sum() > 0
     fused = plan.fused
-    assert fused["result_decode"] == {"indexed": 1, "masked": 0}
+    assert fused["result_decode"] == {"indexed": 1}
     assert fused["route_order"] == {
         "keyed": int(order == "keyed"), "lexsort": int(order == "lexsort")}
     mgr.shutdown()
@@ -603,12 +603,12 @@ def test_an_index_past_the_result_raises_and_reads_no_neighbour(monkeypatch):
     mgr, plan = fused_plan(app_of(0))
     rng = np.random.default_rng(3)
     result = packed(plan, rng, rng.integers(1, 16, (4, plan.inner.P)), 16)
-    flat_words = pattern_plan._flat_words
+    flat_words = lane_grid._flat_words
 
     def doubled(a):
         flat, strides = flat_words(a)
         return flat, tuple(2 * s for s in strides)
-    monkeypatch.setattr(pattern_plan, "_flat_words", doubled)
+    monkeypatch.setattr(lane_grid, "_flat_words", doubled)
     with pytest.raises(IndexError, match="decode index past the result"):
         new_form(plan, [result])
     mgr.shutdown()
@@ -635,7 +635,7 @@ def test_a_tick_chunk_beside_the_cut_result_is_one_batch_a_rule(tick_first):
     assert len({ob.callback_name for ob in got}) == len(got)
     fused = plan.fused
     # (the tick's table is handed in ready made: no decode of it here)
-    assert fused["result_decode"] == {"indexed": 1, "masked": 0}
+    assert fused["result_decode"] == {"indexed": 1}
     assert fused["route_order"] == {"keyed": 1, "lexsort": 0}
     # two cut results in one collect (a drained pipeline) join the same way
     got = new_form(plan, [result, result])
@@ -658,7 +658,7 @@ def test_delivered_memory_is_the_batch_s_own():
         obs = new_form(plan, [(ipack, fpack)])
         arrays = [a for ob in obs for a in (
             ob.batch.timestamps, ob.batch.seqs, *ob.batch.columns.values())]
-        scratch = [*inner._scratch._bufs.values()]
+        scratch = [*inner.decoder.scratch._bufs.values()]
         assert len(scratch) > 8
         for a in arrays:
             assert not any(np.shares_memory(a, b)
@@ -755,7 +755,7 @@ def test_spans_route_and_lane_cut_and_the_fused_record():
     assert sorted(ent["fused"]["lane_cut"]) == [
         "cut_length", "events_replayed", "flushes_cut",
         "flushes_uncuttable", "rows"]
-    assert ent["fused"]["result_decode"] == {"indexed": 2, "masked": 0}
+    assert ent["fused"]["result_decode"] == {"indexed": 2}
     assert ent["fused"]["route_order"] == {"keyed": 2, "lexsort": 0}
     assert plans[0].device_metrics()["fused"] == ent["fused"]
     # a flush within a row: the flat form, a lane result of its own, decoded
@@ -764,5 +764,5 @@ def test_spans_route_and_lane_cut_and_the_fused_record():
     assert "lane_cut" not in st["stages"]
     assert st["stages"]["route"]["batches"] == 2
     fused = ex["queries"][plans[0].name]["fused"]
-    assert fused["result_decode"] == {"indexed": 2, "masked": 0}
+    assert fused["result_decode"] == {"indexed": 2}
     assert fused["route_order"] == {"keyed": 2, "lexsort": 0}

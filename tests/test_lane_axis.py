@@ -1,16 +1,15 @@
-"""The lane axis of the partitioned lane grid (pattern_plan.py
-`_sticky_sixteenth`, `_lane_L`): the rows of a flush (its active lanes, or
+"""The lane axis of the partitioned lane grid (lane_grid.py
+`_sticky_sixteenth`, `LaneGrid.L`): the rows of a flush (its active lanes, or
 the cut rows of its hot ones) pad to a granule of a sixteenth of their
 power of two, never under 8, sticky like the grid's F, then to the mesh's
 device count.  Held here: (a) the padded count, by arithmetic and through a
 plan with and without a mesh; (b) the stickiness, by `lane_fill.grids` and
-by jax's count of backend compilations, and its rollback with `_lane_F`
+by jax's count of backend compilations, and its rollback with `LaneGrid.F`
 when a dispatch fails; (c) that padding is only padding: a cut flush and a
 many-short-lanes run with held tails deliver, row for row and in order,
 what the same run delivers with the lane axis forced to the power of two,
 and what the host interpreter delivers.
 """
-import types
 import warnings
 
 import numpy as np
@@ -20,10 +19,10 @@ import test_lane_cut as lc
 import test_many_short_lanes as msl
 from test_lane_pack import _NoDevice
 from siddhi_tpu import SiddhiManager
-from siddhi_tpu.core import pattern_plan
+from siddhi_tpu.core import lane_grid
 from siddhi_tpu.core.nfa_device import pow2_at_least
-from siddhi_tpu.core.pattern_plan import (DevicePatternPlan,
-                                          _sticky_sixteenth)
+from siddhi_tpu.core.lane_grid import _sticky_sixteenth
+from siddhi_tpu.core.pattern_plan import DevicePatternPlan
 
 T0 = 1_700_000_000_000
 
@@ -111,8 +110,7 @@ class Lanes:
         if mesh_devices:
             # the pack reads the mesh's size alone; the dispatch is cut off
             assert not device
-            self.plan.mesh = types.SimpleNamespace(
-                devices=np.empty(mesh_devices))
+            self.plan.grid.n_devices = mesh_devices
         if not device:
             self.plan._pipe = _NoDevice(self.plan._pipe)
             self.plan._dispatch_par = self._record
@@ -134,7 +132,7 @@ class Lanes:
         self.t += 400
         self.price += 0.25
         self.rt.flush()
-        return self.plan._lane_L
+        return self.plan.grid.L
 
     def grids(self):
         return self.rt.explain()["queries"]["q"]["lane_fill"]["grids"]
@@ -209,15 +207,15 @@ def test_a_failed_dispatch_rolls_the_lane_axis_back_with_F():
     try:
         assert rig.flush(range(43)) == 48
         plan = rig.plan
-        before = (plan._lane_F, plan._lane_L, plan._last_seq)
+        before = (plan.grid.F, plan.grid.L, plan._last_seq)
         assert before[:2] == (16, 48)
-        tail = plan._lane_tail
+        tail = plan.grid.tail
         record = plan._dispatch_par
 
         def fails(*a, **k):
             # the pack has sized the flush by now: 130 lanes, 80 events on
             # the busiest
-            assert (plan._lane_F, plan._lane_L) == (192, 144)
+            assert (plan.grid.F, plan.grid.L) == (192, 144)
             raise RuntimeError("no device")
         plan._dispatch_par = fails
         n = 130 + 79
@@ -231,12 +229,12 @@ def test_a_failed_dispatch_rolls_the_lane_axis_back_with_F():
              "price": np.full(n, 101.0)}, n))
         with pytest.raises(RuntimeError, match="no device"):
             plan.finalize()
-        assert (plan._lane_F, plan._lane_L, plan._last_seq) == before
-        assert plan._lane_tail is tail and len(plan._buffered) == 1
+        assert (plan.grid.F, plan.grid.L, plan._last_seq) == before
+        assert plan.grid.tail is tail and len(plan._buffered) == 1
         # the re-run of the same flush sizes it as the first try did
         plan._dispatch_par = record
         plan.finalize()
-        assert (plan._lane_F, plan._lane_L) == (192, 144)
+        assert (plan.grid.F, plan.grid.L) == (192, 144)
         assert rig.packed["__flat.__ts__"] == (144, 192)
     finally:
         rig.close()
@@ -247,9 +245,9 @@ def test_a_failed_dispatch_rolls_the_lane_axis_back_with_F():
 def _force_pow2(monkeypatch):
     """The lane axis as it stood: the next power of two, whatever is held.
     (`_fused_cut`'s rows, which pass no floor, keep the rule.)"""
-    rule = pattern_plan._sticky_sixteenth
+    rule = lane_grid._sticky_sixteenth
     monkeypatch.setattr(
-        pattern_plan, "_sticky_sixteenth",
+        lane_grid, "_sticky_sixteenth",
         lambda n, held, lo=1: pow2_at_least(n, lo=8) if lo == 8
         else rule(n, held, lo))
 
@@ -291,7 +289,7 @@ def cut_run():
     where the power of two has 64."""
     tape = lc.zipf_tape(21, keys=40, n=1500, flushes=4)
     with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(pattern_plan, "LANE_CUT", 128)
+        mp.setattr(lane_grid, "LANE_CUT", 128)
         dev, ent = _in_order("@app:partitionCapacity(64)\n", CUT_Q, tape)
         host, _e = _in_order(lc.HOST, CUT_Q, tape)
         _force_pow2(mp)

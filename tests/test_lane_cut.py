@@ -1,4 +1,4 @@
-"""The cut of hot lanes (pattern_plan.py `LANE_CUT`, `_cut_rows`): a flush
+"""The cut of hot lanes (lane_grid.py `LANE_CUT`, `_cut_rows`): a flush
 whose longest lane holds more than the cut length lays that lane out as
 several rows of the (Lpad, F) grid, each `[the lane's last `within` of
 events | new events]` with the sequence before its new events as the row's
@@ -29,8 +29,9 @@ import jax
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from benchmark.reference.pattern_chain import matches     # noqa: E402
 from siddhi_tpu import SiddhiManager                      # noqa: E402
-from siddhi_tpu.core import nfa_parallel, pattern_plan    # noqa: E402
-from siddhi_tpu.core.pattern_plan import DevicePatternPlan, _cut_rows  # noqa: E402
+from siddhi_tpu.core import lane_grid, nfa_parallel       # noqa: E402
+from siddhi_tpu.core.lane_grid import _cut_rows          # noqa: E402
+from siddhi_tpu.core.pattern_plan import DevicePatternPlan  # noqa: E402
 
 T0 = 1_700_000_000_000
 STREAM = "define stream S (sym string, price double, volume int);\n"
@@ -124,7 +125,7 @@ def reference_rows(tape, within_ms):
 @pytest.fixture
 def cut128(monkeypatch):
     """The module constant lowered: rows of 128 events."""
-    monkeypatch.setattr(pattern_plan, "LANE_CUT", 128)
+    monkeypatch.setattr(lane_grid, "LANE_CUT", 128)
     return 128
 
 
@@ -139,19 +140,19 @@ def _assert_cut(ent, flushes, F):
 
 
 def test_cut_by_shape_equals_interpreter_and_reference():
-    assert pattern_plan.LANE_CUT <= nfa_parallel.DENSE_MAX_F
+    assert lane_grid.LANE_CUT <= nfa_parallel.DENSE_MAX_F
     # 4 keys, 48% of 6000 events a flush on the first: past LANE_CUT
     tape = zipf_tape(7, keys=4, n=6000, flushes=3)
     assert max(np.bincount(b["key"]).max() for b in tape) \
-        > pattern_plan.LANE_CUT
+        > lane_grid.LANE_CUT
     host, _e, _p = run(HOST, SHAPES["chain3"], tape)
     dev, ent, plan = run(DEVICE, SHAPES["chain3"], tape)
     assert plan.family == "scan" and plan._partitioned
     assert dev == host and len(dev) > 5000
     assert dev == reference_rows(tape, 1000)
-    _assert_cut(ent, 3, pattern_plan.LANE_CUT)
+    _assert_cut(ent, 3, lane_grid.LANE_CUT)
     assert plan.device_metrics()["lane_cut"] == ent["lane_cut"]
-    assert plan._lane_F == pattern_plan.LANE_CUT
+    assert plan.grid.F == lane_grid.LANE_CUT
 
 
 def test_cut_by_shape_on_four_virtual_devices(monkeypatch):
@@ -165,7 +166,7 @@ def test_cut_by_shape_on_four_virtual_devices(monkeypatch):
                          SHAPES["chain3"], tape)
     assert plan.mesh is not None and plan.mesh.devices.size == 4
     assert dev == host == reference_rows(tape, 1000)
-    _assert_cut(ent, 2, pattern_plan.LANE_CUT)
+    _assert_cut(ent, 2, lane_grid.LANE_CUT)
     assert ent["first_hit"]["lanes"] % 4 == 0
 
 
@@ -195,7 +196,7 @@ def test_a_cut_on_every_position_of_a_pending_chain(monkeypatch, lead):
     fillers in front move the row boundaries over every phase of the cycle,
     so some boundary falls before, on and after each position of a chain
     that is pending across it."""
-    monkeypatch.setattr(pattern_plan, "LANE_CUT", 32)
+    monkeypatch.setattr(lane_grid, "LANE_CUT", 32)
     cycle = np.array([101.0, 90.0, 105.0, 90.0, 110.0, 90.0, 90.0])
     n = 150
     hot = np.r_[np.full(lead, 90.0), np.tile(cycle, n // 7 + 1)][:n]
@@ -214,7 +215,7 @@ def test_a_cut_on_every_position_of_a_pending_chain(monkeypatch, lead):
     cut = ent["lane_cut"]
     assert cut["flushes_cut"] == 2 and cut["lanes_cut"] == 2, cut
     assert cut["rows_added"] >= 8 and cut["flushes_uncuttable"] == 0
-    assert plan._lane_F == 32    # the first row ends at phase (32 - lead) % 7
+    assert plan.grid.F == 32    # the first row ends at phase (32 - lead) % 7
 
 
 def test_a_key_whose_window_overfills_a_row_is_not_cut(monkeypatch):
@@ -222,7 +223,7 @@ def test_a_key_whose_window_overfills_a_row_is_not_cut(monkeypatch):
     key's: a 64-event row would leave 7 cells for new events, under a
     quarter of it.  The flush keeps one row a lane (F from the longest),
     is counted, and is exact."""
-    monkeypatch.setattr(pattern_plan, "LANE_CUT", 64)
+    monkeypatch.setattr(lane_grid, "LANE_CUT", 64)
     tape = zipf_tape(3, keys=6, n=600, flushes=3)
     host, _e, _p = run(HOST, SHAPES["chain3"], tape)
     dev, ent, plan = run(DEVICE, SHAPES["chain3"], tape)
@@ -230,14 +231,14 @@ def test_a_key_whose_window_overfills_a_row_is_not_cut(monkeypatch):
     cut = ent["lane_cut"]
     assert cut["flushes_uncuttable"] >= 2 and cut["cut_length"] == 64, cut
     assert cut["flushes_cut"] + cut["flushes_uncuttable"] == 3
-    assert plan._lane_F > 64 or cut["flushes_cut"]
+    assert plan.grid.F > 64 or cut["flushes_cut"]
 
 
 def test_cut_rows_geometry(monkeypatch):
     """`_cut_rows` alone: rows of one lane consecutive, in order, each at
     most the cut; each later row starts at the first event within W of the
     event before its new ones; rows tile the new events exactly once."""
-    monkeypatch.setattr(pattern_plan, "LANE_CUT", 100)
+    monkeypatch.setattr(lane_grid, "LANE_CUT", 100)
     counts = np.array([30, 260, 100, 101])
     run_start = np.cumsum(counts) - counts
     tail_n = np.array([5, 20, 0, 0])
@@ -273,7 +274,7 @@ def test_a_uniform_flush_is_not_cut_and_counts_nothing():
     dev, ent, plan = run(DEVICE, SHAPES["chain3"], tape)
     assert ent["lane_cut"] == dict(
         flushes_cut=0, lanes_cut=0, rows_added=0, events_replayed=0,
-        flushes_uncuttable=0, cut_length=pattern_plan.LANE_CUT)
+        flushes_uncuttable=0, cut_length=lane_grid.LANE_CUT)
     assert list(ent)[:8] == ["path", "plan", "kind", "family",
                              "expiry_queries", "first_hit",
                              "lane_pack_order", "lane_cut"], list(ent)

@@ -1,5 +1,5 @@
-"""The decode of a lane block's packed result (pattern_plan.py `_Filled`,
-`_decode_lanes`, `_unpack_block`): ONE index over the filled cells, built
+"""The decode of a lane block's packed result (lane_grid.py `_Filled`,
+`ResultDecoder.lanes`; pattern_plan.py `_unpack_block`): ONE index over the filled cells, built
 from the count header alone and read off the pulled array's strides, every
 word fetched once through it.  Held here, column for column and in order,
 to the form it replaced, kept below as the plain numpy reference
@@ -19,7 +19,7 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from siddhi_tpu import SiddhiManager                      # noqa: E402
-from siddhi_tpu.core import pattern_plan                  # noqa: E402
+from siddhi_tpu.core import lane_grid                     # noqa: E402
 from siddhi_tpu.core.multi_query import MultiQueryDevicePatternPlan  # noqa: E402
 from siddhi_tpu.core.nfa_device import join64_np          # noqa: E402
 from siddhi_tpu.core.pattern_plan import DevicePatternPlan  # noqa: E402
@@ -147,7 +147,7 @@ def packed(plan, rng, counts, M):
     i32 (+ the f64 `f` pack): `counts[l]` matches in lane l; every word of
     every cell random, past the counts too, flags (`having`, presence) 0
     or 1."""
-    words = plan._out_words()
+    words = plan.decoder.words
     n_i = 1 + sum(2 if dt == np.int64 else 1
                   for pack, _w, dt in words.values() if pack == "i")
     n_f = sum(pack == "f" for pack, _w, _dt in words.values())
@@ -174,7 +174,7 @@ def fill_counts(fill, rng, L, M):
 def decode(plan, ipack, fpack):
     """The production path from the pulled result on: `_materialize_par`
     given numpy arrays where the device's would be (the header read, the
-    overflow check, `_decode_lanes`)."""
+    overflow check, `ResultDecoder.lanes`)."""
     out = {"i": ipack} if fpack is None else {"i": ipack, "f": fpack}
     return plan._materialize_par({"out": out, "M": ipack.shape[-1],
                                   "L": ipack.shape[0], "R": None, **BASES})
@@ -215,9 +215,9 @@ def test_the_indexed_decode_equals_the_masked_form(plans, app, fill):
     counts = fill_counts(fill, rng, L, M)
     ipack, fpack = packed(plan, rng, counts, M)
     if app == "having" and fill == "one-row":       # ... which is kept
-        ipack[:, plan._out_words()["__having__"][1], :] = 1
+        ipack[:, plan.decoder.words["__having__"][1], :] = 1
     want = masked_lanes(plan, ipack, fpack, **BASES)
-    before = dict(plan._result_decode)
+    before = dict(plan.decoder.result_decode)
     got = decode(plan, ipack, fpack)
     assert_same_table(got, want)
     if fill == "empty":
@@ -228,15 +228,15 @@ def test_the_indexed_decode_equals_the_masked_form(plans, app, fill):
             assert len(want[0]) == counts.sum()
         assert (want[5] is not None) == (app == "fused")
         assert bool(want[4]) == (app == "or")
-    assert plan._result_decode == {
-        "indexed": before["indexed"] + (want is not None), "masked": 0}
+    assert plan.decoder.result_decode == {
+        "indexed": before["indexed"] + (want is not None)}
 
 
 def test_a_having_that_keeps_no_row_is_no_table(plans):
     plan = plans["having"]
     rng = np.random.default_rng(2)
     ipack, fpack = packed(plan, rng, fill_counts("ragged", rng, 9, 8), 8)
-    ipack[:, plan._out_words()["__having__"][1], :] = 0
+    ipack[:, plan.decoder.words["__having__"][1], :] = 0
     assert masked_lanes(plan, ipack, fpack, **BASES) is None
     assert decode(plan, ipack, fpack) is None
 
@@ -290,12 +290,12 @@ def test_an_index_past_the_result_raises_and_reads_no_neighbour(
     plan = plans["typed"]
     rng = np.random.default_rng(3)
     ipack, fpack = packed(plan, rng, np.full(6, 8), 8)
-    flat_words = pattern_plan._flat_words
+    flat_words = lane_grid._flat_words
 
     def doubled(a):
         flat, strides = flat_words(a)
         return flat, tuple(2 * s for s in strides)
-    monkeypatch.setattr(pattern_plan, "_flat_words", doubled)
+    monkeypatch.setattr(lane_grid, "_flat_words", doubled)
     with pytest.raises(IndexError, match="decode index past the result"):
         decode(plan, ipack, fpack)
 
@@ -341,7 +341,7 @@ def test_the_table_is_the_flush_s_own_memory(plans):
         arrays = [*got[:3], *got[3].values(), *got[4].values()]
         for a in arrays:
             assert not any(np.shares_memory(a, b) for b in
-                           (ipack, fpack, *plan._scratch._bufs.values()))
+                           (ipack, fpack, *plan.decoder.scratch._bufs.values()))
         kept.append((arrays, [a.copy() for a in arrays]))
     for arrays, copies in kept:
         for a, c in zip(arrays, copies):

@@ -1,4 +1,4 @@
-"""The host pack of the partitioned lane grid (pattern_plan.py): the lane
+"""The host pack of the partitioned lane grid (lane_grid.py): the lane
 order of a flush comes from one stable radix pass over the lane id, on
 rows already in arrival order, and the lane id from a dense key table.
 
@@ -15,8 +15,8 @@ import pytest
 from siddhi_tpu import SiddhiManager
 from siddhi_tpu.core.batch import EventBatch
 from siddhi_tpu.core.nfa_device import LOCAL_SPAN, pow2_at_least
-from siddhi_tpu.core.pattern_plan import (DevicePatternPlan,
-                                          _stable_lane_order)
+from siddhi_tpu.core.lane_grid import _stable_lane_order
+from siddhi_tpu.core.pattern_plan import DevicePatternPlan
 
 _I32 = np.int32
 
@@ -24,7 +24,7 @@ _I32 = np.int32
 # -- (a) the permutation -----------------------------------------------------
 
 def _tail_and_new(rng, lanes, n_new, n_tail, hot=None, held=False):
-    """[tail | new] as _run_lanes_flat_inner hands it over: the tail lane
+    """[tail | new] as LaneGrid.pack hands it over: the tail lane
     by lane with each lane's rows in seq order, every tail seq under every
     new one, the new rows in seq order.  `held`: lanes that sat out the
     flush before ride BEHIND the lanes that did not, as the tail keeps
@@ -78,7 +78,7 @@ def test_stable_lane_order_of_nothing():
 # -- the plain reference: the host pack as it stood before ---------------------
 
 class RefPack:
-    """`part_of`, `_finalize_chunks` steps 1-2 and `_run_lanes_flat_inner`
+    """`part_of`, `_finalize_chunks` steps 1-2 and `LaneGrid.pack`
     in the plain form: two comparison sorts and an np.unique a flush.  It
     reads the plan's static shape only (stream codes, gridded attributes,
     `within`) and keeps its own key map, tails, prev seqs and the sticky
@@ -317,16 +317,16 @@ class Rig:
         pl, ref = self.plan, self.ref
         assert pl._key_to_part == ref.k2p
         assert list(pl._key_to_part) == list(ref.k2p)
-        assert (pl._lane_F, pl._lane_L) == (ref.F, ref.L)
-        assert pl._lane_prev.dtype == ref.prev.dtype
-        assert np.array_equal(pl._lane_prev, ref.prev)
+        assert (pl.grid.F, pl.grid.L) == (ref.F, ref.L)
+        assert pl.grid.prev.dtype == ref.prev.dtype
+        assert np.array_equal(pl.grid.prev, ref.prev)
         for k in ("ts", "seq", "scode", "part"):
-            assert pl._lane_tail[k].dtype == ref.tail[k].dtype, k
-            assert np.array_equal(pl._lane_tail[k], ref.tail[k]), k
-        assert list(pl._lane_tail["cols"]) == list(ref.tail["cols"])
+            assert pl.grid.tail[k].dtype == ref.tail[k].dtype, k
+            assert np.array_equal(pl.grid.tail[k], ref.tail[k]), k
+        assert list(pl.grid.tail["cols"]) == list(ref.tail["cols"])
         for k, v in ref.tail["cols"].items():
-            assert pl._lane_tail["cols"][k].dtype == v.dtype
-            assert np.array_equal(pl._lane_tail["cols"][k], v), k
+            assert pl.grid.tail["cols"][k].dtype == v.dtype
+            assert np.array_equal(pl.grid.tail["cols"][k], v), k
         self.flushes += 1
 
     def restored(self):
@@ -450,7 +450,7 @@ FLOWS = {
 
 @pytest.mark.parametrize("flow", list(FLOWS))
 def test_every_flush_packs_what_the_plain_reference_packs(flow):
-    """(c): ev, _lane_tail, _lane_prev, _lane_F, _lane_L and the lane ids,
+    """(c): ev, the grid's tail, prev, F and L and the lane ids,
     flush after flush, are the plain lexsort + unique implementation's."""
     rig = _flows(flow)
     try:
@@ -470,6 +470,52 @@ def test_lane_pack_order_counts_the_path_each_flush_took(flow):
         assert rig.plan.device_metrics()["lane_pack_order"] == FLOWS[flow][1]
         ent = rig.rt.explain()["queries"]["q"]
         assert ent["lane_pack_order"] == FLOWS[flow][1]
+    finally:
+        rig.close()
+
+
+# what `state_dict()` of a partitioned stateless plan has held since its
+# lane state was the plan's own fields (before lane_grid.py), in order
+SNAPSHOT_KEYS = ["state", "key_to_part", "ts_base", "seq_base",
+                 "next_deadline", "last_seq", "start_anchor", "chunk_tail",
+                 "chunk_prev_last_seq", "chunk_of_dropped", "lane_tail",
+                 "lane_prev", "arm_done"]
+
+
+def test_a_snapshot_keeps_the_keys_and_shapes_written_before_the_grid():
+    """The `snapshot_restore_between_flushes` scenario, on the snapshot
+    itself: the grid's share (`LaneGrid.state`) goes under the keys and in
+    the shapes `state_dict` always wrote, so a snapshot from before the
+    grid was a module restores; and a plan restored from exactly those
+    packs what the reference packs."""
+    rng = np.random.default_rng(5)
+    rig = Rig(ONE.format(key="sym"))
+    try:
+        t = T0
+        for _ in range(2):
+            t = _send_one(rig, rng, list(range(9)), 200, t)
+        d = rig.plan.state_dict()
+        assert list(d) == SNAPSHOT_KEYS
+        tail = d["lane_tail"]
+        assert list(tail) == ["ts", "seq", "scode", "part", "cols"]
+        assert [tail[k].dtype for k in ("ts", "seq", "scode", "part")] \
+            == [np.int64, np.int64, _I32, _I32]
+        n = len(tail["ts"])
+        assert n > 0 and list(tail["cols"]) == ["0.price"]
+        assert all(v.shape == (n,) for k, v in tail.items() if k != "cols")
+        assert tail["cols"]["0.price"].shape == (n,)
+        assert d["lane_prev"].dtype == np.int64 \
+            and d["lane_prev"].shape == (len(d["key_to_part"]),) == (9,)
+        assert d["chunk_tail"] is None and d["arm_done"] is None
+        assert d["chunk_prev_last_seq"] == -1
+        before = rig.flushes
+        rig = rig.restored()
+        assert (rig.plan.grid.F, rig.plan.grid.L) == (0, 0)
+        assert np.array_equal(rig.plan.grid.tail["seq"], tail["seq"])
+        assert np.array_equal(rig.plan.grid.prev, d["lane_prev"])
+        for keys in (range(4, 9), range(12)):
+            t = _send_one(rig, rng, list(keys), 200, t)
+        assert rig.flushes == 2 and before == 2
     finally:
         rig.close()
 

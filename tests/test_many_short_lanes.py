@@ -20,7 +20,7 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from benchmark.reference.pattern_chain import matches     # noqa: E402
 from siddhi_tpu import SiddhiManager                      # noqa: E402
-from siddhi_tpu.core import pattern_plan, telemetry       # noqa: E402
+from siddhi_tpu.core import lane_grid, telemetry          # noqa: E402
 from siddhi_tpu.core.pattern_plan import DevicePatternPlan  # noqa: E402
 
 T0 = 1_700_000_000_000
@@ -272,8 +272,8 @@ def test_lane_fill_counts_what_the_flushes_held(ran):
     tape, (dev, entries, plan, _st, _tr, metrics), _w = ran
     fill = entries[-1]["lane_fill"]
     assert list(fill) == ["flushes", "total", "last", "grids"]
-    assert list(fill["total"]) == list(pattern_plan.LANE_FILL)
-    assert list(fill["last"]) == list(pattern_plan.LANE_FILL) + ["F", "M"]
+    assert list(fill["total"]) == list(lane_grid.LANE_FILL)
+    assert list(fill["last"]) == list(lane_grid.LANE_FILL) + ["F", "M"]
     assert metrics["lane_fill"] == fill == plan.lane_fill
     total = fill["total"]
     assert total["events_new"] == FLUSHES * N
@@ -301,7 +301,7 @@ def test_lane_fill_counts_what_the_flushes_held(ran):
         for p, e in zip([None] + entries, entries))
     assert 0 < with_rows <= FLUSHES
     assert entries[-1]["result_decode"] == metrics["result_decode"] \
-        == plan.result_decode == {"indexed": with_rows, "masked": 0}
+        == plan.result_decode == {"indexed": with_rows}
 
 
 def _small(prices_by_flush):
@@ -335,20 +335,20 @@ def test_lane_fill_on_a_flush_worked_by_hand():
         events_replayed=0, cells_filled=4, cells_total=128,
         result_cells=1024, rows_delivered=0, F=16, M=16)
     assert first["total"] == {k: first["last"][k]
-                              for k in pattern_plan.LANE_FILL}
+                              for k in lane_grid.LANE_FILL}
     assert fill["last"] == dict(
         lanes_active=2, lanes_padded=8, lanes_held=2, events_new=2,
         events_replayed=2, cells_filled=4, cells_total=128,
         result_cells=1024, rows_delivered=1, F=16, M=16)
     assert fill["total"] == {k: first["last"][k] + fill["last"][k]
-                             for k in pattern_plan.LANE_FILL}
+                             for k in lane_grid.LANE_FILL}
     assert fill["flushes"] == 2 and fill["grids"] == {"8x16x16": 2}
     assert list(entries[1])[:10] == [
         "path", "plan", "kind", "family", "expiry_queries", "first_hit",
         "lane_pack_order", "lane_cut", "lane_fill", "result_decode"]
     # flush 0 held no row: nothing decoded, no record yet
     assert "result_decode" not in entries[0]
-    assert entries[1]["result_decode"] == {"indexed": 1, "masked": 0}
+    assert entries[1]["result_decode"] == {"indexed": 1}
 
 
 def test_lane_fill_is_a_partitioned_scan_plans_alone():
@@ -370,7 +370,7 @@ def test_lane_fill_is_a_partitioned_scan_plans_alone():
     assert "lane_fill" not in ent and plan.lane_fill is None
     assert "lane_fill" not in plan.device_metrics()
     # its one flat block held a row: decoded as the one-lane case
-    assert ent["result_decode"] == {"indexed": 1, "masked": 0}
+    assert ent["result_decode"] == {"indexed": 1}
 
 
 # -- span lane_tail -------------------------------------------------------------

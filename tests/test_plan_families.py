@@ -622,7 +622,7 @@ def test_partitioned_quiet_lane_tail_held_aside():
     # flush 2: only B speaks — A's tail must be held aside, not gridded
     ih.send(("B", 102.0, 1), timestamp=ts0 + 2)
     rt.flush()
-    tail_parts = set(plan._lane_tail["part"].tolist())
+    tail_parts = set(plan.grid.tail["part"].tolist())
     assert len(tail_parts) == 2, tail_parts        # A held + B kept
     # flush 3: A resumes and completes its 3-chain from the held tail
     ih.send(("A", 120.0, 1), timestamp=ts0 + 3)

@@ -118,9 +118,10 @@ def test_geometry_invariance(app, two_streams, geos):
                 f"{len(out)} vs {len(ref)} rows")
 
 
-def test_regeometry_respects_can_pipeline():
+def test_a_join_with_side_filters_holds_depth_0_whatever_is_annotated():
     """A join with side filters must sync per flush (_can_pipeline is
-    False): an annotated depth never overrides that."""
+    False): an annotated depth never overrides that, and the controller's
+    batch target still lands on the runtime."""
     app = """
     @app:devicePipeline(3)
     define stream S (sym string, p double, v int);
@@ -136,7 +137,7 @@ def test_regeometry_respects_can_pipeline():
     assert not plan._can_pipeline
     assert plan.pipeline_depth == 0 and plan._pipe.depth == 0
     rt._apply_batch_target(512)
-    assert plan.batch_hint == 512      # the controller's knob still lands
+    assert rt.batch_capacity == 512
     mgr.shutdown()
 
 

@@ -58,6 +58,11 @@ def _host_events(trace_dir):
 
 # (a) one clock read, two sinks: the profiler's host plane and the trackers
 
+# the most a runnable thread waits for a core here, with room: six test
+# workers and their XLA thread pools share eight cores
+QUANTUM_S = 0.025
+
+
 @pytest.mark.parametrize("app,n,want", [
     (FILTER, 1 << 15,
      ("freeze", "host_build", "kernel", "transfer", "unpack", "scatter")),
@@ -100,11 +105,15 @@ def test_profiler_trace_holds_the_engine_spans(tmp_path, app, n, want):
         assert all(w0 <= s and s + d <= w0 + wd for s, d in mine), name
         assert len(mine) == stages[name]["batches"], name
         # the annotation is entered just before the first clock read and
-        # left just after the second: a microsecond or two a span, and
-        # once in a while a collector pause or a thread switch between
-        traced = sum(d for _s, d in mine) / 1e9
-        assert traced == pytest.approx(stages[name]["seconds"], rel=0.05,
-                                       abs=5e-6 * len(mine) + 3e-4), name
+        # left just after the second, so it is never the shorter (the two
+        # clocks agree to under a microsecond a span), and the longer by a
+        # few microseconds a span (some ten where the span reads the page
+        # fault counters in between): unless the worker is descheduled or
+        # the collector runs between an annotation's edge and its clock
+        # read, which under a loaded machine costs a scheduler quantum
+        excess = sum(d for _s, d in mine) / 1e9 - stages[name]["seconds"]
+        assert -1e-6 * len(mine) <= excess <= QUANTUM_S * len(mine), (
+            name, excess, len(mine))
     assert not [e for e in events if e[0] == SPAN_PREFIX + "compile"]
 
 
@@ -335,7 +344,8 @@ WINDOW = STOCK + ("@info(name='q') from S#window.length(100) "
 # path -> (app, events a flush, ms an event, the decode it takes (a flat
 # block's is the one-lane case of a lane result's), `scatter` spans the
 # plan opens a flush: what they were before `unpack` had a span)
-DECODES = ("_decode_lanes", "_decode_cut", "_unpack_block")
+# (lane_grid.ResultDecoder's two, and the plan's one-lane call of `lanes`)
+DECODES = ("lanes", "cut", "_unpack_block")
 RESULT_PATHS = {
     "lane": (PATTERN, 2048, 1, DECODES[:1], 3),
     "fused-row": (FUSED, 512, 50, DECODES[1:2], 1),
@@ -353,18 +363,20 @@ def _run_result_path(path, monkeypatch, header="@app:trace('all')\n",
     """Three send_batch + flush rounds down one result path: (stage
     statistics, the frames' trees, calls of each of DECODES, rows out,
     the runtime's Prometheus text)."""
-    from siddhi_tpu.core import pattern_plan
+    from siddhi_tpu.core import lane_grid, pattern_plan
     app, n, dt, _fn, _sc = RESULT_PATHS[path]
     if path == "fused-row":     # rows of 64 events: a short flush is cut
         monkeypatch.setattr(pattern_plan, "FUSED_ROW_WINDOWS", 2)
         monkeypatch.setattr(pattern_plan, "FUSED_ROW_MIN", 16)
     calls = {}
     for fn in DECODES:
-        def counted(self, *a, _o=getattr(pattern_plan.DevicePatternPlan, fn),
-                    _f=fn, **kw):
+        owner = pattern_plan.DevicePatternPlan if fn == "_unpack_block" \
+            else lane_grid.ResultDecoder
+
+        def counted(self, *a, _o=getattr(owner, fn), _f=fn, **kw):
             calls[_f] = calls.get(_f, 0) + 1
             return _o(self, *a, **kw)
-        monkeypatch.setattr(pattern_plan.DevicePatternPlan, fn, counted)
+        monkeypatch.setattr(owner, fn, counted)
     mgr = SiddhiManager()
     rt = mgr.create_app_runtime(header + app)
     rows = [0]
@@ -445,17 +457,17 @@ def test_the_index_under_unpack_the_columns_under_scatter(path, monkeypatch):
     while `unpack` is the innermost open span, every delivered column is
     fetched while `scatter` is."""
     from contextlib import contextmanager
-    from siddhi_tpu.core import pattern_plan
+    from siddhi_tpu.core import lane_grid, pattern_plan
     app, n, dt, _fns, _sc = RESULT_PATHS[path]
     if path == "fused-row":
         monkeypatch.setattr(pattern_plan, "FUSED_ROW_WINDOWS", 2)
         monkeypatch.setattr(pattern_plan, "FUSED_ROW_MIN", 16)
     open_spans, seen = [], {"_index": [], "column": []}
     for fn in seen:
-        def noted(self, *a, _o=getattr(pattern_plan._Filled, fn), _f=fn):
+        def noted(self, *a, _o=getattr(lane_grid._Filled, fn), _f=fn):
             seen[_f].append(open_spans[-1] if open_spans else None)
             return _o(self, *a)
-        monkeypatch.setattr(pattern_plan._Filled, fn, noted)
+        monkeypatch.setattr(lane_grid._Filled, fn, noted)
     mgr = SiddhiManager()
     rt = mgr.create_app_runtime(app)
     span = rt.span
