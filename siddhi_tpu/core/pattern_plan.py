@@ -26,7 +26,7 @@ import jax
 import numpy as np
 
 from ..query import ast
-from . import lane_grid
+from . import hostmem, lane_grid
 from .batch import EventBatch
 from .expr import ExprError, MultiStreamContext, compile_expression
 from .nfa_device import (ChainSpec, DeviceNFAUnsupported, LOCAL_SPAN,
@@ -819,17 +819,22 @@ class DevicePatternPlan(QueryPlan):
     def _pull(self, out: dict) -> tuple:
         """The blocking pull of one block's packed outputs: the wait for
         the device (a span of its own while a sink is on), then ONE D2H
-        transfer per pack; notes the bytes."""
+        transfer per pack; notes the bytes.  A pull the C library could
+        never recycle the landing buffer of asks it to keep large chunks
+        (hostmem.py): the next pulls land in memory the process holds."""
         span = self.rt.span
         with span("transfer", plan=self.name):
             device_wait(span, self.name, (out["i"], out.get("f")))
             with span("transfer.copy", plan=self.name):
                 ipack = np.asarray(out["i"])
                 fpack = np.asarray(out["f"]) if "f" in out else None
+        fbytes = 0 if fpack is None else fpack.nbytes
+        nbytes = ipack.nbytes + fbytes
+        if max(ipack.nbytes, fbytes) >= hostmem.LARGE:
+            hostmem.keep_large_chunks()
         prof = self.rt.profiler
         if prof is not None:
-            prof.note_bytes(self.name, "d2h", ipack.nbytes
-                            + (0 if fpack is None else fpack.nbytes))
+            prof.note_bytes(self.name, "d2h", nbytes)
         return ipack, fpack
 
     def device_metrics(self) -> dict:

@@ -27,9 +27,11 @@ loop becomes closed-form range reductions over the concatenated
     ride in the carry.
 
 Carry state is a fixed-capacity device buffer packed at the right edge
-(so [carry | batch] keeps global arrival order contiguous); a capacity
-overflow sets a flag and the host doubles C and retries — the same
-adaptive protocol as the pattern kernel (pattern_plan.py).
+(so [carry | batch] keeps global arrival order contiguous).  Every step
+reports, in the word its result always had for the overflow flag, HOW MANY
+entries it had to keep; a count over the capacity is an overflow, and the
+host grows C to the power of two that holds the count (at least double)
+and retries: one recompile for a window that needs 16x, not four.
 """
 from __future__ import annotations
 
@@ -72,6 +74,72 @@ def pow2_at_least(n: int, lo: int = 8) -> int:
 # ---------------------------------------------------------------------------
 # vectorized building blocks
 # ---------------------------------------------------------------------------
+
+# Two forms chosen for what the TPU's compiler makes of them at a carry of
+# 2^20 entries (`roomtemp10m`: N = C + T = 1,310,720; compiled for a described
+# v5e on the sandbox's CPU, PR 48's builder's readings).  A 1-D
+# `associative_scan` is unrolled level by level over the whole length: 150 s
+# and 92 MB of code for ONE pair prefix, three of them a step.  A sort's
+# compile grows with its operands: `lexsort` of two i64 keys (five u32
+# operands) 301 s, `argsort` of an i64 key 183 s, a stable sort of one 32-bit
+# key with one payload 26 s.  `_scan` lays the sequence out as rows (1.3 s for
+# the same prefix); `_order_by_words` chains one-key sorts: the step compiles
+# in 44 s (tests/test_chip_compile.py).  The rows are the faster program at
+# `window1k`'s 263,168 entries too: its step 0.176 ms a call for 0.695 on the
+# chip, 4.3 s of compiles for 14.3 (PERF.md section 5, PR 49).
+
+_SCAN_ROW = 1024
+
+
+def _scan(op, elems):
+    """`jax.lax.associative_scan(op, elems)` over 1-D arrays of one length,
+    laid out as rows of `_SCAN_ROW`: a scan along every row, a scan of the
+    row totals, one combine.  (At most 2 log2(row) + 2 log2(rows) + 1
+    applications of `op` on the way to any entry: no more than the
+    2 log2 N + 1 the bound above `_two_sum` counts on.)  A sequence of
+    fewer than two rows is scanned as it is."""
+    tmap = jax.tree_util.tree_map
+    n = jax.tree_util.tree_leaves(elems)[0].shape[0]
+    if n < 2 * _SCAN_ROW:
+        return jax.lax.associative_scan(op, elems)
+    rows = -(-n // _SCAN_ROW)
+    pad = rows * _SCAN_ROW - n      # behind the last entry: no prefix reads it
+
+    def as_rows(a):
+        if pad:
+            a = jnp.concatenate([a, jnp.zeros(pad, a.dtype)])
+        return a.reshape(rows, _SCAN_ROW)
+    inner = jax.lax.associative_scan(op, tmap(as_rows, elems), axis=1)
+    upto = jax.lax.associative_scan(op, tmap(lambda a: a[:, -1], inner))
+    base = tmap(lambda u, a: jnp.broadcast_to(
+        jnp.concatenate([u[:1], u[:-1]])[:, None], a.shape), upto, inner)
+    first_row = (jnp.arange(rows) == 0)[:, None]
+    return tmap(lambda c, a: jnp.where(first_row, a, c).reshape(-1)[:n],
+                op(base, inner), inner)
+
+
+def _words(c: jnp.ndarray) -> list:
+    """An integer column as 32-bit words, most significant first: one word
+    for a column of 32 bits or fewer, two for a 64-bit one.  Words compare
+    as unsigned: an order, and the same for equal values, which is all that
+    grouping asks."""
+    u32 = lambda w: jax.lax.bitcast_convert_type(w, jnp.uint32)
+    if c.dtype.itemsize <= 4:
+        return [u32(c.astype(jnp.int32))]
+    c = c.astype(jnp.int64)
+    return [u32(_w_hi32(c)), u32(_w_lo32(c))]
+
+
+def _order_by_words(words: list) -> jnp.ndarray:
+    """Arrival order sorted stably by `words` (most significant first): what
+    `jnp.lexsort(words[::-1])` gives, as a chain of stable sorts of ONE
+    32-bit key and one payload, least significant word first."""
+    order = jnp.arange(words[0].shape[0], dtype=jnp.int32)
+    for i, w in enumerate(reversed(words)):
+        _key, order = jax.lax.sort((w if i == 0 else w[order], order),
+                                   num_keys=1, is_stable=True)
+    return order
+
 
 def _floor_log2(x: jnp.ndarray) -> jnp.ndarray:
     """floor(log2(x)) for int64 x >= 1, exact (no float rounding)."""
@@ -149,7 +217,7 @@ def _pair_add(x, y):
 
 def _prefix_pairs(v: jnp.ndarray) -> tuple:
     """Inclusive prefix sums of `v` as (hi, lo) pairs."""
-    return jax.lax.associative_scan(_pair_add, (v, jnp.zeros_like(v)))
+    return _scan(_pair_add, (v, jnp.zeros_like(v)))
 
 
 def _pair_diff(top: tuple, base: tuple) -> jnp.ndarray:
@@ -185,45 +253,85 @@ def _length_left(gpos: jnp.ndarray, first_valid, L: int) -> jnp.ndarray:
     return jnp.maximum(gpos - (L - 1), first_valid)
 
 
+_I32_MAX = 2 ** 31 - 1
+
+
+def _clock_left(all_ts: jnp.ndarray, D: int, first, last) -> jnp.ndarray:
+    """Left edge of each position's time(D) window over the monotone clock
+    `all_ts`: the first index whose clock lies after `all_ts[g] - D`, what
+    `searchsorted(all_ts, all_ts - D, side="right")` finds, exact at every
+    position of `first..last`, the batch's valid entries (the carry's own
+    edges are not read: only the batch's rows leave the step).  The clock
+    is searched as a 32-bit offset from the newest valid entry wherever
+    the oldest of those entries and its edge lie within 2^31 ms of it
+    (`lax.cond`, a batch).  A clock as i64 is two words, and a binary
+    search gathers both every round; at 1.31 M entries that search ran at
+    one of two speeds from process to process, 820 or 870 ms a step (the
+    step 2,504 or 2,622 ms: 6 of 23 runs the slow kind over four leases;
+    PERF.md 7.17), which alone spreads six seeds of `roomtemp10m.sat` by
+    3.9%, over half that cell's bound; on 32-bit offsets it takes 197 ms
+    and the step 1,883, six seeds within 0.3%.  Older keys, the carry's
+    empty slots and the batch's pads clamp to the ends of the range, on
+    the side of the edge they lie on."""
+    wide = lambda: jnp.searchsorted(all_ts, all_ts - D, side="right")
+    if D > _I32_MAX:
+        return wide()
+    rel = all_ts - all_ts[last]         # <= 0 at every valid entry
+
+    def narrow():
+        as32 = lambda x: jnp.clip(x, -_I32_MAX, _I32_MAX).astype(jnp.int32)
+        return jnp.searchsorted(as32(rel), as32(rel - D), side="right")
+    return jax.lax.cond(rel[first] - D >= -_I32_MAX, narrow, wide)
+
+
 def _segment_start(seg: jnp.ndarray) -> jnp.ndarray:
     """Index of the first entry of each entry's run of equal `seg`."""
     n = seg.shape[0]
     is_start = jnp.concatenate([jnp.array([True]), seg[1:] != seg[:-1]])
-    return jax.lax.associative_scan(
-        jnp.maximum, jnp.where(is_start, jnp.arange(n), 0))
+    return _scan(jnp.maximum, jnp.where(is_start, jnp.arange(n), 0))
 
 
 def _by_segment(seg: jnp.ndarray, n: int) -> tuple:
-    """(order, ks): arrival order sorted by (segment, position) and the
-    sorted seg * n + pos keys (invalid entries: a large segment id)."""
-    key = seg * n + jnp.arange(n, dtype=jnp.int64)
-    order = jnp.argsort(key)
+    """(order, ks): arrival order sorted by (segment, position), which is
+    arrival order sorted STABLY by segment, and the sorted seg * n + pos
+    keys (invalid entries: a large segment id).  `seg` in 32 bits (a
+    sliding window's: at most n) sorts as one word, else as two."""
+    order = _order_by_words(_words(seg))
+    key = seg.astype(jnp.int64) * n + jnp.arange(n, dtype=jnp.int64)
     return order, key[order]
 
 
-def _seg_window_sum(seg, v, left, gpos, n):
+def _seg_ranges(seg, left, gpos, n) -> tuple:
+    """(order, first, own) of a grouped sliding window: the (segment,
+    position) order, and in it each entry's own rank and the rank of its
+    segment's first member at or after `left`.  A step takes them ONCE,
+    under scopes of their own (`window/segment_order`, the sort;
+    `window/segment_search`, the two searches): every windowed sum, count,
+    min and max of it reads the same ranges."""
+    with jax.named_scope("window/segment_order"):
+        order, ks = _by_segment(seg.astype(jnp.int32), n)   # seg <= n
+    with jax.named_scope("window/segment_search"):
+        first = jnp.searchsorted(ks, seg * n + left)
+        own = jnp.searchsorted(ks, seg * n + gpos)
+    return order, first, own
+
+
+def _seg_window_sum(ranges, v):
     """Per-entry sum over its segment's members in positions [left, gpos]:
     a range of the (segment, position) order, from the segment's first
     member at or after `left` to the entry itself."""
-    order, ks = _by_segment(seg, n)
-    first = jnp.searchsorted(ks, seg * n + left)
-    own = jnp.searchsorted(ks, seg * n + gpos)
+    order, first, own = ranges
     return _range_sum(_prefix_pairs(v[order]), first - 1, own)
 
 
-def _seg_window_minmax(seg, v, left, gpos, n, is_max):
+def _seg_window_minmax(ranges, v, is_max):
     """Per-entry min/max over its segment's members in positions
-    [left, gpos]: one sort by (segment, position) + a log2 sparse table +
-    two searchsorted bound lookups (the grouped analog of the ungrouped
-    range-reduce; v must carry the neutral at invalid entries)."""
-    key = seg * n + jnp.arange(n, dtype=jnp.int64)
-    order = jnp.argsort(key)
-    ks = key[order]
-    vs = v[order]
-    table = _sparse_table(vs, is_max)
-    l = jnp.searchsorted(ks, seg * n + left)
-    r = jnp.searchsorted(ks, seg * n + gpos)
-    return _range_reduce(table, jnp.minimum(l, r), r, is_max)
+    [left, gpos]: a log2 sparse table over the (segment, position) order
+    (the grouped analog of the ungrouped range-reduce; v must carry the
+    neutral at invalid entries)."""
+    order, first, own = ranges
+    table = _sparse_table(v[order], is_max)
+    return _range_reduce(table, jnp.minimum(first, own), own, is_max)
 
 
 def _seg_running_sum(seg, v, n):
@@ -236,9 +344,7 @@ def _seg_running_sum(seg, v, n):
 
 def _seg_running_minmax(seg, v, is_max, n):
     """Per-entry running min/max within its segment, arrival order."""
-    key = seg * n + jnp.arange(n, dtype=jnp.int64)
-    order = jnp.argsort(key)
-    ks = key[order]
+    order, ks = _by_segment(seg, n)
     ss = seg[order]
     vs = v[order]
     is_start = jnp.concatenate([jnp.array([True]), ss[1:] != ss[:-1]])
@@ -248,8 +354,8 @@ def _seg_running_minmax(seg, v, is_max, n):
         af, av = a
         bf, bv = b
         return (af | bf, jnp.where(bf, bv, op(av, bv)))
-    _f, run = jax.lax.associative_scan(comb, (is_start, vs))
-    return run[jnp.searchsorted(ks, key)]
+    _f, run = _scan(comb, (is_start, vs))
+    return run[jnp.searchsorted(ks, seg * n + jnp.arange(n, dtype=jnp.int64))]
 
 
 # monotone-segment variants: when segment ids are nondecreasing in arrival
@@ -267,7 +373,7 @@ def _mono_running_minmax(seg, v, is_max):
         af, av = a
         bf, bv = b
         return (af | bf, jnp.where(bf, bv, op(av, bv)))
-    _f, run = jax.lax.associative_scan(comb, (is_start, v))
+    _f, run = _scan(comb, (is_start, v))
     return run
 
 
@@ -539,6 +645,9 @@ class DeviceWindowAggPlan(QueryPlan):
         # recompile that a steady window of traffic may not hold
         self.counters = {"carry_overflow_reruns": 0, "carry_grows": 0}
         self._T = None              # the last dispatch's padded length
+        # valid entries the last pulled step kept for the next (the word
+        # that decides an overflow), and the most any has
+        self._held = self._held_max = 0
         # the form each indexed pass of the step takes (`window_step` in
         # EXPLAIN / device_metrics): `_build_step_fn` traces what this
         # says, and it says what the query lets the plan see.  A length
@@ -570,6 +679,16 @@ class DeviceWindowAggPlan(QueryPlan):
                 # (`_range_sum`), which never restarts: no block
                 "sum_form": "pair_prefix", "block": None,
                 **self.counters}
+
+    @property
+    def window_carry(self) -> dict:
+        """How full the carry is, beside `window`: `held` of `capacity`
+        entries after the last step pulled, the most any step held, and
+        what growing to it cost (`window`'s two counters)."""
+        return {"capacity": int(self.C), "held": self._held,
+                "held_max": self._held_max,
+                "grows": self.counters["carry_grows"],
+                "reruns": self.counters["carry_overflow_reruns"]}
 
     # -- state ---------------------------------------------------------------
 
@@ -668,27 +787,27 @@ class DeviceWindowAggPlan(QueryPlan):
             return out
 
         def group_seg(env_all, gvalid, n):
-            """Dense group-segment id per entry (invalid -> n)."""
+            """Dense group-segment id per entry (invalid -> n): the
+            group-by's sort and scatter, under `window/group`."""
             if not group_keys:
                 return jnp.where(gvalid, 0, n).astype(jnp.int64)
-            keys = []
-            for g in group_keys:
-                c = env_all[g]
-                if c.dtype.kind == "f":
-                    c = c.astype(jnp.float64)
-                    c = jnp.where(c == 0.0, 0.0, c).view(jnp.int64)
-                else:
-                    c = c.astype(jnp.int64)
-                keys.append(c)
-            order = jnp.lexsort(keys[::-1])
-            diff = jnp.zeros(n, dtype=bool)
-            for kk in keys:
-                ks = kk[order]
-                diff = diff | jnp.concatenate(
-                    [jnp.array([True]), ks[1:] != ks[:-1]])
-            seg_sorted = jnp.cumsum(diff) - 1
-            seg = jnp.zeros(n, dtype=jnp.int64).at[order].set(seg_sorted)
-            return jnp.where(gvalid, seg, n)
+            with scope("window/group"):
+                words = []
+                for g in group_keys:
+                    c = env_all[g]
+                    if c.dtype.kind == "f":
+                        c = c.astype(jnp.float64)
+                        c = jnp.where(c == 0.0, 0.0, c).view(jnp.int64)
+                    words += _words(c)
+                order = _order_by_words(words)
+                diff = jnp.zeros(n, dtype=bool)
+                for w in words:
+                    ws = w[order]
+                    diff = diff | jnp.concatenate(
+                        [jnp.array([True]), ws[1:] != ws[:-1]])
+                seg_sorted = jnp.cumsum(diff) - 1
+                seg = jnp.zeros(n, dtype=jnp.int64).at[order].set(seg_sorted)
+                return jnp.where(gvalid, seg, n)
 
         def bucket_seg(brel, env_all, all_valid):
             """The tumbling kinds' segment id per entry: its bucket, or
@@ -770,7 +889,7 @@ class DeviceWindowAggPlan(QueryPlan):
                        for c in carry_cols}
             all_ts = None
             if bts is not None:
-                all_ts = jax.lax.associative_scan(      # monotone
+                all_ts = _scan(                         # monotone
                     jnp.maximum, jnp.concatenate([state["ts"], bts]))
                 env_all["__timestamp__"] = all_ts
             gpos = jnp.arange(N, dtype=jnp.int64)
@@ -781,8 +900,10 @@ class DeviceWindowAggPlan(QueryPlan):
                                               dtype=jnp.int64)
                     left = _length_left(gpos, first_valid, L)
                 else:
-                    left = jnp.searchsorted(all_ts, all_ts - D, side="right")
-                seg = group_seg(env_all, all_valid, N) if group_keys else None
+                    left = _clock_left(all_ts, D, C,
+                                       jnp.maximum(C + k - 1, 0))
+            ranges = _seg_ranges(group_seg(env_all, all_valid, N), left,
+                                 gpos, N) if group_keys else None
             vals = site_vals(env_all, N)
 
             def wsum(v):
@@ -792,7 +913,7 @@ class DeviceWindowAggPlan(QueryPlan):
                 ones holding zeros."""
                 with scope("window/sum"):
                     if forms["prefix_read"] == "segmented":
-                        return _seg_window_sum(seg, v, left, gpos, N)
+                        return _seg_window_sum(ranges, v)
                     if forms["prefix_read"] == "shift":
                         return _trailing_sum(_prefix_pairs(v), L)
                     return _range_sum(_prefix_pairs(v), left - 1)
@@ -812,7 +933,7 @@ class DeviceWindowAggPlan(QueryPlan):
                     with scope("window/minmax"):
                         if group_keys:
                             aggs_full.append(_seg_window_minmax(
-                                seg, vv, left, gpos, N, nm == "max"))
+                                ranges, vv, nm == "max"))
                             continue
                         table = _sparse_table(vv, nm == "max")
                         aggs_full.append(_range_reduce(
@@ -846,8 +967,8 @@ class DeviceWindowAggPlan(QueryPlan):
                 keep = (gpos >= start_k) & all_valid
                 nst = carry(state, state["seen"] + k, all_ts, keep, env_all,
                             k)
-                overflow = (jnp.sum(keep) > C).astype(jnp.int32)
-            return nst, outs, row_ok, bts, overflow
+                kept = jnp.sum(keep, dtype=jnp.int32)
+            return nst, outs, row_ok, bts, kept
 
         def step_lengthbatch(state, bts, bvalid, bcols, k):
             all_ts = jnp.concatenate([state["ts"], bts])
@@ -861,16 +982,17 @@ class DeviceWindowAggPlan(QueryPlan):
                 vrank = jnp.cumsum(all_valid.astype(jnp.int64)) - 1
                 gidx = base + vrank
                 brel = jnp.where(all_valid, (gidx - base) // L, -1)
-                segk = bucket_seg(brel, env_all, all_valid)
+            segk = bucket_seg(brel, env_all, all_valid)
             aggs = running(segk, all_valid, site_vals(env_all, N))
             total = base + jnp.sum(all_valid)
             completed = (total // L) * L
             emit = all_valid & (gidx < completed)
             outs, row_ok = finish(env_all, aggs, emit)
             with scope("window/carry"):
-                nst = carry(state, total, all_ts,
-                            all_valid & (gidx >= completed), env_all, k)
-            return nst, outs, row_ok, all_ts, jnp.int32(0)
+                pend = all_valid & (gidx >= completed)  # under L: it fits
+                nst = carry(state, total, all_ts, pend, env_all, k)
+                kept = jnp.sum(pend, dtype=jnp.int32)
+            return nst, outs, row_ok, all_ts, kept
 
         def step_extbatch(state, bts, bvalid, bcols, k):
             """externalTimeBatch: lengthBatch's per-bucket segmented scans
@@ -901,7 +1023,7 @@ class DeviceWindowAggPlan(QueryPlan):
                 bfirst = b[idx0]
                 brel = jnp.where(all_valid, b - bfirst, jnp.int64(-1))
                 blast = jnp.max(b)                    # monotone ts: current
-                segk = bucket_seg(brel, env_all, all_valid)
+            segk = bucket_seg(brel, env_all, all_valid)
             aggs = running(segk, all_valid, site_vals(env_all, N))
             emit = all_valid & (b < blast)
             outs, row_ok = finish(env_all, aggs, emit)
@@ -910,8 +1032,8 @@ class DeviceWindowAggPlan(QueryPlan):
                 nst = carry(state, state["seen"] + k, all_ts, pend, env_all,
                             k)
                 nst["start"] = start
-                overflow = (jnp.sum(pend) > C).astype(jnp.int32)
-            return nst, outs, row_ok, all_ts, overflow
+                kept = jnp.sum(pend, dtype=jnp.int32)
+            return nst, outs, row_ok, all_ts, kept
 
         def compact(mask, arr, fill):
             """`arr`'s entries under `mask` moved to the front, `fill`
@@ -986,21 +1108,24 @@ class DeviceWindowAggPlan(QueryPlan):
             device->host pull pays a fixed cost plus a per-byte cost.
             Sliding kinds are `slim`: row timestamps equal
             the (filter-compacted) input timestamps, which the host already
-            holds, so only a small `b` vector ([overflow, k] + bit-packed
+            holds, so only a small `b` vector ([kept, k] + bit-packed
             masks when needed) plus the out columns travel.  lengthBatch
             rows can emit carried (previous-batch) events, so it keeps the
-            full layout: [overflow]+ok+ts hi/lo rows ahead of the columns."""
-            nst, outs, row_ok, row_ts, overflow = res
+            full layout: [kept]+ok+ts hi/lo rows ahead of the columns.
+            `kept`, the entries the next state has to hold, is the word
+            that was the overflow flag: over the capacity, it overflowed
+            (`_materialize` grows to it)."""
+            nst, outs, row_ok, row_ts, kept = res
             n = row_ok.shape[0]
             irows, frows = [], []
             if slim:
-                bparts = [jnp.stack([overflow, k]).astype(jnp.int32)]
+                bparts = [jnp.stack([kept, k]).astype(jnp.int32)]
                 if has_filter:
                     bparts.append(bits32(mask))
                 if having is not None:
                     bparts.append(bits32(row_ok))
             else:
-                meta = jnp.zeros((n,), jnp.int32).at[0].set(overflow)
+                meta = jnp.zeros((n,), jnp.int32).at[0].set(kept)
                 row_ts = row_ts.astype(jnp.int64)
                 irows += [meta, row_ok.astype(jnp.int32),
                           _w_hi32(row_ts), _w_lo32(row_ts)]
@@ -1120,19 +1245,24 @@ class DeviceWindowAggPlan(QueryPlan):
         slim = self.kind not in ("lengthbatch", "externaltimebatch")
         while True:
             bpack, ipack, fpack = self._pull(entry["res"])
-            if not int(bpack[0] if slim else ipack[0, 0]):
+            kept = int(bpack[0] if slim else ipack[0, 0])
+            if kept <= self.C:      # every entry in flight ran at this C
                 break
-            # carry overflow: grow C and replay this entry plus everything
-            # dispatched after it (their pre-states are now invalid)
+            # carry overflow: grow C to what the step had to keep, at once
+            # (never less than double), and replay this entry plus
+            # everything dispatched after it (their pre-states are now
+            # invalid)
             chain = [entry] + self._pipe.take_all()
             self.state = entry["pre"]
-            self._grow(2 * self.C)
+            self._grow(max(2 * self.C, pow2_at_least(kept)))
             self.counters["carry_grows"] += 1
             self.counters["carry_overflow_reruns"] += len(chain)
             redone = [self._dispatch(e["env"], e["batch"], e["T"])
                       for e in chain]
             entry = redone[0]
             self._pipe.requeue(redone[1:])
+        self._held = kept
+        self._held_max = max(self._held_max, kept)
         with self.rt.span("unpack", plan=self.name, events=entry["batch"].n):
             return self._unpack(entry, slim, bpack, ipack, fpack)
 
@@ -1235,7 +1365,8 @@ class DeviceWindowAggPlan(QueryPlan):
         return {"window_capacity": int(self.C), "window_fill": fill,
                 "window_fill_ratio": round(fill / max(self.C, 1), 4),
                 "window": self.window,
-                "window_step": dict(self.window_step)}
+                "window_step": dict(self.window_step),
+                "window_carry": self.window_carry}
 
     def state_dict(self) -> dict:
         return {"state": {k: np.asarray(v) for k, v in self.state.items()},

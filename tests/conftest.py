@@ -46,6 +46,17 @@ STALE_PINS = {
     "test_the_rehearsal_is_steady_and_finds_the_cells_metrics":
         "pins the rehearsal's lane grid at the power of two (1024x64x64); "
         "832x64x64 since PR 46",
+    # lines 100 and 116-118, `len(data["configs"]) == 7 and
+    # len(data["workloads"]) == 8` and window1k.sat as the LAST cell: true
+    # until PR 49 appended roomtemp10m.sat, as BENCHMARK.json's contract has
+    # every later cell.  What else it holds (the cell's lists, 44 per-layer
+    # entries, nothing before the cell moved) is held, by `index` and so for
+    # every later cell too, by benchmark/test_roomtemp10m_cell.py::
+    # test_the_manifest_gains_the_cell_and_nothing_before_it_moves
+    "benchmark/test_window1k_cell.py::"
+    "test_the_manifest_gains_the_cell_and_nothing_moves":
+        "pins 7 configurations, 8 cells and window1k.sat as the last cell; "
+        "roomtemp10m.sat is the ninth since PR 49",
 }
 
 

@@ -179,3 +179,79 @@ def test_window1ks_step_compiles_for_the_chip_at_its_stated_size(topo):
     assert mem.argument_size_in_bytes // T == 4         # the price as f32
     assert mem.output_size_in_bytes // T == 4           # one f32 word a row
     assert mem.temp_size_in_bytes < 1 << 24             # 2.5 MB (25 before PR 45)
+
+
+def test_roomtemp10ms_step_compiles_for_the_chip_at_its_stated_size(topo):
+    """`roomtemp10m.sat`'s step: 2^18 events on the 2^20-entry carry a
+    10-minute window at 1,000 events/s grows to, [carry | batch] =
+    1,310,720 entries of (ts i64, valid, deviceID i64, roomNo i32, temp
+    f32); deviceID as two words, roomNo, temp and the timestamp offset in,
+    avgTemp, roomNo and deviceID's two words a row out.  The grouped time
+    step is all sort, search and gather: what it holds is RECORDED here by
+    name, as found; the counts are the later `perf_opt`'s to lower (ROADMAP
+    A10), not a floor.  Until PR 49 it held a five-operand `lexsort`, an
+    `argsort` of an i64 key and three 1-D `associative_scan`s, and took
+    308 s to compile here (43 s now: `window_device._scan`,
+    `_order_by_words`)."""
+    import os
+    from siddhi_tpu import SiddhiManager
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "apps",
+                           "roomtemp10m.siddhi")) as f:
+        app = f.read().replace("{source}", "").replace("{sink}", "")
+    mgr = SiddhiManager()
+    rt = mgr.create_app_runtime("@app:deviceMesh('never')\n" + app)
+    plan = rt._plan_by_name["q"]
+    mgr.shutdown()
+    T, C = 1 << 18, 1 << 20
+    assert plan.C == plan.C_START == 1024       # where every time window starts
+    assert plan.cols == ["deviceID", "roomNo", "temp"] and plan._needs_ts
+    assert plan.window_step == {"left_edge": "search",
+                                "prefix_read": "segmented",
+                                "compaction": "identity"}
+    plan.C = C              # as it stands from the third warm-up batch on
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def of(a):
+        a = jnp.asarray(a)
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)
+    state = {k: of(v) for k, v in plan._init_state().items()}
+    assert sorted(state) == ["c.deviceID", "c.roomNo", "c.temp", "seen",
+                             "ts", "valid"]
+    # as `process` uploads it: the DOUBLE temp padded as f32, timestamps as
+    # i32 offsets from an i64 base, validity as a count
+    env = {"__nvalid__": of(np.int32(0)),
+           "__ts_off__": of(np.zeros(T, np.int32)),
+           "__ts_base__": of(np.int64(0)),
+           "deviceID": of(np.zeros(T, np.int64)),
+           "roomNo": of(np.zeros(T, np.int32)),
+           "temp": of(np.zeros(T, np.float32))}
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = plan._build_step_fn(T, C).lower(state, env).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+    # an instruction's opcode follows its (possibly tuple) result type
+    ops = Counter(re.findall(r"(?<![\w\-.%])([a-z][a-z\-]*)\(",
+                             compiled.as_text()))
+    assert ops["fusion"] > 0
+    # as found (PR 49): three one-key sorts for the group-by's three key
+    # words and one for the (segment, position) order, the sum's and the
+    # count's shared; five searches (`while`): the clock's left edge in its
+    # two forms under ONE `conditional` (on 32-bit offsets, and on the i64
+    # clock for a batch that spans more: `_clock_left`; a step runs one),
+    # the two segment ranks, the carry's cut; the gathers of the sorted
+    # orders and of the prefix pairs; the segment ids' scatter
+    assert {k: ops[k] for k in INDEXED} == {"sort": 4, "gather": 24,
+                                            "scatter": 1, "while": 5}
+    assert ops["conditional"] == 1
+    assert {k: ops[k] for k in COLLECTIVES if ops[k]} == {}
+    mem = compiled.memory_analysis()
+    # 4 + 8 + 4 + 4 bytes an event in (20.0: the cell's H2D), and out the
+    # next state beside 4 words a row (16.0: its D2H)
+    assert (mem.argument_size_in_bytes - 25 * C) // T == 20
+    carry_bytes = C * (8 + 1 + 8 + 4 + 4)       # ts, valid, the three columns
+    assert carry_bytes == 26_214_400            # the ~26 MB of live state
+    assert (mem.output_size_in_bytes - carry_bytes) // T == 16
+    assert mem.temp_size_in_bytes < 1 << 28     # 106 MB; the chip has 16 GB

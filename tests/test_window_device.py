@@ -217,6 +217,8 @@ def test_window_record_says_the_plans_form_and_counts_its_reruns():
             "sum_form": "pair_prefix", "block": None,
             "carry_overflow_reruns": 0, "carry_grows": 0}
     assert rt.explain()["queries"]["q"]["window"] == want
+    assert rt.explain()["queries"]["q"]["window_carry"] == {
+        "capacity": 1024, "held": 0, "held_max": 0, "grows": 0, "reruns": 0}
     plan.C = 8
     plan.state = plan._init_state()
     out = []
@@ -227,10 +229,16 @@ def test_window_record_says_the_plans_form_and_counts_its_reruns():
     rt.flush()
     assert out[-1] == ("x", 50.0, 50)
     rec = rt.explain()["queries"]["q"]["window"]
-    # 8 -> 16 -> 32 -> 64: three grows, the one flush re-run each time
+    # 8 -> 64 at once: the step's word said 50, ONE grow and one re-run
+    # (by doubling, until PR 49: three of each)
     assert rec == {**want, "T": 64, "carry_capacity": 64,
-                   "carry_overflow_reruns": 3, "carry_grows": 3}
+                   "carry_overflow_reruns": 1, "carry_grows": 1}
     assert rt.statistics()["device"]["q"]["window"] == rec
+    # beside it, how full the carry is: the count the step's word carries
+    carry = rt.explain()["queries"]["q"]["window_carry"]
+    assert carry == {"capacity": 64, "held": 50, "held_max": 50,
+                     "grows": 1, "reruns": 1}
+    assert rt.statistics()["device"]["q"]["window_carry"] == carry
     assert rt.statistics()["profile"]["plans"]["q"]["bytes"]["d2h"] > 0
     m.shutdown()
     # a length window says its length
@@ -379,6 +387,19 @@ def _et_rows(n, seed, gap=300):
         rows.append((ts, (f"s{r.randint(0, 2)}",
                           round(r.uniform(0, 90), 2), r.randint(1, 9), et)))
     return rows
+
+
+@pytest.mark.parametrize("seed", [3, 4])
+def test_a_clock_that_leaps_by_days_is_searched_wide_and_narrow(seed):
+    """An event clock that moves up to ten days an event, a three-day
+    window: a flush of a few events lies within 2^31 ms (24.8 days) and is
+    searched on 32-bit offsets, a longer one is not (`_clock_left`); both
+    owe what the interpreter delivers."""
+    day = 86_400_000
+    _differential_et(
+        f"from S#window.externalTime(et, {3 * day}) select sym, "
+        "sum(p) as s, count() as c group by sym insert into O;",
+        _et_rows(150, seed, gap=10 * day), seed)
 
 
 @pytest.mark.parametrize("q", [
@@ -741,3 +762,345 @@ def test_window_step_record_says_the_form_of_each_indexed_pass(kind):
         assert d.rt.explain()["queries"]["q"]["window_step"] == want
     finally:
         d.close()
+
+
+# -- the carry grows to what overflowed, at once (PR 49) -----------------------
+# The step's word (the one that was the overflow flag) carries HOW MANY
+# entries the step had to keep; `_materialize` grows to the power of two that
+# holds them (never less than double).  Against growth by doubling, the
+# policy it replaced: the same bits on every row of every carried kind, and
+# one grow where there were four.
+
+G_HEAD = ("@app:playback @app:deviceWindows('always')\n"
+          "define stream S (symbol string, price double, volume int, "
+          "et long);\n@info(name='q') from S")
+GROWN = {
+    "time": "#window.time(1 hour) select sum(price) as s, avg(price) as a, "
+            "count() as c insert into O;",
+    "grouped_time": "#window.time(1 hour) select symbol, avg(price) as a, "
+                    "max(price) as hi group by symbol insert into O;",
+    "external_time": "#window.externalTime(et, 1 hour) select symbol, "
+                     "sum(price) as s group by symbol insert into O;",
+    "length_batch": "#window.lengthBatch(128) select symbol, sum(price) as s, "
+                    "min(price) as lo group by symbol insert into O;",
+    "external_time_batch": "#window.externalTimeBatch(et, 150 milliseconds) "
+                           "select "
+                           "sum(price) as s, count() as c insert into O;",
+}
+
+
+def _grown_run(query, doubling, batches=(120, 7, 40)):
+    """The query on a carry forced down to 8 entries, fed `batches` events
+    (1 ms apart, so an hour's window, a second's bucket and a batch of 128
+    keep all of the first 120: 16x the carry); -> (rows by batch, grows
+    after each batch, window, window_carry)."""
+    d = Driven(query, head=G_HEAD)
+    if doubling:
+        grow = d.plan._grow
+        d.plan._grow = lambda new_c: grow(2 * d.plan.C)
+    d.plan.C = 8
+    d.plan.state = d.plan._init_state()
+    rng = np.random.default_rng(48)
+    rows, grows, sent = [], [], 0
+    for n in batches:
+        cols = {"symbol": rng.integers(0, 3, n).astype(np.int32),
+                "price": cents(rng, n),
+                "volume": np.ones(n, np.int32),
+                "et": 5000 + sent + np.arange(n, dtype=np.int64)}
+        d.got.clear()
+        d.rt.input_handler("S").send_batch(
+            cols, 1000 + sent + np.arange(n, dtype=np.int64))
+        d.rt.flush()
+        sent += n
+        rows.append({k: np.concatenate([g[k] for g in d.got])
+                     for k in d.got[0]} if d.got else None)
+        grows.append((d.plan.counters["carry_grows"], d.plan.C))
+    entry = d.rt.explain()["queries"]["q"]
+    d.close()
+    return rows, grows, entry["window"], entry["window_carry"]
+
+
+@pytest.mark.parametrize("kind", sorted(GROWN))
+def test_growth_by_count_delivers_growth_by_doublings_bits(kind):
+    by_count, grows, win, carry = _grown_run(GROWN[kind], doubling=False)
+    doubled, grows2, win2, carry2 = _grown_run(GROWN[kind], doubling=True)
+    assert len(by_count) == len(doubled) == 3
+    delivered = 0
+    for a, b in zip(by_count, doubled):
+        assert (a is None) == (b is None)
+        if a is None:
+            continue
+        assert sorted(a) == sorted(b)
+        for k in a:
+            assert a[k].dtype == b[k].dtype and a[k].tobytes() == \
+                b[k].tobytes(), (kind, k)
+            delivered += len(a[k])
+    assert delivered > 0
+    # the first batch needs 16x the 8 entries: ONE grow and one re-run once
+    # the step has said 120, where doubling took four of each (16, 32, 64,
+    # 128), each a recompile
+    sliding = "Batch" not in GROWN[kind]
+    assert grows[0] == (1, 128) and grows2[0] == (4, 128)
+    assert grows[1] == grows[0] and grows2[1] == grows2[0]      # 7 more fit
+    # 40 more: 167 in an hour's window overflow the 128 once more, either
+    # way (never less than double); a tumbling kind has emitted its bucket
+    assert grows[2] == ((2, 256) if sliding else (1, 128))
+    assert grows2[2] == ((5, 256) if sliding else (4, 128))
+    for w, g in ((win, grows), (win2, grows2)):
+        assert w["carry_grows"] == w["carry_overflow_reruns"] == g[2][0]
+    assert {k: carry[k] for k in ("held", "held_max")} == \
+        {k: carry2[k] for k in ("held", "held_max")}
+    assert 0 < carry["held"] <= carry["held_max"] <= 167
+    if sliding:         # it keeps everything: the word carried every count
+        assert carry["held"] == carry["held_max"] == 167
+
+
+def test_the_steps_word_is_a_count_and_an_overflow_is_a_count_over_capacity():
+    """120 events into 8 entries: the step says 120 and the carry grows to
+    128 and holds them; a step that fits still says what it keeps; a step
+    that needs 4x goes there at once."""
+    d = Driven("[price > 0.0]#window.time(1 hour) select count() as c "
+               "insert into O;")
+    d.plan.C = 8
+    d.plan.state = d.plan._init_state()
+    assert d.plan.window_carry == {"capacity": 8, "held": 0, "held_max": 0,
+                                   "grows": 0, "reruns": 0}
+    out = d.send(np.full(120, 1.0))
+    assert out["c"].tolist() == list(range(1, 121))
+    assert d.plan.window_carry == {"capacity": 128, "held": 120,
+                                   "held_max": 120, "grows": 1, "reruns": 1}
+    assert int(np.asarray(d.plan.state["valid"]).sum()) == 120
+    out = d.send(np.full(7, 1.0))       # fits: the word still counts
+    assert out["c"].tolist() == list(range(121, 128))
+    assert d.plan.window_carry == {"capacity": 128, "held": 127,
+                                   "held_max": 127, "grows": 1, "reruns": 1}
+    assert d.plan.device_metrics()["window_carry"] == d.plan.window_carry
+    out = d.send(np.full(400, 1.0))     # 527: 1,024 at once, not 256, 512
+    assert out["c"].tolist() == list(range(128, 528))
+    assert d.plan.window_carry == {"capacity": 1024, "held": 527,
+                                   "held_max": 527, "grows": 2, "reruns": 2}
+    d.close()
+
+
+def test_a_time_window_grows_to_each_count_as_it_fills():
+    """`roomtemp10m` at a small size: a 600 ms window at an event a ms, fed
+    in 256-event batches.  The steps of batches 0, 1 and 2 say 256, 512 and
+    600: the carry goes 8 -> 256 -> 512 -> 1,024, three grows and three
+    re-runs (by doubling: seven of each), then holds 600 for good."""
+    d = Driven("#window.time(600 milliseconds) select symbol, avg(price) as a "
+               "group by symbol insert into O;")
+    d.plan.C = 8
+    d.plan.state = d.plan._init_state()
+    for i in range(5):
+        out = d.send(np.full(256, 2.0), symbol=np.arange(256, dtype=np.int32)
+                     % 3)
+        # a mean of equal prices: exact on the CPU, one f32 division (within
+        # 3 ulps) on the chip
+        assert np.allclose(out["a"], 2.0, rtol=4e-7, atol=0)
+        held = min(256 * (i + 1), 600)
+        assert d.plan.window_carry == {
+            "capacity": (256, 512, 1024)[min(i, 2)], "held": held,
+            "held_max": held, "grows": min(i + 1, 3),
+            "reruns": min(i + 1, 3)}
+    # (the (8, 1024) entry is the constructor's shape check at C_START)
+    assert sorted(d.plan._step_cache) == [
+        (8, 1024), (256, 8), (256, 256), (256, 512), (256, 1024)]
+    d.close()
+
+
+@pytest.mark.parametrize("depth", [2, 4])
+def test_a_pipelined_overflow_replays_the_chain_to_depth_0s_rows(depth):
+    """Batches in flight behind a step whose count is over the capacity ran
+    on the carry it could not hold: they are taken back and run again at
+    the grown capacity, every one at the capacity the plan now has.  The
+    rows are depth 0's; the re-runs count the whole chain."""
+    def run(head):
+        d = Driven("#window.time(1 hour) select symbol, sum(price) as s, "
+                   "count() as c group by symbol insert into O;",
+                   head=head + W_HEAD)
+        d.plan.C = 8
+        d.plan.state = d.plan._init_state()
+        rng = np.random.default_rng(3)
+        sent = 0
+        for n in (60, 60, 60, 300, 60):             # no flush between them
+            d.rt.input_handler("S").send_batch(
+                {"symbol": rng.integers(0, 3, n).astype(np.int32),
+                 "price": cents(rng, n), "volume": np.ones(n, np.int32)},
+                1000 + sent + np.arange(n, dtype=np.int64))
+            sent += n
+        d.rt.flush()
+        rows = {k: np.concatenate([g[k] for g in d.got]) for k in d.got[0]}
+        carry = d.plan.window_carry
+        d.close()
+        return rows, carry
+    base, carry0 = run("")
+    piped, carry = run(f"@app:devicePipeline({depth})\n")
+    assert sorted(piped) == sorted(base) and len(base["c"]) == 540
+    assert all(piped[k].tobytes() == base[k].tobytes() for k in base)
+    assert carry0 == {"capacity": 1024, "held": 540, "held_max": 540,
+                      "grows": 5, "reruns": 5}      # 64, 128, 256, 512, 1024
+    assert {**carry, "reruns": 5} == carry0 and carry["reruns"] > 5
+
+
+def test_a_length_windows_word_counts_what_it_holds():
+    """`window1k`'s shape at a small size: the carry never grows, and
+    `held` reads min(n, L) of the capacity the length gives."""
+    d = Driven("#window.length(1000) select avg(price) as ap insert into O;")
+    d.send(np.full(600, 2.0))
+    assert d.plan.window_carry == {"capacity": 1024, "held": 600,
+                                   "held_max": 600, "grows": 0, "reruns": 0}
+    d.send(np.full(600, 2.0))
+    assert d.plan.window_carry == {"capacity": 1024, "held": 1000,
+                                   "held_max": 1000, "grows": 0, "reruns": 0}
+    d.close()
+
+
+# -- forms chosen for the TPU compiler's sake (PR 49) ---------------------------
+
+@pytest.mark.parametrize("n", [5, 2047, 2048, 2049, 5000, 1024 * 9])
+def test_scan_in_rows_is_the_associative_scan(n):
+    rng = np.random.default_rng(n)
+    plain = lambda op: jax.jit(lambda x: jax.lax.associative_scan(op, x))
+    rows = lambda op: jax.jit(lambda x: wd._scan(op, x))
+    ints = jnp.asarray(rng.integers(-2 ** 40, 2 ** 40, n))
+    assert np.array_equal(rows(jnp.maximum)(ints), plain(jnp.maximum)(ints))
+    # a non-commutative operator, flags and values (the segmented scans')
+    flags = jnp.asarray(rng.random(n) < 0.01)
+    vals = jnp.asarray(rng.integers(0, 1000, n))
+
+    def comb(a, b):
+        return a[0] | b[0], jnp.where(b[0], b[1], a[1] + b[1])
+    got, want = rows(comb)((flags, vals)), plain(comb)((flags, vals))
+    assert np.array_equal(got[0], want[0]) and np.array_equal(got[1], want[1])
+    # the pair prefix on a grid: exact, so the same whatever the order
+    v = jnp.asarray((np.round(rng.uniform(15, 35, n) * 4) / 4).astype(
+        np.float32))
+    hi, lo = jax.jit(wd._prefix_pairs)(v)
+    assert np.array_equal(np.asarray(hi, np.float64) + np.asarray(lo),
+                          np.cumsum(np.asarray(v, np.float64)))
+
+
+@pytest.mark.parametrize("query", [
+    "#window.time(3 sec) select symbol, avg(price) as a, max(price) as hi "
+    "group by symbol insert into O;",
+    "#window.length(1000) select sum(price) as s, count() as c insert into O;",
+    "#window.lengthBatch(700) select symbol, sum(price) as s, min(price) as lo "
+    "group by symbol insert into O;",
+], ids=["grouped_time", "length", "grouped_length_batch"])
+def test_a_step_scanned_in_rows_delivers_the_same_rows(query, monkeypatch):
+    """The whole step with its scans laid out as rows (as it is from 2,048
+    entries on) against the step with every scan a 1-D `associative_scan`
+    (as it was until PR 49): [carry | batch] of 3,072 to 6,144 entries,
+    quarter-step prices (every sum exact, so the order of the additions
+    cannot show), the same bits on every row."""
+    def run():
+        d = Driven(query)
+        rng = np.random.default_rng(7)
+        rows = []
+        for n in (2048, 2048, 1500):
+            price = np.round(rng.uniform(90, 130, n) * 4) / 4
+            rows.append(d.send(price, symbol=rng.integers(0, 5, n).astype(
+                np.int32)))
+        d.close()
+        return rows
+    in_rows = run()
+    monkeypatch.setattr(wd, "_scan", jax.lax.associative_scan)
+    plain = run()
+    assert any(r is not None for r in plain)
+    for a, b in zip(plain, in_rows):
+        assert (a is None) == (b is None)
+        if a is not None:
+            assert sorted(a) == sorted(b)
+            assert all(a[k].tobytes() == b[k].tobytes() for k in a)
+
+
+def test_order_by_words_is_the_stable_lexsort():
+    rng = np.random.default_rng(3)
+    n = 4000
+    a = rng.integers(-3, 3, n).astype(np.int32)
+    b = rng.integers(-2 ** 40, 2 ** 40, n) * rng.integers(0, 2, n)
+    c = rng.integers(0, 4, n).astype(np.int64) - 2
+    words = wd._words(jnp.asarray(a)) + wd._words(jnp.asarray(b)) \
+        + wd._words(jnp.asarray(c))
+    assert [w.dtype for w in words] == [jnp.uint32] * 5
+    order = np.asarray(wd._order_by_words(words))
+    # grouped as the keys group, arrival order kept inside a group
+    key = np.stack([a, b, c], 1)[order]
+    change = np.flatnonzero((key[1:] != key[:-1]).any(1)) + 1
+    groups = np.split(order, change)
+    assert len(groups) == len(np.unique(np.stack([a, b, c], 1), axis=0))
+    assert all((np.diff(g) > 0).all() for g in groups)
+    # and as words compare (unsigned), it IS the lexsort
+    as_u = [np.asarray(w) for w in words]
+    assert np.array_equal(order, np.lexsort(as_u[::-1]))
+
+
+# -- the clock's left edge on 32-bit offsets ---------------------------------------
+
+def _clock(carry, batch, pads, empty=0):
+    """[empty carry slots | carry | batch | pads] as a step's `all_ts`
+    (monotone), with the positions of the batch's first and last valid
+    entries."""
+    ts = np.concatenate([np.full(empty, -2 ** 62), carry, batch,
+                         np.full(pads, 2 ** 62)]).astype(np.int64)
+    first = empty + len(carry)
+    return ts, first, max(first + len(batch) - 1, 0)
+
+
+T0 = 1_700_000_000_000
+DAY = 86_400_000
+_CLOCKS = {
+    # a minute of stream a ms apart: every edge within the range
+    "dense": (_clock(T0 + np.arange(300), T0 + 300 + np.arange(200), 12, 8),
+              100, "narrow"),
+    # carry entries a month old, long expired, clamp to the range's floor
+    "old_carry": (_clock(T0 - 40 * DAY + np.arange(50),
+                         T0 + np.arange(0, 5000, 50), 4, 2),
+                  600_000, "narrow"),
+    # the oldest batch entry and its edge EXACTLY 2^31 - 1 ms behind
+    "at_the_limit": (_clock(T0 - 2 ** 31 - 4000 + np.arange(5) * 1000,
+                            [T0 - (2 ** 31 - 1) + 1000, T0 - 7, T0], 0),
+                     1000, "narrow"),
+    # one ms further: the batch spans too far for 32 bits
+    "past_the_limit": (_clock(T0 - 2 ** 31 - 4000 + np.arange(5) * 1000,
+                              [T0 - (2 ** 31 - 1) + 999, T0 - 7, T0], 0),
+                       1000, "wide"),
+    "batch_of_a_month": (_clock(T0 - 40 * DAY + np.arange(9) * DAY,
+                                T0 - 30 * DAY + np.arange(31) * DAY, 3),
+                         2 * DAY, "wide"),
+    # equal timestamps: an edge lies after ALL of a run of equals
+    "ties": (_clock([T0, T0, T0 + 5, T0 + 5],
+                    [T0 + 5, T0 + 10, T0 + 10, T0 + 15], 2, 1),
+             5, "narrow"),
+    # no valid batch entry: nothing is read, nothing must fail
+    "empty_batch": (_clock(T0 + np.arange(10), [], 6, 2), 100, "narrow"),
+    # a window longer than 32 bits of ms hold: never narrowed
+    "a_long_window": (_clock(T0 + np.arange(100), T0 + 100 + np.arange(28),
+                             0), 30 * DAY, "wide"),
+}
+
+
+@pytest.mark.parametrize("case", list(_CLOCKS))
+def test_the_clocks_left_edge_on_32_bits_is_the_i64_search(case, monkeypatch):
+    """`_clock_left` at every valid batch position is
+    `searchsorted(all_ts, all_ts - D, "right")`, whichever form the batch's
+    span lets it take; and it takes the narrow one exactly while the oldest
+    valid batch entry's edge lies within 2^31 - 1 ms of the newest."""
+    (ts, first, last), D, form = _CLOCKS[case]
+    assert (np.diff(ts) >= 0).all()
+    took = []
+    cond = jax.lax.cond
+
+    def spy(pred, narrow, wide):
+        took.append("narrow" if bool(pred) else "wide")
+        return cond(pred, narrow, wide)
+    monkeypatch.setattr(jax.lax, "cond", spy)
+    left = np.asarray(wd._clock_left(jnp.asarray(ts), D, first, last))
+    want = np.searchsorted(ts, ts - D, side="right")
+    assert np.array_equal(left[first:last + 1], want[first:last + 1])
+    assert took == ([] if D > 2 ** 31 - 1 else [form])
+    monkeypatch.undo()
+    # under jit, as the step traces it
+    jitted = jax.jit(lambda a, f, l: wd._clock_left(a, D, f, l))
+    left = np.asarray(jitted(jnp.asarray(ts), first, last))
+    assert np.array_equal(left[first:last + 1], want[first:last + 1])
