@@ -218,6 +218,7 @@ def _query_entry(rt, plan) -> Optional[dict]:
     if kind == "window":
         ent["window"] = plan.window
         ent["window_step"] = dict(plan.window_step)
+        ent["window_ranks"] = dict(plan.window_ranks)
         ent["window_carry"] = plan.window_carry
     if kind == "partition-group":
         ent["queries"] = sorted(

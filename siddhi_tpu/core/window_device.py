@@ -17,9 +17,12 @@ loop becomes closed-form range reductions over the concatenated
     sums/counts/avgs read a range of ONE compensated prefix sum (O(T);
     `_range_sum`: exact to the rounding of the range's own contents),
     min/max read a log2 sparse table (O(T log T) build, O(1) per query).
-  * group-by — per-group prefixes come from one sort by (segment,
-    position) + segmented cumsum + two searchsorted rank lookups; no
-    per-group state is kept at all for sliding windows.
+  * group-by — per-group prefixes come from ONE stable sort by the key
+    words, which is the (segment, position) order: an entry's rank in it
+    is the inverse of the permutation the sort returned (one more sort,
+    `_to_arrival`), its window's first rank a search bounded to its own
+    segment, both taken for the batch's rows only; no per-group state is
+    kept at all for sliding windows.
   * lengthBatch(N) tumbling — per-event running aggregates restart at
     bucket boundaries: a segmented scan keyed by (bucket, group); rows
     emit only when their bucket completes (reference emits the whole
@@ -130,15 +133,21 @@ def _words(c: jnp.ndarray) -> list:
     return [u32(_w_hi32(c)), u32(_w_lo32(c))]
 
 
-def _order_by_words(words: list) -> jnp.ndarray:
-    """Arrival order sorted stably by `words` (most significant first): what
-    `jnp.lexsort(words[::-1])` gives, as a chain of stable sorts of ONE
-    32-bit key and one payload, least significant word first."""
+def _sort_by_words(words: list) -> tuple:
+    """(order, lead): arrival order sorted stably by `words` (most
+    significant first), what `jnp.lexsort(words[::-1])` gives, as a chain of
+    stable sorts of ONE 32-bit key and one payload, least significant word
+    first; and `words[0][order]`, the last sort's own key (no gather)."""
     order = jnp.arange(words[0].shape[0], dtype=jnp.int32)
     for i, w in enumerate(reversed(words)):
-        _key, order = jax.lax.sort((w if i == 0 else w[order], order),
+        lead, order = jax.lax.sort((w if i == 0 else w[order], order),
                                    num_keys=1, is_stable=True)
-    return order
+    return order, lead
+
+
+def _order_by_words(words: list) -> jnp.ndarray:
+    """`_sort_by_words`' order alone."""
+    return _sort_by_words(words)[0]
 
 
 def _floor_log2(x: jnp.ndarray) -> jnp.ndarray:
@@ -229,11 +238,15 @@ def _pair_diff(top: tuple, base: tuple) -> jnp.ndarray:
 
 def _range_sum(pfx: tuple, lo: jnp.ndarray, hi=None) -> jnp.ndarray:
     """Sum of the scanned values over positions (lo, hi]; lo == -1 takes
-    the range from the start, hi None ends each range at its own index."""
+    the range from the start.  `hi` None ends each range at its own index:
+    `lo` then speaks for the LAST len(lo) positions (all of them, or the
+    batch's rows behind the carry), whose prefix is a static slice."""
     ph, pl = pfx
     at = jnp.maximum(lo, 0)
     base = (jnp.where(lo >= 0, ph[at], 0.0), jnp.where(lo >= 0, pl[at], 0.0))
-    return _pair_diff(pfx if hi is None else (ph[hi], pl[hi]), base)
+    tail = ph.shape[0] - lo.shape[0]
+    return _pair_diff((ph[tail:], pl[tail:]) if hi is None
+                      else (ph[hi], pl[hi]), base)
 
 
 def _trailing_sum(pfx: tuple, L: int) -> jnp.ndarray:
@@ -256,76 +269,144 @@ def _length_left(gpos: jnp.ndarray, first_valid, L: int) -> jnp.ndarray:
 _I32_MAX = 2 ** 31 - 1
 
 
-def _clock_left(all_ts: jnp.ndarray, D: int, first, last) -> jnp.ndarray:
-    """Left edge of each position's time(D) window over the monotone clock
-    `all_ts`: the first index whose clock lies after `all_ts[g] - D`, what
-    `searchsorted(all_ts, all_ts - D, side="right")` finds, exact at every
-    position of `first..last`, the batch's valid entries (the carry's own
-    edges are not read: only the batch's rows leave the step).  The clock
-    is searched as a 32-bit offset from the newest valid entry wherever
-    the oldest of those entries and its edge lie within 2^31 ms of it
-    (`lax.cond`, a batch).  A clock as i64 is two words, and a binary
-    search gathers both every round; at 1.31 M entries that search ran at
-    one of two speeds from process to process, 820 or 870 ms a step (the
-    step 2,504 or 2,622 ms: 6 of 23 runs the slow kind over four leases;
-    PERF.md 7.17), which alone spreads six seeds of `roomtemp10m.sat` by
-    3.9%, over half that cell's bound; on 32-bit offsets it takes 197 ms
-    and the step 1,883, six seeds within 0.3%.  Older keys, the carry's
-    empty slots and the batch's pads clamp to the ends of the range, on
-    the side of the edge they lie on."""
-    wide = lambda: jnp.searchsorted(all_ts, all_ts - D, side="right")
+def _clock_left(all_ts: jnp.ndarray, D: int, C: int, last) -> jnp.ndarray:
+    """Left edge of the time(D) window of each of the batch's rows,
+    positions C.. of the monotone clock `all_ts`: the first index whose
+    clock lies after `all_ts[g] - D`, what
+    `searchsorted(all_ts, all_ts - D, side="right")[C:]` finds, exact at
+    every position of `C..last`, the batch's valid entries.  Only the
+    batch's rows leave the step, so only they are asked for: T queries into
+    the whole clock, not C + T (at `roomtemp10m`'s 2^20 + 2^18 a fifth of
+    the gathers a round).  The clock is searched as a 32-bit offset from
+    the newest valid entry wherever the oldest of those entries and its
+    edge lie within 2^31 ms of it (`lax.cond`, a batch).  A clock as i64 is
+    two words, and a binary search gathers both every round; at 1.31 M
+    queries that search ran at one of two speeds from process to process,
+    820 or 870 ms a step (the step 2,504 or 2,622 ms: 6 of 23 runs the slow
+    kind over four leases; PERF.md 7.17), which alone spreads six seeds of
+    `roomtemp10m.sat` by 3.9%, over half that cell's bound; on 32-bit
+    offsets it took 197 ms for those 1.31 M queries (PR 49).  Older keys,
+    the carry's empty slots and the batch's pads clamp to the ends of the
+    range, on the side of the edge they lie on."""
+    wide = lambda: jnp.searchsorted(all_ts, all_ts[C:] - D, side="right")
     if D > _I32_MAX:
         return wide()
     rel = all_ts - all_ts[last]         # <= 0 at every valid entry
 
     def narrow():
         as32 = lambda x: jnp.clip(x, -_I32_MAX, _I32_MAX).astype(jnp.int32)
-        return jnp.searchsorted(as32(rel), as32(rel - D), side="right")
-    return jax.lax.cond(rel[first] - D >= -_I32_MAX, narrow, wide)
+        return jnp.searchsorted(as32(rel), as32(rel[C:] - D), side="right")
+    return jax.lax.cond(rel[C] - D >= -_I32_MAX, narrow, wide)
+
+
+def _run_flags(seg: jnp.ndarray) -> tuple:
+    """(is_start, is_last) of each entry in its run of equal `seg`."""
+    edge = seg[1:] != seg[:-1]
+    one = jnp.array([True])
+    return jnp.concatenate([one, edge]), jnp.concatenate([edge, one])
 
 
 def _segment_start(seg: jnp.ndarray) -> jnp.ndarray:
     """Index of the first entry of each entry's run of equal `seg`."""
     n = seg.shape[0]
-    is_start = jnp.concatenate([jnp.array([True]), seg[1:] != seg[:-1]])
-    return _scan(jnp.maximum, jnp.where(is_start, jnp.arange(n), 0))
+    is_start, _ = _run_flags(seg)
+    return _scan(jnp.maximum,
+                 jnp.where(is_start, jnp.arange(n, dtype=jnp.int32), 0))
 
 
-def _by_segment(seg: jnp.ndarray, n: int) -> tuple:
-    """(order, ks): arrival order sorted by (segment, position), which is
-    arrival order sorted STABLY by segment, and the sorted seg * n + pos
-    keys (invalid entries: a large segment id).  `seg` in 32 bits (a
-    sliding window's: at most n) sorts as one word, else as two."""
-    order = _order_by_words(_words(seg))
-    key = seg.astype(jnp.int64) * n + jnp.arange(n, dtype=jnp.int64)
-    return order, key[order]
+def _segment_end(seg: jnp.ndarray) -> jnp.ndarray:
+    """Index past the last entry of each entry's run of equal `seg`."""
+    n = seg.shape[0]
+    _, is_last = _run_flags(seg)
+    ends = jnp.where(is_last, jnp.arange(1, n + 1, dtype=jnp.int32), n)
+    return _scan(jnp.minimum, ends[::-1])[::-1]
 
 
-def _seg_ranges(seg, left, gpos, n) -> tuple:
-    """(order, first, own) of a grouped sliding window: the (segment,
-    position) order, and in it each entry's own rank and the rank of its
-    segment's first member at or after `left`.  A step takes them ONCE,
-    under scopes of their own (`window/segment_order`, the sort;
-    `window/segment_search`, the two searches): every windowed sum, count,
-    min and max of it reads the same ranges."""
-    with jax.named_scope("window/segment_order"):
-        order, ks = _by_segment(seg.astype(jnp.int32), n)   # seg <= n
-    with jax.named_scope("window/segment_search"):
-        first = jnp.searchsorted(ks, seg * n + left)
-        own = jnp.searchsorted(ks, seg * n + gpos)
+# A group-by sorts arrival order ONCE (`_group_order`: stable, so positions
+# ascend inside a group).  Everything that was looked up in that order by a
+# binary search over 64-bit seg * n + pos keys is read off the permutation
+# itself (PR 50; on the chip a sort of 1.31 M 32-bit keys is ~2 ms, one
+# round of gathers of as many 7-24 ns an element, a scatter of as many
+# 151 ms):
+
+def _group_order(cols: list, valid: jnp.ndarray) -> tuple:
+    """(order, seg_sorted) of a group-by over the key columns `cols`:
+    arrival order sorted stably by (invalid, the keys' words), which is the
+    (segment, position) order with the invalid entries last, and in it each
+    entry's dense segment id (invalid -> n).  A float key groups by value
+    (-0.0 with 0.0), as the interpreter's."""
+    n = valid.shape[0]
+    words = [(~valid).astype(jnp.uint32)]
+    for c in cols:
+        if c.dtype.kind == "f":
+            c = c.astype(jnp.float64)
+            c = jnp.where(c == 0.0, 0.0, c).view(jnp.int64)
+        words += _words(c)
+    order, inval = _sort_by_words(words)
+    diff = jnp.zeros(n, dtype=bool)
+    for w in words[1:]:
+        diff = diff | _run_flags(w[order])[0]
+    seg_sorted = jnp.cumsum(diff, dtype=jnp.int32) - 1
+    return order, jnp.where(inval == 0, seg_sorted, n)
+
+
+def _to_arrival(order: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
+    """`x`, given in the sorted order (`x[r]` belongs to arrival index
+    `order[r]`), in arrival order: the inverse permutation applied as ONE
+    sort of (order, x) by `order` (the keys are unique, so no stability is
+    asked for), where a scatter `.at[order].set(x)` was.  `x` = iota gives
+    each entry's RANK in the order, what
+    `searchsorted(seg[order] * n + order, seg * n + arange(n))` found."""
+    return jax.lax.sort((order, x), num_keys=1, is_stable=False)[1]
+
+
+def _first_at_or_after(order, lo, hi, left) -> jnp.ndarray:
+    """Per query the first rank r of [lo, hi) with `order[r] >= left`, `hi`
+    where there is none: [lo, hi) a run of `order` whose positions ascend
+    (a segment).  A binary search of ONE 32-bit gather a round, for as many
+    rounds as the longest run asked about needs (its bit length: 10 for
+    `roomtemp10m`'s ~730 members a sensor, not 21 for N)."""
+    rounds = 32 - jax.lax.clz(jnp.max(hi - lo, initial=0))
+
+    def halve(_, lh):
+        lo, hi = lh
+        mid = (lo + hi) >> 1            # lo <= mid < hi wherever lo < hi
+        before = order[mid] < left
+        return (jnp.where(before & (mid < hi), mid + 1, lo),
+                jnp.where(before, hi, mid))
+    return jax.lax.fori_loop(0, rounds, halve, (lo, hi))[0]
+
+
+def _seg_ranges(order, seg_sorted, left, live) -> tuple:
+    """(order, first, own) of a grouped sliding window, for the batch's T
+    rows (`left` and `live`, its valid rows, are T long): `order` the
+    (segment, position) order with `seg_sorted` its segment ids (the
+    group-by's own sort: `_group_order`), and in it each row's own rank and
+    the rank of its segment's first member at or after `left`, what
+    `searchsorted(ks, seg * n + gpos)` and `searchsorted(ks, seg * n +
+    left)` found over ks = (seg * n + pos)[order] at every live row.  A
+    step takes them ONCE, under a scope of its own
+    (`window/segment_rank`): every windowed sum, count, min and max of it
+    reads the same ranges."""
+    with jax.named_scope("window/segment_rank"):
+        n, T = order.shape[0], left.shape[0]
+        own = _to_arrival(order, jnp.arange(n, dtype=jnp.int32))[n - T:]
+        lo = jnp.where(live, _segment_start(seg_sorted)[own], 0)
+        hi = jnp.where(live, _segment_end(seg_sorted)[own], 0)
+        first = _first_at_or_after(order, lo, hi, left.astype(jnp.int32))
     return order, first, own
 
 
 def _seg_window_sum(ranges, v):
-    """Per-entry sum over its segment's members in positions [left, gpos]:
+    """Per-row sum over its segment's members in positions [left, gpos]:
     a range of the (segment, position) order, from the segment's first
-    member at or after `left` to the entry itself."""
+    member at or after `left` to the row's entry itself."""
     order, first, own = ranges
     return _range_sum(_prefix_pairs(v[order]), first - 1, own)
 
 
 def _seg_window_minmax(ranges, v, is_max):
-    """Per-entry min/max over its segment's members in positions
+    """Per-row min/max over its segment's members in positions
     [left, gpos]: a log2 sparse table over the (segment, position) order
     (the grouped analog of the ungrouped range-reduce; v must carry the
     neutral at invalid entries)."""
@@ -334,28 +415,19 @@ def _seg_window_minmax(ranges, v, is_max):
     return _range_reduce(table, jnp.minimum(first, own), own, is_max)
 
 
-def _seg_running_sum(seg, v, n):
+def _seg_running_sum(seg, v):
     """Per-entry running sum within its segment, arrival order."""
-    order, ks = _by_segment(seg, n)
+    order = _order_by_words(_words(seg))
     run = _range_sum(_prefix_pairs(v[order]),
                      _segment_start(seg[order]) - 1)
-    return run[jnp.searchsorted(ks, seg * n + jnp.arange(n, dtype=jnp.int64))]
+    return _to_arrival(order, run)
 
 
-def _seg_running_minmax(seg, v, is_max, n):
+def _seg_running_minmax(seg, v, is_max):
     """Per-entry running min/max within its segment, arrival order."""
-    order, ks = _by_segment(seg, n)
-    ss = seg[order]
-    vs = v[order]
-    is_start = jnp.concatenate([jnp.array([True]), ss[1:] != ss[:-1]])
-    op = jnp.maximum if is_max else jnp.minimum
-
-    def comb(a, b):
-        af, av = a
-        bf, bv = b
-        return (af | bf, jnp.where(bf, bv, op(av, bv)))
-    _f, run = _scan(comb, (is_start, vs))
-    return run[jnp.searchsorted(ks, seg * n + jnp.arange(n, dtype=jnp.int64))]
+    order = _order_by_words(_words(seg))
+    return _to_arrival(order, _mono_running_minmax(seg[order], v[order],
+                                                   is_max))
 
 
 # monotone-segment variants: when segment ids are nondecreasing in arrival
@@ -661,6 +733,18 @@ class DeviceWindowAggPlan(QueryPlan):
                             "shift" if self.kind == "length" else "gather"),
             "compaction": "scatter" if self._filter is not None
             else "identity"}
+        # beside it, how a group-by's ranks are taken (`window_ranks`; a
+        # record of its own because a benchmark cell's test holds
+        # `window_step` to its three keys): an entry's rank in the
+        # (segment, position) order read off the permutation the group-by's
+        # sort returned (`_to_arrival`; a search over 64-bit keys until
+        # PR 50), a sliding window's first member searched inside its own
+        # segment of that order (`_first_at_or_after`)
+        sliding = self.kind in ("length", "time")
+        self.window_ranks = {
+            "segment_rank": "order" if self.group_keys else None,
+            "window_first": "bounded_search"
+            if self.group_keys and sliding else None}
 
         self.state = self._init_state()
         jax.eval_shape(self._step_fn(8, self.C), self.state, self._dummy(8))
@@ -786,35 +870,18 @@ class DeviceWindowAggPlan(QueryPlan):
                     out.append(arg.fn(env_all).astype(FDT))
             return out
 
-        def group_seg(env_all, gvalid, n):
-            """Dense group-segment id per entry (invalid -> n): the
-            group-by's sort and scatter, under `window/group`."""
-            if not group_keys:
-                return jnp.where(gvalid, 0, n).astype(jnp.int64)
+        def group_order(env_all, gvalid):
+            """`_group_order` of the group-by's key columns: the one sort
+            of a grouped step, under `window/group`."""
             with scope("window/group"):
-                words = []
-                for g in group_keys:
-                    c = env_all[g]
-                    if c.dtype.kind == "f":
-                        c = c.astype(jnp.float64)
-                        c = jnp.where(c == 0.0, 0.0, c).view(jnp.int64)
-                    words += _words(c)
-                order = _order_by_words(words)
-                diff = jnp.zeros(n, dtype=bool)
-                for w in words:
-                    ws = w[order]
-                    diff = diff | jnp.concatenate(
-                        [jnp.array([True]), ws[1:] != ws[:-1]])
-                seg_sorted = jnp.cumsum(diff) - 1
-                seg = jnp.zeros(n, dtype=jnp.int64).at[order].set(seg_sorted)
-                return jnp.where(gvalid, seg, n)
+                return _group_order([env_all[g] for g in group_keys], gvalid)
 
         def bucket_seg(brel, env_all, all_valid):
             """The tumbling kinds' segment id per entry: its bucket, or
             (bucket, group) under a group-by."""
             if not group_keys:
                 return brel
-            seg = group_seg(env_all, all_valid, N)
+            seg = _to_arrival(*group_order(env_all, all_valid))
             return jnp.where(all_valid, brel * (N + 1) + seg,
                              jnp.int64((N + 2) * (N + 1)))
 
@@ -859,8 +926,8 @@ class DeviceWindowAggPlan(QueryPlan):
             lowest incomplete bucket), so the sort inside the segmented
             scans is a no-op and is skipped."""
             if group_keys:
-                rsum = lambda v_: _seg_running_sum(segk, v_, N)
-                rmm = lambda v_, mx: _seg_running_minmax(segk, v_, mx, N)
+                rsum = lambda v_: _seg_running_sum(segk, v_)
+                rmm = lambda v_, mx: _seg_running_minmax(segk, v_, mx)
             else:
                 rsum = lambda v_: _mono_running_sum(segk, v_)
                 rmm = lambda v_, mx: _mono_running_minmax(segk, v_, mx)
@@ -892,7 +959,10 @@ class DeviceWindowAggPlan(QueryPlan):
                 all_ts = _scan(                         # monotone
                     jnp.maximum, jnp.concatenate([state["ts"], bts]))
                 env_all["__timestamp__"] = all_ts
-            gpos = jnp.arange(N, dtype=jnp.int64)
+            # only the batch's rows leave the step: every edge, rank and
+            # prefix read below is taken for those T positions, C.. of
+            # [carry | batch]; the sorts and the scans run over all N
+            gpos = jnp.arange(C, N, dtype=jnp.int64)
             with scope("window/left_edge"):
                 if forms["left_edge"] == "arithmetic":
                     # the valid run of [carry | batch] (see `carry`)
@@ -902,8 +972,8 @@ class DeviceWindowAggPlan(QueryPlan):
                 else:
                     left = _clock_left(all_ts, D, C,
                                        jnp.maximum(C + k - 1, 0))
-            ranges = _seg_ranges(group_seg(env_all, all_valid, N), left,
-                                 gpos, N) if group_keys else None
+            ranges = _seg_ranges(*group_order(env_all, all_valid), left,
+                                 bvalid) if group_keys else None
             vals = site_vals(env_all, N)
 
             def wsum(v):
@@ -915,7 +985,7 @@ class DeviceWindowAggPlan(QueryPlan):
                     if forms["prefix_read"] == "segmented":
                         return _seg_window_sum(ranges, v)
                     if forms["prefix_read"] == "shift":
-                        return _trailing_sum(_prefix_pairs(v), L)
+                        return _trailing_sum(_prefix_pairs(v), L)[C:]
                     return _range_sum(_prefix_pairs(v), left - 1)
 
             def wcount():
@@ -923,34 +993,38 @@ class DeviceWindowAggPlan(QueryPlan):
                 if forms["prefix_read"] == "shift":
                     # exact: an integer under 2^24
                     return jnp.clip(gpos - first_valid + 1, 0, L).astype(FDT)
+                if forms["prefix_read"] == "segmented":
+                    # the ranks ARE the count (the invalid entries sort
+                    # last): what the range of a prefix of ones reads
+                    _order, first, own = ranges
+                    return (own - first + 1).astype(FDT)
                 return wsum(all_valid.astype(FDT))
 
-            aggs_full = []
+            aggs = []
             for i, (nm, _arg, _ot) in enumerate(sites):
                 if nm in ("min", "max"):
                     neutral = NEG if nm == "max" else POS
                     vv = jnp.where(all_valid, vals[i], neutral)
                     with scope("window/minmax"):
                         if group_keys:
-                            aggs_full.append(_seg_window_minmax(
+                            aggs.append(_seg_window_minmax(
                                 ranges, vv, nm == "max"))
                             continue
                         table = _sparse_table(vv, nm == "max")
-                        aggs_full.append(_range_reduce(
+                        aggs.append(_range_reduce(
                             table, jnp.minimum(left, gpos), gpos,
                             nm == "max"))
                     continue
                 if nm == "count":
-                    aggs_full.append(wcount())
+                    aggs.append(wcount())
                     continue
                 s = wsum(jnp.where(all_valid, vals[i], 0.0))
                 if nm == "avg":
                     s = s / jnp.maximum(wcount(), 1.0)
-                aggs_full.append(s)
+                aggs.append(s)
 
             # rows align with the compacted batch part (raw timestamps:
             # the monotonic clamp is internal to expiry math only)
-            aggs = [a[C:] for a in aggs_full]
             benv = {c: bcols[c] for c in cols}
             if bts is not None:
                 benv["__timestamp__"] = bts
@@ -964,7 +1038,7 @@ class DeviceWindowAggPlan(QueryPlan):
                     last_ts = all_ts[jnp.maximum(C + k - 1, 0)]
                     start_k = jnp.searchsorted(all_ts, last_ts - D,
                                                side="right")
-                keep = (gpos >= start_k) & all_valid
+                keep = (jnp.arange(N) >= start_k) & all_valid
                 nst = carry(state, state["seen"] + k, all_ts, keep, env_all,
                             k)
                 kept = jnp.sum(keep, dtype=jnp.int32)
@@ -1366,6 +1440,7 @@ class DeviceWindowAggPlan(QueryPlan):
                 "window_fill_ratio": round(fill / max(self.C, 1), 4),
                 "window": self.window,
                 "window_step": dict(self.window_step),
+                "window_ranks": dict(self.window_ranks),
                 "window_carry": self.window_carry}
 
     def state_dict(self) -> dict:

@@ -187,12 +187,17 @@ def test_roomtemp10ms_step_compiles_for_the_chip_at_its_stated_size(topo):
     1,310,720 entries of (ts i64, valid, deviceID i64, roomNo i32, temp
     f32); deviceID as two words, roomNo, temp and the timestamp offset in,
     avgTemp, roomNo and deviceID's two words a row out.  The grouped time
-    step is all sort, search and gather: what it holds is RECORDED here by
-    name, as found; the counts are the later `perf_opt`'s to lower (ROADMAP
-    A10), not a floor.  Until PR 49 it held a five-operand `lexsort`, an
-    `argsort` of an i64 key and three 1-D `associative_scan`s, and took
-    308 s to compile here (43 s now: `window_device._scan`,
-    `_order_by_words`)."""
+    step is sorts, scans and a few searches: what it holds is RECORDED here
+    by name, as found, not a floor.  Until PR 49 it held a five-operand
+    `lexsort`, an `argsort` of an i64 key and three 1-D `associative_scan`s,
+    and took 308 s to compile here (43 s since: `window_device._scan`,
+    `_order_by_words`).  Until PR 50 it held the segment ids' scatter and
+    two binary searches over 64-bit seg * n + pos keys for all 1,310,720
+    entries (71% of the step on the chip); since, the ranks are read off
+    the group-by's own sort (`window_ranks`) and every lookup is made for
+    the batch's 2^18 rows: 46 s to compile here, the parent's 44 on the
+    same machine in the same hour (one sort more, one `while` and a scatter
+    fewer), and half the temporaries (53 MB for 107)."""
     import os
     from siddhi_tpu import SiddhiManager
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -236,15 +241,19 @@ def test_roomtemp10ms_step_compiles_for_the_chip_at_its_stated_size(topo):
     ops = Counter(re.findall(r"(?<![\w\-.%])([a-z][a-z\-]*)\(",
                              compiled.as_text()))
     assert ops["fusion"] > 0
-    # as found (PR 49): three one-key sorts for the group-by's three key
-    # words and one for the (segment, position) order, the sum's and the
-    # count's shared; five searches (`while`): the clock's left edge in its
-    # two forms under ONE `conditional` (on 32-bit offsets, and on the i64
+    # as found (PR 50): four one-key sorts for the group-by (validity, then
+    # the three key words) and one for the inverse permutation (a row's own
+    # rank); four searches (`while`): the clock's left edge in its two
+    # forms under ONE `conditional` (on 32-bit offsets, and on the i64
     # clock for a batch that spans more: `_clock_left`; a step runs one),
-    # the two segment ranks, the carry's cut; the gathers of the sorted
-    # orders and of the prefix pairs; the segment ids' scatter
-    assert {k: ops[k] for k in INDEXED} == {"sort": 4, "gather": 24,
-                                            "scatter": 1, "while": 5}
+    # the carry's cut, and the window's first rank bounded to the row's own
+    # segment (`_first_at_or_after`: 32-bit, a trip count read off the
+    # longest segment); the gathers of the sorted orders (1,310,720
+    # entries) and of the batch's rows (262,144); NO scatter
+    assert {k: ops[k] for k in INDEXED} == {"sort": 5, "gather": 17,
+                                            "scatter": 0, "while": 4}
+    assert plan.window_ranks == {"segment_rank": "order",
+                                 "window_first": "bounded_search"}
     assert ops["conditional"] == 1
     assert {k: ops[k] for k in COLLECTIVES if ops[k]} == {}
     mem = compiled.memory_analysis()
@@ -254,4 +263,4 @@ def test_roomtemp10ms_step_compiles_for_the_chip_at_its_stated_size(topo):
     carry_bytes = C * (8 + 1 + 8 + 4 + 4)       # ts, valid, the three columns
     assert carry_bytes == 26_214_400            # the ~26 MB of live state
     assert (mem.output_size_in_bytes - carry_bytes) // T == 16
-    assert mem.temp_size_in_bytes < 1 << 28     # 106 MB; the chip has 16 GB
+    assert mem.temp_size_in_bytes < 1 << 26     # 53 MB (107 until PR 50)

@@ -54,6 +54,126 @@ def test_length_left_and_trailing_sum_against_search_and_gather(fill, k, L):
                           counted[valid])
 
 
+
+# -- a group-by's ranks read off its own sort (PR 50) --------------------------
+
+def _searched_ranks(cols, valid, left, C):
+    """The forms PR 50 replaced, kept here as the plain reference: PR 49's
+    `group_seg` (dense segment ids in the order of the keys' words, brought
+    to arrival order by a scatter, invalid -> n) and `_seg_ranges` (a stable
+    sort by segment, then `searchsorted` over seg * n + pos keys: each
+    entry's own rank and the rank of its segment's first member at or after
+    `left`), with `_seg_running_sum`'s way back to arrival order."""
+    n = len(valid)
+    words = []
+    for c in cols:
+        if c.dtype.kind == "f":
+            c = c.astype(np.float64)
+            c = np.where(c == 0.0, 0.0, c).view(np.int64)
+        words += [np.asarray(w) for w in wd._words(jnp.asarray(c))]
+    order = np.lexsort(words[::-1])
+    diff = np.zeros(n, bool)
+    for w in words:
+        diff |= np.r_[True, w[order][1:] != w[order][:-1]]
+    seg = np.zeros(n, np.int64)
+    seg[order] = np.cumsum(diff) - 1
+    seg = np.where(valid, seg, n)
+    gpos = np.arange(n, dtype=np.int64)
+    by = np.argsort(seg, kind="stable")
+    ks = (seg * n + gpos)[by]
+    own = np.searchsorted(ks, seg * n + gpos)
+    first = np.searchsorted(ks, seg[C:] * n + left)
+    return seg, by, own, first
+
+
+def _ranked(name):
+    """(key columns, valid, left of the batch's rows, C) of a case: a carry
+    of C entries packed right, a batch compacted left."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    C, T, fill, k, groups = 1024, 256, 700, 200, 40
+    if name == "one_group":
+        groups = 1
+    elif name == "every_entry_its_own_group":
+        groups = 0
+    elif name == "two_thousand_groups":
+        C, T, fill, k, groups = 8192, 2048, 6000, 2048, 2000
+    elif name == "no_invalid_entry":
+        fill, k = C, T
+    elif name == "an_empty_carry":
+        fill = 0
+    elif name == "an_empty_batch":
+        k = 0
+    elif name == "under_two_scan_rows":
+        C, T, fill, k = 1024, 1000, 1000, 990          # N = 2024 < 2048
+    elif name == "over_two_scan_rows_and_ragged":
+        C, T, fill, k = 4096, 1001, 3000, 900          # N = 5097 = 4 x 1024 + 1001
+    n = C + T
+    g = np.arange(n)
+    valid = (g >= C - fill) & (g < C + k)
+    key = g.astype(np.int32) if groups == 0 \
+        else rng.integers(0, groups, n).astype(np.int32)
+    cols = [key]
+    if name == "float_keys":            # -0.0 groups with 0.0, halves apart
+        cols = [np.where(key % 5 == 0, -0.0, key % 5 * 0.5).astype(
+            np.float32), (key // 5).astype(np.float64) - 3.5]
+    elif name == "wide_keys":           # both words of an i64 tell groups apart
+        cols = [(key % 4).astype(np.int64) * (2 ** 33 + 7) - 2 ** 40,
+                (key // 4).astype(np.int32) - 3]
+    # a sliding window's edge: some way back, never past the row itself
+    left = np.maximum(g[C:] - rng.integers(0, fill + 2, T), 0)
+    if name == "left_past_the_segments_last_member":
+        left = np.minimum(g[C:] + rng.integers(0, 3, T)     # an edge is a
+                          * rng.integers(1, T, T), n)       # position, or n
+    return cols, valid, left, C
+
+
+RANKED = ["one_group", "every_entry_its_own_group", "two_thousand_groups",
+          "invalid_in_carry_and_pads", "no_invalid_entry", "an_empty_carry",
+          "an_empty_batch", "left_past_the_segments_last_member",
+          "under_two_scan_rows", "over_two_scan_rows_and_ragged",
+          "float_keys", "wide_keys"]
+
+
+@pytest.mark.parametrize("jitted", [False, True], ids=["eager", "jit"])
+@pytest.mark.parametrize("case", RANKED)
+def test_ranks_off_the_group_bys_order_are_the_searched_ranks(case, jitted):
+    """`_group_order`, `_to_arrival` and `_seg_ranges` against the sort,
+    scatter and two 64-bit `searchsorted`s they replaced, at every valid
+    batch row: the order over the valid entries, a row's own rank and its
+    window's first rank; and the two ways back to arrival order (segment
+    ids, a tumbling kind's running values) at every entry."""
+    cols, valid, left, C = _ranked(case)
+    n = len(valid)
+    seg, by, own, first = _searched_ranks(cols, valid, left, C)
+
+    def ranks(cols, valid, left):
+        order, seg_sorted = wd._group_order(cols, valid)
+        _, first, own = wd._seg_ranges(order, seg_sorted, left, valid[C:])
+        run = jnp.arange(n, dtype=jnp.float32) * 0.5      # any payload
+        return (order, wd._to_arrival(order, seg_sorted), first, own,
+                wd._to_arrival(order, run))
+    got = (jax.jit(ranks) if jitted else ranks)(
+        [jnp.asarray(c) for c in cols], jnp.asarray(valid), jnp.asarray(left))
+    order, seg_back, first_got, own_got, run_back = map(np.asarray, got)
+    held = int(valid.sum())
+    live = valid[C:]
+    assert np.array_equal(order[:held], by[:held])
+    assert sorted(order[held:]) == sorted(by[held:])     # the invalid, last
+    # the same groups in the same order, numbered over the valid entries
+    # alone (PR 49's ids skipped a key that only invalid entries held)
+    assert np.array_equal(seg_back[valid],
+                          np.unique(seg[valid], return_inverse=True)[1])
+    assert (seg_back[~valid] == n).all()
+    assert np.array_equal(own_got[live], own[C:][live])
+    assert np.array_equal(first_got[live], first[live])
+    # a tumbling kind's way back, `run[searchsorted(ks, seg * n + arange)]`
+    # over ks = (seg * n + arange)[order]: the rank of every entry
+    ks = (seg * n + np.arange(n))[by]
+    rank = np.searchsorted(ks, seg * n + np.arange(n))
+    assert np.array_equal(run_back[valid], (rank * 0.5)[valid])
+    assert sorted(run_back) == list(np.arange(n) * 0.5)
+
+
 def run_app(app, rows, batch_sizes=None, rng=None):
     m = SiddhiManager()
     rt = m.create_app_runtime(app)
@@ -745,6 +865,43 @@ WINDOW_STEP_RECORDS = {
 }
 
 
+WINDOW_RANKS_RECORDS = {
+    "length": ("#window.length(9) select avg(price) as a insert into O;",
+               (None, None)),
+    "time": ("#window.time(1 sec) select avg(price) as a insert into O;",
+             (None, None)),
+    "length_grouped": ("#window.length(9) select symbol, avg(price) as a "
+                       "group by symbol insert into O;",
+                       ("order", "bounded_search")),
+    "time_grouped": ("#window.time(1 sec) select symbol, max(price) as hi, "
+                     "count() as c group by symbol insert into O;",
+                     ("order", "bounded_search")),
+    "lengthBatch_grouped": ("#window.lengthBatch(9) select symbol, "
+                            "sum(price) as s group by symbol insert into O;",
+                            ("order", None)),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(WINDOW_RANKS_RECORDS))
+def test_window_ranks_record_says_how_a_group_bys_ranks_are_taken(kind):
+    """`window_ranks`, one more SIBLING of `window` (not a key of
+    `window_step`, whose three keys a benchmark cell's test holds as they
+    are): `segment_rank` "order" wherever the step groups ("search" no
+    longer occurs), `window_first` "bounded_search" where a grouped window
+    slides; None where nothing is grouped."""
+    query, (rank, first) = WINDOW_RANKS_RECORDS[kind]
+    d = Driven(query)
+    try:
+        want = {"segment_rank": rank, "window_first": first}
+        ent = d.rt.explain()["queries"]["q"]
+        assert ent["window_ranks"] == want
+        assert "segment_rank" not in ent["window_step"]
+        d.send([100.0, 101.0], np.array([5, 5], np.int32))
+        assert d.plan.device_metrics()["window_ranks"] == want
+    finally:
+        d.close()
+
+
 @pytest.mark.parametrize("kind", sorted(WINDOW_STEP_RECORDS))
 def test_window_step_record_says_the_form_of_each_indexed_pass(kind):
     """`window_step`, a SIBLING of `window` in `rt.explain()` and
@@ -1082,7 +1239,8 @@ _CLOCKS = {
 
 @pytest.mark.parametrize("case", list(_CLOCKS))
 def test_the_clocks_left_edge_on_32_bits_is_the_i64_search(case, monkeypatch):
-    """`_clock_left` at every valid batch position is
+    """`_clock_left`, asked for the batch's rows alone (positions `first`..,
+    the step's static C), is at every valid one of them
     `searchsorted(all_ts, all_ts - D, "right")`, whichever form the batch's
     span lets it take; and it takes the narrow one exactly while the oldest
     valid batch entry's edge lies within 2^31 - 1 ms of the newest."""
@@ -1096,11 +1254,13 @@ def test_the_clocks_left_edge_on_32_bits_is_the_i64_search(case, monkeypatch):
         return cond(pred, narrow, wide)
     monkeypatch.setattr(jax.lax, "cond", spy)
     left = np.asarray(wd._clock_left(jnp.asarray(ts), D, first, last))
-    want = np.searchsorted(ts, ts - D, side="right")
-    assert np.array_equal(left[first:last + 1], want[first:last + 1])
+    want = np.searchsorted(ts, ts - D, side="right")[first:]
+    live = max(last + 1 - first, 0)
+    assert left.shape == want.shape         # the batch's rows, no more
+    assert np.array_equal(left[:live], want[:live])
     assert took == ([] if D > 2 ** 31 - 1 else [form])
     monkeypatch.undo()
     # under jit, as the step traces it
-    jitted = jax.jit(lambda a, f, l: wd._clock_left(a, D, f, l))
-    left = np.asarray(jitted(jnp.asarray(ts), first, last))
-    assert np.array_equal(left[first:last + 1], want[first:last + 1])
+    jitted = jax.jit(lambda a, l: wd._clock_left(a, D, first, l))
+    left = np.asarray(jitted(jnp.asarray(ts), last))
+    assert np.array_equal(left[:live], want[:live])
